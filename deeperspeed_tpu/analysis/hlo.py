@@ -76,7 +76,8 @@ class ProgramSpec:
 # jaxpr walking
 
 _COLLECTIVE_AXIS_PARAMS = ("axis_name", "axes")
-_CALLBACK_MARKERS = ("callback", "outside_call", "host_call")
+# jax.debug.print lowers to a primitive named "debug_print" (jax >= 0.5)
+_CALLBACK_MARKERS = ("callback", "outside_call", "host_call", "debug_print")
 _PROMOTION_PRIMS = {"add", "sub", "mul", "div", "max", "min"}
 
 

@@ -329,6 +329,9 @@ def run_search(args, log=print):
 
 def main(argv=None):
     args = _parse_args(argv)
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     _reexec_if_needed(args.devices)
     report = run_search(args)
     best = report["best"]

@@ -20,6 +20,7 @@ from __future__ import annotations
 import argparse
 import base64
 import collections
+import glob
 import json
 import os
 import shutil
@@ -258,16 +259,22 @@ def encode_world_info(world_info):
 
 
 def _local_chip_count() -> int:
-    """Best-effort local accelerator count without initializing jax."""
+    """Local accelerator count, read WITHOUT importing jax: a chip belongs
+    to one process at a time, so a launcher parent that built a backend to
+    count chips would hold the very chips its training child needs.
+    Sources, in order: TPU_VISIBLE_CHIPS, then the TPU device nodes
+    (/dev/accel* or /dev/vfio/<n>). With neither, pass --num_chips."""
     visible = os.environ.get("TPU_VISIBLE_CHIPS")
     if visible:
         return len(visible.split(","))
-    try:
-        import jax
-
-        return jax.local_device_count()
-    except Exception:
-        return 1
+    for pattern in ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*"):
+        nodes = glob.glob(pattern)
+        if nodes:
+            return len(nodes)
+    raise RuntimeError(
+        "cannot count local chips without initialising jax (no "
+        "TPU_VISIBLE_CHIPS, no /dev/accel* or /dev/vfio/<n> nodes); "
+        "pass --num_chips N")
 
 
 def _build_launch_cmd(args, world_info_base64, node_rank=None):
@@ -292,7 +299,8 @@ def main(args=None):
     resource_pool = fetch_hostfile(args.hostfile)
     multi_node_exec = resource_pool is not None
     if resource_pool is None:
-        resource_pool = collections.OrderedDict(localhost=_local_chip_count())
+        n_local = args.num_chips if args.num_chips > 0 else _local_chip_count()
+        resource_pool = collections.OrderedDict(localhost=n_local)
         args.master_addr = "127.0.0.1"
 
     if not multi_node_exec and args.num_nodes > 1:

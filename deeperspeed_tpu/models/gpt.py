@@ -494,7 +494,12 @@ def make_gpt(cfg: GPTConfig, mesh=None):
         v = _shard_act(v, mesh, P(DATA_AXIS, SEQ_AXIS, MODEL_AXIS, None))
         if cp_attend is not None:
             return cp_attend(q, k, v), None
-        return causal_attention(q, k, v, impl=cfg.attn_impl), None
+        if mesh is None:  # an engine tracing this model names its mesh
+            return causal_attention(q, k, v, impl=cfg.attn_impl), None
+        from ..ops import kernel_config
+
+        with kernel_config.mesh_scope(mesh):
+            return causal_attention(q, k, v, impl=cfg.attn_impl), None
 
     moe_cfg = cfg.moe
 

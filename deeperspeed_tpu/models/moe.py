@@ -276,7 +276,7 @@ def _moe_ffn_dropless_ep(params, x, cfg: MoEConfig, act, mesh):
     dropped_frac); since one sender holds at most k*T_local assignments
     for any destination, ``ep_buffer_factor >= ep`` is mathematically
     dropless under arbitrary routing skew."""
-    from ..ops.ring_attention import _SHMAP_CHECK_KWARGS, shard_map
+    from jax import shard_map
     from ..parallel.topology import filter_spec
 
     B, S, D = x.shape
@@ -383,7 +383,7 @@ def _moe_ffn_dropless_ep(params, x, cfg: MoEConfig, act, mesh):
         in_specs=(tok_spec, P(None, None), exp(None, None), exp(None),
                   exp(None, None), exp(None)),
         out_specs=(tok_spec, P()),
-        **_SHMAP_CHECK_KWARGS,
+        check_vma=False,
     )(x.reshape(T, D),
       params["router"]["wg"],
       params["experts"]["wi"], params["experts"]["bi"],

@@ -6,7 +6,9 @@ This module gives them a spine:
 
   * one record schema — ``{metric, value, direction, platform, source,
     git_rev, wall_time, run}`` (run context from runctx) — appended as
-    JSON lines to ``PERF_LEDGER.jsonl``;
+    JSON lines to ``BENCH_LEDGER.jsonl`` (git-ignored; ``PERF_LEDGER.jsonl``
+    at the repo root belongs to the benchmark driver and is never read
+    or written here);
   * a tracked-metric table (:data:`METRIC_SPECS`) mapping each headline
     number in the BENCH corpus to its file, JSON path, direction
     (higher/lower-is-better), and per-metric tolerance;
@@ -50,7 +52,7 @@ __all__ = [
     "main",
 ]
 
-DEFAULT_LEDGER = "PERF_LEDGER.jsonl"
+DEFAULT_LEDGER = "BENCH_LEDGER.jsonl"
 DEFAULT_BASELINE_N = 5
 
 
@@ -164,9 +166,6 @@ METRIC_SPECS: Tuple[MetricSpec, ...] = (
     MetricSpec("elastic.max_loss_delta", "BENCH_elastic.json",
                ("max_loss_delta",), "lower", 0.0, 1e-6,
                note="world-size resharding must stay bit-identical"),
-    # hardware MFU (last real-TPU window)
-    MetricSpec("mfu.1p3b.micro_step_floor_tflops", "MFU_DECOMP.json",
-               ("1.3b", "micro_step_floor_tflops"), "higher", 0.10),
     # sharding substrate (PR 13): loss parity across layouts is an
     # exactness gate; step time per layout is wide-band (CPU-host noise)
     MetricSpec("mesh.parity.max_loss_delta", "BENCH_mesh.json",

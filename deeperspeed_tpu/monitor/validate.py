@@ -83,7 +83,7 @@ EVENT_ARG_SCHEMAS = {
     "comm/reduce": ("bucket", "mode"),
     "comm/overlap_window": ("buckets",),
     # perf doctor: compiled-cost captures, live per-step MFU, and the
-    # device-memory watermark lane — PERF_LEDGER tooling and the
+    # device-memory watermark lane — ledger tooling and the
     # roofline readout join on these
     "perf/compiled": ("entry", "flops", "bytes", "peak_hbm"),
     "perf/step": ("entry", "mfu", "wall_ms", "verdict"),

@@ -103,7 +103,7 @@ class FusedAdam:
         if self.use_pallas is False:
             return False, False, False
         if self.use_pallas is True:
-            interp = kernel_config.get().interpret or not kernel_config._on_tpu()
+            interp = kernel_config.get().interpret or not kernel_config.on_tpu()
             return True, interp, True
         use, interp = kernel_config.resolve("fused_adam")
         return use, interp, kernel_config.get().mode == "fused"

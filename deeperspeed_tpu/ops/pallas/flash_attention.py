@@ -35,14 +35,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu only resolves on TPU builds; interpret mode works anywhere
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from .. import kernel_config
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
@@ -50,19 +45,16 @@ NEG_INF = -1e30
 
 
 def _vmem_spec(block_shape=None, index_map=None):
-    kwargs = {}
-    if _VMEM is not None:
-        kwargs["memory_space"] = _VMEM
     if block_shape is None:
-        return pl.BlockSpec(**kwargs)
-    return pl.BlockSpec(block_shape, index_map, **kwargs)
+        return pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 def _compiler_params(interpret, n_parallel, semantics=None):
     """Grid dimension semantics for Mosaic pipelining: "parallel" dims may
     reorder, "arbitrary" ones run in order (accumulation dims). Default:
     all-parallel with n_parallel dims; pass an explicit tuple otherwise."""
-    if interpret or pltpu is None:
+    if interpret:
         return {}
     return {
         "compiler_params": pltpu.CompilerParams(
@@ -93,12 +85,7 @@ def _auto_block(S, default):
 
 def is_available(q) -> bool:
     """Cheap static gate used by models' attn_impl='auto'."""
-    try:
-        import jax as _jax
-
-        if _jax.devices()[0].platform != "tpu":
-            return False
-    except Exception:
+    if not kernel_config.on_tpu():
         return False
     B, S, H, Dh = q.shape
     if S < 128 or S % 8 or Dh % 8:
@@ -497,29 +484,45 @@ def attention_dispatch(shape, itemsize=2, causal=True, interpret=False,
 
     'xla' is advisory for model-level callers (flash_attention_bhsd itself
     never falls back — callers gate on is_available and friends)."""
-    from ..kernel_config import get as _kernels_config
     from .flash_static import (MAX_STATIC_SEQ, supertile_geometry_ok)
 
     B, H, S, Dh = shape
-    kc = _kernels_config()
+    kc = kernel_config.get()
     if mode is None:
         mode = kc.mode if kc.supertile else "off"
-    if platform is None:
-        try:
-            platform = jax.devices()[0].platform
-        except Exception:  # pragma: no cover
-            platform = "cpu"
-    on_tpu = platform == "tpu"
-    if mode == "fused" or (mode == "auto" and on_tpu):
+    tpu = kernel_config.on_tpu() if platform is None else platform == "tpu"
+    if mode == "fused" or (mode == "auto" and tpu):
         if supertile_geometry_ok(B, H, S, Dh, itemsize):
             return "supertile"
     if interpret:
         return "stream"  # CPU tests target the v1 streaming blocks
-    if not on_tpu:
+    if not tpu:
         return "xla"
     if S <= MAX_STATIC_SEQ and S >= 8 and S % 8 == 0 and Dh % 8 == 0:
         return "static"
     return "stream"
+
+
+def _mesh_spec_bhsd(mesh, B, H):
+    """(B, H, S, Dh) PartitionSpec over ``mesh``: batch over the data
+    axes, heads over the tensor-parallel axis, each only where it divides
+    (an axis that does not divide is left out: the operand is gathered
+    along it and every shard computes the whole of that dim)."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...sharding.rules import batch_axes, tp_axis
+
+    def fit(axes, n):
+        kept = []
+        for a in axes:
+            if mesh.shape[a] > 1 and n % mesh.shape[a] == 0:
+                kept.append(a)
+                n //= mesh.shape[a]
+        return tuple(kept) or None
+
+    tp = tp_axis(mesh)
+    return P(fit(batch_axes(mesh), B), fit((tp,) if tp else (), H),
+             None, None)
 
 
 def flash_attention_bhsd(
@@ -534,6 +537,11 @@ def flash_attention_bhsd(
 ):
     """Head-major entry point: q, k, v (B, H, S, Dh) -> (B, H, S, Dh).
 
+    Traced under a multi-device mesh (kernel_config.mesh_scope) the call
+    wraps itself in a ``shard_map`` over batch and heads: XLA cannot
+    partition a Mosaic kernel, and attention is independent per
+    (batch, head), so each shard runs the kernel on its own rows.
+
     This is the layout the kernels run in; callers that already hold
     head-major tensors avoid the boundary transposes.
 
@@ -545,10 +553,21 @@ def flash_attention_bhsd(
     kernel. interpret=True keeps v1 (CPU tests target its blocks) unless
     the kernels config forces the super-tile path."""
     B, H, S, Dh = q.shape
+    mesh = kernel_config.active_mesh()
+    if mesh is not None:
+        spec = _mesh_spec_bhsd(mesh, B, H)
+
+        def per_shard(q, k, v):
+            with kernel_config.mesh_scope(None):
+                return flash_attention_bhsd(
+                    q, k, v, causal=causal, sm_scale=sm_scale,
+                    block_q=block_q, block_k=block_k, interpret=interpret)
+
+        return jax.shard_map(per_shard, mesh=mesh, in_specs=(spec,) * 3,
+                             out_specs=spec, check_vma=False)(q, k, v)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(Dh)
     if block_q is None and block_k is None:
-        from ..kernel_config import resolve as _resolve_kernels
         from .flash_static import (flash_attention_static_bhsd,
                                    flash_attention_supertile_bhsd,
                                    is_static_available)
@@ -560,7 +579,7 @@ def flash_attention_bhsd(
             trace_instant("kernels/attention_dispatch", lane="kernels",
                           impl="supertile", shape=list(q.shape),
                           causal=causal)
-            st_interpret = interpret or _resolve_kernels("supertile")[1]
+            st_interpret = interpret or kernel_config.resolve("supertile")[1]
             return flash_attention_supertile_bhsd(
                 q, k, v, causal=causal, sm_scale=sm_scale,
                 interpret=st_interpret)

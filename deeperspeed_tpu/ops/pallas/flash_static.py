@@ -42,13 +42,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu only resolves on TPU builds; interpret mode works anywhere
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from .. import kernel_config
+from .flash_attention import _compiler_params
+from .flash_attention import _vmem_spec as _spec
 
 NEG_INF = -1e30
 # unroll budget: S=2048 at block 512 is 10 causal (16 full) block pairs in
@@ -56,25 +52,6 @@ NEG_INF = -1e30
 # S=4096 would be 36/180 and compile time starts to hurt
 MAX_STATIC_SEQ = 2048
 _BLOCK = 512
-
-
-def _spec(block_shape=None, index_map=None):
-    kwargs = {}
-    if _VMEM is not None:
-        kwargs["memory_space"] = _VMEM
-    if block_shape is None:
-        return pl.BlockSpec(**kwargs)
-    return pl.BlockSpec(block_shape, index_map, **kwargs)
-
-
-def _params(interpret, semantics):
-    if interpret or pltpu is None:
-        return {}
-    return {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=semantics
-        )
-    }
 
 
 def _block_of(S):
@@ -93,10 +70,7 @@ def is_static_available(q_bhsd) -> bool:
     """Gate for the auto dispatch: (B, H, S, Dh) head-major shape. The
     budget below is sized for the worst case (non-causal backward), so
     causality does not change the decision."""
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-    except Exception:
+    if not kernel_config.on_tpu():
         return False
     B, H, S, Dh = q_bhsd.shape
     if S > MAX_STATIC_SEQ or S < 8 or S % 8 or Dh % 8:
@@ -188,7 +162,7 @@ def _fwd(q, k, v, sm_scale, causal, interpret):
             jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32),
         ],
         interpret=interpret,
-        **_params(interpret, ("parallel", "parallel")),
+        **_compiler_params(interpret, 2),
     )(q, k, v)
     return o, lse
 
@@ -282,7 +256,7 @@ def _bwd(res, g, sm_scale, causal, interpret):
             jax.ShapeDtypeStruct((B, H, S, Dh), q.dtype),
         ],
         interpret=interpret,
-        **_params(interpret, ("parallel", "parallel")),
+        **_compiler_params(interpret, 2),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -382,10 +356,7 @@ def supertile_geometry_ok(B, H, S, Dh, itemsize=2) -> bool:
 
 
 def is_supertile_available(q_bhsd) -> bool:
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-    except Exception:
+    if not kernel_config.on_tpu():
         return False
     B, H, S, Dh = q_bhsd.shape
     itemsize = q_bhsd.dtype.itemsize if hasattr(q_bhsd.dtype, "itemsize") else 2
@@ -480,7 +451,7 @@ def _st_fwd(qg, kg, vg, sm_scale, causal, seq, interpret):
             jax.ShapeDtypeStruct((NG, 1, T), jnp.float32),
         ],
         interpret=interpret,
-        **_params(interpret, ("parallel",)),
+        **_compiler_params(interpret, 1),
     )(qg, kg, vg)
     return o, lse
 
@@ -523,7 +494,7 @@ def _st_vjp_bwd(sm_scale, causal, seq, interpret, res, g):
             jax.ShapeDtypeStruct((NG, T, Dh), qg.dtype),
         ],
         interpret=interpret,
-        **_params(interpret, ("parallel",)),
+        **_compiler_params(interpret, 1),
     )(qg, kg, vg, do, lse, delta)
     return dq, dk, dv
 

@@ -39,10 +39,8 @@ MIN_AUTO_SIZE = 16384
 
 
 def _smem_spec(shape):
-    kwargs = {}
-    if pltpu is not None:
-        kwargs["memory_space"] = pltpu.SMEM
-    return pl.BlockSpec(shape, lambda i: (0,) * len(shape), **kwargs)
+    return pl.BlockSpec(shape, lambda i: (0,) * len(shape),
+                        memory_space=pltpu.SMEM)
 
 
 def _leaf_2d(shape):

@@ -21,10 +21,12 @@ flash kernels' lse). Geometries with no suitable row block fall back to
 XLA under `auto` — correctness never depends on the kernel firing.
 
 Backwards are `jax.custom_vjp`s: dx is computed in a row-tiled kernel;
-the dw/db reductions over rows are emitted as per-block partials (one
-(1, D) row per grid step) and summed outside the kernel — a cross-block
-accumulation inside the kernel would force an "arbitrary" grid dimension
-and serialize the pipeline.
+the dw/db reductions over rows are emitted as per-block partials and
+summed outside the kernel — a cross-block accumulation inside the kernel
+would force an "arbitrary" grid dimension and serialize the pipeline.
+Each partial is written as an (8, D) tile (the row replicated over the 8
+sublanes): the TPU lowering refuses a (1, D) block over an (nb, D) array,
+whose second-to-last block dim must be a multiple of 8 or the full extent.
 """
 
 import functools
@@ -40,6 +42,25 @@ _SQRT_2_OVER_PI = 0.7978845608028654
 _GELU_C = 0.044715
 _INV_SQRT2 = 0.7071067811865476
 _INV_SQRT_2PI = 0.3989422804014327
+_SUBLANES = 8
+
+
+def _part_spec(D):
+    """Per-grid-step partial-sum tile of an (nb, 8, D) output."""
+    return _vmem_spec((1, _SUBLANES, D), lambda i: (i, 0, 0))
+
+
+def _part_shape(nb, D):
+    return jax.ShapeDtypeStruct((nb, _SUBLANES, D), jnp.float32)
+
+
+def _write_part(ref, row):
+    ref[0] = jnp.broadcast_to(row[None, :], ref.shape[1:])
+
+
+def _sum_parts(parts, dtype):
+    """(nb, 8, D) replicated partial tiles -> the (1, D) reduction."""
+    return jnp.sum(parts[:, 0, :], axis=0, keepdims=True).astype(dtype)
 
 
 def _row_block(R, D, lane128):
@@ -100,8 +121,8 @@ def _ln_bwd_kernel(x_ref, w_ref, mu_ref, rs_ref, g_ref,
     rs = rs_ref[0][:, None]
     dx, dwp, dbp = _ln_dx(x, g, w, mu, rs)
     dx_ref[...] = dx.astype(dx_ref.dtype)
-    dwp_ref[0] = dwp
-    dbp_ref[0] = dbp
+    _write_part(dwp_ref, dwp)
+    _write_part(dbp_ref, dbp)
 
 
 def _ln_fwd_call(x2, w2, b2, eps, block, interpret):
@@ -143,7 +164,7 @@ def _ln_vjp_bwd(eps, block, interpret, res, g):
     feat = _vmem_spec((1, D), lambda i: (0, 0))
     rows = _vmem_spec((block, D), lambda i: (i, 0))
     stat = _vmem_spec((1, block), lambda i: (0, i))
-    part = _vmem_spec((1, D), lambda i: (i, 0))
+    part = _part_spec(D)
     dx, dwp, dbp = pl.pallas_call(
         _ln_bwd_kernel,
         grid=(nb,),
@@ -151,14 +172,14 @@ def _ln_vjp_bwd(eps, block, interpret, res, g):
         out_specs=[rows, part, part],
         out_shape=[
             jax.ShapeDtypeStruct((R, D), x2.dtype),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
+            _part_shape(nb, D),
+            _part_shape(nb, D),
         ],
         interpret=interpret,
         **_compiler_params(interpret, 1),
     )(x2, w2, mu, rs, g)
-    dw = jnp.sum(dwp, axis=0, keepdims=True).astype(w2.dtype)
-    db = jnp.sum(dbp, axis=0, keepdims=True).astype(w2.dtype)
+    dw = _sum_parts(dwp, w2.dtype)
+    db = _sum_parts(dbp, w2.dtype)
     return dx, dw, db
 
 
@@ -190,8 +211,8 @@ def _aln_bwd_kernel(x_ref, r_ref, w_ref, mu_ref, rs_ref, g_ref,
     rs = rs_ref[0][:, None]
     ds, dwp, dbp = _ln_dx(s, g, w, mu, rs)
     ds_ref[...] = ds.astype(ds_ref.dtype)
-    dwp_ref[0] = dwp
-    dbp_ref[0] = dbp
+    _write_part(dwp_ref, dwp)
+    _write_part(dbp_ref, dbp)
 
 
 def _aln_fwd_call(x2, r2, w2, b2, eps, block, interpret):
@@ -232,7 +253,7 @@ def _aln_vjp_bwd(eps, block, interpret, res, g):
     feat = _vmem_spec((1, D), lambda i: (0, 0))
     rows = _vmem_spec((block, D), lambda i: (i, 0))
     stat = _vmem_spec((1, block), lambda i: (0, i))
-    part = _vmem_spec((1, D), lambda i: (i, 0))
+    part = _part_spec(D)
     ds, dwp, dbp = pl.pallas_call(
         _aln_bwd_kernel,
         grid=(nb,),
@@ -240,14 +261,14 @@ def _aln_vjp_bwd(eps, block, interpret, res, g):
         out_specs=[rows, part, part],
         out_shape=[
             jax.ShapeDtypeStruct((R, D), x2.dtype),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
+            _part_shape(nb, D),
+            _part_shape(nb, D),
         ],
         interpret=interpret,
         **_compiler_params(interpret, 1),
     )(x2, r2, w2, mu, rs, g)
-    dw = jnp.sum(dwp, axis=0, keepdims=True).astype(w2.dtype)
-    db = jnp.sum(dbp, axis=0, keepdims=True).astype(w2.dtype)
+    dw = _sum_parts(dwp, w2.dtype)
+    db = _sum_parts(dbp, w2.dtype)
     # d/dx and d/dresidual of LN(x + r) are the same cotangent
     return ds, ds, dw, db
 
@@ -260,11 +281,35 @@ _aln.defvjp(_aln_vjp_fwd, _aln_vjp_bwd)
 # ------------------------------------------------------------------ #
 
 
+# erf(x) ~= x * P(x^2) / Q(x^2) on [-4, 4] (the single-precision rational
+# fit XLA and Eigen use; within 4.2e-7 of float64 erf, i.e. fp32 rounding).
+# Written out because the Pallas TPU lowering has no rule for lax.erf.
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04,
+          -2.95459980854025e-03, -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
+
+
+def _horner(x, coeffs):
+    acc = jnp.full_like(x, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def _erf_f32(x):
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    return x * _horner(x2, _ERF_P) / _horner(x2, _ERF_Q)
+
+
 def _gelu_fwd_f32(u, approximate):
     if approximate:
         inner = _SQRT_2_OVER_PI * (u + _GELU_C * u * u * u)
         return 0.5 * u * (1.0 + jnp.tanh(inner))
-    return 0.5 * u * (1.0 + jax.lax.erf(u * _INV_SQRT2))
+    return 0.5 * u * (1.0 + _erf_f32(u * _INV_SQRT2))
 
 
 def _gelu_grad_f32(u, approximate):
@@ -273,7 +318,7 @@ def _gelu_grad_f32(u, approximate):
         t = jnp.tanh(inner)
         dinner = _SQRT_2_OVER_PI * (1.0 + 3.0 * _GELU_C * u * u)
         return 0.5 * (1.0 + t) + 0.5 * u * (1.0 - t * t) * dinner
-    phi = 0.5 * (1.0 + jax.lax.erf(u * _INV_SQRT2))
+    phi = 0.5 * (1.0 + _erf_f32(u * _INV_SQRT2))
     return phi + u * jnp.exp(-0.5 * u * u) * _INV_SQRT_2PI
 
 
@@ -286,7 +331,7 @@ def _bg_bwd_kernel(x_ref, b_ref, g_ref, dx_ref, dbp_ref, *, approximate):
     u = x_ref[...].astype(jnp.float32) + b_ref[0].astype(jnp.float32)
     dx = g_ref[...].astype(jnp.float32) * _gelu_grad_f32(u, approximate)
     dx_ref[...] = dx.astype(dx_ref.dtype)
-    dbp_ref[0] = jnp.sum(dx, axis=0)
+    _write_part(dbp_ref, jnp.sum(dx, axis=0))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
@@ -315,7 +360,7 @@ def _bg_vjp_bwd(approximate, block, interpret, res, g):
     nb = R // block
     feat = _vmem_spec((1, D), lambda i: (0, 0))
     rows = _vmem_spec((block, D), lambda i: (i, 0))
-    part = _vmem_spec((1, D), lambda i: (i, 0))
+    part = _part_spec(D)
     dx, dbp = pl.pallas_call(
         functools.partial(_bg_bwd_kernel, approximate=approximate),
         grid=(nb,),
@@ -323,12 +368,12 @@ def _bg_vjp_bwd(approximate, block, interpret, res, g):
         out_specs=[rows, part],
         out_shape=[
             jax.ShapeDtypeStruct((R, D), x2.dtype),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
+            _part_shape(nb, D),
         ],
         interpret=interpret,
         **_compiler_params(interpret, 1),
     )(x2, b2, g)
-    db = jnp.sum(dbp, axis=0, keepdims=True).astype(b2.dtype)
+    db = _sum_parts(dbp, b2.dtype)
     return dx, db
 
 

@@ -79,13 +79,29 @@ def supports(block: int) -> bool:
     return block >= 128 and block % 128 == 0
 
 
+_SUBLANES = 8
+
+
+def _pad_rows(x, axis: int):
+    """Zero-pad the tiled row axis to a multiple of 8 sublanes: the TPU
+    lowering refuses a block whose second-to-last dim is neither a
+    multiple of 8 nor the full extent, and the row counts here (world
+    size x blocks per chunk) are arbitrary. Zero rows quantize to zeros
+    and dequantize to zeros; callers slice them off."""
+    pad = -x.shape[axis] % _SUBLANES
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
+
+
 def _tile_rows(n_rows: int) -> int:
-    """Largest divisor of ``n_rows`` <= _MAX_TILE_ROWS, preferring
-    sublane multiples of 8 so f32 tiles land on (8, 128) boundaries."""
+    """Largest multiple-of-8 divisor of ``n_rows`` (itself a multiple of
+    8, see :func:`_pad_rows`) that is <= _MAX_TILE_ROWS."""
     cap = min(n_rows, _MAX_TILE_ROWS)
-    divs = [d for d in range(1, cap + 1) if n_rows % d == 0]
-    mult8 = [d for d in divs if d % 8 == 0]
-    return max(mult8 or divs)
+    return max(d for d in range(_SUBLANES, cap + 1, _SUBLANES)
+               if n_rows % d == 0)
 
 
 def _use_pallas(choice: str, interpret: bool, block: int) -> bool:
@@ -140,29 +156,30 @@ def quantize_rows(x, block, *, want_residual=True, choice="xla",
     if not _use_pallas(choice, interpret, block):
         return _quantize_rows_xla(x, block, want_residual)
     NB = R * nb
-    br = _tile_rows(NB)
-    x2 = x.astype(jnp.float32).reshape(NB, block)
+    x2 = _pad_rows(x.astype(jnp.float32).reshape(NB, block), 0)
+    NBp = x2.shape[0]
+    br = _tile_rows(NBp)
     spec = _vmem_spec((br, block), lambda i: (i, 0))
     sspec = _vmem_spec((br, 1), lambda i: (i, 0))
-    outs = [jax.ShapeDtypeStruct((NB, block), jnp.int8),
-            jax.ShapeDtypeStruct((NB, 1), jnp.float32)]
+    outs = [jax.ShapeDtypeStruct((NBp, block), jnp.int8),
+            jax.ShapeDtypeStruct((NBp, 1), jnp.float32)]
     out_specs = [spec, sspec]
     kernel = _quant_kernel
     if want_residual:
         kernel = _quant_residual_kernel
-        outs.append(jax.ShapeDtypeStruct((NB, block), jnp.float32))
+        outs.append(jax.ShapeDtypeStruct((NBp, block), jnp.float32))
         out_specs.append(spec)
     got = pl.pallas_call(
         kernel,
-        grid=(NB // br,),
+        grid=(NBp // br,),
         in_specs=[spec],
         out_specs=out_specs,
         out_shape=outs,
         interpret=interpret,
         **_compiler_params(interpret, 1),
     )(x2)
-    q, s = got[0].reshape(R, C), got[1].reshape(R, nb)
-    r = got[2].reshape(R, C) if want_residual else None
+    q, s = got[0][:NB].reshape(R, C), got[1][:NB].reshape(R, nb)
+    r = got[2][:NB].reshape(R, C) if want_residual else None
     return q, s, r
 
 
@@ -173,8 +190,8 @@ def quantize_rows(x, block, *, want_residual=True, choice="xla",
 
 def _dequant_sum_kernel(q_ref, s_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)  # (R, bn, block)
-    s = s_ref[...].astype(jnp.float32)  # (R, bn)
-    o_ref[...] = jnp.sum(q * s[:, :, None], axis=0)
+    s = s_ref[...].astype(jnp.float32)  # (R, bn, 1): one scale per sublane
+    o_ref[...] = jnp.sum(q * s, axis=0)
 
 
 def dequant_sum_rows(q, s, block, *, choice="xla", interpret=False):
@@ -189,18 +206,21 @@ def dequant_sum_rows(q, s, block, *, choice="xla", interpret=False):
     if not _use_pallas(choice, interpret, block):
         vals = q.astype(jnp.float32).reshape(R, nb, block) * s[:, :, None]
         return jnp.sum(vals, axis=0).reshape(-1)
-    bn = _tile_rows(nb)
+    q3 = _pad_rows(q.reshape(R, nb, block), 1)
+    s3 = _pad_rows(s.reshape(R, nb, 1), 1)
+    nbp = q3.shape[1]
+    bn = _tile_rows(nbp)
     out = pl.pallas_call(
         _dequant_sum_kernel,
-        grid=(nb // bn,),
+        grid=(nbp // bn,),
         in_specs=[_vmem_spec((R, bn, block), lambda j: (0, j, 0)),
-                  _vmem_spec((R, bn), lambda j: (0, j))],
+                  _vmem_spec((R, bn, 1), lambda j: (0, j, 0))],
         out_specs=_vmem_spec((bn, block), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nbp, block), jnp.float32),
         interpret=interpret,
         **_compiler_params(interpret, 1),
-    )(q.reshape(R, nb, block), s)
-    return out.reshape(-1)
+    )(q3, s3)
+    return out[:nb].reshape(-1)
 
 
 # --------------------------------------------------------------------------
@@ -210,8 +230,8 @@ def dequant_sum_rows(q, s, block, *, choice="xla", interpret=False):
 
 def _dequant_kernel(q_ref, s_ref, o_ref, *, divisor):
     q = q_ref[...].astype(jnp.float32)  # (1, bn, block)
-    s = s_ref[...].astype(jnp.float32)  # (1, bn)
-    o_ref[...] = q * s[:, :, None] / divisor
+    s = s_ref[...].astype(jnp.float32)  # (1, bn, 1)
+    o_ref[...] = q * s / divisor
 
 
 def dequant_rows(q, s, block, *, divisor=1.0, choice="xla",
@@ -223,18 +243,21 @@ def dequant_rows(q, s, block, *, divisor=1.0, choice="xla",
     if not _use_pallas(choice, interpret, block):
         vals = q.astype(jnp.float32).reshape(R, nb, block) * s[:, :, None]
         return (vals / divisor).reshape(R, C)
-    bn = _tile_rows(nb)
+    q3 = _pad_rows(q.reshape(R, nb, block), 1)
+    s3 = _pad_rows(s.reshape(R, nb, 1), 1)
+    nbp = q3.shape[1]
+    bn = _tile_rows(nbp)
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, divisor=float(divisor)),
-        grid=(R, nb // bn),
+        grid=(R, nbp // bn),
         in_specs=[_vmem_spec((1, bn, block), lambda i, j: (i, j, 0)),
-                  _vmem_spec((1, bn), lambda i, j: (i, j))],
+                  _vmem_spec((1, bn, 1), lambda i, j: (i, j, 0))],
         out_specs=_vmem_spec((1, bn, block), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, nb, block), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R, nbp, block), jnp.float32),
         interpret=interpret,
         **_compiler_params(interpret, 2),
-    )(q.reshape(R, nb, block), s)
-    return out.reshape(R, C)
+    )(q3, s3)
+    return out[:, :nb].reshape(R, C)
 
 
 # --------------------------------------------------------------------------

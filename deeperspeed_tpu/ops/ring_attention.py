@@ -30,14 +30,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-
-    _SHMAP_CHECK_KWARGS = {"check_vma": False}
-except ImportError:  # older jax: different module AND different kwarg name
-    from jax.experimental.shard_map import shard_map
-
-    _SHMAP_CHECK_KWARGS = {"check_rep": False}
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..parallel.topology import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
@@ -187,7 +180,7 @@ def make_context_parallel_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_SHMAP_CHECK_KWARGS,
+        check_vma=False,
     )
     def attend(q, k, v):
         return inner(q, k, v, axis_name=resolved_seq, causal=causal)

@@ -22,13 +22,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..pallas.flash_attention import _compiler_params, _vmem_spec
-
-try:  # pltpu also imports on CPU jax builds; interpret mode works anywhere
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
 
 NEG_INF = -1e30
 
@@ -42,11 +38,6 @@ def _lut_pallas_call(kernel, grid, in_specs, out_specs, out_shape,
     Mosaic) instead of pinning full-sequence tensors in VMEM — the TPU idiom
     replacing the triton kernels' LUT pointer arguments, with no VMEM cap on
     sequence length."""
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "Pallas TPU namespace unavailable; use the XLA fallback "
-            "(block_sparse_attention_xla)"
-        )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
@@ -64,8 +55,6 @@ def _lut_pallas_call(kernel, grid, in_specs, out_specs, out_shape,
 
 
 def _scratch(shape):
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("Pallas TPU namespace unavailable")
     return pltpu.VMEM(shape, jnp.float32)
 
 
@@ -934,11 +923,6 @@ def _bs_bwd_dkdv_kernel_res(wins_ref, bitmaps_ref, counts_ref, nfull_ref,
 
 def _res_pallas_call(kernel, grid, in_specs, out_specs, out_shape,
                      interpret, n_prefetch=4):
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "Pallas TPU namespace unavailable; use the XLA fallback "
-            "(block_sparse_attention_xla)"
-        )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
         grid=grid,

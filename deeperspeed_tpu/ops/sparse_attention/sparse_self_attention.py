@@ -14,6 +14,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from .. import kernel_config
 from .kernels import (
     block_sparse_attention_xla,
     make_block_sparse_attention,
@@ -22,12 +23,11 @@ from .sparsity_config import SparsityConfig
 
 
 def _pallas_ok(block: int, Dh: int) -> bool:
-    try:
-        import jax
-
-        if jax.devices()[0].platform != "tpu":
-            return False
-    except Exception:  # pragma: no cover
+    if not kernel_config.on_tpu():
+        return False
+    # the block-sparse kernels have no shard_map wrapper, and XLA cannot
+    # partition a Mosaic kernel: under a multi-device mesh 'auto' is XLA
+    if kernel_config.active_mesh() is not None:
         return False
     # Mosaic lane rule: the lse/delta outputs carry (1, 1, block) tiles, so
     # the sparsity block must be a lane multiple (128) on hardware — %8

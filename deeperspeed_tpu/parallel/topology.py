@@ -214,8 +214,8 @@ def build_mesh(
     Axis order follows the dict order (put the axis with the heaviest
     communication last so it lands on the innermost ICI ring). Dims of -1 are
     inferred from the device count. Uses `mesh_utils.create_device_mesh` for
-    ICI-topology-aware device ordering on real TPU slices, falling back to a
-    simple reshape on CPU meshes.
+    ICI-topology-aware device ordering on real TPU slices (on CPU meshes it
+    is a plain reshape). A topology it cannot arrange raises.
     """
     import jax
 
@@ -238,16 +238,13 @@ def build_mesh(
         )
 
     shape = tuple(dims.values())
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
-        mesh_devices = mesh_utils.create_device_mesh(
-            shape,
-            devices=devices,
-            allow_split_physical_axes=allow_split_physical_axes,
-        )
-    except Exception:
-        mesh_devices = np.asarray(devices).reshape(shape)
+    mesh_devices = mesh_utils.create_device_mesh(
+        shape,
+        devices=devices,
+        allow_split_physical_axes=allow_split_physical_axes,
+    )
     # all Mesh objects are constructed through the sharding factory (lazy
     # import: sharding.mesh.from_config calls back into build_mesh)
     from ..sharding.mesh import make_mesh

@@ -23,10 +23,10 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ...ops.ring_attention import _SHMAP_CHECK_KWARGS, shard_map
 from ...parallel.topology import DATA_AXIS
 from .compressed import _pack_signs, _unpack_signs
 
@@ -151,7 +151,7 @@ def make_onebit_spmd_train_step(loss_fn, optimizer, mesh,
         body, mesh=mesh,
         in_specs=(rep, rep, rep, sh, sh, P(data_axis), rep, rep),
         out_specs=(rep, rep, rep, sh, sh, rep),
-        **_SHMAP_CHECK_KWARGS,
+        check_vma=False,
     )
 
     @jax.jit
@@ -258,7 +258,7 @@ def make_onebit_lamb_spmd_train_step(loss_fn, optimizer, mesh,
         body, mesh=mesh,
         in_specs=(rep, rep, rep, rep, sh, sh, P(data_axis), rep),
         out_specs=(rep, rep, rep, rep, sh, sh, rep),
-        **_SHMAP_CHECK_KWARGS,
+        check_vma=False,
     )
 
     @jax.jit

@@ -47,16 +47,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-
-    _SHMAP_CHECK_KWARGS = {"check_vma": False}
-except ImportError:  # older jax: different module AND different kwarg name
-    from jax.experimental.shard_map import shard_map
-
-    _SHMAP_CHECK_KWARGS = {"check_rep": False}
 
 from ...monitor import trace_span
 from ...ops.pallas import fused_quant
@@ -119,7 +111,7 @@ def exact_slot_mean(tree, mesh, axis, canonical):
 
     fn = shard_map(body, mesh=mesh, in_specs=in_specs,
                    out_specs=tuple(P() for _ in leaves),
-                   **_SHMAP_CHECK_KWARGS)
+                   check_vma=False)
     pinned = [jax.lax.with_sharding_constraint(l, s)
               for l, s in zip(leaves, slot_sh)]
     return jax.tree.unflatten(treedef, list(fn(*pinned)))
@@ -644,7 +636,7 @@ class GradReducer:
                     in_specs=([self._leaf_spec(s) for s in b.shapes],
                               res_spec),
                     out_specs=([P() for _ in b.shapes], res_spec),
-                    **_SHMAP_CHECK_KWARGS)
+                    check_vma=False)
                 bucket_out, nr = fn([leaves[i] for i in b.leaf_ids],
                                     state[j])
                 for i, leaf in zip(b.leaf_ids, bucket_out):
@@ -668,7 +660,7 @@ class GradReducer:
         out_specs = ([P() for _ in leaves],
                      jax.tree.map(lambda _: P(self.axis, None), state))
         fn = shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                       out_specs=out_specs, **_SHMAP_CHECK_KWARGS)
+                       out_specs=out_specs, check_vma=False)
         outs, new_state = fn(leaves, state)
         return jax.tree.unflatten(treedef, outs), new_state
 
@@ -749,7 +741,7 @@ class GradReducer:
             fn = shard_map(bucket_body, mesh=self.mesh,
                            in_specs=(P(self.axis, None), res_spec),
                            out_specs=(P(), res_spec),
-                           **_SHMAP_CHECK_KWARGS)
+                           check_vma=False)
             red, nr = fn(flat, rb)
             for i, leaf in zip(b.leaf_ids, bucketing.unpack(b, red)):
                 outs[i] = leaf
@@ -787,7 +779,7 @@ class GradReducer:
             fn = jax.jit(shard_map(self._bucket_body(j), mesh=self.mesh,
                                    in_specs=in_specs,
                                    out_specs=out_specs,
-                                   **_SHMAP_CHECK_KWARGS))
+                                   check_vma=False))
             self._jit_cache[key] = fn
         return fn
 

@@ -45,6 +45,7 @@ from ..checkpoint.serialization import (
     validate_tag_across_processes,
     write_latest,
 )
+from ..ops import kernel_config
 from ..ops.adam import DeepSpeedCPUAdam, FusedAdam
 from ..ops.lamb import FusedLamb
 from ..ops.sgd import SGD
@@ -235,9 +236,7 @@ class Engine(ConfigAccessorsMixin):
         # are free functions deep inside model code; must land before
         # _configure_basic_optimizer so FusedAdam sees the mode.
         if getattr(config, "kernels_params", None):
-            from ..ops.kernel_config import configure as _configure_kernels
-
-            _configure_kernels(**config.kernels_params)
+            kernel_config.configure(**config.kernels_params)
 
         # resilience (resilience/ package): a "resilience" config block
         # installs the process-global manager (async two-phase-commit
@@ -679,11 +678,14 @@ class Engine(ConfigAccessorsMixin):
         if self._pld_active():
             batch, theta = batch
             kwargs["pld_theta"] = theta
-        out = (
-            self.loss_fn(params, batch, rng, **kwargs)
-            if self._takes_rng
-            else self.loss_fn(params, batch, **kwargs)
-        )
+        # the model's Pallas kernels need to know the mesh they are
+        # traced under (XLA cannot partition a Mosaic kernel)
+        with kernel_config.mesh_scope(self.mesh):
+            out = (
+                self.loss_fn(params, batch, rng, **kwargs)
+                if self._takes_rng
+                else self.loss_fn(params, batch, **kwargs)
+            )
         loss, aux = out if isinstance(out, tuple) else (out, None)
         return (loss.astype(jnp.float32) * scale), loss
 
@@ -812,7 +814,7 @@ class Engine(ConfigAccessorsMixin):
         ``(world, *shape)`` (sharded ``P(data)``); averaging the stack
         over the axis reproduces the global-mean-gradient semantics of
         :meth:`_batch_grads`. Returns (global mean loss, stacked grads)."""
-        from .comm.reducer import _SHMAP_CHECK_KWARGS, shard_map
+        from jax import shard_map
 
         scale = state.scaler.loss_scale
         theta = None
@@ -823,7 +825,9 @@ class Engine(ConfigAccessorsMixin):
             def one(mb, key):
                 if theta is not None:
                     mb = (mb, theta)
-                return self._micro_grads(params, mb, key, scale_)
+                # this body is one shard: kernels run on local rows
+                with kernel_config.mesh_scope(None):
+                    return self._micro_grads(params, mb, key, scale_)
 
             if gas == 1:
                 loss, grads = one(batch_, rng_)
@@ -867,7 +871,7 @@ class Engine(ConfigAccessorsMixin):
         )
         out_specs = (P(), jax.tree.map(lambda _: dspec, state.params))
         fn = shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                       out_specs=out_specs, **_SHMAP_CHECK_KWARGS)
+                       out_specs=out_specs, check_vma=False)
         return fn(state.params, scale, batch, rng)
 
     def _batch_grads_canonical(self, state, batch, rng, C):
@@ -1206,14 +1210,17 @@ class Engine(ConfigAccessorsMixin):
         # with the fused Pallas Adam active, the fp32->compute-dtype
         # master-weight cast rides inside the optimizer kernel (one HBM
         # pass) instead of a separate full-param cast here
-        fused_cast = (self._use_master
-                      and getattr(opt, "pallas_active", lambda: False)())
-        if fused_cast:
-            new_target, new_opt, new_cast = opt.update(
-                grads, state.opt_state, target, lr,
-                cast_dtype=self._compute_dtype)
-        else:
-            new_target, new_opt = opt.update(grads, state.opt_state, target, lr)
+        # (the optimizer's Pallas route needs the mesh it is traced under)
+        with kernel_config.mesh_scope(self.mesh):
+            fused_cast = (self._use_master
+                          and getattr(opt, "pallas_active", lambda: False)())
+            if fused_cast:
+                new_target, new_opt, new_cast = opt.update(
+                    grads, state.opt_state, target, lr,
+                    cast_dtype=self._compute_dtype)
+            else:
+                new_target, new_opt = opt.update(
+                    grads, state.opt_state, target, lr)
         keep = lambda new, old: jax.tree.map(
             lambda n, o: jnp.where(overflow, o, n), new, old
         )

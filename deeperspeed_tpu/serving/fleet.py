@@ -354,7 +354,6 @@ class SubprocessReplica:
         with open(spec_path, "w") as f:
             json.dump(self._spec, f)
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         # run-scoped observability: the child's trace lane is labeled by
         # role + incarnation, correlated to ours by the shared run id
         env[RUN_ID_ENV] = ensure_run_id()

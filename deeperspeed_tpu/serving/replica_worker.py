@@ -33,16 +33,11 @@ watchdogs must distinguish.
 
 import argparse
 import json
-import os
 import queue
 import sys
 import threading
 import time
 from typing import Optional, Sequence
-
-# the worker always serves on the host platform unless told otherwise —
-# replicas are CPU-testable by design (same rationale as serving_bench)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 WARM_RID = "_warm"   # internal warmup request, never reported
 
@@ -234,6 +229,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     args = ap.parse_args(argv)
     with open(args.spec) as f:
         spec = json.load(f)
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     return serve(spec)
 
 

@@ -442,7 +442,7 @@ def main():
     if args.slo or args.shared_prefix or args.speculative:
         # offline attribution over the trace just written: where every
         # request's TTFT went, who blocked whom, and what a kilotoken
-        # costs — the keys PERF_LEDGER gates (serving.ttft_p99_ms,
+        # costs — the keys monitor/ledger.py gates (serving.ttft_p99_ms,
         # serving.cost_per_1k_tokens)
         from deeperspeed_tpu.monitor.reqledger import build_ledger
 

@@ -209,7 +209,7 @@ def test_host_callback_flagged_in_hot_path():
 
 
 def test_collective_axis_checked_against_mesh():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from deeperspeed_tpu.sharding.mesh import make_mesh

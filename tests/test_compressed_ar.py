@@ -19,12 +19,7 @@ from deeperspeed_tpu.runtime.comm.compressed import (
     reconstruct,
 )
 
-try:
-    shard_map = partial(jax.shard_map, check_vma=False)
-except AttributeError:  # older jax: experimental location, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shmap
-
-    shard_map = partial(_shmap, check_rep=False)
+shard_map = partial(jax.shard_map, check_vma=False)
 
 
 def _mesh():

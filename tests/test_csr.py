@@ -8,14 +8,7 @@ from functools import partial
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-
-    shard_map = partial(_shard_map, check_vma=False)
-except ImportError:  # older jax: different module AND different kwarg name
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    shard_map = partial(_shard_map, check_rep=False)
+shard_map = partial(jax.shard_map, check_vma=False)
 
 from deeperspeed_tpu.runtime.csr_tensor import (
     CSRTensor,

@@ -150,3 +150,39 @@ def test_auto_block_is_lane_legal():
             b = _auto_block(S, default)
             assert b % 128 == 0 or b == S, (S, default, b)
             assert b <= S, (S, default, b)
+
+
+def test_flash_under_a_mesh_scope_shards_batch_and_heads():
+    """Traced under a multi-device mesh, flash attention wraps itself in a
+    shard_map over batch and heads (XLA cannot partition a Mosaic kernel);
+    the result and the gradient are those of the unwrapped call."""
+    from jax.sharding import PartitionSpec as P
+
+    from deeperspeed_tpu.ops import kernel_config
+    from deeperspeed_tpu.ops.pallas.flash_attention import (
+        _mesh_spec_bhsd, flash_attention_bhsd)
+    from deeperspeed_tpu.parallel import build_mesh
+
+    mesh = build_mesh({"data": 4, "model": 2})
+    assert _mesh_spec_bhsd(mesh, 8, 4) == P(("data",), ("model",), None, None)
+    # an axis that does not divide is left out (gathered), never an error
+    assert _mesh_spec_bhsd(mesh, 2, 3) == P(None, None, None, None)
+
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (8, 4, 128, 32),
+                                 jnp.float32) for i in range(3))
+
+    def loss(q, k, v):
+        return (flash_attention_bhsd(q, k, v, causal=True,
+                                     interpret=True) ** 2).sum()
+
+    ref, ref_g = jax.value_and_grad(loss)(q, k, v)
+
+    def scoped(q, k, v):
+        with kernel_config.mesh_scope(mesh):
+            return loss(q, k, v)
+
+    jaxpr = str(jax.make_jaxpr(scoped)(q, k, v))
+    assert "shard_map" in jaxpr
+    got, got_g = jax.jit(jax.value_and_grad(scoped))(q, k, v)
+    np.testing.assert_allclose(got, ref, rtol=1e-5)
+    np.testing.assert_allclose(got_g, ref_g, rtol=1e-4, atol=1e-5)

@@ -190,7 +190,11 @@ def test_routing_follows_kernel_config():
 def test_supports_gate_and_tiling():
     assert fq.supports(128) and fq.supports(256)
     assert not fq.supports(8) and not fq.supports(130)
+    # row axes are zero-padded to 8 sublanes before tiling (the TPU
+    # lowering refuses blocks of 13 or 65 rows): 13 -> 16, 260 -> 264
+    assert fq._pad_rows(jnp.ones((13, 128)), 0).shape == (16, 128)
+    assert fq._pad_rows(jnp.ones((2, 260, 1)), 1).shape == (2, 264, 1)
     assert fq._tile_rows(104) == 104  # fits one tile, multiple of 8
-    assert fq._tile_rows(13) == 13    # no multiple of 8 divides 13
+    assert fq._tile_rows(16) == 16
     assert fq._tile_rows(1024) == 128
-    assert fq._tile_rows(260) == 65   # largest divisor under the cap
+    assert fq._tile_rows(264) == 88   # largest multiple-of-8 divisor

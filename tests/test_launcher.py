@@ -364,3 +364,47 @@ def test_aml_env_discovery(monkeypatch):
                 os.environ.pop(v, None)
             else:
                 os.environ[v] = old
+
+
+class TestLauncherParentStaysOffTheChip:
+    """A chip belongs to one process: the launcher parent must count chips
+    and spawn its children without ever building a JAX backend."""
+
+    def test_chip_count_sources(self, monkeypatch):
+        from deeperspeed_tpu.launcher import runner
+
+        monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0,1,2,3")
+        assert runner._local_chip_count() == 4
+        monkeypatch.delenv("TPU_VISIBLE_CHIPS")
+        monkeypatch.setattr(
+            runner.glob, "glob",
+            lambda pat: ["/dev/vfio/0", "/dev/vfio/1"] if "vfio" in pat else [])
+        assert runner._local_chip_count() == 2
+        monkeypatch.setattr(runner.glob, "glob", lambda pat: [])
+        with pytest.raises(RuntimeError, match="--num_chips"):
+            runner._local_chip_count()
+
+    def test_single_node_launch_builds_no_backend(self, tmp_path):
+        code = (
+            "import subprocess, sys\n"
+            "from deeperspeed_tpu.launcher import runner\n"
+            "class Done:\n"
+            "    returncode = 0\n"
+            "    def __init__(self, cmd, env=None):\n"
+            "        print('CMD', ' '.join(cmd))\n"
+            "    def wait(self):\n"
+            "        return 0\n"
+            "subprocess.Popen = Done\n"
+            f"runner.main(['--hostfile', r'{tmp_path}/none', '--num_chips',"
+            " '2', 'train.py'])\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "print('PARENT OFF THE CHIP')\n"
+        )
+        proc = subprocess.run([sys.executable, "-c", code],
+                              capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr
+        assert "PARENT OFF THE CHIP" in proc.stdout
+        world = [t for t in proc.stdout.split() if t.startswith("--world_info")]
+        info = json.loads(base64.urlsafe_b64decode(world[0].split("=", 1)[1]))
+        assert info == {"localhost": [0, 1]}
