@@ -174,19 +174,20 @@ def make_bert(cfg: BertConfig, mesh=None):
                  rng=None):
         cdt = cfg.dtype
         B, S = input_ids.shape
-        e = params["embed"]
-        x = jnp.take(e["word"].astype(cdt), input_ids, axis=0)
-        x = x + e["pos"][:S].astype(cdt)
-        if token_type_ids is None:
-            token_type_ids = jnp.zeros_like(input_ids)
-        x = x + jnp.take(e["type"].astype(cdt), token_type_ids, axis=0)
-        x = _layer_norm(x, e["ln_w"].astype(cdt), e["ln_b"].astype(cdt),
-                        cfg.layernorm_eps)
-        # context-parallel long sequences: activations sharded over the
-        # 'seq' axis (as in make_gpt)
-        from jax.sharding import PartitionSpec as P
+        with jax.named_scope("ds.embed"):
+            e = params["embed"]
+            x = jnp.take(e["word"].astype(cdt), input_ids, axis=0)
+            x = x + e["pos"][:S].astype(cdt)
+            if token_type_ids is None:
+                token_type_ids = jnp.zeros_like(input_ids)
+            x = x + jnp.take(e["type"].astype(cdt), token_type_ids, axis=0)
+            x = _layer_norm(x, e["ln_w"].astype(cdt), e["ln_b"].astype(cdt),
+                            cfg.layernorm_eps)
+            # context-parallel long sequences: activations sharded over the
+            # 'seq' axis (as in make_gpt)
+            from jax.sharding import PartitionSpec as P
 
-        x = _shard_act(x, mesh, P(DATA_AXIS, SEQ_AXIS, None))
+            x = _shard_act(x, mesh, P(DATA_AXIS, SEQ_AXIS, None))
 
         additive = None
         if attention_mask is not None:
@@ -256,43 +257,44 @@ def make_bert(cfg: BertConfig, mesh=None):
         attention_mask = batch[2] if len(batch) > 2 else None
         seq_out, _ = apply_fn(params, input_ids, attention_mask=attention_mask,
                               rng=rng)
-        B, S, D = seq_out.shape
-        if cfg.mlm_gather_frac:
-            # run the vocab-width head only on scored positions: stable
-            # argsort orders scored rows first, the head consumes a
-            # lane-aligned prefix (see mlm_gather_frac docstring for the
-            # upper-bound contract)
-            BS = B * S
-            K = min(BS, int(math.ceil(cfg.mlm_gather_frac * BS / 128)) * 128)
-            flat_lab = labels.reshape(BS)
-            n_scored = jnp.sum(flat_lab != -100)
-            order = jnp.argsort(flat_lab == -100, stable=True)[:K]
-            seq_out = seq_out.reshape(BS, D)[order][None]
-            labels = flat_lab[order][None]
-            # overflow telemetry (MoE dropped_frac analog): positions past
-            # the cut are silently unscored, so surface the count to layer-
-            # output collectors instead of hiding it
-            hooks.record_layer_output(
-                "mlm_dropped", jnp.maximum(n_scored - K, 0))
-            B, S = 1, K
-        chunk = pick_ce_chunk(S, cfg.ce_chunk)
-        if chunk and S > chunk:
-            n = S // chunk
-            xs = jnp.moveaxis(seq_out.reshape(B, n, chunk, D), 1, 0)
-            ls = jnp.moveaxis(labels.reshape(B, n, chunk), 1, 0)
-            ck = jax.checkpoint(lambda xc, lc: _chunk_nll(params, xc, lc))
+        with jax.named_scope("ds.loss"):
+            B, S, D = seq_out.shape
+            if cfg.mlm_gather_frac:
+                # run the vocab-width head only on scored positions: stable
+                # argsort orders scored rows first, the head consumes a
+                # lane-aligned prefix (see mlm_gather_frac docstring for the
+                # upper-bound contract)
+                BS = B * S
+                K = min(BS, int(math.ceil(cfg.mlm_gather_frac * BS / 128)) * 128)
+                flat_lab = labels.reshape(BS)
+                n_scored = jnp.sum(flat_lab != -100)
+                order = jnp.argsort(flat_lab == -100, stable=True)[:K]
+                seq_out = seq_out.reshape(BS, D)[order][None]
+                labels = flat_lab[order][None]
+                # overflow telemetry (MoE dropped_frac analog): positions past
+                # the cut are silently unscored, so surface the count to layer-
+                # output collectors instead of hiding it
+                hooks.record_layer_output(
+                    "mlm_dropped", jnp.maximum(n_scored - K, 0))
+                B, S = 1, K
+            chunk = pick_ce_chunk(S, cfg.ce_chunk)
+            if chunk and S > chunk:
+                n = S // chunk
+                xs = jnp.moveaxis(seq_out.reshape(B, n, chunk, D), 1, 0)
+                ls = jnp.moveaxis(labels.reshape(B, n, chunk), 1, 0)
+                ck = jax.checkpoint(lambda xc, lc: _chunk_nll(params, xc, lc))
 
-            def body(carry, xt):
-                tot, cnt = carry
-                t, c = ck(*xt)
-                return (tot + t, cnt + c), None
+                def body(carry, xt):
+                    tot, cnt = carry
+                    t, c = ck(*xt)
+                    return (tot + t, cnt + c), None
 
-            (total, count), _ = jax.lax.scan(
-                body, (jnp.float32(0.0), jnp.int32(0)), (xs, ls)
-            )
-        else:
-            total, count = _chunk_nll(params, seq_out, labels)
-        return total / jnp.maximum(count, 1)
+                (total, count), _ = jax.lax.scan(
+                    body, (jnp.float32(0.0), jnp.int32(0)), (xs, ls)
+                )
+            else:
+                total, count = _chunk_nll(params, seq_out, labels)
+            return total / jnp.maximum(count, 1)
 
     def init_fn(rng):
         return init_params(rng, cfg)

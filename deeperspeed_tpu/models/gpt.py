@@ -396,67 +396,69 @@ def decoder_block(cfg: GPTConfig, mesh, x, layer_params, positions, attend,
     cdt = cfg.dtype
     B, S, D = x.shape
     H, Dh = cfg.n_head, cfg.head_dim
-    mlp_in_shared = None
-    if cfg.parallel_residual:
-        # ln1(x) and ln2(x) normalize the SAME x — share the mean/var pass
-        attn_in, mlp_in_shared = layer_norm2(
-            x, layer_params["ln1_scale"], layer_params["ln1_bias"],
-            layer_params["ln2_scale"], layer_params["ln2_bias"],
-            cfg.layernorm_eps,
-        )
-    else:
-        attn_in = layer_norm(
-            x, layer_params["ln1_scale"], layer_params["ln1_bias"],
-            cfg.layernorm_eps,
-        )
-    qkv = attn_in @ layer_params["attn"]["wqkv"].astype(cdt) + layer_params[
-        "attn"
-    ]["bqkv"].astype(cdt)
-    Hkv = cfg.kv_heads
-    q = qkv[..., : H * Dh].reshape(B, S, H, Dh)
-    k = qkv[..., H * Dh: (H + Hkv) * Dh].reshape(B, S, Hkv, Dh)
-    v = qkv[..., (H + Hkv) * Dh:].reshape(B, S, Hkv, Dh)
-    if cfg.rotary:
-        rd = int(cfg.rotary_pct * Dh) // 2 * 2
-        q = rotary_embedding(q, positions, rd)
-        k = rotary_embedding(k, positions, rd)
-    # named for selective remat (remat_policy='matmuls'): saving the
-    # post-rotary q/k/v lets the backward skip the qkv projection+rotary
-    q = checkpoint_name(q, "attn_q")
-    k = checkpoint_name(k, "attn_k")
-    v = checkpoint_name(v, "attn_v")
-    ctx, aux = attend(q, k, v)
-    attn = ctx.reshape(B, S, D)
-    attn_out = attn @ layer_params["attn"]["wo"].astype(cdt) + layer_params[
-        "attn"
-    ]["bo"].astype(cdt)
-
-    if cfg.parallel_residual:
-        # NeoX: x + attn(ln1(x)) + mlp(ln2(x)); mlp_in computed above in
-        # the shared-normalization pass
-        mlp_in = mlp_in_shared
-    else:
-        x = x + attn_out
-        mlp_in = layer_norm(
-            x, layer_params["ln2_scale"], layer_params["ln2_bias"], cfg.layernorm_eps
-        )
-    if mlp_fn is not None:
-        mlp_out, moe_aux = mlp_fn(mlp_in)
-        aux = (aux, moe_aux)
-    else:
-        from ..ops.pallas.fused_blocks import bias_gelu
-
-        h = mlp_in @ layer_params["mlp"]["wi"].astype(cdt)
-        # pre-gelu: saving it skips the ffn-in matmul recompute while the
-        # bias+gelu stays cheap to replay (saved pre-bias so the fused
-        # kernel owns the add)
-        h = checkpoint_name(h, "mlp_pre")
-        h = bias_gelu(h, layer_params["mlp"]["bi"].astype(cdt),
-                      approximate=True)
-        h = _shard_act(h, mesh, P(DATA_AXIS, SEQ_AXIS, MODEL_AXIS))
-        mlp_out = h @ layer_params["mlp"]["wo"].astype(cdt) + layer_params[
-            "mlp"
+    with jax.named_scope("ds.attn"):
+        mlp_in_shared = None
+        if cfg.parallel_residual:
+            # ln1(x) and ln2(x) normalize the SAME x — share the mean/var pass
+            attn_in, mlp_in_shared = layer_norm2(
+                x, layer_params["ln1_scale"], layer_params["ln1_bias"],
+                layer_params["ln2_scale"], layer_params["ln2_bias"],
+                cfg.layernorm_eps,
+            )
+        else:
+            attn_in = layer_norm(
+                x, layer_params["ln1_scale"], layer_params["ln1_bias"],
+                cfg.layernorm_eps,
+            )
+        qkv = attn_in @ layer_params["attn"]["wqkv"].astype(cdt) + layer_params[
+            "attn"
+        ]["bqkv"].astype(cdt)
+        Hkv = cfg.kv_heads
+        q = qkv[..., : H * Dh].reshape(B, S, H, Dh)
+        k = qkv[..., H * Dh: (H + Hkv) * Dh].reshape(B, S, Hkv, Dh)
+        v = qkv[..., (H + Hkv) * Dh:].reshape(B, S, Hkv, Dh)
+        if cfg.rotary:
+            rd = int(cfg.rotary_pct * Dh) // 2 * 2
+            q = rotary_embedding(q, positions, rd)
+            k = rotary_embedding(k, positions, rd)
+        # named for selective remat (remat_policy='matmuls'): saving the
+        # post-rotary q/k/v lets the backward skip the qkv projection+rotary
+        q = checkpoint_name(q, "attn_q")
+        k = checkpoint_name(k, "attn_k")
+        v = checkpoint_name(v, "attn_v")
+        ctx, aux = attend(q, k, v)
+        attn = ctx.reshape(B, S, D)
+        attn_out = attn @ layer_params["attn"]["wo"].astype(cdt) + layer_params[
+            "attn"
         ]["bo"].astype(cdt)
+
+    with jax.named_scope("ds.mlp"):
+        if cfg.parallel_residual:
+            # NeoX: x + attn(ln1(x)) + mlp(ln2(x)); mlp_in computed above in
+            # the shared-normalization pass
+            mlp_in = mlp_in_shared
+        else:
+            x = x + attn_out
+            mlp_in = layer_norm(
+                x, layer_params["ln2_scale"], layer_params["ln2_bias"], cfg.layernorm_eps
+            )
+        if mlp_fn is not None:
+            mlp_out, moe_aux = mlp_fn(mlp_in)
+            aux = (aux, moe_aux)
+        else:
+            from ..ops.pallas.fused_blocks import bias_gelu
+
+            h = mlp_in @ layer_params["mlp"]["wi"].astype(cdt)
+            # pre-gelu: saving it skips the ffn-in matmul recompute while the
+            # bias+gelu stays cheap to replay (saved pre-bias so the fused
+            # kernel owns the add)
+            h = checkpoint_name(h, "mlp_pre")
+            h = bias_gelu(h, layer_params["mlp"]["bi"].astype(cdt),
+                          approximate=True)
+            h = _shard_act(h, mesh, P(DATA_AXIS, SEQ_AXIS, MODEL_AXIS))
+            mlp_out = h @ layer_params["mlp"]["wo"].astype(cdt) + layer_params[
+                "mlp"
+            ]["bo"].astype(cdt)
 
     if cfg.parallel_residual:
         x = x + attn_out + mlp_out
@@ -523,12 +525,13 @@ def make_gpt(cfg: GPTConfig, mesh=None):
         summed moe auxiliary loss — 0.0 for dense models)."""
         cdt = cfg.dtype
         B, S = tokens.shape
-        wte = params["embed"]["wte"].astype(cdt)
-        x = jnp.take(wte, tokens, axis=0)  # (B, S, D)
-        positions = jnp.arange(S, dtype=jnp.int32)
-        if not cfg.rotary:
-            x = x + params["embed"]["wpe"][:S].astype(cdt)
-        x = _shard_act(x, mesh, P(DATA_AXIS, SEQ_AXIS, None))
+        with jax.named_scope("ds.embed"):
+            wte = params["embed"]["wte"].astype(cdt)
+            x = jnp.take(wte, tokens, axis=0)  # (B, S, D)
+            positions = jnp.arange(S, dtype=jnp.int32)
+            if not cfg.rotary:
+                x = x + params["embed"]["wpe"][:S].astype(cdt)
+            x = _shard_act(x, mesh, P(DATA_AXIS, SEQ_AXIS, None))
 
         step = partial(block, positions=positions)
         if cfg.remat:
@@ -581,38 +584,39 @@ def make_gpt(cfg: GPTConfig, mesh=None):
         else:
             inputs, targets = batch[:, :-1], batch[:, 1:]
         x, moe_aux = hidden_fn(params, inputs)
-        w = head_weight(params)
-        B, S, D = x.shape
-        chunk = pick_ce_chunk(S, cfg.ce_chunk)
-        if chunk and S > chunk:
-            # stream the cross-entropy over sequence chunks: the (B, S, V)
-            # logits are never materialized. Each chunk's logits are
-            # recomputed in the backward (one extra head matmul) in exchange
-            # for GBs of saved HBM — this is what unlocks large micro-batches
-            # (the reference's fp16 fused softmax-xent serves the same role,
-            # csrc/transformer/softmax_kernels.cu)
-            n = S // chunk
-            xs = jnp.moveaxis(x.reshape(B, n, chunk, D), 1, 0)
-            ts = jnp.moveaxis(targets.reshape(B, n, chunk), 1, 0)
+        with jax.named_scope("ds.loss"):
+            w = head_weight(params)
+            B, S, D = x.shape
+            chunk = pick_ce_chunk(S, cfg.ce_chunk)
+            if chunk and S > chunk:
+                # stream the cross-entropy over sequence chunks: the (B, S, V)
+                # logits are never materialized. Each chunk's logits are
+                # recomputed in the backward (one extra head matmul) in exchange
+                # for GBs of saved HBM — this is what unlocks large micro-batches
+                # (the reference's fp16 fused softmax-xent serves the same role,
+                # csrc/transformer/softmax_kernels.cu)
+                n = S // chunk
+                xs = jnp.moveaxis(x.reshape(B, n, chunk, D), 1, 0)
+                ts = jnp.moveaxis(targets.reshape(B, n, chunk), 1, 0)
 
-            @jax.checkpoint
-            def chunk_nll(xc, tc):
-                logits = (xc @ w).astype(jnp.float32)  # (B, chunk, V)
-                lse = jax.scipy.special.logsumexp(logits, axis=-1)
-                tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
-                return jnp.sum(lse - tgt)
+                @jax.checkpoint
+                def chunk_nll(xc, tc):
+                    logits = (xc @ w).astype(jnp.float32)  # (B, chunk, V)
+                    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+                    tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
+                    return jnp.sum(lse - tgt)
 
-            def body(acc, xt):
-                return acc + chunk_nll(*xt), None
+                def body(acc, xt):
+                    return acc + chunk_nll(*xt), None
 
-            total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, ts))
-            return total / (B * S) + moe_aux
-        logits = (x @ w).astype(jnp.float32)
-        # nll = logsumexp - target_logit, WITHOUT materializing the fp32
-        # log-softmax over the full (B, S, V) tensor (pure HBM traffic)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        return jnp.mean(lse - tgt) + moe_aux
+                total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, ts))
+                return total / (B * S) + moe_aux
+            logits = (x @ w).astype(jnp.float32)
+            # nll = logsumexp - target_logit, WITHOUT materializing the fp32
+            # log-softmax over the full (B, S, V) tensor (pure HBM traffic)
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+            return jnp.mean(lse - tgt) + moe_aux
 
     def init_fn(rng):
         return init_params(rng, cfg)

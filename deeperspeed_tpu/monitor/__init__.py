@@ -50,7 +50,12 @@ from .tracer import (
     trace_span,
 )
 from .validate import validate_events, validate_file
-from .watchdog import RecompileError, RecompileWatchdog
+from .watchdog import (
+    RecompileError,
+    RecompileWatchdog,
+    compile_account,
+    install_compile_listener,
+)
 
 __all__ = [
     "Monitor",
@@ -69,6 +74,8 @@ __all__ = [
     "extract_cost_analysis",
     "extract_memory_analysis",
     "platform_peaks",
+    "compile_account",
+    "install_compile_listener",
     "current_run_context",
     "ensure_run_id",
     "export_to_tensorboard",

@@ -19,7 +19,11 @@ never double-counted (a compile inside the first ``engine/train_batch``
 span is compile time, not productive time):
 
   ====================  =============================================
-  ``compile``           ``xla_compile`` instants (duration in args)
+  ``compile``           ``xla_compile`` instants (duration in args):
+                        since PR 24 one for each LOWERING as well as
+                        for each backend compile or cache load
+                        (``phase`` in args), so the bucket holds the
+                        time before the backend too
   ``remesh``            ``lifecycle/remesh`` spans — live in-process
                         topology flips (the zero-restart elasticity
                         path pays a stall, not a relaunch)
@@ -28,7 +32,11 @@ span is compile time, not productive time):
   ``rework``            train-step spans whose ``step`` arg was
                         already executed by an earlier incarnation —
                         the replay tax of checkpoint-interval resume
-  ``productive``        remaining train/serving step span time
+  ``productive``        remaining train/serving step span time;
+                        ``engine/train_batch`` covers the batch's
+                        fetch and placement since PR 24 (its child
+                        ``engine/train_batch/feed``), less whatever
+                        ``datapipe/wait`` claims of it as ``stall``
   ``restart``           gaps between a child's exit and the next
                         launch (supervisor backoff + spawn)
   ``other``             the remainder of each child's lifetime

@@ -21,6 +21,8 @@ dropped):
   ``compile``           ``xla_compile`` inside the window — split
                         out of the rid's own prefill first, then
                         whatever else fires on its serving process
+                        (lowerings as well as backend compiles and
+                        cache loads since PR 24: ``phase`` in args)
   ``prefill``           the rid's own ``serving/prefill`` +
                         ``serving/prefill_chunk`` spans, compile time
                         removed (chunked prefill is own prefill,
@@ -66,6 +68,7 @@ CLI: ``python -m deeperspeed_tpu.monitor.slo``.
 
 import dataclasses
 import math
+import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from .goodput import (
@@ -75,6 +78,11 @@ from .goodput import (
     interval_union,
     load_trace_events,
 )
+from .tracer import RID_SEP
+
+# a decode span's ``rids`` are joined with tracer.RID_SEP; traces from
+# before the spans went to the profiler too (which splits on ",") used ","
+_RID_SPLIT = re.compile("[" + re.escape(RID_SEP) + ",]")
 
 __all__ = [
     "ATTRIBUTION_BUCKETS",
@@ -302,7 +310,7 @@ def build_index(events: List[dict]) -> TraceIndex:
             elif name == "serving/decode":
                 decodes_by_pid.setdefault(pid, []).append((start, end))
                 riders = [r for r in
-                          str(args.get("rids", "")).split(",") if r]
+                          _RID_SPLIT.split(str(args.get("rids", ""))) if r]
                 n = int(args.get("n_active", len(riders)) or 1)
                 for r in riders:
                     tl(r).decodes.append((start, end, pid, n))

@@ -10,17 +10,27 @@ pairs so ring-buffer eviction can never orphan half a pair; the schema
 validator (``monitor/validate.py``) still checks B/E balance for traces
 that carry them (e.g. hand-merged ones).
 
-The hot-path contract: when no tracer is installed, ``trace_span`` returns
-a shared no-op context manager and ``trace_instant``/``trace_counter``
-return immediately — observability off means a dict lookup and a branch,
-nothing else. Engines therefore call the module-level helpers
-unconditionally.
+One span, two sinks. Every span and instant made through the module-level
+helpers also enters a ``jax.profiler.TraceAnnotation`` of the same name
+and arguments, whether or not a ``Tracer`` is installed. While a JAX
+profiler session is live (``jax.profiler.start_trace``, a TensorBoard
+capture) the program's spans therefore lie on the host plane of the same
+``.xplane.pb`` as the device operations, on the profiler's clock, nested
+by containment, with their arguments (``rid=``, ``step=``) as statistics;
+an instant is a zero-length annotation. The profiler splits the encoded
+arguments on ``,`` and ``#``, so several request ids in one argument are
+joined with ``RID_SEP``.
 
-Timestamps are ``time.perf_counter()`` microseconds (monotonic); ``pid``
-is the OS pid, ``tid`` is either the real thread id or a named logical
-lane (``lane="serving"``) so Perfetto renders one track per subsystem
-(engine / pipeline stages / offload / serving) instead of interleaving
-everything on the main thread's track.
+The hot-path contract: with no session and no tracer the annotation is
+inert (about a microsecond for a span with two arguments),
+``.note()`` is a no-op and ``trace_counter`` returns immediately.
+Engines therefore call the module-level helpers unconditionally.
+
+The ring's timestamps are ``time.perf_counter()`` microseconds
+(monotonic); ``pid`` is the OS pid, ``tid`` is either the real thread id
+or a named logical lane (``lane="serving"``) so Perfetto renders one track
+per subsystem (engine / pipeline stages / offload / serving) instead of
+interleaving everything on the main thread's track.
 
 Two run-scoped extras feed the cross-process story (monitor/aggregate):
 
@@ -47,9 +57,12 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .runctx import RunContext, clock_anchor, current as current_run
 
 __all__ = [
+    "RID_SEP",
     "Tracer",
     "get_tracer",
     "set_tracer",
@@ -59,38 +72,45 @@ __all__ = [
 ]
 
 
-class _NullSpan:
-    """Shared no-op context manager for the tracer-disabled path."""
+# joins several request ids in ONE span argument ("," and "#" are the
+# profiler's own separators: rid="r1,r2" would arrive as "r1");
+# monitor/reqledger.py splits on the same
+RID_SEP = "|"
 
-    __slots__ = ()
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+class _ProfilerSpan(TraceAnnotation):
+    """What ``trace_span`` hands out with no ``Tracer`` installed: the
+    profiler's annotation alone, inert unless a session is live."""
 
     def note(self, **args):
         return self
 
-
-_NULL_SPAN = _NullSpan()
+    def elapsed_s(self) -> float:
+        return time.perf_counter() - self._t0
 
 
 class _Span:
-    """Context manager emitting one "X" (complete) event on exit."""
+    """Context manager emitting one "X" (complete) event on exit, inside
+    the profiler's annotation of the same name and arguments."""
 
-    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_tid", "_args", "_t0", "_ann")
 
     def __init__(self, tracer, name, tid, args):
         self._tracer = tracer
         self._name = name
         self._tid = tid
         self._args = args
+        self._ann = TraceAnnotation(name, **args)
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def elapsed_s(self) -> float:
+        """Seconds since the span was entered, on the span's own clock
+        (for work inside it that needs its length so far)."""
+        return time.perf_counter() - self._t0
 
     def note(self, **args):
         """Attach args discovered mid-span (MFU, HBM watermarks — values
@@ -113,6 +133,7 @@ class _Span:
             "tid": self._tid,
             **({"args": self._args} if self._args else {}),
         })
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -306,14 +327,19 @@ def get_tracer() -> Optional[Tracer]:
 
 
 def trace_span(name: str, lane: Optional[str] = None, **args):
-    """Span against the global tracer; a shared no-op when tracing is off."""
+    """Span on the profiler's clock and, when a tracer is installed, in
+    its ring too."""
     t = _GLOBAL
     if t is None:
-        return _NULL_SPAN
+        sp = _ProfilerSpan(name, **args)
+        sp._t0 = time.perf_counter()    # entered where it is made
+        return sp
     return t.span(name, lane, **args)
 
 
 def trace_instant(name: str, lane: Optional[str] = None, **args) -> None:
+    with TraceAnnotation(name, **args):
+        pass
     t = _GLOBAL
     if t is not None:
         t.instant(name, lane, **args)

@@ -67,6 +67,10 @@ EVENT_ARG_SCHEMAS = {
     "kv/reuse": ("rid", "matched_tokens", "shared_blocks"),
     "kv/cow_split": ("rid", "block", "rows"),
     "serving/prefill_chunk": ("rid", "chunk", "tokens"),
+    # read on the profiler's clock by the benchmark's per-layer metrics
+    # (monitor/tracer.py enters a TraceAnnotation for every span): a
+    # scheduler span says which of its three calls it is
+    "serving/schedule": ("what",),
     "req/submit": ("rid", "prompt_len"),
     "req/accept": ("rid", "cost_tokens"),
     "req/requeue": ("rid", "backoff_s"),

@@ -15,9 +15,16 @@ Two signals:
     invocation. The first observation that finds a non-empty cache marks
     the function WARM and records the baseline; any growth past the
     baseline afterwards fires the watchdog.
-  * ``jax.monitoring`` backend-compile duration events (when available)
-    feed a process-global compile counter and a trace instant per
-    compile, so even unwatched compiles show up on the timeline.
+  * ``jax.monitoring`` duration events feed a process-global compile
+    ACCOUNT by program name (``compile_account()``): JAX hands every
+    listener the ``fun_name`` of what it traced (``ds_decode_step``),
+    lowered and compiled or loaded from the persistent cache
+    (``jit(ds_decode_step)``), so the account says which program cost
+    what before and in the backend, and a lowering for a second shape
+    shows as a second count. Each event is also an ``xla_compile``
+    instant, so unwatched compiles show on the timeline. The engines'
+    constructors install the listener whether or not a monitor is on: a
+    dict update per compile, nothing per step.
 
 Firing emits a trace instant (``recompile!``) plus a rank-0 warning; in
 ``strict`` mode it raises :class:`RecompileError` instead — the mode the
@@ -25,6 +32,7 @@ serving tests run under, proving the decode step compiles exactly once
 across a multi-request run.
 """
 
+import functools
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -33,32 +41,82 @@ from ..utils.logging import logger
 from .runctx import current as current_run
 from .tracer import trace_instant
 
-__all__ = ["RecompileError", "RecompileWatchdog", "install_compile_listener"]
+__all__ = ["RecompileError", "RecompileWatchdog", "compile_account",
+           "install_compile_listener"]
 
 MODES = ("off", "warn", "strict")
 
-# process-global compile-event counter fed by jax.monitoring (see
-# install_compile_listener); None until the listener is installed
-_compile_events = 0
+# the three phases JAX times for every program: tracing the function to
+# a jaxpr, lowering the jaxpr to a module, and the backend's compile (a
+# load from the persistent cache fires the last one too)
+COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+EAGER_ROW = "eager"
+
+# process-global compile account fed by jax.monitoring (see
+# install_compile_listener): program name -> phase -> [count, seconds]
+_account: Dict[str, Dict[str, List[float]]] = {}
 _last_compile_t: Optional[float] = None  # perf_counter of the newest one
 _listener_installed = False
 _listener_lock = threading.Lock()
-_COMPILE_EVENT_KEY = "backend_compile"
 
 
 def _on_duration_event(event: str, duration: float, **kwargs) -> None:
-    global _compile_events, _last_compile_t
-    if _COMPILE_EVENT_KEY in event:
-        _compile_events += 1
+    global _last_compile_t
+    phase = COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    fun_name = str(kwargs.get("fun_name", "<unknown>"))
+    # tracing names the bare function, lowering and compiling "jit(<it>)"
+    name = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+    cell = _account.setdefault(name, {}).setdefault(phase, [0, 0.0])
+    cell[0] += 1
+    cell[1] += duration
+    if phase == "compile":
         _last_compile_t = time.perf_counter()
-        trace_instant("xla_compile", lane="compile",
-                      seconds=round(duration, 4))
+    if phase != "trace":      # every traced sub-function fires "trace"
+        trace_instant("xla_compile", lane="compile", fun_name=fun_name,
+                      phase=phase, seconds=round(duration, 4))
+
+
+@functools.lru_cache(maxsize=None)
+def _one_primitive_names():
+    """Names of the programs an eager one-primitive dispatch lowers
+    (``jit(convert_element_type)``, ``jit(broadcast_in_dim)``)."""
+    from jax.extend.core import primitives as prims
+
+    return frozenset(
+        getattr(prims, n).name for n in dir(prims) if n.endswith("_p"))
+
+
+def compile_account() -> Dict[str, Dict[str, Dict[str, float]]]:
+    """What this process traced, lowered and compiled since the listener
+    was installed: ``{program: {phase: {"count", "seconds"}}}`` with the
+    phases ``trace``, ``lower`` and ``compile`` (compiled, or loaded
+    from the persistent cache). A program is named as ``jax.jit`` names
+    it, without the ``jit(...)``: the engines' own all start ``ds_``.
+    Every program of one primitive, which is what an eager operation on
+    an array dispatches, is summed under the one row ``eager``; a row
+    with no ``lower`` is a function traced inside another program."""
+    eager = _one_primitive_names()
+    out: Dict[str, Dict[str, Dict[str, float]]] = {}
+    for name, phases in list(_account.items()):
+        row = out.setdefault(EAGER_ROW if name in eager else name, {})
+        for phase, (count, seconds) in list(phases.items()):
+            cell = row.setdefault(phase, {"count": 0, "seconds": 0.0})
+            cell["count"] += count
+            cell["seconds"] += seconds
+    return out
 
 
 def install_compile_listener() -> bool:
-    """Register the jax.monitoring duration listener (once per process;
-    jax offers no per-listener unregister so it stays installed). Returns
-    True when the listener is active."""
+    """Register the jax.monitoring duration listener that keeps the
+    compile account (once per process; jax offers no per-listener
+    unregister so it stays installed). Returns True when the listener
+    is active."""
     global _listener_installed
     with _listener_lock:
         if _listener_installed:
@@ -71,11 +129,6 @@ def install_compile_listener() -> bool:
             return False
         _listener_installed = True
         return True
-
-
-def global_compile_events() -> int:
-    """Backend compiles observed process-wide since listener install."""
-    return _compile_events
 
 
 def _cache_size(fn) -> Optional[int]:

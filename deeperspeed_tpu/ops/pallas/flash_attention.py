@@ -198,6 +198,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             _vmem_spec((1, 1, block_q, Dh), lambda b, h, i: (b, h, i, 0)),
@@ -380,6 +381,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret):
     )
     dk, dv = pl.pallas_call(
         dkdv,
+        name="flash_bwd_dkv",
         grid=(B, H, pl.cdiv(S, block_k)),
         in_specs=[
             _vmem_spec((1, 1, S, Dh), lambda b, h, i: (b, h, 0, 0)),  # q
@@ -407,6 +409,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret):
     )
     dq = pl.pallas_call(
         dqk,
+        name="flash_bwd_dq",
         grid=(B, H, pl.cdiv(S, block_q)),
         in_specs=[
             _vmem_spec((1, 1, block_q, Dh), lambda b, h, i: (b, h, i, 0)),  # q

@@ -147,6 +147,7 @@ def _fwd(q, k, v, sm_scale, causal, interpret):
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_static_fwd",
         grid=(B, H),
         in_specs=[
             _spec((1, 1, S, Dh), lambda b, h: (b, h, 0, 0)),
@@ -247,6 +248,7 @@ def _bwd(res, g, sm_scale, causal, interpret):
     row = lambda: _spec((1, 1, 1, S), lambda b, h: (b, h, 0, 0))
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="flash_static_bwd",
         grid=(B, H),
         in_specs=[full(), full(), full(), full(), row(), row()],
         out_specs=[full(), full(), full()],
@@ -443,6 +445,7 @@ def _st_fwd(qg, kg, vg, sm_scale, causal, seq, interpret):
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_supertile_fwd",
         grid=(NG,),
         in_specs=[tile(), tile(), tile()],
         out_specs=[tile(), row()],
@@ -485,6 +488,7 @@ def _st_vjp_bwd(sm_scale, causal, seq, interpret, res, g):
     )
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="flash_supertile_bwd",
         grid=(NG,),
         in_specs=[tile(), tile(), tile(), tile(), row(), row()],
         out_specs=[tile(), tile(), tile()],

@@ -131,6 +131,7 @@ def fused_adam_leaf(p, g, m, v, lr, bc1, bc2, *, b1, b2, eps, wd, adam_w,
     )
     out = pl.pallas_call(
         kernel,
+        name="fused_adam",
         grid=(R // br,),
         in_specs=[_smem_spec((1, 4)), rows, rows, rows, rows],
         out_specs=[rows] * len(out_shape),

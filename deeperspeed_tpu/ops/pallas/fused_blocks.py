@@ -133,6 +133,7 @@ def _ln_fwd_call(x2, w2, b2, eps, block, interpret):
     stat = _vmem_spec((1, block), lambda i: (0, i))
     return pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
+        name="layer_norm_fwd",
         grid=grid,
         in_specs=[rows, feat, feat],
         out_specs=[rows, stat, stat],
@@ -167,6 +168,7 @@ def _ln_vjp_bwd(eps, block, interpret, res, g):
     part = _part_spec(D)
     dx, dwp, dbp = pl.pallas_call(
         _ln_bwd_kernel,
+        name="layer_norm_bwd",
         grid=(nb,),
         in_specs=[rows, feat, stat, stat, rows],
         out_specs=[rows, part, part],
@@ -222,6 +224,7 @@ def _aln_fwd_call(x2, r2, w2, b2, eps, block, interpret):
     stat = _vmem_spec((1, block), lambda i: (0, i))
     return pl.pallas_call(
         functools.partial(_aln_fwd_kernel, eps=eps),
+        name="add_layer_norm_fwd",
         grid=(R // block,),
         in_specs=[rows, rows, feat, feat],
         out_specs=[rows, stat, stat],
@@ -256,6 +259,7 @@ def _aln_vjp_bwd(eps, block, interpret, res, g):
     part = _part_spec(D)
     ds, dwp, dbp = pl.pallas_call(
         _aln_bwd_kernel,
+        name="add_layer_norm_bwd",
         grid=(nb,),
         in_specs=[rows, rows, feat, stat, stat, rows],
         out_specs=[rows, part, part],
@@ -341,6 +345,7 @@ def _bg(x2, b2, approximate, block, interpret):
     rows = _vmem_spec((block, D), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_bg_fwd_kernel, approximate=approximate),
+        name="bias_gelu_fwd",
         grid=(R // block,),
         in_specs=[rows, feat],
         out_specs=rows,
@@ -363,6 +368,7 @@ def _bg_vjp_bwd(approximate, block, interpret, res, g):
     part = _part_spec(D)
     dx, dbp = pl.pallas_call(
         functools.partial(_bg_bwd_kernel, approximate=approximate),
+        name="bias_gelu_bwd",
         grid=(nb,),
         in_specs=[rows, feat, rows],
         out_specs=[rows, part],

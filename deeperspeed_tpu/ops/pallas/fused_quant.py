@@ -171,6 +171,7 @@ def quantize_rows(x, block, *, want_residual=True, choice="xla",
         out_specs.append(spec)
     got = pl.pallas_call(
         kernel,
+        name="quantize_rows",
         grid=(NBp // br,),
         in_specs=[spec],
         out_specs=out_specs,
@@ -212,6 +213,7 @@ def dequant_sum_rows(q, s, block, *, choice="xla", interpret=False):
     bn = _tile_rows(nbp)
     out = pl.pallas_call(
         _dequant_sum_kernel,
+        name="dequant_sum_rows",
         grid=(nbp // bn,),
         in_specs=[_vmem_spec((R, bn, block), lambda j: (0, j, 0)),
                   _vmem_spec((R, bn, 1), lambda j: (0, j, 0))],
@@ -249,6 +251,7 @@ def dequant_rows(q, s, block, *, divisor=1.0, choice="xla",
     bn = _tile_rows(nbp)
     out = pl.pallas_call(
         functools.partial(_dequant_kernel, divisor=float(divisor)),
+        name="dequant_rows",
         grid=(R, nbp // bn),
         in_specs=[_vmem_spec((1, bn, block), lambda i, j: (i, j, 0)),
                   _vmem_spec((1, bn, 1), lambda i, j: (i, j, 0))],

@@ -29,7 +29,7 @@ from ..pallas.flash_attention import _compiler_params, _vmem_spec
 NEG_INF = -1e30
 
 
-def _lut_pallas_call(kernel, grid, in_specs, out_specs, out_shape,
+def _lut_pallas_call(name, kernel, grid, in_specs, out_specs, out_shape,
                      scratch_shapes, interpret):
     """pallas_call wrapper feeding the two integer LUT arrays (cols/counts)
     as scalar-prefetch args: whole-array SMEM residents, readable from BOTH
@@ -49,8 +49,8 @@ def _lut_pallas_call(kernel, grid, in_specs, out_specs, out_shape,
     # into scratch and must run in order
     kwargs = _compiler_params(interpret, 2, ("parallel", "arbitrary"))
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
-        **kwargs,
+        kernel, name=name, grid_spec=grid_spec, out_shape=out_shape,
+        interpret=interpret, **kwargs,
     )
 
 
@@ -272,6 +272,7 @@ def _bs_fwd(q, k, v, rows, cols, sm_scale, block, causal, interpret):
         num_heads=H, n_entries=n_entries,
     )
     o, lse = _lut_pallas_call(
+        "block_sparse_fwd",
         kernel,
         grid=grid,
         in_specs=(
@@ -462,6 +463,7 @@ def _bs_bwd(res, g, rows, cols, keys_t, qrows_t, sm_scale, block, causal,
     n_entries_t = qrows_t.shape[-1]
 
     dq = _lut_pallas_call(
+        "block_sparse_bwd_dq",
         functools.partial(
             _bs_bwd_dq_kernel, sm_scale=sm_scale, block=block, causal=causal,
             num_heads=H, n_entries=n_entries,
@@ -489,6 +491,7 @@ def _bs_bwd(res, g, rows, cols, keys_t, qrows_t, sm_scale, block, causal,
     kb_spec = _vmem_spec((1, block, Dh),
                          lambda b, i, kk, r: (b, kk[b % H, i * LANE], 0))
     dk, dv = _lut_pallas_call(
+        "block_sparse_bwd_dkv",
         functools.partial(
             _bs_bwd_dkdv_kernel, sm_scale=sm_scale, block=block,
             causal=causal, num_heads=H, n_entries=n_entries_t,
@@ -921,7 +924,7 @@ def _bs_bwd_dkdv_kernel_res(wins_ref, bitmaps_ref, counts_ref, nfull_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _res_pallas_call(kernel, grid, in_specs, out_specs, out_shape,
+def _res_pallas_call(name, kernel, grid, in_specs, out_specs, out_shape,
                      interpret, n_prefetch=4):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
@@ -932,7 +935,7 @@ def _res_pallas_call(kernel, grid, in_specs, out_specs, out_shape,
     # no cross-step state: both grid dims reorder/pipeline freely
     kwargs = _compiler_params(interpret, 2, ("parallel", "parallel"))
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        kernel, name=name, grid_spec=grid_spec, out_shape=out_shape,
         interpret=interpret, **kwargs,
     )
 
@@ -951,6 +954,7 @@ def _bs_fwd_res(q, k, v, lut, sm_scale, block, chunk, causal, srow,
     )
     blk = lambda b, i, *_: (b, i, 0)
     o, lse = _res_pallas_call(
+        "block_sparse_res_fwd",
         kernel,
         grid=(B * H, nsr),
         in_specs=[
@@ -986,6 +990,7 @@ def _bs_bwd_res(res, g, lut, lut_t, sm_scale, block, chunk, causal, srow,
     full = lambda b, i, *_: (b, 0, 0)
 
     dq = _res_pallas_call(
+        "block_sparse_res_bwd_dq",
         functools.partial(
             _bs_bwd_dq_kernel_res, sm_scale=sm_scale, block=block,
             chunk=chunk, srow=srow, causal=causal, num_heads=H,
@@ -1005,6 +1010,7 @@ def _bs_bwd_res(res, g, lut, lut_t, sm_scale, block, chunk, causal, srow,
     )(*lut, qf, kf, vf, do, lse, delta)
 
     dk, dv = _res_pallas_call(
+        "block_sparse_res_bwd_dkv",
         functools.partial(
             _bs_bwd_dkdv_kernel_res, sm_scale=sm_scale, block=block,
             chunk=chunk, srow=srow, causal=causal, num_heads=H,

@@ -32,7 +32,6 @@ map 1:1. Weight orientation is (in, out) as used by `x @ w`.
 
 import dataclasses
 import json
-from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -249,6 +248,7 @@ def _transformer_forward(params, x, config: DeepSpeedTransformerConfig,
 
     eps = config.layernorm_eps
 
+    @jax.named_scope("ds.attn")
     def attn_block(x):
         h = _layer_norm(x, p["attn_nw"], p["attn_nb"], eps) if config.pre_layer_norm else x
         qkv = h @ p["attn_qkvw"] + p["attn_qkvb"]
@@ -265,6 +265,7 @@ def _transformer_forward(params, x, config: DeepSpeedTransformerConfig,
         out = ctx.reshape(B, S, H) @ p["attn_ow"] + p["attn_ob"]
         return _dropout(out, config.hidden_dropout_ratio, r2)
 
+    @jax.named_scope("ds.mlp")
     def ffn_block(x):
         h = _layer_norm(x, p["norm_w"], p["norm_b"], eps) if config.pre_layer_norm else x
         # saved pre-bias so the fused kernel owns the bias add; the XLA
@@ -308,7 +309,12 @@ def transformer_layer_fn(config: DeepSpeedTransformerConfig):
     key = config._cache_key()
     fn = _LAYER_FN_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(partial(_transformer_forward, config=config))
+        def ds_transformer_layer(params, x, attention_mask=None, rng=None,
+                                 pld_theta=None):
+            return _transformer_forward(params, x, config, attention_mask,
+                                        rng, pld_theta)
+
+        fn = jax.jit(ds_transformer_layer)
         _LAYER_FN_CACHE[key] = fn
     return fn
 

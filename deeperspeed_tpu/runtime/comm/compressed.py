@@ -76,6 +76,7 @@ def decompress(m, e, meta, dtype=jnp.float32):
     return _decompress_blocks(m, e, n).reshape(shape).astype(dtype)
 
 
+@jax.named_scope("ds.comm/compressed")
 def compressed_all_reduce(x, axis_name: str = "data", block: int = BLOCK,
                           average: bool = False):
     """SUM (or mean) allreduce over ``axis_name`` shipping 24 bits/element.
@@ -152,6 +153,7 @@ def onebit_compress(x, error):
     return packed, scale, corrected - quantized
 
 
+@jax.named_scope("ds.comm/onebit")
 def onebit_all_reduce(x, axis_name: str = "data", error=None):
     """Average `x` over the mesh axis shipping ~1 bit/element + one scale.
 

@@ -47,6 +47,7 @@ def _chunk_len(n: int, W: int) -> int:
     return -(-c // 8) * 8
 
 
+@jax.named_scope("ds.comm/onebit_2phase")
 def onebit_all_reduce_2phase(x, axis_name: str, werr, serr, W: int):
     """Two-phase error-compensated 1-bit mean over ``axis_name``.
 

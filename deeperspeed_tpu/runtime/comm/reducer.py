@@ -79,6 +79,7 @@ def dequantize_int8_blocks(q, s):
     return (q.astype(jnp.float32) * s[:, None]).reshape(-1)
 
 
+@jax.named_scope("ds.comm/slot_mean")
 def exact_slot_mean(tree, mesh, axis, canonical):
     """Layout-invariant mean over the leading (slot) axis of every leaf.
 
@@ -323,6 +324,7 @@ class GradReducer:
     # per-bucket wire formats (per-device views, traced inside shard_map)
     # ------------------------------------------------------------------ #
 
+    @jax.named_scope("ds.comm/allreduce")
     def _reduce_flat(self, v, res):
         """One bucket: local (L,) fp32 contribution -> mean over the axis.
 
@@ -352,6 +354,7 @@ class GradReducer:
             return self._reduce_int8_hier(v, res)
         return self._reduce_int8_flat(v, res)
 
+    @jax.named_scope("ds.comm/compressed")
     def _reduce_compressed_flat(self, v, res):
         """24-bit block-exponent gather: compress -> all_gather -> rebuild
         the exact sum of quantized contributions.  With the fused_quant
@@ -385,6 +388,7 @@ class GradReducer:
             interpret=interpret)
         return total / W, {"e": new_e}
 
+    @jax.named_scope("ds.comm/int8")
     def _reduce_int8_flat(self, v, res):
         """Two-phase int8: quantize -> all_to_all chunks -> exact partial
         sums -> re-quantize -> all_gather.  ~2(L + 4L/block) wire bytes vs
@@ -413,6 +417,7 @@ class GradReducer:
         out = (aq.astype(jnp.float32) * as_[..., None]).reshape(-1) / W
         return out, {"e": new_e, "e2": new_e2}
 
+    @jax.named_scope("ds.comm/int8_fused")
     def _reduce_int8_flat_fused(self, v, res, choice, interpret):
         """Same two-phase schedule through the fused wire-format kernels:
         one quantize pass also emits the error-feedback residual, scales
@@ -464,6 +469,7 @@ class GradReducer:
         return jax.lax.bitcast_convert_type(
             jnp.transpose(planes, perm), jnp.float32)
 
+    @jax.named_scope("ds.comm/lossless")
     def _reduce_lossless_flat(self, v, res):
         """Lossless byte-plane gather: every rank ships its exact fp32
         contribution as int8 byte planes, reassembles all W vectors
@@ -474,6 +480,7 @@ class GradReducer:
         g = jax.lax.all_gather(self._to_byte_planes(v), ax)  # (W, 4, L)
         return pairwise_slot_sum(self._from_byte_planes(g)) / W, res
 
+    @jax.named_scope("ds.comm/lossless_hier")
     def _reduce_lossless_hier(self, v, res):
         """Two-level lossless: intra-host fp32 reduce-scatter (fast
         links, exact), byte-plane all_gather + pairwise tree across hosts
@@ -492,6 +499,7 @@ class GradReducer:
                                  tiled=True)
         return out, res
 
+    @jax.named_scope("ds.comm/int8_hier")
     def _reduce_int8_hier(self, v, res):
         """qgZ-style two-level schedule: intra-group reduce-scatter in full
         precision (fast links), int8 all_gather across groups, then an int8
@@ -695,6 +703,7 @@ class GradReducer:
         new_res = {"e": c - out} if ef else res
         return out, new_res
 
+    @jax.named_scope("ds.comm/canonical")
     def _reduce_canonical_flat(self, v, res):
         """One bucket, canonical mode, eager reference: (C, L) per-slot
         contributions -> mean over the slot axis via the graph-fixed

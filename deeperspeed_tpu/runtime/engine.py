@@ -49,7 +49,13 @@ from ..ops import kernel_config
 from ..ops.adam import DeepSpeedCPUAdam, FusedAdam
 from ..ops.lamb import FusedLamb
 from ..ops.sgd import SGD
-from ..monitor import get_monitor, init_monitor, trace_instant, trace_span
+from ..monitor import (
+    get_monitor,
+    init_monitor,
+    install_compile_listener,
+    trace_instant,
+    trace_span,
+)
 from ..resilience.manifest import resolve_load_tag
 from ..parallel.topology import DATA_AXIS  # noqa: F401 — re-exported for callers
 from ..utils.logging import log_dist, logger
@@ -110,6 +116,10 @@ class Engine(ConfigAccessorsMixin):
         mpu=None,
         batch_axis_in_batch: int = 0,
     ):
+        # the compile account by program name (monitor.compile_account)
+        # is kept whether or not a monitor is, from before the first
+        # array is placed: a dict update per compile, nothing per step
+        install_compile_listener()
         self._config = config
         self.loss_fn = model
         self.module = model  # reference-compatible alias
@@ -578,8 +588,11 @@ class Engine(ConfigAccessorsMixin):
         master = (place(params, self.master_specs, jnp.float32)
                   if self._use_master else None)
         opt_src = master if self._use_master else params_c
+        def ds_init_opt_state(src):
+            return self.optimizer.init(src)
+
         opt_state = jax.jit(
-            self.optimizer.init,
+            ds_init_opt_state,
             out_shardings=_opt_state_shardings(
                 self.optimizer, opt_src, mesh, self.master_specs
             ),
@@ -726,13 +739,13 @@ class Engine(ConfigAccessorsMixin):
 
         def build():
             if self.comm is not None:
-                def comm_fn(state, batch, rng):
+                def ds_forward_grad(state, batch, rng):
                     rng = self._fold_rng(rng)
                     return self._batch_grads_local(state, batch, rng, 1)
 
-                return jax.jit(comm_fn)
+                return jax.jit(ds_forward_grad)
 
-            def fn(state, batch, rng):
+            def ds_forward_grad(state, batch, rng):
                 rng = self._fold_rng(rng)
                 loss, grads = self._micro_grads(
                     state.params, batch, rng, state.scaler.loss_scale
@@ -740,18 +753,18 @@ class Engine(ConfigAccessorsMixin):
                 grads = partition.constrain(grads, self.grad_specs, self.mesh)
                 return loss, grads
 
-            return jax.jit(fn)
+            return jax.jit(ds_forward_grad)
 
         return self._get_compiled("forward_grad", build)
 
     def _forward_only_fn(self):
         def build():
-            def fn(state, batch, rng):
+            def ds_forward_only(state, batch, rng):
                 rng = self._fold_rng(rng)
                 _, loss = self._call_loss(state.params, batch, rng, jnp.float32(1.0))
                 return loss
 
-            return jax.jit(fn)
+            return jax.jit(ds_forward_only)
 
         return self._get_compiled("forward_only", build)
 
@@ -759,7 +772,10 @@ class Engine(ConfigAccessorsMixin):
         """jitted (state, grads, lr, gas) -> (new_state, metrics)."""
 
         def build():
-            return jax.jit(self._apply_update_body, donate_argnums=(0,))
+            def ds_apply_update(state, grads, lr, gas):
+                return self._apply_update_body(state, grads, lr, gas)
+
+            return jax.jit(ds_apply_update, donate_argnums=(0,))
 
         return self._get_compiled("apply_update", build)
 
@@ -794,8 +810,10 @@ class Engine(ConfigAccessorsMixin):
                 state.params, mb, jax.random.fold_in(rng, i), scale
             )
             grads = partition.constrain(grads, self.grad_specs, self.mesh)
-            acc = jax.tree.map(lambda a, g: a + g.astype(a.dtype), acc, grads)
-            acc = partition.constrain(acc, self.grad_specs, self.mesh)
+            with jax.named_scope("ds.accum"):
+                acc = jax.tree.map(
+                    lambda a, g: a + g.astype(a.dtype), acc, grads)
+                acc = partition.constrain(acc, self.grad_specs, self.mesh)
             return (acc, loss_sum + loss, i + 1), None
 
         (grads, loss_sum, _), _ = jax.lax.scan(
@@ -844,8 +862,9 @@ class Engine(ConfigAccessorsMixin):
                 def mb_body(carry, mb):
                     acc, loss_sum, i = carry
                     mb_loss, grads = one(mb, jax.random.fold_in(rng_, i))
-                    acc = jax.tree.map(
-                        lambda a, g: a + g.astype(a.dtype), acc, grads)
+                    with jax.named_scope("ds.accum"):
+                        acc = jax.tree.map(
+                            lambda a, g: a + g.astype(a.dtype), acc, grads)
                     return (acc, loss_sum + mb_loss, i + 1), None
 
                 (grads, loss_sum, _), _ = jax.lax.scan(
@@ -937,7 +956,7 @@ class Engine(ConfigAccessorsMixin):
                 from .comm.reducer import exact_slot_mean
 
                 if self.comm is not None:
-                    def canon_comm_fn(state, comm_state, batch, lr, rng):
+                    def ds_train_step(state, comm_state, batch, lr, rng):
                         rng = self._fold_rng(rng)
                         losses, slots = self._batch_grads_canonical(
                             state, batch, rng, C)
@@ -954,9 +973,9 @@ class Engine(ConfigAccessorsMixin):
                         metrics["loss"] = loss
                         return new_state, new_comm, metrics
 
-                    return jax.jit(canon_comm_fn, donate_argnums=(0, 1))
+                    return jax.jit(ds_train_step, donate_argnums=(0, 1))
 
-                def canon_fn(state, batch, lr, rng):
+                def ds_train_step(state, batch, lr, rng):
                     rng = self._fold_rng(rng)
                     losses, slots = self._batch_grads_canonical(
                         state, batch, rng, C)
@@ -973,14 +992,14 @@ class Engine(ConfigAccessorsMixin):
                     metrics["loss"] = loss
                     return new_state, metrics
 
-                return jax.jit(canon_fn, donate_argnums=(0,))
+                return jax.jit(ds_train_step, donate_argnums=(0,))
 
             if self.comm is not None:
                 # comm path: local grads via shard_map, explicit bucketed
                 # reduction, then the shared update body. The comm state
                 # (error-feedback residuals) threads through the jit with
                 # donation like the engine state.
-                def comm_fn(state, comm_state, batch, lr, rng):
+                def ds_train_step(state, comm_state, batch, lr, rng):
                     rng = self._fold_rng(rng)
                     loss, local = self._batch_grads_local(
                         state, batch, rng, gas)
@@ -996,16 +1015,16 @@ class Engine(ConfigAccessorsMixin):
                     metrics["loss"] = loss
                     return new_state, new_comm, metrics
 
-                return jax.jit(comm_fn, donate_argnums=(0, 1))
+                return jax.jit(ds_train_step, donate_argnums=(0, 1))
 
-            def fn(state, batch, lr, rng):
+            def ds_train_step(state, batch, lr, rng):
                 rng = self._fold_rng(rng)
                 loss, grads = self._batch_grads(state, batch, rng, gas)
                 new_state, metrics = self._apply_update_body(state, grads, lr, gas)
                 metrics["loss"] = loss
                 return new_state, metrics
 
-            return jax.jit(fn, donate_argnums=(0,))
+            return jax.jit(ds_train_step, donate_argnums=(0,))
 
         return self._get_compiled("train_batch", build)
 
@@ -1018,7 +1037,7 @@ class Engine(ConfigAccessorsMixin):
             gas = self.gradient_accumulation_steps()
             clip = float(self._config.gradient_clipping or 0.0)
 
-            def fn(state, batch, rng):
+            def ds_offload_grads(state, batch, rng):
                 rng = self._fold_rng(rng)
                 loss, grads = self._batch_grads(state, batch, rng, gas)
                 grads, gnorm, finite = self._postprocess_grads(
@@ -1029,7 +1048,7 @@ class Engine(ConfigAccessorsMixin):
                 )
                 return loss, grads, gnorm, finite
 
-            return jax.jit(fn)
+            return jax.jit(ds_offload_grads)
 
         return self._get_compiled("offload_grads", build)
 
@@ -1045,19 +1064,21 @@ class Engine(ConfigAccessorsMixin):
         and the update is discarded wholesale (the `keep` select in
         _apply_update_body), matching the reference's skip-step
         (runtime/engine.py:1184-1192 + CheckOverflow, runtime/utils.py)."""
-        inv = 1.0 / (state.scaler.loss_scale * gas)
-        raw_sq = jnp.sum(
-            jnp.stack([jnp.sum(g.astype(jnp.float32) ** 2)
-                       for g in jax.tree.leaves(grads)])
-        )
-        gnorm = jnp.sqrt(raw_sq) * inv  # norm of the UNSCALED grads
-        finite = jnp.isfinite(gnorm)
-        coef = inv
-        if clip > 0:
-            coef = coef * jnp.minimum(1.0, clip / (gnorm + 1e-6))
-        grads = jax.tree.map(
-            lambda g: (g.astype(jnp.float32) * coef).astype(g.dtype), grads
-        )
+        with jax.named_scope("ds.update/clip"):
+            inv = 1.0 / (state.scaler.loss_scale * gas)
+            raw_sq = jnp.sum(
+                jnp.stack([jnp.sum(g.astype(jnp.float32) ** 2)
+                           for g in jax.tree.leaves(grads)])
+            )
+            gnorm = jnp.sqrt(raw_sq) * inv  # norm of the UNSCALED grads
+            finite = jnp.isfinite(gnorm)
+            coef = inv
+            if clip > 0:
+                coef = coef * jnp.minimum(1.0, clip / (gnorm + 1e-6))
+            grads = jax.tree.map(
+                lambda g: (g.astype(jnp.float32) * coef).astype(g.dtype),
+                grads
+            )
         return grads, gnorm, finite
 
     def _offload_post_fn(self):
@@ -1067,7 +1088,7 @@ class Engine(ConfigAccessorsMixin):
         def build():
             clip = float(self._config.gradient_clipping or 0.0)
 
-            def fn(state, grads, gas):
+            def ds_offload_post(state, grads, gas):
                 grads, gnorm, finite = self._postprocess_grads(
                     state, grads, gas, clip
                 )
@@ -1076,7 +1097,7 @@ class Engine(ConfigAccessorsMixin):
                 )
                 return grads, gnorm, finite
 
-            return jax.jit(fn)
+            return jax.jit(ds_offload_post)
 
         return self._get_compiled("offload_post", build)
 
@@ -1090,10 +1111,10 @@ class Engine(ConfigAccessorsMixin):
             )
             cdt = self._compute_dtype
 
-            def fn(t):
+            def ds_offload_reshard(t):
                 return jax.tree.map(lambda x: x.astype(cdt), t)
 
-            return jax.jit(fn, out_shardings=shardings)
+            return jax.jit(ds_offload_reshard, out_shardings=shardings)
 
         return self._get_compiled("offload_reshard", build)
 
@@ -1166,10 +1187,10 @@ class Engine(ConfigAccessorsMixin):
                 lambda s: NamedSharding(self.mesh, s), self.master_specs
             )
 
-            def fn(t):
+            def ds_to_master(t):
                 return jax.tree.map(lambda x: x.astype(jnp.float32), t)
 
-            return jax.jit(fn, out_shardings=shardings)
+            return jax.jit(ds_to_master, out_shardings=shardings)
 
         return self._get_compiled("offload_to_master", build)(params)
 
@@ -1211,7 +1232,8 @@ class Engine(ConfigAccessorsMixin):
         # master-weight cast rides inside the optimizer kernel (one HBM
         # pass) instead of a separate full-param cast here
         # (the optimizer's Pallas route needs the mesh it is traced under)
-        with kernel_config.mesh_scope(self.mesh):
+        with kernel_config.mesh_scope(self.mesh), \
+                jax.named_scope("ds.update/optimizer"):
             fused_cast = (self._use_master
                           and getattr(opt, "pallas_active", lambda: False)())
             if fused_cast:
@@ -1491,100 +1513,107 @@ class Engine(ConfigAccessorsMixin):
         """Fused one-step API (the TPU-native hot path). Accepts either a full
         global batch (leading dim = gas * micro * dp) or pulls one from the
         engine dataloader / provided iterator."""
-        placed = False
-        if batch is None:
-            if self.datapipe is not None and data_iter is None:
-                # the pipe hands over a full global batch, usually
-                # already staged on the mesh by the prefetch thread
-                batch, placed = self.datapipe.next_global_batch()
-            else:
-                it = data_iter or self._train_iter()
-                parts = [next(it)
-                         for _ in range(self.gradient_accumulation_steps())]
-                batch = jax.tree.map(
-                    lambda *xs: np.concatenate(xs, axis=0), *parts)
-        if not placed:
-            batch = self._place_batch(batch)
-        batch = self._pack_pld(batch)
-        rng = self._rng_args()
-        lr = np.float32(self._current_lr())
         wall = self._config.wall_clock_breakdown
-        if wall:
-            self._timer_start("train_batch")
-        self.tput_timer.start()
-        if self._layer_collector is not None:
-            self._layer_collector.clear()
         wd = self.monitor.watchdog if self.monitor is not None else None
         ci = self.monitor.cost_index if self.monitor is not None else None
         mw = self.monitor.memwatch if self.monitor is not None else None
         step_fn = step_args = None  # what the perf doctor re-lowers
         with trace_span("engine/train_batch", lane="engine",
                         step=self.global_steps) as _tb_sp:
-            _t0 = time.perf_counter()
-            if self._offload is not None:
-                loss, grads, gnorm, finite = self._offload_grads_fn()(
-                    self.state, batch, rng
-                )
-                metrics = self._offload_apply(grads, gnorm, finite, loss)
-            elif self.store_gradients:
-                # unfused route so the grads are observable (reference
-                # engine.py:1156 clones p.grad at step time)
-                loss, grads = self._batch_grads_fn()(self.state, batch, rng)
-                self._store_grads(grads)
-                new_state, metrics = self._apply_update_fn()(
-                    self.state, grads, lr,
-                    np.float32(self.gradient_accumulation_steps()),
-                )
-                metrics = dict(metrics, loss=loss)
-                self.state = new_state
-            else:
-                fn = self._train_batch_fn()
-                if wd is not None:
-                    wd.watch("engine/train_step", fn)
-                if self.comm is not None:
-                    step_args = (self.state, self._comm_state, batch, lr, rng)
-                    new_state, self._comm_state, metrics = fn(*step_args)
-                    self.comm.record_reduction_counters()
+            with trace_span("engine/train_batch/feed", lane="engine"):
+                placed = False
+                if batch is None:
+                    if self.datapipe is not None and data_iter is None:
+                        # the pipe hands over a full global batch, usually
+                        # already staged on the mesh by the prefetch thread
+                        batch, placed = self.datapipe.next_global_batch()
+                    else:
+                        it = data_iter or self._train_iter()
+                        parts = [next(it) for _ in range(
+                            self.gradient_accumulation_steps())]
+                        batch = jax.tree.map(
+                            lambda *xs: np.concatenate(xs, axis=0), *parts)
+                if not placed:
+                    batch = self._place_batch(batch)
+                batch = self._pack_pld(batch)
+                rng = self._rng_args()
+                lr = np.float32(self._current_lr())
+            if wall:
+                self._timer_start("train_batch")
+            self.tput_timer.start()
+            if self._layer_collector is not None:
+                self._layer_collector.clear()
+            with trace_span("engine/train_batch/dispatch",
+                            lane="engine") as _disp_sp:
+                if self._offload is not None:
+                    loss, grads, gnorm, finite = self._offload_grads_fn()(
+                        self.state, batch, rng
+                    )
+                    metrics = self._offload_apply(grads, gnorm, finite, loss)
+                elif self.store_gradients:
+                    # unfused route so the grads are observable (reference
+                    # engine.py:1156 clones p.grad at step time)
+                    loss, grads = self._batch_grads_fn()(
+                        self.state, batch, rng)
+                    self._store_grads(grads)
+                    new_state, metrics = self._apply_update_fn()(
+                        self.state, grads, lr,
+                        np.float32(self.gradient_accumulation_steps()),
+                    )
+                    metrics = dict(metrics, loss=loss)
+                    self.state = new_state
                 else:
-                    step_args = (self.state, batch, lr, rng)
-                    new_state, metrics = fn(*step_args)
-                step_fn = fn
-                self.state = new_state
-            if ci is not None and step_fn is not None:
-                # perf doctor is opt-in precisely because of this sync:
-                # per-step MFU needs the real wall time, so the step
-                # result is blocked on INSIDE the span (the default
-                # path stays fully async — ThroughputTimer only syncs
-                # on reporting steps)
-                jax.block_until_ready(metrics["loss"])
-                _wall = time.perf_counter() - _t0
-                ci.observe("engine/train_step", step_fn, step_args)
-                _stats = ci.note_step("engine/train_step", _wall)
-                if _stats is not None:
-                    _tb_sp.note(mfu=round(_stats["mfu"], 6),
-                                tflops=round(_stats["tflops"], 4),
-                                verdict=_stats["verdict"])
+                    fn = self._train_batch_fn()
+                    if wd is not None:
+                        wd.watch("engine/train_step", fn)
+                    if self.comm is not None:
+                        step_args = (self.state, self._comm_state, batch,
+                                     lr, rng)
+                        new_state, self._comm_state, metrics = fn(*step_args)
+                        self.comm.record_reduction_counters()
+                    else:
+                        step_args = (self.state, batch, lr, rng)
+                        new_state, metrics = fn(*step_args)
+                    step_fn = fn
+                    self.state = new_state
+                if ci is not None and step_fn is not None:
+                    # perf doctor is opt-in precisely because of this
+                    # sync: per-step MFU needs the real wall time, so the
+                    # step result is blocked on INSIDE the span (the
+                    # default path stays fully async — ThroughputTimer
+                    # only syncs on reporting steps)
+                    jax.block_until_ready(metrics["loss"])
+                    _wall = _disp_sp.elapsed_s()
+                    ci.observe("engine/train_step", step_fn, step_args)
+                    _stats = ci.note_step("engine/train_step", _wall)
+                    if _stats is not None:
+                        _tb_sp.note(mfu=round(_stats["mfu"], 6),
+                                    tflops=round(_stats["tflops"], 4),
+                                    verdict=_stats["verdict"])
             if mw is not None:
                 mw.annotate(_tb_sp, "train_batch")
-        if self._layer_collector is not None:
-            # jax.debug.callback taps inside the layer scan are silently
-            # dropped once the scan is linearized under grad, so the
-            # train step itself can never surface them; replay the same
-            # (packed) batch and rng through the forward-only program,
-            # where the taps do fire — forward hooks observe forward
-            # activations, matching the reference semantics
-            self._forward_only_fn()(self.state, batch, rng)
-        if wd is not None:
-            # the train step must compile once (after sharding commits,
-            # see __init__) and stay compiled; cache growth past the warm
-            # baseline means a shape/dtype leaked into the trace
-            if self._wd_warmup_left:
-                self._wd_warmup_left -= 1
-            else:
-                wd.observe(step=self.global_steps)
-        self.micro_steps += self.gradient_accumulation_steps()
-        self._after_optimizer_step(metrics)
-        self.tput_timer.stop(global_step=True, sync_with=metrics["loss"])
+            if self._layer_collector is not None:
+                # jax.debug.callback taps inside the layer scan are
+                # silently dropped once the scan is linearized under grad,
+                # so the train step itself can never surface them; replay
+                # the same (packed) batch and rng through the forward-only
+                # program, where the taps do fire — forward hooks observe
+                # forward activations, matching the reference semantics
+                self._forward_only_fn()(self.state, batch, rng)
+            if wd is not None:
+                # the train step must compile once (after sharding
+                # commits, see __init__) and stay compiled; cache growth
+                # past the warm baseline means a shape/dtype leaked into
+                # the trace
+                if self._wd_warmup_left:
+                    self._wd_warmup_left -= 1
+                else:
+                    wd.observe(step=self.global_steps)
+            with trace_span("engine/train_batch/after", lane="engine"):
+                self.micro_steps += self.gradient_accumulation_steps()
+                self._after_optimizer_step(metrics)
+                self.tput_timer.stop(global_step=True,
+                                     sync_with=metrics["loss"])
         if wall:
             self.timers("train_batch").stop(sync_with=metrics["loss"])
             self._wall_steps = getattr(self, "_wall_steps", 0) + 1
@@ -1649,11 +1678,11 @@ class Engine(ConfigAccessorsMixin):
         def build():
             gas = self.gradient_accumulation_steps()
 
-            def fn(state, batch, rng):
+            def ds_batch_grads(state, batch, rng):
                 rng = self._fold_rng(rng)
                 return self._batch_grads(state, batch, rng, gas)
 
-            return jax.jit(fn)
+            return jax.jit(ds_batch_grads)
 
         return self._get_compiled("batch_grads", build)
 
@@ -1747,7 +1776,11 @@ class Engine(ConfigAccessorsMixin):
     def _fully_replicate(self, tree):
         """All-gather a sharded pytree so each process holds a full copy."""
         reps = jax.tree.map(lambda _: NamedSharding(self.mesh, P()), tree)
-        return jax.jit(lambda t: t, out_shardings=reps)(tree)
+
+        def ds_replicate(t):
+            return t
+
+        return jax.jit(ds_replicate, out_shardings=reps)(tree)
 
     def _global_rows(self) -> int:
         """Rows consumed per optimizer step (micro * dp * gas) — the unit

@@ -49,12 +49,17 @@ from ..models.generation import apply_with_cache, init_cache, \
     prep_sampling_logits
 from ..models.gpt import GPTConfig, decoder_block, layer_norm
 from ..models.speculative import engine_sample_key
-from ..monitor import get_monitor, init_monitor
-from ..monitor.tracer import trace_counter, trace_instant, trace_span
+from ..monitor import get_monitor, init_monitor, install_compile_listener
+from ..monitor.tracer import (
+    RID_SEP,
+    trace_counter,
+    trace_instant,
+    trace_span,
+)
 from ..utils.logging import logger
 from .config import ServingConfig
 from .kv_cache import NULL_BLOCK, PagedKVCache, blocks_needed, paged_attend
-from .metrics import DECODE_TIMER, PREFILL_TIMER, ServingMetrics
+from .metrics import ServingMetrics
 from .scheduler import Request, Scheduler
 
 
@@ -139,16 +144,17 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig):
         top_k = None  # full-vocab top-k is a no-op filter
 
     @partial(jax.jit, donate_argnums=(1, 2))
-    def decode_step(params, k_pool, v_pool, tables, lengths, tokens,
-                    temps, seeds, counts):
+    def ds_decode_step(params, k_pool, v_pool, tables, lengths, tokens,
+                       temps, seeds, counts):
         cdt = cfg.dtype
         N = tokens.shape[0]
-        wte = params["embed"]["wte"].astype(cdt)
-        x = jnp.take(wte, tokens, axis=0)[:, None, :]       # (N, 1, D)
-        positions = lengths[:, None]                        # (N, 1)
-        if not cfg.rotary:
-            x = x + jnp.take(params["embed"]["wpe"], positions,
-                             axis=0).astype(cdt)
+        with jax.named_scope("ds.embed"):
+            wte = params["embed"]["wte"].astype(cdt)
+            x = jnp.take(wte, tokens, axis=0)[:, None, :]   # (N, 1, D)
+            positions = lengths[:, None]                    # (N, 1)
+            if not cfg.rotary:
+                x = x + jnp.take(params["embed"]["wpe"], positions,
+                                 axis=0).astype(cdt)
         wblk = tables[jnp.arange(N), lengths // scfg.block_size]
         woff = lengths % scfg.block_size
 
@@ -163,27 +169,28 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig):
         x, (k_new, v_new) = jax.lax.scan(
             scan_body, x, (params["layers"], k_pool, v_pool)
         )
-        x = layer_norm(x, params["final_ln"]["scale"],
-                       params["final_ln"]["bias"], cfg.layernorm_eps)
-        if cfg.tie_embeddings:
-            logits = x @ params["embed"]["wte"].astype(cdt).T
-        else:
-            logits = x @ params["lm_head"].astype(cdt)
-        logits = logits[:, 0]                               # (N, V)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        l32 = logits.astype(jnp.float32) / jnp.maximum(
-            temps, 1e-6)[:, None]
-        if top_k is not None:
-            kth = jax.lax.top_k(l32, top_k)[0][..., -1:]
-            l32 = jnp.where(l32 < kth, -1e30, l32)
-        keys = jax.vmap(request_sample_key)(seeds, counts)
-        sampled = jax.vmap(
-            lambda k, row: jax.random.categorical(k, row)
-        )(keys, l32).astype(jnp.int32)
-        nxt = jnp.where(temps > 0.0, sampled, greedy)
+        with jax.named_scope("ds.decode/sample"):
+            x = layer_norm(x, params["final_ln"]["scale"],
+                           params["final_ln"]["bias"], cfg.layernorm_eps)
+            if cfg.tie_embeddings:
+                logits = x @ params["embed"]["wte"].astype(cdt).T
+            else:
+                logits = x @ params["lm_head"].astype(cdt)
+            logits = logits[:, 0]                           # (N, V)
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            l32 = logits.astype(jnp.float32) / jnp.maximum(
+                temps, 1e-6)[:, None]
+            if top_k is not None:
+                kth = jax.lax.top_k(l32, top_k)[0][..., -1:]
+                l32 = jnp.where(l32 < kth, -1e30, l32)
+            keys = jax.vmap(request_sample_key)(seeds, counts)
+            sampled = jax.vmap(
+                lambda k, row: jax.random.categorical(k, row)
+            )(keys, l32).astype(jnp.int32)
+            nxt = jnp.where(temps > 0.0, sampled, greedy)
         return nxt, k_new, v_new
 
-    return decode_step
+    return ds_decode_step
 
 
 # ------------------------------------------------------------------ #
@@ -208,6 +215,9 @@ class _ServingBase:
             self.telemetry = get_monitor()
         registry = (self.telemetry.registry
                     if self.telemetry is not None else None)
+        # the compile account by program name (monitor.compile_account)
+        # is kept whether or not a monitor is: a dict update per compile
+        install_compile_listener()
         self.metrics = ServingMetrics(scfg.num_slots, clock, monitor,
                                       registry, slo=scfg.slo)
         self._rid_counter = itertools.count()
@@ -289,11 +299,17 @@ class _ServingBase:
         n_done = len(self.sched.finished)
         with trace_span("serving/step", lane="serving", step=self._step_i):
             now = self.clock()
-            for req in self.sched.expire_timeouts(now):
+            with trace_span("serving/schedule", lane="serving",
+                            what="expire"):
+                expired = self.sched.expire_timeouts(now)
+            for req in expired:
                 self.metrics.record_finish(req, now)
             self._prefill_phase()
-            for _ in self.sched.ensure_decode_capacity(
-                    self._decode_window()):
+            with trace_span("serving/schedule", lane="serving",
+                            what="capacity"):
+                preempted = self.sched.ensure_decode_capacity(
+                    self._decode_window())
+            for _ in preempted:
                 self.metrics.record_preemption()
             trace_counter("serving/load", {
                 "queued": len(self.sched.queue),
@@ -301,8 +317,9 @@ class _ServingBase:
             }, lane="serving")
             if self._has_decodable():
                 self._decode_all()
-        self._step_i += 1
-        self.metrics.export(self._step_i)
+            self._step_i += 1
+            with trace_span("serving/export", lane="serving"):
+                self.metrics.export(self._step_i)
         return self.sched.finished[n_done:]
 
     def run(self, max_steps: Optional[int] = None) -> Dict[str, List[int]]:
@@ -340,8 +357,19 @@ class _ServingBase:
         hold slots), only NEW admissions stop."""
         if self._draining:
             return
-        while (adm := self.sched.pop_admissible()) is not None:
+        while (adm := self._pop_admissible()) is not None:
             self._admit_one(*adm)
+
+    def _pop_admissible(self):
+        """The scheduler's admission decision under its own span, apart
+        from the prefill it admits."""
+        with trace_span("serving/schedule", lane="serving", what="admit"):
+            adm = self.sched.pop_admissible()
+        if adm is not None:
+            req = adm[1]
+            if req.admissions == 1:
+                self.metrics.record_queue_wait(req.admit_t - req.arrival_t)
+        return adm
 
     def _has_decodable(self) -> bool:
         """Whether any slot has a pending token to decode this step
@@ -398,19 +426,24 @@ class ServingEngine(_ServingBase):
         super().__init__(scfg, Scheduler(scfg, self.kv.allocator, clock),
                          clock, monitor, monitor_config)
         self._decode_step = make_decode_step(cfg, scfg)
+
         # retraces once per prefill bucket (toks.shape[1] varies)
-        self._prefill_step = jax.jit(
-            lambda params, toks: apply_with_cache(
+        def ds_prefill(params, toks):
+            return apply_with_cache(
                 cfg, params, toks,
-                init_cache(cfg, toks.shape[0], toks.shape[1]), 0))
+                init_cache(cfg, toks.shape[0], toks.shape[1]), 0)
+
         # suffix/chunked prefill over a gathered staging cache: the write
         # offset is TRACED, so one compile serves every (matched, chunk)
         # position and it retraces only per (chunk len, staging len)
         # shape pair; staging buffers are donated chunk to chunk
-        self._suffix_prefill = jax.jit(
-            lambda params, toks, kc, vc, offset: apply_with_cache(
-                cfg, params, toks, {"k": kc, "v": vc}, offset),
-            donate_argnums=(2, 3))
+        def ds_suffix_prefill(params, toks, kc, vc, offset):
+            return apply_with_cache(
+                cfg, params, toks, {"k": kc, "v": vc}, offset)
+
+        self._prefill_step = jax.jit(ds_prefill)
+        self._suffix_prefill = jax.jit(ds_suffix_prefill,
+                                       donate_argnums=(2, 3))
         # slot -> in-flight chunked-prefill state (staging cache, cursor)
         self._chunking: Dict[int, dict] = {}
         self._prefill_spent = 0   # prompt tokens prefilled this step
@@ -486,7 +519,11 @@ class ServingEngine(_ServingBase):
                 if dp > 1 and n % dp == 0 else P())
         return jax.device_put(x, NamedSharding(self.mesh, spec))
 
-    # compile counters (tests assert decode compiles exactly once)
+    # compile counters (tests assert decode compiles exactly once). Each
+    # is ONE jitted callable's cache size: none sees the scatter of the
+    # prefilled pages (kv_cache.ds_scatter_prefill_pages, once a prefill
+    # bucket), the page gather, or what eager operations lower. The
+    # whole account, by program name, is monitor.compile_account().
     @property
     def decode_compile_count(self) -> int:
         return getattr(self._decode_step, "_cache_size", lambda: -1)()
@@ -559,7 +596,7 @@ class ServingEngine(_ServingBase):
         if self._draining:
             return
         while self._budget_ok() and \
-                (adm := self.sched.pop_admissible()) is not None:
+                (adm := self._pop_admissible()) is not None:
             self._admit_one(*adm)
 
     def _has_decodable(self) -> bool:
@@ -634,19 +671,23 @@ class ServingEngine(_ServingBase):
                 cm = trace_span("serving/prefill_chunk", lane="serving",
                                 rid=req.rid, chunk=c, tokens=hi - lo)
             with cm as _sp:
-                timer = self.metrics.timers(PREFILL_TIMER)
-                timer.safe_start()
-                toks = np.zeros((1, chunk), np.int32)
-                toks[0, :hi - lo] = suffix[lo:hi]
-                _pargs = (self.params, jnp.asarray(toks), state["k"],
-                          state["v"], state["m"] + lo)
-                logits, cache = self._suffix_prefill(*_pargs)
+                with trace_span("serving/prefill/pack", lane="serving"):
+                    toks = np.zeros((1, chunk), np.int32)
+                    toks[0, :hi - lo] = suffix[lo:hi]
+                    _pargs = (self.params, jnp.asarray(toks), state["k"],
+                              state["v"], state["m"] + lo)
+                with trace_span("serving/prefill/dispatch",
+                                lane="serving"):
+                    logits, cache = self._suffix_prefill(*_pargs)
                 state["k"], state["v"] = cache["k"], cache["v"]
                 if final:
-                    self._finish_staged(req, state)
-                    tok = self._pick_token(logits[0, hi - lo - 1], req)
+                    with trace_span("serving/prefill/scatter",
+                                    lane="serving"):
+                        self._finish_staged(req, state)
+                    with trace_span("serving/prefill/pick",
+                                    lane="serving"):
+                        tok = self._pick_token(logits[0, hi - lo - 1], req)
                     req.generated.append(tok)
-                timer.stop(sync_with=self.kv.k if final else state["k"])
                 tel = self.telemetry
                 if tel is not None:
                     if tel.cost_index is not None:
@@ -707,19 +748,24 @@ class ServingEngine(_ServingBase):
         bucket = self.scfg.bucket_for(L)
         with trace_span("serving/prefill", lane="serving", rid=req.rid,
                         slot=slot, ctx_len=L, bucket=bucket) as _sp:
-            timer = self.metrics.timers(PREFILL_TIMER)
-            timer.safe_start()
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :L] = ctx
-            _pargs = (self.params, jnp.asarray(toks))
-            logits, cache = self._prefill_step(*_pargs)
-            # admission allocated headroom for the first decode write;
-            # only the context's own pages carry prefill data
-            data_blocks = blocks[:blocks_needed(L, self.scfg.block_size)]
-            self.kv.write_prefill(cache["k"], cache["v"], data_blocks, L)
-            tok = self._pick_token(logits[0, L - 1], req)
+            with trace_span("serving/prefill/pack", lane="serving"):
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :L] = ctx
+                _pargs = (self.params, jnp.asarray(toks))
+            with trace_span("serving/prefill/dispatch", lane="serving"):
+                logits, cache = self._prefill_step(*_pargs)
+            with trace_span("serving/prefill/scatter", lane="serving"):
+                # admission allocated headroom for the first decode
+                # write; only the context's own pages carry prefill data
+                data_blocks = blocks[:blocks_needed(L,
+                                                    self.scfg.block_size)]
+                self.kv.write_prefill(cache["k"], cache["v"],
+                                      data_blocks, L)
+            with trace_span("serving/prefill/pick", lane="serving"):
+                # the argmax is read back: the host waits here for the
+                # prefill and its scatter
+                tok = self._pick_token(logits[0, L - 1], req)
             req.generated.append(tok)
-            timer.stop(sync_with=self.kv.k)
             tel = self.telemetry
             if tel is not None:
                 if tel.cost_index is not None:
@@ -751,28 +797,31 @@ class ServingEngine(_ServingBase):
         The caller owns the surrounding span/metrics — this is both the
         whole decode phase (speculation off) and the fallback program
         for non-speculating slots (speculation on)."""
-        N = self.scfg.num_slots
-        tables = np.zeros((N, self.scfg.blocks_per_slot), np.int32)
-        lengths = np.zeros(N, np.int32)
-        tokens = np.zeros(N, np.int32)
-        temps = np.zeros(N, np.float32)
-        seeds = np.zeros(N, np.int32)
-        counts = np.zeros(N, np.int32)
-        for s, req in active:
-            tables[s] = self.sched.slot_table_row(s)
-            lengths[s] = req.cached_len
-            tokens[s] = req.pending_token
-            temps[s] = req.temperature
-            seeds[s] = req.seed
-            counts[s] = len(req.generated)
-        _place = (self._place_slot_array if self.mesh is not None
-                  else jnp.asarray)
-        _dargs = (self.params, self.kv.k, self.kv.v, _place(tables),
-                  _place(lengths), _place(tokens),
-                  _place(temps), _place(seeds),
-                  _place(counts))
-        nxt, self.kv.k, self.kv.v = self._decode_step(*_dargs)
-        nxt = np.asarray(nxt)                   # device sync
+        with trace_span("serving/decode/pack", lane="serving"):
+            N = self.scfg.num_slots
+            tables = np.zeros((N, self.scfg.blocks_per_slot), np.int32)
+            lengths = np.zeros(N, np.int32)
+            tokens = np.zeros(N, np.int32)
+            temps = np.zeros(N, np.float32)
+            seeds = np.zeros(N, np.int32)
+            counts = np.zeros(N, np.int32)
+            for s, req in active:
+                tables[s] = self.sched.slot_table_row(s)
+                lengths[s] = req.cached_len
+                tokens[s] = req.pending_token
+                temps[s] = req.temperature
+                seeds[s] = req.seed
+                counts[s] = len(req.generated)
+            _place = (self._place_slot_array if self.mesh is not None
+                      else jnp.asarray)
+            _dargs = (self.params, self.kv.k, self.kv.v, _place(tables),
+                      _place(lengths), _place(tokens),
+                      _place(temps), _place(seeds),
+                      _place(counts))
+        with trace_span("serving/decode/dispatch", lane="serving"):
+            nxt, self.kv.k, self.kv.v = self._decode_step(*_dargs)
+        with trace_span("serving/decode/wait", lane="serving"):
+            nxt = np.asarray(nxt)               # device sync
         self._last_dargs = _dargs
         return nxt
 
@@ -785,37 +834,35 @@ class ServingEngine(_ServingBase):
         active = self._active_decodable()
         with trace_span("serving/decode", lane="serving",
                         n_active=len(active),
-                        rids=",".join(r.rid for _, r in active)) as _sp:
-            _t0 = time.perf_counter()
-            timer = self.metrics.timers(DECODE_TIMER)
-            timer.safe_start()
+                        rids=RID_SEP.join(r.rid for _, r in active)) as _sp:
             nxt = self._dispatch_plain(active)
-            timer.stop()
             tel = self.telemetry
             if tel is not None:
                 if tel.cost_index is not None:
-                    # the sync above already happened, so this wall time
-                    # is real; the AOT re-lower never touches the decode
-                    # jit's cache (one-compile decode stays one-compile)
+                    # the sync above already happened, so the span's
+                    # length so far is real; the AOT re-lower never
+                    # touches the decode jit's cache (one-compile decode
+                    # stays one-compile)
+                    _wall = _sp.elapsed_s()
                     tel.cost_index.observe("serving/decode_step",
                                            self._decode_step,
                                            self._last_dargs)
                     _stats = tel.cost_index.note_step(
-                        "serving/decode_step", time.perf_counter() - _t0)
+                        "serving/decode_step", _wall)
                     if _stats is not None:
                         _sp.note(mfu=round(_stats["mfu"], 6),
                                  verdict=_stats["verdict"])
                 if tel.memwatch is not None:
                     tel.memwatch.annotate(_sp, "decode")
-        if self.telemetry is not None:
-            self.telemetry.watchdog.observe("serving/decode_step",
-                                            step=self._step_i)
-        self.metrics.record_decode_step(len(active), len(self.sched.queue),
-                                        self.clock())
-        for s, req in active:
-            req.cached_len += 1
-            req.generated.append(int(nxt[s]))
-            self._record_emitted(req, prefill=False)
+                tel.watchdog.observe("serving/decode_step",
+                                     step=self._step_i)
+            self.metrics.record_decode_step(
+                len(active), len(self.sched.queue), self.clock())
+            with trace_span("serving/decode/emit", lane="serving"):
+                for s, req in active:
+                    req.cached_len += 1
+                    req.generated.append(int(nxt[s]))
+                    self._record_emitted(req, prefill=False)
 
 
 # ------------------------------------------------------------------ #
@@ -875,21 +922,15 @@ class PipelineServingBridge(_ServingBase):
     def _admit_one(self, slot: int, req: Request, blocks) -> None:
         with trace_span("serving/prefill", lane="serving", rid=req.rid,
                         slot=slot, ctx_len=len(req.context)):
-            timer = self.metrics.timers(PREFILL_TIMER)
-            timer.safe_start()
             self._emit_next(req, prefill=True)
-            timer.stop()
 
     def _decode_all(self) -> None:
         active = list(self.sched.active)
         with trace_span("serving/decode", lane="serving",
                         n_active=len(active),
-                        rids=",".join(r.rid for r in active)):
-            timer = self.metrics.timers(DECODE_TIMER)
-            timer.safe_start()
+                        rids=RID_SEP.join(r.rid for r in active)):
             for req in active:
                 self._emit_next(req, prefill=False)
-            timer.stop()
         self.metrics.record_decode_step(len(active),
                                         len(self.sched.queue),
                                         self.clock())

@@ -338,10 +338,12 @@ class PagedKVCache:
         self.k = jnp.zeros(shape, cfg.dtype)
         self.v = jnp.zeros(shape, cfg.dtype)
         self.allocator = BlockAllocator(nb)
-        self._write_prefill = jax.jit(_scatter_prefill_pages,
+        # both retrace once per page count of their dense side: the
+        # scatter once a prefill bucket, the gather once a staging-cache
+        # bucket (monitor.compile_account() counts them by name)
+        self._write_prefill = jax.jit(ds_scatter_prefill_pages,
                                       donate_argnums=(0, 1))
-        # retraces once per page count (one per staging-cache bucket)
-        self._gather_pages = jax.jit(_gather_prefix_pages)
+        self._gather_pages = jax.jit(ds_gather_pages)
 
     def write_prefill(self, k_dense, v_dense, blocks: List[int],
                       length: int) -> None:
@@ -383,7 +385,7 @@ class PagedKVCache:
         return self._gather_pages(self.k, self.v, idx)
 
 
-def _scatter_prefill_pages(k_pool, v_pool, k_dense, v_dense, idx):
+def ds_scatter_prefill_pages(k_pool, v_pool, k_dense, v_dense, idx):
     """(L, 1, bucket, Hkv, Dh) dense prefill cache -> pool pages at idx."""
     L, _, bucket, Hkv, Dh = k_dense.shape
     bs = k_pool.shape[2]
@@ -395,7 +397,7 @@ def _scatter_prefill_pages(k_pool, v_pool, k_dense, v_dense, idx):
             v_pool.at[:, idx].set(pages_v.astype(v_pool.dtype)))
 
 
-def _gather_prefix_pages(k_pool, v_pool, idx):
+def ds_gather_pages(k_pool, v_pool, idx):
     """Pool pages at idx -> dense (L, 1, n_pages * bs, Hkv, Dh) pair."""
     L, _, bs, Hkv, Dh = k_pool.shape
     n = idx.shape[0]
@@ -425,28 +427,31 @@ def paged_attend_multi(k_pool_l, v_pool_l, q, k_new, v_new, tables,
     N, T = q.shape[0], q.shape[1]
     Hq, Dh = q.shape[2], q.shape[3]
     cdt = k_pool_l.dtype
-    # duplicate (null block, t) targets across idle lanes may race;
-    # block 0 is never read unmasked, so last-writer-wins is fine
-    k_pool_l = k_pool_l.at[write_blocks, write_offs].set(
-        k_new.astype(cdt))
-    v_pool_l = v_pool_l.at[write_blocks, write_offs].set(
-        v_new.astype(cdt))
+    with jax.named_scope("ds.decode/kv_write"):
+        # duplicate (null block, t) targets across idle lanes may race;
+        # block 0 is never read unmasked, so last-writer-wins is fine
+        k_pool_l = k_pool_l.at[write_blocks, write_offs].set(
+            k_new.astype(cdt))
+        v_pool_l = v_pool_l.at[write_blocks, write_offs].set(
+            v_new.astype(cdt))
     bs = k_pool_l.shape[1]
     view = tables.shape[1] * bs
-    k_c = k_pool_l[tables].reshape(N, view, k_pool_l.shape[2], Dh)
-    v_c = v_pool_l[tables].reshape(N, view, v_pool_l.shape[2], Dh)
+    with jax.named_scope("ds.decode/kv_gather"):
+        k_c = k_pool_l[tables].reshape(N, view, k_pool_l.shape[2], Dh)
+        v_c = v_pool_l[tables].reshape(N, view, v_pool_l.shape[2], Dh)
     Hkv = k_c.shape[2]
     rep = Hq // Hkv
-    qg = q.reshape(N, T, Hkv, rep, Dh)
-    scores = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k_c,
-                        preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(Dh)
-    key_pos = jnp.arange(view, dtype=jnp.int32)
-    q_pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    valid = key_pos[None, None, :] <= q_pos[:, :, None]   # (N, T, view)
-    scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    ctx = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v_c)
+    with jax.named_scope("ds.decode/attn"):
+        qg = q.reshape(N, T, Hkv, rep, Dh)
+        scores = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k_c,
+                            preferred_element_type=jnp.float32)
+        scores = scores / math.sqrt(Dh)
+        key_pos = jnp.arange(view, dtype=jnp.int32)
+        q_pos = lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        valid = key_pos[None, None, :] <= q_pos[:, :, None]  # (N, T, view)
+        scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        ctx = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v_c)
     return ctx.reshape(N, T, Hq, Dh), k_pool_l, v_pool_l
 
 
@@ -468,27 +473,30 @@ def paged_attend(k_pool_l, v_pool_l, q, k_new, v_new, tables, lengths,
     N = q.shape[0]
     Hq, Dh = q.shape[2], q.shape[3]
     cdt = k_pool_l.dtype
-    # write the new row: idle slots target (null block, 0) by construction
-    k_pool_l = k_pool_l.at[write_block, write_off].set(
-        k_new[:, 0].astype(cdt))
-    v_pool_l = v_pool_l.at[write_block, write_off].set(
-        v_new[:, 0].astype(cdt))
+    with jax.named_scope("ds.decode/kv_write"):
+        # the new row: idle slots target (null block, 0) by construction
+        k_pool_l = k_pool_l.at[write_block, write_off].set(
+            k_new[:, 0].astype(cdt))
+        v_pool_l = v_pool_l.at[write_block, write_off].set(
+            v_new[:, 0].astype(cdt))
     # gather each slot's pages into a contiguous logical view
     bs = k_pool_l.shape[1]
     view = tables.shape[1] * bs
-    k_c = k_pool_l[tables].reshape(N, view, k_pool_l.shape[2], Dh)
-    v_c = v_pool_l[tables].reshape(N, view, v_pool_l.shape[2], Dh)
+    with jax.named_scope("ds.decode/kv_gather"):
+        k_c = k_pool_l[tables].reshape(N, view, k_pool_l.shape[2], Dh)
+        v_c = v_pool_l[tables].reshape(N, view, v_pool_l.shape[2], Dh)
     Hkv = k_c.shape[2]
     rep = Hq // Hkv
-    qg = q.reshape(N, 1, Hkv, rep, Dh)
-    scores = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k_c,
-                        preferred_element_type=jnp.float32)
-    scores = scores / math.sqrt(Dh)
-    # valid keys: logical positions 0..length inclusive (the row written
-    # above sits at position == length)
-    key_pos = jnp.arange(view, dtype=jnp.int32)
-    valid = key_pos[None, :] <= lengths[:, None]          # (N, view)
-    scores = jnp.where(valid[:, None, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    ctx = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v_c)
+    with jax.named_scope("ds.decode/attn"):
+        qg = q.reshape(N, 1, Hkv, rep, Dh)
+        scores = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k_c,
+                            preferred_element_type=jnp.float32)
+        scores = scores / math.sqrt(Dh)
+        # valid keys: logical positions 0..length inclusive (the row
+        # written above sits at position == length)
+        key_pos = jnp.arange(view, dtype=jnp.int32)
+        valid = key_pos[None, :] <= lengths[:, None]      # (N, view)
+        scores = jnp.where(valid[:, None, None, None, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        ctx = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v_c)
     return ctx.reshape(N, 1, Hq, Dh), k_pool_l, v_pool_l

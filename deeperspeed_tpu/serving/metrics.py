@@ -2,10 +2,11 @@
 tokens/s.
 
 Collection is host-side and allocation-light (floats appended to lists);
-export goes through the same surfaces the training engine uses —
-``utils/timer.SynchronizedWallClockTimer`` for the prefill/decode wall
-clocks and ``utils/tensorboard.TensorBoardMonitor`` for scalar series —
-so serving shows up in the exact dashboards training already feeds.
+export goes through ``utils/tensorboard.TensorBoardMonitor`` for scalar
+series, the surface the training engine uses, so serving shows up in the
+dashboards training already feeds. The prefill and decode wall clocks
+are the ``serving/prefill`` and ``serving/decode`` spans
+(monitor/tracer.py), not timers of this module.
 """
 
 import time
@@ -16,11 +17,6 @@ import numpy as np
 from ..monitor.metrics import DEFAULT_LATENCY_BUCKETS, MetricsRegistry
 from ..monitor.tracer import trace_instant
 from ..utils.tensorboard import TensorBoardMonitor
-from ..utils.timer import SynchronizedWallClockTimer
-
-# timer names (appear in SynchronizedWallClockTimer.log output)
-PREFILL_TIMER = "serving/prefill"
-DECODE_TIMER = "serving/decode"
 
 
 class SLOTracker:
@@ -138,7 +134,7 @@ class ServingMetrics:
         self.monitor = monitor
         self.registry = registry
         self.slo_tracker = SLOTracker(slo, registry)
-        self.timers = SynchronizedWallClockTimer()
+        self.queue_wait_s: List[float] = []   # admit less arrival
         self.ttft_s: List[float] = []
         self.tpot_s: List[float] = []
         self.queue_depth: List[int] = []
@@ -189,6 +185,10 @@ class ServingMetrics:
             self._g_occ = registry.gauge(
                 "serving_slot_occupancy",
                 "Active slots / num_slots at the last decode step.")
+            self._h_queue_wait = registry.histogram(
+                "serving_queue_wait_seconds",
+                "Arrival to first admission (the wait in the queue).",
+                buckets=DEFAULT_LATENCY_BUCKETS)
             self._h_ttft = registry.histogram(
                 "serving_ttft_seconds", "Time to first token.",
                 buckets=DEFAULT_LATENCY_BUCKETS)
@@ -200,6 +200,12 @@ class ServingMetrics:
     # ------------------------------------------------------------ #
     # recording
     # ------------------------------------------------------------ #
+
+    def record_queue_wait(self, wait_s: float) -> None:
+        """A request's first admission: how long it stood in the queue."""
+        self.queue_wait_s.append(wait_s)
+        if self.registry is not None:
+            self._h_queue_wait.observe(wait_s)
 
     def record_prefill(self, now: float,
                        ttft_s: Optional[float] = None) -> None:
@@ -369,6 +375,7 @@ class ServingMetrics:
             "elapsed_s": self.elapsed_s,
             "tokens_per_sec": self.total_generated / self.elapsed_s
             if self.elapsed_s else 0.0,
+            "queue_wait_s": _percentiles(self.queue_wait_s),
             "ttft_s": _percentiles(self.ttft_s),
             "tpot_s": _percentiles(self.tpot_s),
             "slot_occupancy": float(occ.mean()) if occ.size else 0.0,

@@ -71,6 +71,7 @@ class Request:
     # a slot — the per-request share of the paged pool
     kv_block_s: float = 0.0
     kv_accrue_t: Optional[float] = None
+    admit_t: Optional[float] = None        # first time it left the queue
     first_token_t: Optional[float] = None
     last_token_t: Optional[float] = None   # progress clock for timeouts
     finish_t: Optional[float] = None
@@ -222,6 +223,8 @@ class Scheduler:
         req.cached_len = len(req.context)
         req.admissions += 1
         req.kv_accrue_t = self.clock()
+        if req.admit_t is None:
+            req.admit_t = req.kv_accrue_t
         req.prefix_matched = matched
         req.prefix_shared_blocks = len(full)
         req.prefix_src = partial

@@ -37,8 +37,7 @@ from ...utils.logging import logger
 from ..config import ServingConfig, SpeculativeConfig
 from ..kv_cache import NULL_BLOCK, PagedKVCache, PrefixCache, \
     blocks_needed
-from ..metrics import DECODE_TIMER
-from ...monitor.tracer import trace_instant, trace_span
+from ...monitor.tracer import RID_SEP, trace_instant, trace_span
 from .steps import make_draft_step, make_verify_step
 
 
@@ -119,10 +118,11 @@ class SpecRuntime:
         self.slot_blocks: List[List[int]] = [[] for _ in range(n_slots)]
         self._draft_step = make_draft_step(self.dcfg, scfg, self.K)
         self._verify_step = make_verify_step(cfg, scfg, self.K)
-        self._suffix = jax.jit(
-            lambda p, toks, kc, vc, off: apply_with_cache(
-                self.dcfg, p, toks, {"k": kc, "v": vc}, off),
-            donate_argnums=(2, 3))
+        def ds_drafter_prefill(p, toks, kc, vc, off):
+            return apply_with_cache(
+                self.dcfg, p, toks, {"k": kc, "v": vc}, off)
+
+        self._suffix = jax.jit(ds_drafter_prefill, donate_argnums=(2, 3))
         if engine.telemetry is not None:
             # all three decode-path programs are watched; draft/verify
             # compile once each (static shapes over the full slot array)
@@ -342,9 +342,7 @@ class SpecRuntime:
         draft_s = verify_s = 0.0
         with trace_span("serving/decode", lane="serving",
                         n_active=len(active),
-                        rids=",".join(r.rid for _, r in active)) as _sp:
-            timer = eng.metrics.timers(DECODE_TIMER)
-            timer.safe_start()
+                        rids=RID_SEP.join(r.rid for _, r in active)) as _sp:
             if spec_lanes:
                 _t0 = time.perf_counter()
                 drafts = self._dispatch_draft(spec_lanes)
@@ -360,7 +358,6 @@ class SpecRuntime:
                               dur_us=round(verify_s * 1e6, 1))
             if fallback:
                 nxt = eng._dispatch_plain(fallback)
-            timer.stop()
             tel = eng.telemetry
             if tel is not None and tel.memwatch is not None:
                 tel.memwatch.annotate(_sp, "decode")

@@ -92,8 +92,8 @@ def make_draft_step(cfg: GPTConfig, scfg: ServingConfig, draft_k: int):
     top_k = _resolve_top_k(cfg, scfg)
 
     @partial(jax.jit, donate_argnums=(1, 2))
-    def draft_step(params, k_pool, v_pool, tables, lengths, tokens,
-                   temps, seeds, counts):
+    def ds_draft_step(params, k_pool, v_pool, tables, lengths, tokens,
+                      temps, seeds, counts):
         cdt = cfg.dtype
         N = tokens.shape[0]
         wte = params["embed"]["wte"].astype(cdt)
@@ -130,7 +130,7 @@ def make_draft_step(cfg: GPTConfig, scfg: ServingConfig, draft_k: int):
             jnp.arange(draft_k + 1, dtype=jnp.int32))
         return drafts[:draft_k].T, k_pool, v_pool
 
-    return draft_step
+    return ds_draft_step
 
 
 def _paged_block_multi(cfg: GPTConfig, x, layer_params, k_l, v_l,
@@ -176,8 +176,8 @@ def make_verify_step(cfg: GPTConfig, scfg: ServingConfig, draft_k: int):
     top_k = _resolve_top_k(cfg, scfg)
 
     @partial(jax.jit, donate_argnums=(1, 2))
-    def verify_step(params, k_pool, v_pool, tables, lengths, tokens,
-                    temps, seeds, counts):
+    def ds_verify_step(params, k_pool, v_pool, tables, lengths, tokens,
+                       temps, seeds, counts):
         cdt = cfg.dtype
         N = tokens.shape[0]
         wte = params["embed"]["wte"].astype(cdt)
@@ -213,4 +213,4 @@ def make_verify_step(cfg: GPTConfig, scfg: ServingConfig, draft_k: int):
         return (n_acc.astype(jnp.int32), bonus.astype(jnp.int32),
                 k_pool, v_pool)
 
-    return verify_step
+    return ds_verify_step

@@ -550,3 +550,274 @@ def test_throughput_timer_zero_elapsed_does_not_divide_by_zero():
     finally:
         timer_mod.time.time = real_time
     assert tt.step_elapsed_time == 0.0
+
+
+# ------------------------------------------------------------------ #
+# the program's spans on the profiler's clock (one span, two sinks)
+# ------------------------------------------------------------------ #
+
+SERVING_CHILDREN = {
+    "serving/step": ("serving/schedule", "serving/prefill", "serving/decode",
+                     "serving/export"),
+    "serving/prefill": ("serving/prefill/pack", "serving/prefill/dispatch",
+                        "serving/prefill/scatter", "serving/prefill/pick"),
+    "serving/decode": ("serving/decode/pack", "serving/decode/dispatch",
+                       "serving/decode/wait", "serving/decode/emit"),
+}
+TRAIN_CHILDREN = ("engine/train_batch/feed", "engine/train_batch/dispatch",
+                  "engine/train_batch/after")
+SERVING_CONFIG = {"num_slots": 2, "num_blocks": 16, "block_size": 8,
+                  "max_seq_len": 64, "max_new_tokens": 4}
+
+
+def _profiler_events(path):
+    """(name, start ns, end ns, {argument: value}) of the host plane."""
+    import glob
+    import warnings
+
+    from jax.profiler import ProfileData
+
+    (pb,) = glob.glob(str(path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    out = []
+    with warnings.catch_warnings():
+        # reading an event's statistics warns once an event (jaxlib's
+        # binding of the type has no __module__)
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(pb).planes:
+            if plane.name.startswith("/device:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """A toy serving run and three toy training steps under ONE live
+    jax.profiler session, with no Tracer installed: the spans reach the
+    profiler on their own."""
+    assert get_tracer() is None
+    cfg, params = _serving_model()
+    eng = ServingEngine(cfg, params, SERVING_CONFIG)
+    engine, _, _, _ = deepspeed.initialize(
+        model=_loss_fn, model_parameters={"w": jnp.zeros((8, 2))},
+        config_params=_train_config({}))
+    x = jnp.asarray(np.random.RandomState(0).randn(8, 8).astype(np.float32))
+    y = jnp.asarray(np.random.RandomState(1).randn(8, 2).astype(np.float32))
+    path = tmp_path_factory.mktemp("xplane")
+    jax.profiler.start_trace(str(path))
+    try:
+        for i in range(3):
+            eng.submit([1 + i, 2, 3], max_new_tokens=3, request_id=f"r{i}")
+        eng.run()
+        for _ in range(3):
+            engine.train_batch(batch=(x, y))
+    finally:
+        jax.profiler.stop_trace()
+    return {"events": _profiler_events(path), "engine": eng}
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def _within(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+@pytest.mark.parametrize("parent", sorted(SERVING_CHILDREN))
+def test_profiler_session_holds_the_serving_spans_nested(profiled, parent):
+    events = profiled["events"]
+    parents = _named(events, parent)
+    assert parents, f"no {parent} span reached the profiler"
+    for child in SERVING_CHILDREN[parent]:
+        spans = _named(events, child)
+        assert spans, f"no {child} span reached the profiler"
+        for c in spans:
+            assert any(_within(c, p) for p in parents), (child, parent)
+
+
+def test_profiler_spans_carry_their_arguments(profiled):
+    events = profiled["events"]
+    steps = _named(events, "serving/step")
+    assert [s[3]["step"] for s in steps] == list(range(len(steps)))
+    assert {s[3]["what"] for s in _named(events, "serving/schedule")} \
+        == {"expire", "admit", "capacity"}
+    # a decode span of a step with several active slots names every one
+    from deeperspeed_tpu.monitor.tracer import RID_SEP
+
+    decodes = _named(events, "serving/decode")
+    assert max(d[3]["n_active"] for d in decodes) == 2
+    for d in decodes:
+        rids = d[3]["rids"].split(RID_SEP)
+        assert len(rids) == d[3]["n_active"]
+        assert set(rids) <= {"r0", "r1", "r2"}
+
+
+@pytest.mark.parametrize("rid", ["r0", "r1", "r2"])
+def test_one_requests_spans_share_its_rid(profiled, rid):
+    events = profiled["events"]
+    for name in ("req/submit", "serving/admit", "serving/prefill",
+                 "serving/finish"):
+        mine = [e for e in _named(events, name) if e[3].get("rid") == rid]
+        assert len(mine) == 1, (name, rid)
+    t = {n: next(e for e in _named(events, n) if e[3].get("rid") == rid)
+         for n in ("req/submit", "serving/admit", "serving/prefill",
+                   "serving/finish")}
+    assert t["req/submit"][1] <= t["serving/admit"][1] \
+        <= t["serving/prefill"][1] <= t["serving/finish"][1]
+
+
+def test_profiler_session_holds_the_training_spans_nested(profiled):
+    events = profiled["events"]
+    steps = _named(events, "engine/train_batch")
+    assert [s[3]["step"] for s in steps] == [0, 1, 2]
+    for step in steps:
+        kids = [next(c for c in _named(events, n) if _within(c, step))
+                for n in TRAIN_CHILDREN]
+        # feed, then the fused call, then the bookkeeping: in that order
+        assert kids[0][2] <= kids[1][1] and kids[1][2] <= kids[2][1]
+
+
+def test_no_session_no_tracer_records_nothing_and_queue_wait_fills(profiled):
+    assert get_tracer() is None
+    with trace_span("serving/step", lane="serving", step=0) as sp:
+        assert sp.note(mfu=1.0) is sp     # a no-op, not a recorder
+        assert sp.elapsed_s() >= 0.0
+    eng = profiled["engine"]
+    assert not hasattr(eng.metrics, "timers")
+    assert len(eng.metrics.queue_wait_s) == 3
+    assert all(w >= 0.0 for w in eng.metrics.queue_wait_s)
+    summary = eng.metrics.summary()["queue_wait_s"]
+    assert summary["max"] == max(eng.metrics.queue_wait_s)
+    for r in eng.sched.finished:
+        assert r.arrival_t <= r.admit_t <= r.first_token_t
+
+
+def test_queue_wait_counts_the_first_admission_only():
+    """Three requests on two slots: the third waits for a slot. Its wait
+    is admit less arrival on the engine's clock, and is in the registry."""
+    now = [0.0]
+    cfg, params = _serving_model()
+    eng = ServingEngine(cfg, params, SERVING_CONFIG, clock=lambda: now[0],
+                        monitor_config={"trace_enabled": False})
+    for i in range(3):
+        eng.submit([1 + i, 2, 3], max_new_tokens=2)
+    while eng.has_work():
+        now[0] += 1.0
+        eng.step()
+    assert eng.metrics.queue_wait_s[:2] == [1.0, 1.0]
+    assert eng.metrics.queue_wait_s[2] > 1.0
+    assert "serving_queue_wait_seconds_count 3" \
+        in eng.telemetry.registry.render()
+
+
+def test_span_and_ring_tracer_get_the_same_spans():
+    """With a Tracer installed the ring holds what the profiler gets."""
+    t = Tracer()
+    set_tracer(t)
+    cfg, params = _serving_model()
+    eng = ServingEngine(cfg, params, SERVING_CONFIG)
+    eng.submit([5, 6, 7], max_new_tokens=2, request_id="solo")
+    eng.run()
+    names = {e["name"] for e in t.events()}
+    for parent, kids in SERVING_CHILDREN.items():
+        assert {parent, *kids} <= names
+    assert validate_events(t.to_dict()["traceEvents"], strict=True) == []
+
+
+# ------------------------------------------------------------------ #
+# the compile account by program name
+# ------------------------------------------------------------------ #
+
+
+def _lowered(account, name):
+    return account.get(name, {}).get("lower", {}).get("count", 0)
+
+
+def test_compile_account_names_the_serving_programs_and_counts_shapes():
+    from deeperspeed_tpu.monitor import compile_account
+
+    # a width no other test of this file uses, so that nothing here is
+    # found already lowered in JAX's own caches
+    cfg = GPTConfig(vocab_size=89, n_layer=2, n_head=2, d_model=48,
+                    max_seq=64, remat=False, dtype=jnp.float32,
+                    attn_impl="xla")
+    params = make_gpt(cfg)[0](jax.random.PRNGKey(1))
+    before = compile_account()
+    eng = ServingEngine(cfg, params, SERVING_CONFIG)   # installs the listener
+    eng.submit([1, 2, 3], max_new_tokens=3)            # bucket 8: one page
+    eng.run()
+    one = compile_account()
+    for name in ("ds_decode_step", "ds_prefill", "ds_scatter_prefill_pages"):
+        assert _lowered(one, name) - _lowered(before, name) == 1, name
+        assert one[name]["trace"]["count"] >= 1
+        assert one[name]["compile"]["seconds"] > 0.0
+    assert eng.decode_compile_count == 1
+    # a prompt of another bucket holds another number of pages: the
+    # prefill and the scatter are lowered again, the decode step is not
+    eng.submit(list(range(1, 12)), max_new_tokens=2)   # bucket 16: two pages
+    eng.run()
+    two = compile_account()
+    assert _lowered(two, "ds_scatter_prefill_pages") \
+        - _lowered(one, "ds_scatter_prefill_pages") == 1
+    assert _lowered(two, "ds_prefill") - _lowered(one, "ds_prefill") == 1
+    assert _lowered(two, "ds_decode_step") == _lowered(one, "ds_decode_step")
+    # what an eager operation dispatches is summed under one row
+    assert _lowered(two, "eager") > 0
+    assert not any(n in two for n in ("convert_element_type",
+                                      "broadcast_in_dim"))
+
+
+def test_compile_account_names_the_train_step():
+    from deeperspeed_tpu.monitor import compile_account
+
+    before = compile_account()
+    engine, _, _, _ = deepspeed.initialize(
+        model=_loss_fn, model_parameters={"w": jnp.zeros((6, 3))},
+        config_params=_train_config({}))
+    x = jnp.ones((8, 6))
+    y = jnp.ones((8, 3))
+    for _ in range(3):
+        engine.train_batch(batch=(x, y))
+    after = compile_account()
+    assert _lowered(after, "ds_train_step") > _lowered(before, "ds_train_step")
+    assert not any("lambda" in n for n in after
+                   if _lowered(after, n) > _lowered(before, n))
+
+
+def test_compile_instants_name_the_program_and_phase():
+    t = Tracer()
+    set_tracer(t)
+    from deeperspeed_tpu.monitor import install_compile_listener
+
+    install_compile_listener()
+
+    def ds_probe_program(x):
+        return x * 3 + 1
+
+    jax.jit(ds_probe_program)(jnp.ones(7))
+    mine = [e["args"] for e in t.events() if e["name"] == "xla_compile"
+            and "ds_probe_program" in e["args"]["fun_name"]]
+    assert [a["phase"] for a in mine] == ["lower", "compile"]
+    assert all(a["fun_name"] == "jit(ds_probe_program)" for a in mine)
+
+
+def test_trace_event_names_lint_passes_with_the_new_names():
+    """The registry in monitor/validate.py and every call site agree, in
+    both directions, with the spans this file's tests look for."""
+    import os
+
+    from deeperspeed_tpu.analysis import astlint
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    rule = astlint.TraceEventNamesRule()
+    found = astlint.lint_paths(root, rules=[rule])
+    assert [f.to_dict() for f in found] == []
+    emitted = {n for n, exact, _, _ in
+               rule._emitted(astlint.collect_modules(root)) if exact}
+    for parent, kids in SERVING_CHILDREN.items():
+        assert {parent, *kids} <= emitted
+    assert set(TRAIN_CHILDREN) <= emitted
