@@ -25,8 +25,10 @@ counter). Prefill compiles once per length bucket.
 
 Decode math reuses ``models/gpt.decoder_block`` (the same layer the
 training forward and ``models/generation`` use) with a paged-cache
-``attend`` (serving/kv_cache.paged_attend), which is what makes greedy
-serving outputs token-identical to per-request ``make_generator`` calls.
+``attend`` (serving/kv_cache.paged_attend_rows), which is what makes
+greedy serving outputs token-identical to per-request ``make_generator``
+calls. The pool stays where it is: layers read it, and the step writes
+the new rows of all layers once, after the loop.
 
 ``PipelineServingBridge`` gives pipelined models (PipelineModule over a
 'pipe' mesh) the same submit/step/run surface by driving
@@ -58,7 +60,12 @@ from ..monitor.tracer import (
 )
 from ..utils.logging import logger
 from .config import ServingConfig
-from .kv_cache import NULL_BLOCK, PagedKVCache, blocks_needed, paged_attend
+from .kv_cache import (
+    NULL_BLOCK,
+    PagedKVCache,
+    blocks_needed,
+    decode_attend_for,
+)
 from .metrics import ServingMetrics
 from .scheduler import Request, Scheduler
 
@@ -100,17 +107,12 @@ def request_sample_key(seed: int, count: int):
 # ------------------------------------------------------------------ #
 
 
-def _paged_block(cfg: GPTConfig, x, layer_params, k_l, v_l, tables,
-                 lengths, wblk, woff, positions):
-    """One decoder layer over all slots' single new tokens, reading and
-    writing the paged pool. The layer math is gpt.decoder_block — only
-    the attention core differs (mirrors generation._cached_block)."""
-
-    def attend(q, k, v):
-        ctx, k2, v2 = paged_attend(k_l, v_l, q, k, v, tables, lengths,
-                                   wblk, woff)
-        return ctx, (k2, v2)
-
+def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend):
+    """One decoder layer over all slots' single new tokens. The layer
+    math is gpt.decoder_block — only the attention core differs (mirrors
+    generation._cached_block): ``attend(q, k, v) -> (ctx, kv)`` reads the
+    paged pool, and ``kv`` (what the caller keeps of the new token's keys
+    and values) comes back beside the layer's output."""
     moe_cfg = cfg.moe
     if moe_cfg is not None:
         from ..models.moe import moe_ffn
@@ -118,22 +120,26 @@ def _paged_block(cfg: GPTConfig, x, layer_params, k_l, v_l, tables,
         def mlp_fn(mlp_in):
             return moe_ffn(layer_params["moe"], mlp_in, moe_cfg)
 
-        x, ((k_l, v_l), _) = decoder_block(
+        x, (kv, _) = decoder_block(
             cfg, None, x, layer_params, positions, attend, mlp_fn=mlp_fn
         )
     else:
-        x, (k_l, v_l) = decoder_block(cfg, None, x, layer_params,
-                                      positions, attend)
-    return x, k_l, v_l
+        x, kv = decoder_block(cfg, None, x, layer_params, positions,
+                              attend)
+    return x, kv
 
 
-def make_decode_step(cfg: GPTConfig, scfg: ServingConfig):
+def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     """Build the jitted all-slots decode step.
 
     decode_step(params, k_pool, v_pool, tables, lengths, tokens, temps,
     seeds, counts) -> (next_tokens (N,), k_pool', v_pool'). Pools are
     donated — the caller's old handles die each step (no second pool in
-    HBM). temps[i] <= 0 selects greedy argmax for slot i; > 0 samples at
+    HBM) — and stay in place: the layer loop only READS them (each layer
+    attends over the pool's positions below the slot's length plus the
+    new token's own row), yields the new rows, and one scatter after the
+    loop writes all layers' rows into the donated buffers.
+    temps[i] <= 0 selects greedy argmax for slot i; > 0 samples at
     that temperature under the config's static top_k, keyed by
     ``request_sample_key(seeds[i], counts[i])`` so the sampled stream is
     a pure per-request function — retries and cross-replica failovers
@@ -155,20 +161,32 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig):
             if not cfg.rotary:
                 x = x + jnp.take(params["embed"]["wpe"], positions,
                                  axis=0).astype(cdt)
-        wblk = tables[jnp.arange(N), lengths // scfg.block_size]
-        woff = lengths % scfg.block_size
+        attend_rows = decode_attend_for(k_pool, tables, cfg.n_head, mesh)
 
-        def scan_body(carry, xs):
-            x = carry
-            layer_params, k_l, v_l = xs
-            x, k_l, v_l = _paged_block(cfg, x, layer_params, k_l, v_l,
-                                       tables, lengths, wblk, woff,
-                                       positions)
-            return x, (k_l, v_l)
+        def scan_body(x, xs):
+            layer_params, layer = xs
 
-        x, (k_new, v_new) = jax.lax.scan(
-            scan_body, x, (params["layers"], k_pool, v_pool)
-        )
+            def attend(q, k, v):
+                # cast first: the layer attends over the values the pool
+                # will hold
+                k_row = k[:, 0].astype(k_pool.dtype)
+                v_row = v[:, 0].astype(v_pool.dtype)
+                ctx = attend_rows(k_pool, v_pool, layer, q, k_row, v_row,
+                                  tables, lengths)
+                return ctx, (k_row, v_row)
+
+            return _paged_block(cfg, x, layer_params, positions, attend)
+
+        x, (k_rows, v_rows) = jax.lax.scan(
+            scan_body, x,
+            (params["layers"], jnp.arange(cfg.n_layer, dtype=jnp.int32)))
+        with jax.named_scope("ds.decode/kv_write"):
+            # (L, N, Hkv, Dh) rows into the donated pools, in place; idle
+            # slots all target (null block, 0), never read unmasked
+            wblk = tables[jnp.arange(N), lengths // scfg.block_size]
+            woff = lengths % scfg.block_size
+            k_pool = k_pool.at[:, wblk, woff].set(k_rows)
+            v_pool = v_pool.at[:, wblk, woff].set(v_rows)
         with jax.named_scope("ds.decode/sample"):
             x = layer_norm(x, params["final_ln"]["scale"],
                            params["final_ln"]["bias"], cfg.layernorm_eps)
@@ -188,7 +206,7 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig):
                 lambda k, row: jax.random.categorical(k, row)
             )(keys, l32).astype(jnp.int32)
             nxt = jnp.where(temps > 0.0, sampled, greedy)
-        return nxt, k_new, v_new
+        return nxt, k_pool, v_pool
 
     return ds_decode_step
 
@@ -425,7 +443,7 @@ class ServingEngine(_ServingBase):
             self._place_kv_pools()
         super().__init__(scfg, Scheduler(scfg, self.kv.allocator, clock),
                          clock, monitor, monitor_config)
-        self._decode_step = make_decode_step(cfg, scfg)
+        self._decode_step = make_decode_step(cfg, scfg, mesh)
 
         # retraces once per prefill bucket (toks.shape[1] varies)
         def ds_prefill(params, toks):
@@ -805,9 +823,14 @@ class ServingEngine(_ServingBase):
             temps = np.zeros(N, np.float32)
             seeds = np.zeros(N, np.int32)
             counts = np.zeros(N, np.int32)
+            live_pages = 0
             for s, req in active:
                 tables[s] = self.sched.slot_table_row(s)
                 lengths[s] = req.cached_len
+                # the pages that hold a live position of a live slot,
+                # the new token's included: all the pool a step need read
+                live_pages += blocks_needed(req.cached_len + 1,
+                                            self.scfg.block_size)
                 tokens[s] = req.pending_token
                 temps[s] = req.temperature
                 seeds[s] = req.seed
@@ -818,7 +841,9 @@ class ServingEngine(_ServingBase):
                       _place(lengths), _place(tokens),
                       _place(temps), _place(seeds),
                       _place(counts))
-        with trace_span("serving/decode/dispatch", lane="serving"):
+        self.metrics.record_kv_pages(live_pages, tables.size)
+        with trace_span("serving/decode/dispatch", lane="serving",
+                        live_pages=live_pages, view_pages=tables.size):
             nxt, self.kv.k, self.kv.v = self._decode_step(*_dargs)
         with trace_span("serving/decode/wait", lane="serving"):
             nxt = np.asarray(nxt)               # device sync

@@ -16,11 +16,12 @@ per-slot length.
 Writes are static-shape updates into slot pages: prefill scatters whole
 ``block_size`` pages (the dense prefill cache reshaped to pages, indexed
 by the allocated block list), decode scatters each slot's single new
-(K, V) row at ``(block_table[len // bs], len % bs)``. Reads gather the
-slot's pages back into a contiguous ``blocks_per_slot * block_size``
-view per layer — the XLA-gather formulation of paged attention; a Pallas
-kernel that walks the table in HBM without materializing the view is the
-planned TPU fast path (see docs/tutorials/serving.md).
+(K, V) row at ``(block_table[len // bs], len % bs)``, all layers in one
+scatter after the layer loop. Reads never copy the pool: the XLA form
+(``paged_attend_rows``) gathers the slot's pages into a contiguous
+``blocks_per_slot * block_size`` view per layer; on one TPU
+``ops/pallas/paged_decode_attn`` walks the table in HBM and copies only
+the pages that hold live positions (``decode_attend_for`` chooses).
 
 Prefix reuse generalizes the null-block trick into copy-on-write
 sharing: blocks are REFCOUNTED, and a ``PrefixCache`` (radix trie over
@@ -455,9 +456,56 @@ def paged_attend_multi(k_pool_l, v_pool_l, q, k_new, v_new, tables,
     return ctx.reshape(N, T, Hq, Dh), k_pool_l, v_pool_l
 
 
+def paged_attend_rows(k_pool, v_pool, layer, q, k_row, v_row, tables,
+                      lengths):
+    """One layer of single-token paged attention that only READS the
+    pool — the XLA form, the oracle of ops/pallas/paged_decode_attn.
+
+    k_pool/v_pool: the STACKED pools (L, num_blocks, bs, Hkv, Dh);
+    ``layer`` (traced) selects the layer inside the gather's index, so no
+    whole-layer slice of the pool is ever made. q: (N, 1, H, Dh).
+    k_row/v_row: (N, Hkv, Dh) — the new token's rows, already in the
+    pool's dtype (the values the pool will hold). Slot i attends over
+    the pool's logical positions ``< lengths[i]`` plus its own new row
+    at position ``lengths[i]``; whoever calls writes the rows into the
+    pool afterwards. Returns ctx (N, 1, H, Dh).
+
+    Mirrors models/generation._cached_block's grouped-einsum math (GQA
+    reads at the small Hkv width) so greedy serving outputs are
+    token-identical to make_generator's.
+    """
+    N = q.shape[0]
+    Hq, Dh = q.shape[2], q.shape[3]
+    bs, Hkv = k_pool.shape[2], k_pool.shape[3]
+    view = tables.shape[1] * bs
+    key_pos = jnp.arange(view, dtype=jnp.int32)
+    with jax.named_scope("ds.decode/kv_gather"):
+        # each slot's pages as a contiguous logical view, the new row
+        # laid over position == length (where the pool's row is stale)
+        new = (key_pos[None, :] == lengths[:, None])[:, :, None, None]
+        k_c = jnp.where(new, k_row[:, None],
+                        k_pool[layer, tables].reshape(N, view, Hkv, Dh))
+        v_c = jnp.where(new, v_row[:, None],
+                        v_pool[layer, tables].reshape(N, view, Hkv, Dh))
+    rep = Hq // Hkv
+    with jax.named_scope("ds.decode/attn"):
+        qg = q.reshape(N, 1, Hkv, rep, Dh)
+        scores = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k_c,
+                            preferred_element_type=jnp.float32)
+        scores = scores / math.sqrt(Dh)
+        # valid keys: logical positions 0..length inclusive
+        valid = key_pos[None, :] <= lengths[:, None]      # (N, view)
+        scores = jnp.where(valid[:, None, None, None, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        ctx = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v_c)
+    return ctx.reshape(N, 1, Hq, Dh)
+
+
 def paged_attend(k_pool_l, v_pool_l, q, k_new, v_new, tables, lengths,
                  write_block, write_off):
-    """One layer of single-token paged-cache attention for all slots.
+    """``paged_attend_rows`` over ONE layer's pool, which it also writes:
+    the form the speculative drafter's scan still carries its pool
+    through (serving/spec/steps.py).
 
     k_pool_l/v_pool_l: (num_blocks, bs, Hkv, Dh) — this layer's pool.
     q: (N, 1, H, Dh); k_new/v_new: (N, 1, Hkv, Dh) — the new token's
@@ -465,38 +513,29 @@ def paged_attend(k_pool_l, v_pool_l, q, k_new, v_new, tables, lengths,
     (N,) tokens already cached per slot; write_block/write_off: (N,)
     physical block + in-block offset for the new row.
 
-    Returns (ctx (N, 1, H, Dh), k_pool_l', v_pool_l'). Mirrors
-    models/generation._cached_block's grouped-einsum math (GQA reads at
-    the small Hkv width) so greedy serving outputs are token-identical to
-    make_generator's.
+    Returns (ctx (N, 1, H, Dh), k_pool_l', v_pool_l').
     """
-    N = q.shape[0]
-    Hq, Dh = q.shape[2], q.shape[3]
     cdt = k_pool_l.dtype
+    k_row, v_row = k_new[:, 0].astype(cdt), v_new[:, 0].astype(cdt)
+    ctx = paged_attend_rows(k_pool_l[None], v_pool_l[None], 0, q, k_row,
+                            v_row, tables, lengths)
     with jax.named_scope("ds.decode/kv_write"):
         # the new row: idle slots target (null block, 0) by construction
-        k_pool_l = k_pool_l.at[write_block, write_off].set(
-            k_new[:, 0].astype(cdt))
-        v_pool_l = v_pool_l.at[write_block, write_off].set(
-            v_new[:, 0].astype(cdt))
-    # gather each slot's pages into a contiguous logical view
-    bs = k_pool_l.shape[1]
-    view = tables.shape[1] * bs
-    with jax.named_scope("ds.decode/kv_gather"):
-        k_c = k_pool_l[tables].reshape(N, view, k_pool_l.shape[2], Dh)
-        v_c = v_pool_l[tables].reshape(N, view, v_pool_l.shape[2], Dh)
-    Hkv = k_c.shape[2]
-    rep = Hq // Hkv
-    with jax.named_scope("ds.decode/attn"):
-        qg = q.reshape(N, 1, Hkv, rep, Dh)
-        scores = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k_c,
-                            preferred_element_type=jnp.float32)
-        scores = scores / math.sqrt(Dh)
-        # valid keys: logical positions 0..length inclusive (the row
-        # written above sits at position == length)
-        key_pos = jnp.arange(view, dtype=jnp.int32)
-        valid = key_pos[None, :] <= lengths[:, None]      # (N, view)
-        scores = jnp.where(valid[:, None, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        ctx = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v_c)
-    return ctx.reshape(N, 1, Hq, Dh), k_pool_l, v_pool_l
+        k_pool_l = k_pool_l.at[write_block, write_off].set(k_row)
+        v_pool_l = v_pool_l.at[write_block, write_off].set(v_row)
+    return ctx, k_pool_l, v_pool_l
+
+
+def decode_attend_for(k_pool, tables, n_head, mesh):
+    """The form of one layer's decode attention (the signature of
+    ``paged_attend_rows``) that a decode program takes, from what it can
+    see when it is traced: on one TPU, shapes the kernel can tile get
+    ops/pallas/paged_decode_attn (live pages only); every other platform
+    and shape, and a multi-device mesh (XLA cannot partition a Mosaic
+    kernel), get the XLA form, which GSPMD shards."""
+    from ..ops.pallas import paged_decode_attn as kernel
+
+    if (mesh is None or mesh.size == 1) and kernel.is_available(
+            k_pool, tables, n_head):
+        return kernel.paged_decode_attn
+    return paged_attend_rows

@@ -141,6 +141,10 @@ class ServingMetrics:
         self.occupancy: List[float] = []
         self.total_generated = 0
         self.decode_steps = 0
+        # pages holding live positions of live slots against the pages
+        # of every slot's whole view, summed over plain decode steps
+        self.kv_live_pages = 0
+        self.kv_view_pages = 0
         self.prefills = 0
         self.preemptions = 0
         # prefix reuse / chunked prefill: admissions is every context
@@ -279,6 +283,10 @@ class ServingMetrics:
             self._g_active.set(n_active)
             self._g_occ.set(n_active / self.num_slots)
 
+    def record_kv_pages(self, live_pages: int, view_pages: int) -> None:
+        self.kv_live_pages += live_pages
+        self.kv_view_pages += view_pages
+
     def record_preemption(self) -> None:
         self.preemptions += 1
         if self.registry is not None:
@@ -379,6 +387,8 @@ class ServingMetrics:
             "ttft_s": _percentiles(self.ttft_s),
             "tpot_s": _percentiles(self.tpot_s),
             "slot_occupancy": float(occ.mean()) if occ.size else 0.0,
+            "kv_live_page_frac": (self.kv_live_pages / self.kv_view_pages
+                                  if self.kv_view_pages else 0.0),
             "queue_depth_max": int(max(self.queue_depth, default=0)),
             "slo": self.slo_tracker.summary(),
             "prefix_reuse": {

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from ...models.gpt import GPTConfig, layer_norm
 from ..config import ServingConfig
 from ..engine import _paged_block, request_sample_key
-from ..kv_cache import paged_attend_multi
+from ..kv_cache import paged_attend, paged_attend_multi
 
 
 def _choose(logits, temps, seeds, idx, top_k):
@@ -111,10 +111,14 @@ def make_draft_step(cfg: GPTConfig, scfg: ServingConfig, draft_k: int):
 
             def scan_body(h, xs):
                 layer_params, k_l, v_l = xs
-                h, k_l, v_l = _paged_block(cfg, h, layer_params, k_l,
-                                           v_l, tables, pos, wblk, woff,
-                                           positions)
-                return h, (k_l, v_l)
+
+                def attend(q, k, v):
+                    ctx, k2, v2 = paged_attend(k_l, v_l, q, k, v, tables,
+                                               pos, wblk, woff)
+                    return ctx, (k2, v2)
+
+                return _paged_block(cfg, h, layer_params, positions,
+                                    attend)
 
             x, (k_pool, v_pool) = jax.lax.scan(
                 scan_body, x, (params["layers"], k_pool, v_pool))
@@ -131,34 +135,6 @@ def make_draft_step(cfg: GPTConfig, scfg: ServingConfig, draft_k: int):
         return drafts[:draft_k].T, k_pool, v_pool
 
     return ds_draft_step
-
-
-def _paged_block_multi(cfg: GPTConfig, x, layer_params, k_l, v_l,
-                       tables, lengths, wblk, woff, positions):
-    """One decoder layer over all slots' T-token windows — the multi-
-    token twin of engine._paged_block (same decoder_block math, the
-    attention core swapped for paged_attend_multi)."""
-    from ...models.gpt import decoder_block
-
-    def attend(q, k, v):
-        ctx, k2, v2 = paged_attend_multi(k_l, v_l, q, k, v, tables,
-                                         lengths, wblk, woff)
-        return ctx, (k2, v2)
-
-    moe_cfg = cfg.moe
-    if moe_cfg is not None:
-        from ...models.moe import moe_ffn
-
-        def mlp_fn(mlp_in):
-            return moe_ffn(layer_params["moe"], mlp_in, moe_cfg)
-
-        x, ((k_l, v_l), _) = decoder_block(
-            cfg, None, x, layer_params, positions, attend, mlp_fn=mlp_fn
-        )
-    else:
-        x, (k_l, v_l) = decoder_block(cfg, None, x, layer_params,
-                                      positions, attend)
-    return x, k_l, v_l
 
 
 def make_verify_step(cfg: GPTConfig, scfg: ServingConfig, draft_k: int):
@@ -192,10 +168,13 @@ def make_verify_step(cfg: GPTConfig, scfg: ServingConfig, draft_k: int):
 
         def scan_body(h, xs):
             layer_params, k_l, v_l = xs
-            h, k_l, v_l = _paged_block_multi(cfg, h, layer_params, k_l,
-                                             v_l, tables, lengths, wblk,
-                                             woff, positions)
-            return h, (k_l, v_l)
+
+            def attend(q, k, v):
+                ctx, k2, v2 = paged_attend_multi(k_l, v_l, q, k, v, tables,
+                                                 lengths, wblk, woff)
+                return ctx, (k2, v2)
+
+            return _paged_block(cfg, h, layer_params, positions, attend)
 
         x, (k_pool, v_pool) = jax.lax.scan(
             scan_body, x, (params["layers"], k_pool, v_pool))

@@ -307,6 +307,43 @@ def _kernel_sections():
                    lambda q: (flash_attention_supertile_bhsd(q, q, q, causal=c)
                               .astype(jnp.float32) ** 2).sum())(q)))
 
+    # ---- paged decode attention ---------------------------------------- #
+    # the serving cell's geometry (16 slots x 128 pages of 16, 16 heads of
+    # 128) and a grouped-query one, ragged lengths with idle slots first,
+    # between and last, against the XLA form the CPU runs
+    from deeperspeed_tpu.ops.pallas.paged_decode_attn import (
+        is_available, paged_decode_attn)
+    from deeperspeed_tpu.serving.kv_cache import paged_attend_rows
+
+    for H, Hkv in ((16, 16), (32, 16)):
+        N, L, bs, bps, Dh = 16, 2, 16, 128, 128
+        lengths = [0, 1, 16, 17, 0, 128, 129, 2047, 700, 0, 333, 1024, 5, 64,
+                   1999, 0]
+        nb = 1 + sum(-(-(n + 1) // bs) for n in lengths if n)
+        rng = np.random.default_rng(H)
+        tables, nxt = np.zeros((N, bps), np.int32), 1
+        for i, n in enumerate(lengths):
+            used = -(-(n + 1) // bs) if n else 0
+            tables[i, :used] = np.arange(nxt, nxt + used)
+            nxt += used
+
+        def arr(*shape, rng=rng):
+            return jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+
+        kp, vp = arr(L, nb, bs, Hkv, Dh), arr(L, nb, bs, Hkv, Dh)
+        q, kr, vr = arr(N, 1, H, Dh), arr(N, Hkv, Dh), arr(N, Hkv, Dh)
+        tables, lengths = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+        assert is_available(kp, tables, H)
+
+        def parity(args=(kp, vp, jnp.int32(1), q, kr, vr, tables, lengths)):
+            got = paged_decode_attn(*args).astype(jnp.float32)
+            want = jax.jit(paged_attend_rows)(*args).astype(jnp.float32)
+            gap = float(jnp.max(jnp.abs(got - want)))
+            assert gap < 0.05, f"kernel and XLA form differ by {gap}"
+            return got
+
+        _check(f"paged decode attn H={H} Hkv={Hkv} vs XLA form", parity)
+
     # ---- fused transformer layer -------------------------------------- #
     from deeperspeed_tpu.ops.transformer import (
         DeepSpeedTransformerConfig, DeepSpeedTransformerLayer)

@@ -212,3 +212,56 @@ def test_single_device_surfaces_leave_a_multi_device_mesh_to_xla():
             kernel_config.mesh_scope(mesh):
         with pytest.raises(NotImplementedError, match="single-device"):
             kernel_config.resolve("fused_blocks")
+
+
+def _decode_step_text(mesh=None, **cfg_kw):
+    """``ds_decode_step`` lowered for TPU at 16 key heads of 128 (the
+    widths the kernel's gate admits), from shapes alone."""
+    from deeperspeed_tpu.models.gpt import GPTConfig, make_gpt
+    from deeperspeed_tpu.serving import ServingConfig
+    from deeperspeed_tpu.serving.engine import make_decode_step
+
+    cfg = GPTConfig(vocab_size=256, n_layer=2, n_head=16, d_model=2048,
+                    max_seq=256, **cfg_kw)
+    scfg = ServingConfig(num_slots=4, block_size=16, num_blocks=65,
+                         max_seq_len=256)
+    params = jax.eval_shape(make_gpt(cfg)[0], jax.random.PRNGKey(0))
+    N = scfg.num_slots
+    pool = _sds((cfg.n_layer, scfg.num_blocks, scfg.block_size,
+                 cfg.kv_heads, cfg.head_dim))
+    args = (params, pool, pool, _sds((N, scfg.blocks_per_slot), jnp.int32),
+            _sds((N,), jnp.int32), _sds((N,), jnp.int32),
+            _sds((N,), jnp.float32), _sds((N,), jnp.int32),
+            _sds((N,), jnp.int32))
+    return make_decode_step(cfg, scfg, mesh).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("H,Hkv,dtype", [(16, 16, BF16), (32, 16, BF16),
+                                         (8, 8, jnp.float32)])
+def test_paged_decode_attn_lowers(H, Hkv, dtype):
+    from deeperspeed_tpu.ops.pallas.paged_decode_attn import (
+        is_available, paged_decode_attn)
+
+    N, bs, bps, Dh = 16, 16, 128, 128       # the serving cell's geometry
+    pool = _sds((2, 129, bs, Hkv, Dh), dtype)
+    tables = _sds((N, bps), jnp.int32)
+    assert is_available(pool, tables, H)
+    assert _mosaic_calls(
+        paged_decode_attn, pool, pool, _sds((), jnp.int32),
+        _sds((N, 1, H, Dh), dtype), _sds((N, Hkv, Dh), dtype),
+        _sds((N, Hkv, Dh), dtype), tables, _sds((N,), jnp.int32)) == 1
+
+
+def test_decode_step_reads_the_pool_through_the_kernel_on_one_tpu():
+    text = _decode_step_text()
+    assert text.count("tpu_custom_call") == 1       # once, in the layer scan
+    assert "paged_decode_attn" in text
+
+
+def test_decode_step_keeps_the_xla_form_on_a_multi_device_mesh():
+    """XLA cannot partition a Mosaic kernel and this one has no shard_map
+    wrapper: dp x tp serving keeps the gather GSPMD can shard."""
+    from deeperspeed_tpu.sharding import default_mesh
+
+    assert "tpu_custom_call" not in _decode_step_text(default_mesh())
