@@ -36,6 +36,70 @@ from ..utils import hooks
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseAttnConfig:
+    """The constants of an InfLLM-v2 block-sparse attention layer (the
+    ``minicpm4`` mixer): keys are mean-pooled over windows of
+    ``kernel_size`` every ``kernel_stride`` tokens, a query scores the
+    pooled keys, and attends over ``topk`` blocks of ``block_size`` tokens
+    (the first ``init_blocks`` and the ``window_size`` tokens' blocks that
+    end at its own among them). A query that sees ``dense_len`` tokens or
+    fewer attends to all of them."""
+    block_size: int = 64
+    topk: int = 64
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    init_blocks: int = 1
+    window_size: int = 2048
+    dense_len: int = 8192
+
+    def __post_init__(self):
+        if self.block_size % self.kernel_stride \
+                or self.kernel_size != 2 * self.kernel_stride \
+                or self.kernel_size > self.block_size:
+            raise ValueError(
+                "sparse attention: block_size must be a multiple of "
+                "kernel_stride and kernel_size twice kernel_stride "
+                f"(got {self})")
+        if self.window_size % self.block_size \
+                or self.dense_len % self.block_size:
+            raise ValueError(
+                "sparse attention: window_size and dense_len must be "
+                f"multiples of block_size (got {self})")
+        if self.init_blocks + self.local_blocks > self.topk:
+            raise ValueError(
+                f"sparse attention: topk ({self.topk}) is less than the "
+                f"forced blocks ({self.init_blocks} + {self.local_blocks})")
+
+    @property
+    def local_blocks(self) -> int:
+        return self.window_size // self.block_size
+
+    @property
+    def windows_per_block(self) -> int:
+        """Pooled keys whose window STARTS in one block."""
+        return self.block_size // self.kernel_stride
+
+    @property
+    def list_blocks(self) -> int:
+        """Width of a query's page list: the selection, or every block of
+        a context the dense rule still covers."""
+        return max(self.topk, self.dense_len // self.block_size)
+
+
+# The kinds of layer a stack may hold, by the name of the mixer. The kind
+# settles the rest of the layer, so nothing else is configured: what its
+# mixer keeps between tokens (its cache), its norm and its feed-forward.
+#   attention  gpt.decoder_block: LayerNorm, softmax attention over every
+#              key (pages of keys and values), a GeLU feed-forward, biases
+#   minicpm4   mixers.mixed_block: RMSNorm, InfLLM-v2 block-sparse attention
+#              (pages, and a pooled key per stride for the selector), a
+#              gated SiLU feed-forward, no bias
+#   lightning  mixers.mixed_block: RMSNorm, decayed linear attention (one
+#              float32 state row a slot, no pages), gated SiLU, no bias
+LAYER_KINDS = ("attention", "minicpm4", "lightning")
+
+
+@dataclasses.dataclass(frozen=True)
 class GPTConfig:
     vocab_size: int = 50304
     n_layer: int = 12
@@ -84,6 +148,33 @@ class GPTConfig:
     # EP-dropless receive-buffer headroom (see MoEConfig.ep_buffer_factor);
     # >= the 'expert' axis size guarantees zero drops under any skew
     moe_ep_buffer_factor: float = 2.0
+    # what each layer is, one of LAYER_KINDS a layer. () = ``n_layer``
+    # attention layers (the stacked ``layers`` tree, one scan). A stack
+    # that names its kinds keeps its weights stacked by kind
+    # (models/mixers.py) and its layer loop runs kind by kind in order.
+    mixer_types: Tuple[str, ...] = ()
+    # muP scalings (MiniCPM): the embedding times scale_emb, every residual
+    # branch of a mixed_block times residual_scale, the logits times
+    # logit_scale
+    scale_emb: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    sparse: Optional[SparseAttnConfig] = None   # the minicpm4 layers'
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The stack, layer by layer: what prefill, chunk and decode
+        programs dispatch on."""
+        return self.mixer_types or ("attention",) * self.n_layer
+
+    @property
+    def classic(self) -> bool:
+        """Every layer is ``decoder_block``'s (the stacked ``layers`` tree,
+        the model ``make_gpt`` trains)."""
+        return not self.mixer_types
+
+    def count(self, kind: str) -> int:
+        return self.layer_kinds.count(kind)
 
     @property
     def moe(self):
@@ -109,6 +200,18 @@ class GPTConfig:
                 f"n_head ({self.n_head}) must be a multiple of n_kv_head "
                 f"({kv})"
             )
+        if self.mixer_types:
+            # attention layers keep their own weight tree and a pool laid
+            # out position by position: they do not mix with the others
+            mixable = set(LAYER_KINDS) - {"attention"}
+            if set(self.mixer_types) - mixable \
+                    or len(self.mixer_types) != self.n_layer:
+                raise ValueError(
+                    f"mixer_types must name one of {sorted(mixable)} for "
+                    f"each of the {self.n_layer} layers (or be empty: a "
+                    f"stack of attention layers), got {self.mixer_types}")
+            if "minicpm4" in self.mixer_types and self.sparse is None:
+                raise ValueError("minicpm4 layers need cfg.sparse")
         if self.remat_policy not in ("full", "flash", "matmuls", "dots",
                                      "dots_all"):
             raise ValueError(
@@ -620,6 +723,20 @@ def make_gpt(cfg: GPTConfig, mesh=None):
 
     def init_fn(rng):
         return init_params(rng, cfg)
+
+    if not cfg.classic:
+        # served only (serving/engine.py); the training block is
+        # decoder_block's. A loss that ran a wrong model would be worse
+        # than none.
+        def refuse(*_a, **_k):
+            raise NotImplementedError(
+                f"training a stack of {sorted(set(cfg.mixer_types))} layers "
+                "is not implemented: this model is served only "
+                "(ServingEngine); models/mixers.py has its forward")
+
+        from .mixers import init_params as init_mixed
+
+        return (lambda rng: init_mixed(rng, cfg)), refuse, refuse, None
 
     return init_fn, apply_fn, loss_fn, param_specs(cfg)
 

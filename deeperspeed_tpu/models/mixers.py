@@ -1,0 +1,411 @@
+"""The layer kinds beside ``gpt.decoder_block``'s: RMSNorm, a gated SiLU
+feed-forward, muP scalings, and two mixers that keep something other than
+every key and value between tokens:
+
+``lightning``  decayed linear attention (Lightning Attention): per head a
+               state ``S_t = lam S_{t-1} + k_t^T v_t`` (Dh x Dh, float32),
+               ``o_t = (q_t / sqrt(Dh)) S_t``; a prompt computes the same
+               sum chunkwise, a decode step is the recurrence.
+``minicpm4``   InfLLM-v2 block-sparse attention: keys mean-pooled over
+               windows, a query scores the pooled keys and attends over
+               the tokens of ``topk`` blocks only.
+
+A model whose ``GPTConfig.mixer_types`` names them (MiniCPM-SALA) keeps
+its weights stacked BY KIND (``params["sparse"]``, ``params["lightning"]``)
+and is served only; the layer loop of every program goes run by run
+(``layer_runs``). ``mixed_block`` is the one layer the whole forward, the
+chunked prefill and the decode step share: a program hands it the
+cache-dependent ``core(q, k, v) -> (ctx, aux)`` alone.
+"""
+
+import math
+from typing import List, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .gpt import GPTConfig, SparseAttnConfig, layer_norm, rotary_embedding
+
+NEG = -1e30
+# where each kind's stacked weights live in the parameter tree
+STACK_KEY = {"attention": "layers", "minicpm4": "sparse",
+             "lightning": "lightning"}
+
+
+# ------------------------------------------------------------------ #
+# small parts
+# ------------------------------------------------------------------ #
+
+
+def rms_norm(x, scale, eps):
+    """x / rms(x) * scale over the last axis, statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def gated_ffn(u, p, cdt):
+    h = jax.nn.silu(u @ p["w_gate"].astype(cdt)) * (u @ p["w_up"].astype(cdt))
+    return h @ p["w_down"].astype(cdt)
+
+
+def lightning_slopes(n_head: int):
+    """s_h = 2^(-8 (h+1) / n_head); the decay of head h is exp(-s_h)."""
+    return jnp.exp2(-8.0 * jnp.arange(1, n_head + 1, dtype=jnp.float32)
+                    / n_head)
+
+
+def layer_runs(cfg: GPTConfig) -> List[Tuple[str, int, int]]:
+    """The stack as runs of one kind: (kind, first index INSIDE the
+    kind's stacked weights, count), in layer order."""
+    runs, seen = [], {}
+    for kind in cfg.layer_kinds:
+        i = seen.get(kind, 0)
+        seen[kind] = i + 1
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1], runs[-1][2] + 1)
+        else:
+            runs.append((kind, i, 1))
+    return runs
+
+
+def scan_runs(cfg: GPTConfig, params, carry, body):
+    """The layer loop of every serving program: one ``lax.scan`` a run.
+    ``body(kind, carry, layer_params, index) -> (carry, out)`` with
+    ``index`` the layer's place among its kind; returns the carry and, by
+    kind, the runs' stacked ``out`` in that kind's order. What is large
+    and kept a layer (a state row) belongs in the carry, written in
+    place; ``out`` is for the small (a new token's keys)."""
+    outs = {}
+    for kind, first, count in layer_runs(cfg):
+        stack = params[STACK_KEY[kind]]
+        ids = first + jnp.arange(count, dtype=jnp.int32)
+        if count == jax.tree.leaves(stack)[0].shape[0]:
+            # the whole stack: scan over it (the classic model's one scan)
+            carry, out = jax.lax.scan(
+                lambda c, xs, kind=kind: body(kind, c, *xs), carry,
+                (stack, ids))
+        else:
+            def step(c, i, kind=kind, stack=stack):
+                return body(kind, c, jax.tree.map(lambda a: a[i], stack), i)
+
+            carry, out = jax.lax.scan(step, carry, ids)
+        outs.setdefault(kind, []).append(out)
+    return carry, {m: jax.tree.map(lambda *a: jnp.concatenate(a), *o)
+                   for m, o in outs.items()}
+
+
+# ------------------------------------------------------------------ #
+# weights
+# ------------------------------------------------------------------ #
+
+
+def init_params(rng, cfg: GPTConfig):
+    """float32 weights of a mixed stack, stacked by kind; no bias."""
+    D, F, V = cfg.d_model, cfg.ffn_dim, cfg.vocab_size
+    H, Dh = cfg.n_head, cfg.head_dim
+    std, out_std = 0.02, 0.02 / math.sqrt(2.0 * cfg.n_layer)
+    keys = iter(jax.random.split(rng, 32))
+
+    def w(shape, s):
+        return jax.random.normal(next(keys), shape, jnp.float32) * s
+
+    def kind(n, kv_heads, o_norm):
+        p = {"ln1": jnp.ones((n, D)), "ln2": jnp.ones((n, D)),
+             # one projection: all query heads, then the key heads, then
+             # the value heads (as decoder_block's fused qkv)
+             "wqkv": w((n, D, (H + 2 * kv_heads) * Dh), std),
+             "wg": w((n, D, H * Dh), std), "wo": w((n, H * Dh, D), out_std),
+             "q_norm": jnp.ones((n, Dh)), "k_norm": jnp.ones((n, Dh)),
+             "mlp": {"w_gate": w((n, D, F), std), "w_up": w((n, D, F), std),
+                     "w_down": w((n, F, D), out_std)}}
+        if o_norm:
+            p["o_norm"] = jnp.ones((n, H * Dh))
+        return p
+
+    params = {"embed": {"wte": w((V, D), std)},
+              "final_norm": {"scale": jnp.ones((D,))},
+              "lm_head": w((D, V), std)}
+    if cfg.count("minicpm4"):
+        params["sparse"] = kind(cfg.count("minicpm4"), cfg.kv_heads, False)
+    if cfg.count("lightning"):
+        params["lightning"] = kind(cfg.count("lightning"), H, True)
+    return params
+
+
+# ------------------------------------------------------------------ #
+# the shared layer
+# ------------------------------------------------------------------ #
+
+
+def mixed_block(cfg: GPTConfig, kind: str, x, p, positions, core):
+    """A ``minicpm4`` or ``lightning`` layer: x + r Mixer(RMSNorm(x)), then
+    x + r FFN(RMSNorm(x)), the FFN gated SiLU. ``core(q, k, v) ->
+    (ctx (B, S, H, Dh), aux)`` is the part that knows the cache: q and k
+    come normed per head and, for ``lightning``, rotated; q is NOT yet
+    scaled."""
+    cdt, eps, r = cfg.dtype, cfg.layernorm_eps, cfg.residual_scale
+    B, S, _ = x.shape
+    H, Dh = cfg.n_head, cfg.head_dim
+    Hkv = cfg.kv_heads if kind == "minicpm4" else H
+    with jax.named_scope("ds.attn"):
+        u = rms_norm(x, p["ln1"], eps)
+        qkv = u @ p["wqkv"].astype(cdt)
+        q = qkv[..., :H * Dh].reshape(B, S, H, Dh)
+        k = qkv[..., H * Dh:(H + Hkv) * Dh].reshape(B, S, Hkv, Dh)
+        v = qkv[..., (H + Hkv) * Dh:].reshape(B, S, Hkv, Dh)
+        q = rms_norm(q, p["q_norm"], eps)
+        k = rms_norm(k, p["k_norm"], eps)
+        if kind == "lightning":
+            q = rotary_embedding(q, positions, Dh)
+            k = rotary_embedding(k, positions, Dh)
+        ctx, aux = core(q, k, v)
+        ctx = ctx.astype(cdt)
+        if kind == "lightning":         # the output norm, head by head
+            ctx = rms_norm(ctx, p["o_norm"].reshape(H, Dh), eps)
+        gate = jax.nn.sigmoid(u @ p["wg"].astype(cdt))
+        y = (ctx.reshape(B, S, H * Dh) * gate) @ p["wo"].astype(cdt)
+        x = x + (r * y).astype(cdt)
+    with jax.named_scope("ds.mlp"):
+        m = gated_ffn(rms_norm(x, p["ln2"], eps), p["mlp"], cdt)
+        x = x + (r * m).astype(cdt)
+    return x, aux
+
+
+def embed_tokens(cfg: GPTConfig, params, tokens, positions=None):
+    """Where the residual stream starts, for either parameter tree: the
+    table's rows of ``tokens`` (any shape), times ``scale_emb`` where the
+    model scales them, plus the learned rows of ``positions`` (tokens'
+    shape) where the model has such a table."""
+    emb = params["embed"]
+    x = jnp.take(emb["wte"].astype(cfg.dtype), tokens, axis=0)
+    if cfg.scale_emb != 1.0:
+        x = x * jnp.asarray(cfg.scale_emb, cfg.dtype)
+    if "wpe" in emb:
+        x = x + jnp.take(emb["wpe"], positions, axis=0).astype(cfg.dtype)
+    return x
+
+
+def head_logits(cfg: GPTConfig, params, x):
+    """The final norm and the head, for either parameter tree: a stack of
+    attention layers ends in a LayerNorm (``final_ln``), a mixed one in an
+    RMSNorm (``final_norm``)."""
+    if "final_ln" in params:
+        x = layer_norm(x, params["final_ln"]["scale"],
+                       params["final_ln"]["bias"], cfg.layernorm_eps)
+    else:
+        x = rms_norm(x, params["final_norm"]["scale"], cfg.layernorm_eps)
+    if cfg.tie_embeddings:
+        logits = x @ params["embed"]["wte"].astype(cfg.dtype).T
+    else:
+        logits = x @ params["lm_head"].astype(cfg.dtype)
+    if cfg.logit_scale != 1.0:
+        logits = logits * jnp.asarray(cfg.logit_scale, cfg.dtype)
+    return logits
+
+
+# ------------------------------------------------------------------ #
+# lightning: the chunkwise form and the recurrence
+# ------------------------------------------------------------------ #
+
+LIGHTNING_BLOCK = 256
+
+
+def lightning_chunk_xla(q, k, v, s_in, slopes, n_valid, block=None):
+    """The chunkwise form in plain XLA, and the oracle of
+    ops/pallas/lightning_chunk. q, k, v: (C, H, Dh); s_in: (H, Dh, Dh)
+    float32, the state BEFORE position 0; ``n_valid`` (traced) of the C
+    positions are real: the rest neither decay the state nor add to it.
+    Returns (o (C, H, Dh) float32, the state after position n_valid-1)."""
+    C, H, Dh = q.shape
+    B = min(block or LIGHTNING_BLOCK, C)
+    assert C % B == 0, (C, B)
+    scale = 1.0 / math.sqrt(Dh)
+    s = slopes.astype(jnp.float32)[:, None, None]          # (H, 1, 1)
+    pos = jnp.arange(B, dtype=jnp.int32)
+
+    def blk(S, xs):
+        qb, kb, vb, start = xs                              # (B, H, Dh)
+        nvl = jnp.clip(n_valid - start, 0, B)
+        cnt = jnp.minimum(pos + 1, nvl).astype(jnp.float32)  # decays so far
+        live = pos < nvl
+        qh, kh, vh = (jnp.swapaxes(t, 0, 1) for t in (qb, kb, vb))
+        a = jnp.einsum("hid,hjd->hij", qh, kh,
+                       preferred_element_type=jnp.float32) * scale
+        keep = (pos[None, :] <= pos[:, None]) & live[None, :]
+        m = jnp.where(keep, jnp.exp(-s * (cnt[:, None] - cnt[None, :])), 0.0)
+        v32 = vh.astype(jnp.float32)
+        o = jnp.einsum("hij,hjd->hid", a * m, v32, precision="highest")
+        o = o + jnp.exp(-s * cnt[None, :, None]) * jnp.einsum(
+            "hid,hde->hie", qh.astype(jnp.float32) * scale, S,
+            precision="highest")
+        kd = jnp.where(live[None, :, None], kh.astype(jnp.float32)
+                       * jnp.exp(-s * (nvl - cnt)[None, :, None]), 0.0)
+        S = jnp.exp(-s * nvl) * S + jnp.einsum("hjd,hje->hde", kd, v32,
+                                               precision="highest")
+        return S, jnp.swapaxes(o, 0, 1)
+
+    split = lambda t: t.reshape(C // B, B, H, Dh)
+    S, o = jax.lax.scan(blk, s_in.astype(jnp.float32),
+                        (split(q), split(k), split(v),
+                         jnp.arange(0, C, B, dtype=jnp.int32)))
+    return o.reshape(C, H, Dh), S
+
+
+def lightning_step(q, k, v, S, slopes):
+    """The recurrence for one token a row. q, k, v: (N, H, Dh); S: (N, H,
+    Dh, Dh) float32. Returns (o (N, H, Dh) float32, the new state)."""
+    lam = jnp.exp(-slopes.astype(jnp.float32))[None, :, None, None]
+    S = lam * S + (k.astype(jnp.float32)[..., :, None]
+                   * v.astype(jnp.float32)[..., None, :])
+    o = jnp.einsum("nhd,nhde->nhe",
+                   q.astype(jnp.float32) / math.sqrt(q.shape[-1]), S,
+                   precision="highest")
+    return o, S
+
+
+# ------------------------------------------------------------------ #
+# minicpm4: pooled keys, block scores, the selection
+# ------------------------------------------------------------------ #
+
+
+def pool_windows(k, sp: SparseAttnConfig):
+    """Means of k over the windows [i st, i st + ks) that lie wholly
+    inside its T tokens (T a multiple of the stride, the first token on a
+    stride boundary). k: (T, Hkv, Dh) -> (T / st - 1, Hkv, Dh), summed in
+    float32, in k's dtype."""
+    T = k.shape[0]
+    st = sp.kernel_stride
+    half = jnp.sum(k.astype(jnp.float32).reshape(T // st, st, *k.shape[1:]), 1)
+    return ((half[:-1] + half[1:]) / sp.kernel_size).astype(k.dtype)
+
+
+def block_scores(q, kbar, visible, sp: SparseAttnConfig):
+    """The score of every block for every query row and key head.
+    q: (R, H, Dh); kbar: (R, Hkv, J, Dh), or (Hkv, J, Dh) shared by the
+    rows, pooled key j the window that starts at token j * stride;
+    visible: (R, J) bool, the windows
+    wholly inside the row's causal past. Each head's softmax over the
+    visible windows, summed over a group's heads (float32), max-pooled
+    over the windows that touch a block. -> (R, Hkv, J / windows a
+    block) float32; a block with no visible window scores -1."""
+    R, H, Dh = q.shape
+    Hkv, J = kbar.shape[-3], kbar.shape[-2]
+    w = sp.windows_per_block
+    qg = q.reshape(R, Hkv, H // Hkv, Dh)
+    s = jnp.einsum("rhgd,rhjd->rhgj" if kbar.ndim == 4 else "rhgd,hjd->rhgj",
+                   qg, kbar,
+                   preferred_element_type=jnp.float32) / math.sqrt(Dh)
+    vis = visible[:, None, None, :]
+    p = jax.nn.softmax(jnp.where(vis, s, NEG), axis=-1)
+    a = jnp.where(visible[:, None, :], jnp.sum(jnp.where(vis, p, 0.0), 2),
+                  -1.0)                                     # (R, Hkv, J)
+    # block m is touched by the windows m w - 1 .. m w + w - 1
+    own = jnp.max(a.reshape(R, Hkv, J // w, w), -1)
+    before = jnp.concatenate(
+        [jnp.full((R, Hkv, 1), -1.0), a[..., w - 1::w][..., :-1]], -1)
+    return jnp.maximum(own, before)
+
+
+def select_blocks(b, q_block, sp: SparseAttnConfig):
+    """The blocks a query attends to under the sparse rule. b: (R, Hkv,
+    M) block scores; q_block: (R,) the block of the query's own position.
+    The first ``init_blocks`` and the ``local_blocks`` that end at the
+    query's own are forced in, the best-scored of its past fill the rest
+    of ``topk``. -> (blocks (R, Hkv, topk) int32, valid (R, Hkv, topk))."""
+    M = b.shape[-1]
+    m = jnp.arange(M, dtype=jnp.int32)[None, None, :]
+    bt = q_block[:, None, None]
+    forced = (m < sp.init_blocks) | ((m <= bt) & (m > bt - sp.local_blocks))
+    score = jnp.where(forced, 1e9, jnp.where(m <= bt, b, -1e9))
+    if M < sp.topk:
+        score = jnp.pad(score, ((0, 0), (0, 0), (0, sp.topk - M)),
+                        constant_values=-1e9)
+    vals, idx = jax.lax.top_k(score, sp.topk)
+    return idx.astype(jnp.int32), vals >= 0.0
+
+
+def visible_windows(q_pos, J: int, sp: SparseAttnConfig):
+    """(R, J): window j (tokens j st .. j st + ks - 1) lies wholly at or
+    before the query's position."""
+    j = jnp.arange(J, dtype=jnp.int32)[None, :]
+    return j * sp.kernel_stride + sp.kernel_size - 1 <= q_pos[:, None]
+
+
+def page_list(blocks, valid, q_pos, sp: SparseAttnConfig, width: int):
+    """A query's list of blocks, ``width`` wide, under the one causal
+    rule: the selection if it sees more than ``dense_len`` tokens, else
+    every block up to its own. The entries that count come first and the
+    query's own (partly filled) block last of them. blocks, valid: (R,
+    Hkv, topk) from ``select_blocks``; q_pos: (R,). -> (blocks (R, Hkv,
+    width) int32, n (R, Hkv) how many count)."""
+    R, Hkv, K = blocks.shape
+    bt = (q_pos // sp.block_size)[:, None, None]
+    pad = ((0, 0), (0, 0), (0, width - K))
+    sel_b, sel_v = jnp.pad(blocks, pad), jnp.pad(valid, pad)
+    m = jnp.arange(width, dtype=jnp.int32)[None, None, :]
+    dense = (q_pos + 1 <= sp.dense_len)[:, None, None]
+    blk = jnp.where(dense, jnp.broadcast_to(m, sel_b.shape), sel_b)
+    ok = jnp.where(dense, m <= bt, sel_v)
+    rank = jnp.where(ok, jnp.where(blk == bt, 1, 0), 2)
+    order = jnp.argsort(rank, axis=-1, stable=True)
+    blk = jnp.take_along_axis(blk, order, -1)
+    return blk, jnp.sum(ok, -1).astype(jnp.int32)
+
+
+# ------------------------------------------------------------------ #
+# the whole forward, no cache (tests, and what a cache must agree with)
+# ------------------------------------------------------------------ #
+
+
+def dense_sparse_attention(q, k, v, sp: SparseAttnConfig):
+    """minicpm4 over a whole sequence by its definition: every query
+    scores, selects and attends under the one causal rule. q: (S, H, Dh);
+    k, v: (S, Hkv, Dh); S a multiple of the block size. O(S^2) memory: a
+    small-size form. -> (S, H, Dh) float32."""
+    S, H, Dh = q.shape
+    Hkv = k.shape[1]
+    bs = sp.block_size
+    pos = jnp.arange(S, dtype=jnp.int32)
+    kbar = pool_windows(k, sp)                              # (S/st - 1, ...)
+    J = (S // bs) * sp.windows_per_block
+    kbar = jnp.pad(kbar, ((0, J - kbar.shape[0]), (0, 0), (0, 0)))
+    b = block_scores(q, jnp.swapaxes(kbar, 0, 1),
+                     visible_windows(pos, J, sp), sp)
+    blocks, valid = select_blocks(b, pos // bs, sp)
+    chosen = jnp.zeros((S, Hkv, S // bs), bool).at[
+        jnp.arange(S)[:, None, None], jnp.arange(Hkv)[None, :, None],
+        jnp.minimum(blocks, S // bs - 1)].max(valid)
+    see = jnp.where((pos + 1 <= sp.dense_len)[:, None, None], True, chosen)
+    see = jnp.repeat(see, bs, axis=-1) & (pos[None, None, :] <= pos[:, None, None])
+    qg = q.reshape(S, Hkv, H // Hkv, Dh)
+    s = jnp.einsum("qhgd,khd->qhgk", qg, k,
+                   preferred_element_type=jnp.float32) / math.sqrt(Dh)
+    p = jax.nn.softmax(jnp.where(see[:, :, None, :], s, NEG), -1)
+    o = jnp.einsum("qhgk,khd->qhgd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(S, H, Dh)
+
+
+def forward(cfg: GPTConfig, params, tokens):
+    """tokens (1, S) -> logits (1, S, V): the mixed stack with no cache,
+    each mixer by its definition (S a multiple of the sparse block)."""
+    S = tokens.shape[1]
+    slopes = lightning_slopes(cfg.n_head)
+    x = embed_tokens(cfg, params, tokens)
+    positions = jnp.arange(S, dtype=jnp.int32)
+
+    def body(kind, x, p, _i):
+        def core(q, k, v):
+            if kind == "lightning":
+                o, _ = lightning_chunk_xla(
+                    q[0], k[0], v[0],
+                    jnp.zeros((cfg.n_head, cfg.head_dim, cfg.head_dim)),
+                    slopes, S, block=S)
+            else:
+                o = dense_sparse_attention(q[0], k[0], v[0], cfg.sparse)
+            return o[None], None
+        return mixed_block(cfg, kind, x, p, positions, core)[0], ()
+
+    x, _ = scan_runs(cfg, params, x, body)
+    return head_logits(cfg, params, x)

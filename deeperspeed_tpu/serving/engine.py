@@ -49,7 +49,8 @@ import numpy as np
 
 from ..models.generation import apply_with_cache, init_cache, \
     prep_sampling_logits
-from ..models.gpt import GPTConfig, decoder_block, layer_norm
+from ..models import mixers
+from ..models.gpt import GPTConfig, decoder_block
 from ..models.speculative import engine_sample_key
 from ..monitor import get_monitor, init_monitor, install_compile_listener
 from ..monitor.tracer import (
@@ -65,6 +66,13 @@ from .kv_cache import (
     PagedKVCache,
     blocks_needed,
     decode_attend_for,
+    lightning_chunk_for,
+    sparse_attend_for,
+    decode_write_indices,
+    sparse_chunk_attend,
+    sparse_decode_attend,
+    write_chunk,
+    write_decode_rows,
 )
 from .metrics import ServingMetrics
 from .scheduler import Request, Scheduler
@@ -107,12 +115,19 @@ def request_sample_key(seed: int, count: int):
 # ------------------------------------------------------------------ #
 
 
-def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend):
-    """One decoder layer over all slots' single new tokens. The layer
-    math is gpt.decoder_block — only the attention core differs (mirrors
-    generation._cached_block): ``attend(q, k, v) -> (ctx, kv)`` reads the
-    paged pool, and ``kv`` (what the caller keeps of the new token's keys
-    and values) comes back beside the layer's output."""
+def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
+                 kind: str = "attention"):
+    """One layer of the stack inside a serving program, by its ``kind``
+    (one of ``cfg.layer_kinds``). The layer math is the model's own:
+    gpt.decoder_block's for an ``attention`` layer (the block training
+    runs), mixers.mixed_block's for the others. Only the core differs
+    (mirrors generation._cached_block): ``attend(q, k, v) -> (ctx, kept)``
+    reads the layer's cache (pages, a state row), and ``kept`` (what the
+    caller keeps of the new tokens: keys and values, a new state) comes
+    back beside the layer's output."""
+    if kind != "attention":
+        return mixers.mixed_block(cfg, kind, x, layer_params, positions,
+                                  attend)
     moe_cfg = cfg.moe
     if moe_cfg is not None:
         from ..models.moe import moe_ffn
@@ -133,7 +148,15 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     """Build the jitted all-slots decode step.
 
     decode_step(params, k_pool, v_pool, tables, lengths, tokens, temps,
-    seeds, counts) -> (next_tokens (N,), k_pool', v_pool'). Pools are
+    seeds, counts, kc_pool, state) -> (next_tokens (N,), k_pool', v_pool',
+    kc_pool', state'): one shape for every model. ``kc_pool`` and
+    ``state`` (``PagedKVCache.kc``, ``.state``) are None, in and out, for
+    a stack of attention layers; a model of mixed layers
+    (``cfg.mixer_types``) passes its pooled keys and its state rows,
+    donated like the pools: its sparse layers score the slot's pooled
+    keys, pick pages and read only those, its lightning layers read and
+    write their state row, and the layer loop goes run by run of one kind
+    (``mixers.scan_runs``; a classic model is one run). Pools are
     donated — the caller's old handles die each step (no second pool in
     HBM) — and stay in place: the layer loop only READS them (each layer
     attends over the pool's positions below the slot's length plus the
@@ -149,52 +172,81 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     if top_k is not None and top_k >= cfg.vocab_size:
         top_k = None  # full-vocab top-k is a no-op filter
 
-    @partial(jax.jit, donate_argnums=(1, 2))
+    sp = cfg.sparse
+    kinds = set(cfg.layer_kinds)
+    slopes = mixers.lightning_slopes(cfg.n_head)
+
+    @partial(jax.jit, donate_argnums=(1, 2, 9, 10))
     def ds_decode_step(params, k_pool, v_pool, tables, lengths, tokens,
-                       temps, seeds, counts):
-        cdt = cfg.dtype
+                       temps, seeds, counts, kc_pool=None, state=None):
         N = tokens.shape[0]
+        positions = lengths[:, None]                        # (N, 1)
         with jax.named_scope("ds.embed"):
-            wte = params["embed"]["wte"].astype(cdt)
-            x = jnp.take(wte, tokens, axis=0)[:, None, :]   # (N, 1, D)
-            positions = lengths[:, None]                    # (N, 1)
-            if not cfg.rotary:
-                x = x + jnp.take(params["embed"]["wpe"], positions,
-                                 axis=0).astype(cdt)
-        attend_rows = decode_attend_for(k_pool, tables, cfg.n_head, mesh)
+            x = mixers.embed_tokens(cfg, params, tokens,
+                                    lengths)[:, None, :]    # (N, 1, D)
+        # what the kinds of layer in this stack need beside the pools
+        if "attention" in kinds:
+            attend_rows = decode_attend_for(k_pool, tables, cfg.n_head, mesh)
+        if "minicpm4" in kinds:
+            attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
+            at = decode_write_indices(sp, tables, lengths)
+        if "lightning" in kinds:
+            # a slot whose prompt is still being chunked in is idle here:
+            # its state row is the chunks' to write
+            live = (lengths > 0)[:, None, None, None]
 
-        def scan_body(x, xs):
-            layer_params, layer = xs
+        def layer_body(kind, carry, layer_params, layer):
+            """A layer by its kind: the new token's row cast to what the
+            cache will hold, then the cache's read. The state rows ride
+            the carry and are written in place."""
+            x, rows = carry
 
-            def attend(q, k, v):
-                # cast first: the layer attends over the values the pool
-                # will hold
+            def attention(q, k, v):
                 k_row = k[:, 0].astype(k_pool.dtype)
                 v_row = v[:, 0].astype(v_pool.dtype)
                 ctx = attend_rows(k_pool, v_pool, layer, q, k_row, v_row,
                                   tables, lengths)
                 return ctx, (k_row, v_row)
 
-            return _paged_block(cfg, x, layer_params, positions, attend)
+            def minicpm4(q, k, v):
+                k_row = k[:, 0].astype(k_pool.dtype)
+                v_row = v[:, 0].astype(v_pool.dtype)
+                ctx, pooled = sparse_decode_attend(
+                    sp, k_pool, v_pool, kc_pool, layer, q, k_row, v_row,
+                    tables, lengths, at, attend_pages)
+                return ctx, (k_row, v_row, pooled)
 
-        x, (k_rows, v_rows) = jax.lax.scan(
-            scan_body, x,
-            (params["layers"], jnp.arange(cfg.n_layer, dtype=jnp.int32)))
+            def lightning(q, k, v):
+                o, new = mixers.lightning_step(q[:, 0], k[:, 0], v[:, 0],
+                                               rows[layer], slopes)
+                return o[:, None], jnp.where(live, new, rows[layer])
+
+            core = {"attention": attention, "minicpm4": minicpm4,
+                    "lightning": lightning}[kind]
+            x, kept = _paged_block(cfg, x, layer_params, positions, core,
+                                   kind)
+            if kind == "lightning":
+                rows = jax.lax.dynamic_update_index_in_dim(rows, kept, layer, 0)
+                kept = ()
+            return (x, rows), kept
+
+        (x, state), kept = mixers.scan_runs(cfg, params, (x, state),
+                                            layer_body)
         with jax.named_scope("ds.decode/kv_write"):
-            # (L, N, Hkv, Dh) rows into the donated pools, in place; idle
-            # slots all target (null block, 0), never read unmasked
-            wblk = tables[jnp.arange(N), lengths // scfg.block_size]
-            woff = lengths % scfg.block_size
-            k_pool = k_pool.at[:, wblk, woff].set(k_rows)
-            v_pool = v_pool.at[:, wblk, woff].set(v_rows)
+            # the layers' new rows into the donated pools, in place, by
+            # the kind that kept them; idle slots all target (null block,
+            # 0), never read unmasked
+            if "attention" in kept:
+                wblk = tables[jnp.arange(N), lengths // scfg.block_size]
+                woff = lengths % scfg.block_size
+                k_rows, v_rows = kept["attention"]      # (L, N, Hkv, Dh)
+                k_pool = k_pool.at[:, wblk, woff].set(k_rows)
+                v_pool = v_pool.at[:, wblk, woff].set(v_rows)
+            if "minicpm4" in kept:
+                k_pool, v_pool, kc_pool = write_decode_rows(
+                    sp, k_pool, v_pool, kc_pool, at, *kept["minicpm4"])
         with jax.named_scope("ds.decode/sample"):
-            x = layer_norm(x, params["final_ln"]["scale"],
-                           params["final_ln"]["bias"], cfg.layernorm_eps)
-            if cfg.tie_embeddings:
-                logits = x @ params["embed"]["wte"].astype(cdt).T
-            else:
-                logits = x @ params["lm_head"].astype(cdt)
-            logits = logits[:, 0]                           # (N, V)
+            logits = mixers.head_logits(cfg, params, x)[:, 0]   # (N, V)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             l32 = logits.astype(jnp.float32) / jnp.maximum(
                 temps, 1e-6)[:, None]
@@ -206,9 +258,93 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 lambda k, row: jax.random.categorical(k, row)
             )(keys, l32).astype(jnp.int32)
             nxt = jnp.where(temps > 0.0, sampled, greedy)
-        return nxt, k_pool, v_pool
+        return nxt, k_pool, v_pool, kc_pool, state
 
     return ds_decode_step
+
+
+def prefill_chunk_for(cfg: GPTConfig, scfg: ServingConfig) -> int:
+    """Tokens a chunk of a mixed stack's prompt holds: the configured
+    ``prefill_chunk``, else the sparse layers' local window (1024 with
+    none). Whole pages, whole pooling strides; every query of a chunk
+    falls on one side of ``dense_len`` and has the chunk's own keys
+    inside its local window."""
+    sp = cfg.sparse
+    C = scfg.prefill_chunk or (sp.window_size if sp is not None else 1024)
+    bad = C % scfg.block_size != 0
+    if sp is not None:
+        bad = bad or C > sp.window_size or sp.dense_len % C \
+            or C % sp.kernel_stride
+    if bad:
+        raise ValueError(
+            f"prefill_chunk {C} does not suit this model: it must be a "
+            f"multiple of block_size ({scfg.block_size})"
+            + (f" and of the pooling stride, at most window_size "
+               f"({sp.window_size}), and divide dense_len ({sp.dense_len})"
+               if sp is not None else ""))
+    return C
+
+
+def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
+    """Build the jitted prompt-chunk program of a mixed stack: how every
+    prompt of such a model enters.
+
+    prefill_chunk(params, k_pool, v_pool, kc_pool, state, tokens (1, C),
+    table_row (blocks_per_slot,), slot, offset, n_valid) -> (logits (V,)
+    at the chunk's last real position, k_pool', v_pool', kc_pool',
+    state'). ``offset`` (a multiple of C), ``slot`` and ``n_valid`` are
+    TRACED: one lowering serves every chunk of every prompt. The chunk
+    carries the slot's lightning state in (zeros at offset 0: a row is
+    cleared by whoever enters it, never by who left) and out, attends
+    over the slot's pages (sparse layers) and writes its own keys,
+    values and pooled keys after the layer loop, in place."""
+    C = prefill_chunk_for(cfg, scfg)
+    bs = scfg.block_size
+    sp = cfg.sparse
+    slopes = mixers.lightning_slopes(cfg.n_head)
+
+    @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
+    def ds_prefill_chunk(params, k_pool, v_pool, kc_pool, state, tokens,
+                         table_row, slot, offset, n_valid):
+        x = mixers.embed_tokens(cfg, params, tokens)        # (1, C, D)
+        positions = offset + jnp.arange(C, dtype=jnp.int32)
+        # the last chunk may run past the table's end: null pages there
+        table_row = jnp.pad(table_row, (0, C // bs))
+        attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
+        carried = jnp.where(
+            offset == 0, 0.0,
+            jax.lax.dynamic_index_in_dim(state, slot, 1, keepdims=False))
+
+        def layer_body(kind, x, layer_params, layer):
+            def minicpm4(q, k, v):
+                kk, vv = k[0].astype(k_pool.dtype), v[0].astype(v_pool.dtype)
+                ctx, pooled = sparse_chunk_attend(
+                    sp, k_pool, v_pool, kc_pool, layer, q[0], kk, vv,
+                    table_row, offset, attend_pages)
+                return ctx[None], (kk, vv, pooled)
+
+            def lightning(q, k, v):
+                o, new = lightning_chunk_for(q[0], mesh)(
+                    q[0], k[0], v[0], carried[layer], slopes, n_valid)
+                return o[None], new
+
+            core = {"minicpm4": minicpm4, "lightning": lightning}[kind]
+            return _paged_block(cfg, x, layer_params, positions, core, kind)
+
+        x, kept = mixers.scan_runs(cfg, params, x, layer_body)
+        with jax.named_scope("ds.prefill/kv_write"):
+            if "minicpm4" in kept:
+                k_pool, v_pool, kc_pool = write_chunk(
+                    sp, k_pool, v_pool, kc_pool, table_row, offset,
+                    *kept["minicpm4"])
+            if "lightning" in kept:
+                state = jax.lax.dynamic_update_slice(
+                    state, kept["lightning"][:, None], (0, slot, 0, 0, 0))
+        last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0)
+        return (mixers.head_logits(cfg, params, last)[0], k_pool, v_pool,
+                kc_pool, state)
+
+    return ds_prefill_chunk
 
 
 # ------------------------------------------------------------------ #
@@ -429,6 +565,18 @@ class ServingEngine(_ServingBase):
                 f"model's learned-position table ({cfg.max_seq})"
             )
         self.cfg = cfg
+        if not cfg.classic:
+            kinds = sorted(set(cfg.mixer_types))
+            if scfg.prefix_caching and cfg.count("lightning"):
+                raise ValueError(
+                    "prefix_caching cannot serve a model with recurrent "
+                    f"layers ({kinds}): a cached prefix's pages say nothing "
+                    "of the lightning state after it, and no snapshot of "
+                    "that state is kept. Turn prefix_caching off")
+            if mesh is not None or scfg.speculative is not None:
+                raise NotImplementedError(
+                    f"a stack of {kinds} layers is served on one device, "
+                    "without speculation")
         # dp×tp serving: with a mesh, params place by their TP specs
         # (sharding rule table translates the model's legacy 'model'
         # specs onto a canonical tp axis), the paged KV pools shard
@@ -462,9 +610,16 @@ class ServingEngine(_ServingBase):
         self._prefill_step = jax.jit(ds_prefill)
         self._suffix_prefill = jax.jit(ds_suffix_prefill,
                                        donate_argnums=(2, 3))
+        # a mixed stack's prompts all enter chunk by chunk, straight into
+        # the pool and the slot's state row (no staging cache)
+        self._chunk_step = (None if cfg.classic
+                            else make_chunk_step(cfg, scfg))
         # slot -> in-flight chunked-prefill state (staging cache, cursor)
         self._chunking: Dict[int, dict] = {}
         self._prefill_spent = 0   # prompt tokens prefilled this step
+        self._chunk_ran = False   # ... of them any in a chunk
+        if self.kv.state is not None:
+            self.metrics.state_bytes = self.kv.state.nbytes
         if self.telemetry is not None:
             # decode must stay one-compile forever; prefill legitimately
             # retraces per length bucket, so it is deliberately unwatched
@@ -606,6 +761,7 @@ class ServingEngine(_ServingBase):
         the launch that crosses it still runs, so progress is guaranteed
         and a prompt longer than the budget cannot starve)."""
         self._prefill_spent = 0
+        self._chunk_ran = False
         self._sweep_chunk_states()
         for slot in sorted(self._chunking):
             if not self._budget_ok():
@@ -633,7 +789,11 @@ class ServingEngine(_ServingBase):
     def _admit_one(self, slot: int, req: Request, blocks: List[int]) -> None:
         """Prefill the request's context into its allocated blocks.
 
-        Three paths: (1) no cached prefix, prompt within one chunk —
+        A mixed stack's prompt enters chunk by chunk through
+        ``ds_prefill_chunk``, in place (its pool is laid out page by page
+        and its state rows are no pages: nothing of it can be staged). A
+        stack of attention layers has three paths: (1) no cached prefix,
+        prompt within one chunk —
         the original full bucketed prefill; (2) cached prefix — gather
         shared pages into a staging cache, forward only the suffix at
         the matched offset, scatter back the private pages (the matched
@@ -643,6 +803,16 @@ class ServingEngine(_ServingBase):
         long prompt."""
         ctx = req.context
         L = len(ctx)
+        if self._chunk_step is not None:
+            C = prefill_chunk_for(self.cfg, self.scfg)
+            state = {"req": req, "ctx": ctx, "L": L, "chunk": C,
+                     "n": -(-L // C), "next": 0,
+                     "forward": self._forward_chunk,
+                     "table": jnp.asarray(self.sched.slot_table_row(slot),
+                                          jnp.int32)}
+            self._chunking[slot] = state
+            self._pump_slot(slot, state)
+            return
         plan = (self.scfg.prefill_plan(L, req.prefix_matched)
                 if (req.prefix_matched > 0
                     or self.scfg.prefill_chunk is not None) else None)
@@ -663,71 +833,126 @@ class ServingEngine(_ServingBase):
             "req": req, "blocks": blocks, "m": m, "L": L,
             "suffix": ctx[m:], "n": n_chunks, "chunk": chunk,
             "cache_len": cache_len, "k": k_stage, "v": v_stage,
-            "next": 0,
+            "next": 0, "forward": self._forward_staged,
         }
         self._chunking[slot] = state
         self._pump_slot(slot, state)
 
     def _pump_slot(self, slot: int, state: dict) -> None:
-        """Forward staged prompt chunks for one slot while the step
-        budget allows; the final chunk scatters the staging cache into
-        the pool and emits the request's first token."""
-        req = state["req"]
-        chunk = state["chunk"]
-        suffix = state["suffix"]
+        """Forward prompt chunks for one slot while the step budget
+        allows, each by the route its prompt entered on
+        (``state["forward"]``: staged or in place); the final chunk emits
+        the request's first token."""
         while state["next"] < state["n"] and self._budget_ok():
-            c = state["next"]
-            lo = c * chunk
-            hi = min(lo + chunk, len(suffix))
-            final = (c + 1) == state["n"]
+            state["forward"](slot, state)
+
+    def _forward_staged(self, slot: int, state: dict) -> None:
+        """One chunk of a staged suffix through ``ds_suffix_prefill``; the
+        final chunk scatters the staging cache into the pool."""
+        req, chunk, suffix = state["req"], state["chunk"], state["suffix"]
+        c = state["next"]
+        lo = c * chunk
+        hi = min(lo + chunk, len(suffix))
+        final = (c + 1) == state["n"]
+        if final:
+            cm = trace_span("serving/prefill", lane="serving",
+                            rid=req.rid, slot=slot,
+                            ctx_len=state["L"],
+                            bucket=state["cache_len"])
+        else:
+            cm = trace_span("serving/prefill_chunk", lane="serving",
+                            rid=req.rid, chunk=c, tokens=hi - lo)
+        with cm as _sp:
+            with trace_span("serving/prefill/pack", lane="serving"):
+                toks = np.zeros((1, chunk), np.int32)
+                toks[0, :hi - lo] = suffix[lo:hi]
+                _pargs = (self.params, jnp.asarray(toks), state["k"],
+                          state["v"], state["m"] + lo)
+            with trace_span("serving/prefill/dispatch",
+                            lane="serving"):
+                logits, cache = self._suffix_prefill(*_pargs)
+            state["k"], state["v"] = cache["k"], cache["v"]
             if final:
-                cm = trace_span("serving/prefill", lane="serving",
-                                rid=req.rid, slot=slot,
-                                ctx_len=state["L"],
-                                bucket=state["cache_len"])
-            else:
-                cm = trace_span("serving/prefill_chunk", lane="serving",
-                                rid=req.rid, chunk=c, tokens=hi - lo)
-            with cm as _sp:
-                with trace_span("serving/prefill/pack", lane="serving"):
-                    toks = np.zeros((1, chunk), np.int32)
-                    toks[0, :hi - lo] = suffix[lo:hi]
-                    _pargs = (self.params, jnp.asarray(toks), state["k"],
-                              state["v"], state["m"] + lo)
-                with trace_span("serving/prefill/dispatch",
+                with trace_span("serving/prefill/scatter",
                                 lane="serving"):
-                    logits, cache = self._suffix_prefill(*_pargs)
-                state["k"], state["v"] = cache["k"], cache["v"]
-                if final:
-                    with trace_span("serving/prefill/scatter",
-                                    lane="serving"):
-                        self._finish_staged(req, state)
-                    with trace_span("serving/prefill/pick",
-                                    lane="serving"):
-                        tok = self._pick_token(logits[0, hi - lo - 1], req)
-                    req.generated.append(tok)
-                tel = self.telemetry
-                if tel is not None:
-                    if tel.cost_index is not None:
-                        # one compile per (chunk len, staging len) pair;
-                        # the traced offset keeps every chunk position
-                        # on the same program
-                        tel.cost_index.observe(
-                            f"serving/suffix_prefill"
-                            f"[s{chunk}c{state['cache_len']}]",
-                            self._suffix_prefill, _pargs)
-                    if tel.memwatch is not None:
-                        tel.memwatch.annotate(_sp, "prefill")
-            self._prefill_spent += hi - lo
-            self.metrics.record_prefill_chunk(hi - lo)
-            state["next"] += 1
+                    self._finish_staged(req, state)
+                with trace_span("serving/prefill/pick",
+                                lane="serving"):
+                    tok = self._pick_token(logits[0, hi - lo - 1], req)
+                req.generated.append(tok)
+            tel = self.telemetry
+            if tel is not None:
+                if tel.cost_index is not None:
+                    # one compile per (chunk len, staging len) pair;
+                    # the traced offset keeps every chunk position
+                    # on the same program
+                    tel.cost_index.observe(
+                        f"serving/suffix_prefill"
+                        f"[s{chunk}c{state['cache_len']}]",
+                        self._suffix_prefill, _pargs)
+                if tel.memwatch is not None:
+                    tel.memwatch.annotate(_sp, "prefill")
+        self._prefill_spent += hi - lo
+        self._chunk_ran = True
+        self.metrics.record_prefill_chunk(hi - lo)
+        state["next"] += 1
+        if final:
+            del self._chunking[slot]
+            logger.debug(
+                "serving: admitted %s to slot %d (ctx=%d matched=%d "
+                "chunks=%d)", req.rid, slot, state["L"], state["m"],
+                state["n"])
+            self._record_emitted(req, prefill=True)
+
+    def chunk_pages_read(self, offset: int) -> int:
+        """Pool pages the sparse layers' selections name in one prompt
+        chunk at ``offset`` (0 while the dense rule holds: that read is a
+        gather of the slot's first pages, not a selection): every query
+        and key head ``topk`` blocks less those of the chunk itself."""
+        sp = self.cfg.sparse
+        if sp is None or offset < sp.dense_len:
+            return 0
+        C = prefill_chunk_for(self.cfg, self.scfg)
+        n = C // sp.block_size
+        return (self.cfg.count("minicpm4") * self.cfg.kv_heads
+                * (C * sp.topk - sp.block_size * n * (n + 1) // 2))
+
+    def _forward_chunk(self, slot: int, state: dict) -> None:
+        """One chunk of a mixed stack's prompt through ``ds_prefill_chunk``:
+        pages, pooled keys and the slot's state row are written in place;
+        the last chunk yields the request's first token."""
+        req, C, c = state["req"], state["chunk"], state["next"]
+        lo = c * C
+        hi = min(lo + C, state["L"])
+        final = (c + 1) == state["n"]
+        # serving/prefill: a request's prompt work inside one step, as for
+        # every model; the chunk inside it says where in the prompt it is
+        with trace_span("serving/prefill", lane="serving", rid=req.rid,
+                        slot=slot, ctx_len=state["L"], bucket=C), \
+                trace_span("serving/prefill_chunk", lane="serving",
+                           rid=req.rid, chunk=c, tokens=hi - lo, offset=lo,
+                           pages=self.chunk_pages_read(lo)):
+            with trace_span("serving/prefill/pack", lane="serving"):
+                toks = np.zeros((1, C), np.int32)
+                toks[0, :hi - lo] = state["ctx"][lo:hi]
+                kv = self.kv
+                _pargs = (self.params, kv.k, kv.v, kv.kc, kv.state,
+                          jnp.asarray(toks), state["table"], np.int32(slot),
+                          np.int32(lo), np.int32(hi - lo))
+            with trace_span("serving/prefill/dispatch", lane="serving"):
+                logits, kv.k, kv.v, kv.kc, kv.state = \
+                    self._chunk_step(*_pargs)
             if final:
-                del self._chunking[slot]
-                logger.debug(
-                    "serving: admitted %s to slot %d (ctx=%d matched=%d "
-                    "chunks=%d)", req.rid, slot, state["L"], state["m"],
-                    state["n"])
-                self._record_emitted(req, prefill=True)
+                with trace_span("serving/prefill/pick", lane="serving"):
+                    req.generated.append(self._pick_token(logits, req))
+        self._prefill_spent += hi - lo
+        self._chunk_ran = True
+        self.metrics.record_prefill_chunk(hi - lo)
+        state["next"] += 1
+        if final:
+            del self._chunking[slot]
+            self.metrics.record_reuse(0, state["L"])
+            self._record_emitted(req, prefill=True)
 
     def _finish_staged(self, req: Request, state: dict) -> None:
         """Scatter the staged suffix into the slot's private blocks.
@@ -823,14 +1048,20 @@ class ServingEngine(_ServingBase):
             temps = np.zeros(N, np.float32)
             seeds = np.zeros(N, np.int32)
             counts = np.zeros(N, np.int32)
-            live_pages = 0
+            live_pages = selected_pages = 0
+            sp = self.cfg.sparse
             for s, req in active:
                 tables[s] = self.sched.slot_table_row(s)
                 lengths[s] = req.cached_len
                 # the pages that hold a live position of a live slot,
                 # the new token's included: all the pool a step need read
-                live_pages += blocks_needed(req.cached_len + 1,
-                                            self.scfg.block_size)
+                live = blocks_needed(req.cached_len + 1,
+                                     self.scfg.block_size)
+                live_pages += live
+                if sp is not None:
+                    # what one selection of a sparse layer names of them
+                    selected_pages += (live if req.cached_len + 1
+                                       <= sp.dense_len else sp.topk)
                 tokens[s] = req.pending_token
                 temps[s] = req.temperature
                 seeds[s] = req.seed
@@ -840,11 +1071,14 @@ class ServingEngine(_ServingBase):
             _dargs = (self.params, self.kv.k, self.kv.v, _place(tables),
                       _place(lengths), _place(tokens),
                       _place(temps), _place(seeds),
-                      _place(counts))
-        self.metrics.record_kv_pages(live_pages, tables.size)
+                      _place(counts), self.kv.kc, self.kv.state)
+        self.metrics.record_kv_pages(live_pages, tables.size,
+                                     selected_pages)
         with trace_span("serving/decode/dispatch", lane="serving",
-                        live_pages=live_pages, view_pages=tables.size):
-            nxt, self.kv.k, self.kv.v = self._decode_step(*_dargs)
+                        live_pages=live_pages, view_pages=tables.size,
+                        selected_pages=selected_pages):
+            nxt, self.kv.k, self.kv.v, self.kv.kc, self.kv.state = \
+                self._decode_step(*_dargs)
         with trace_span("serving/decode/wait", lane="serving"):
             nxt = np.asarray(nxt)               # device sync
         self._last_dargs = _dargs
@@ -882,7 +1116,8 @@ class ServingEngine(_ServingBase):
                 tel.watchdog.observe("serving/decode_step",
                                      step=self._step_i)
             self.metrics.record_decode_step(
-                len(active), len(self.sched.queue), self.clock())
+                len(active), len(self.sched.queue), self.clock(),
+                held_chunk=self._chunk_ran)
             with trace_span("serving/decode/emit", lane="serving"):
                 for s, req in active:
                     req.cached_len += 1

@@ -5,6 +5,18 @@ Layout: one pool per cache side, stacked over layers —
 
     k, v: (n_layer, num_blocks, block_size, n_kv_head, head_dim)
 
+A model of mixed layers (``GPTConfig.mixer_types``) keeps pages for its
+``minicpm4`` layers only, a page's ``(block_size, head_dim)`` last so that
+two key heads are not padded to a tile of sixteen, and beside them what its
+other layers keep between tokens:
+
+    k, v:  (n_sparse, num_blocks, n_kv_head, block_size, head_dim)
+    kc:    (n_sparse, num_blocks, n_kv_head * windows_per_block, head_dim)
+           the selector's pooled keys: a page's row h * w + i is key head
+           h's mean over the window that starts at the page's token i * st
+    state: (n_lightning, num_slots, n_head, head_dim, head_dim) float32,
+           one row a SLOT (not pages: it has one size whatever the length)
+
 A request's cache lives in whichever blocks the allocator hands it; the
 per-slot BLOCK TABLE (``(num_slots, blocks_per_slot)`` int32) maps the
 request's logical block ``i`` to its physical block. Block 0 is the
@@ -334,8 +346,25 @@ class PagedKVCache:
         self.cfg = cfg
         self.scfg = scfg
         nb = scfg.num_blocks if num_blocks is None else int(num_blocks)
-        shape = (cfg.n_layer, nb, scfg.block_size,
-                 cfg.kv_heads, cfg.head_dim)
+        self.kc = self.state = None
+        if cfg.classic:
+            shape = (cfg.n_layer, nb, scfg.block_size,
+                     cfg.kv_heads, cfg.head_dim)
+        else:
+            sp = cfg.sparse
+            if sp is not None and (scfg.block_size != sp.block_size
+                                   or scfg.blocks_per_slot < sp.list_blocks):
+                raise ValueError(
+                    f"a page is one selection block: block_size must be "
+                    f"{sp.block_size} (got {scfg.block_size}) and "
+                    f"max_seq_len cover dense_len ({sp.dense_len})")
+            n_sp, n_li = cfg.count("minicpm4"), cfg.count("lightning")
+            shape = (n_sp, nb, cfg.kv_heads, scfg.block_size, cfg.head_dim)
+            w = sp.windows_per_block if sp is not None else 1
+            self.kc = jnp.zeros((n_sp, nb, cfg.kv_heads * w, cfg.head_dim),
+                                cfg.dtype)
+            self.state = jnp.zeros((n_li, scfg.num_slots, cfg.n_head,
+                                    cfg.head_dim, cfg.head_dim), jnp.float32)
         self.k = jnp.zeros(shape, cfg.dtype)
         self.v = jnp.zeros(shape, cfg.dtype)
         self.allocator = BlockAllocator(nb)
@@ -539,3 +568,303 @@ def decode_attend_for(k_pool, tables, n_head, mesh):
             k_pool, tables, n_head):
         return kernel.paged_decode_attn
     return paged_attend_rows
+
+
+# ------------------------------------------------------------------ #
+# mixed stacks: selected pages, pooled keys, a state row a slot
+# ------------------------------------------------------------------ #
+
+
+def paged_sparse_attend_xla(k_pool, v_pool, layer, q, row_head, pages,
+                            n_tokens, m0, l0, acc0):
+    """Attention of R rows, each over the pages ITS list names — the XLA
+    form, the oracle of ops/pallas/paged_sparse_attn.
+
+    k_pool/v_pool: (L, num_blocks, Hkv, bs, Dh); ``layer`` traced. q: (R,
+    G, Dh), a row's G query heads share the key head ``row_head[r]``.
+    pages: (R, P) physical pages, read in order; the first ``n_tokens[r]``
+    positions of their concatenation count (so only the last page that
+    counts may be partly filled). m0, l0 (R, G) and acc0 (R, G, Dh),
+    float32: the running maximum, sum and unnormalised output of what the
+    caller already attended over (the new token itself, the chunk's own
+    keys); the result is normalised over both. -> (R, G, Dh) in q's dtype.
+    """
+    R, G, Dh = q.shape
+    P, bs = pages.shape[1], k_pool.shape[3]
+    scale = 1.0 / math.sqrt(Dh)
+    col = jnp.arange(P * bs, dtype=jnp.int32)
+
+    def rows(a):
+        q, rh, pg, nt, m0, l0, acc0 = a
+        k = k_pool[layer, pg, rh[:, None]].reshape(-1, P * bs, Dh)
+        v = v_pool[layer, pg, rh[:, None]].reshape(-1, P * bs, Dh)
+        s = jnp.einsum("rgd,rkd->rgk", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where((col[None, :] < nt[:, None])[:, None, :], s, -1e30)
+        m = jnp.maximum(m0, jnp.max(s, -1))
+        p = jnp.exp(s - m[..., None])
+        alpha = jnp.exp(m0 - m)
+        l = alpha * l0 + jnp.sum(p, -1)
+        acc = alpha[..., None] * acc0 + jnp.einsum(
+            "rgk,rkd->rgd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return (acc / l[..., None]).astype(q.dtype)
+
+    args = (q, row_head, pages, n_tokens, m0, l0, acc0)
+    batch = 64      # rows gathered at once: P pages each, materialised
+    if R <= batch or R % batch:
+        return rows(args)
+    out = jax.lax.map(rows, jax.tree.map(
+        lambda a: a.reshape(R // batch, batch, *a.shape[1:]), args))
+    return out.reshape(R, G, Dh)
+
+
+def sparse_attend_for(k_pool, n_head, mesh):
+    """``paged_sparse_attend_xla`` or, on one TPU at shapes it can tile,
+    the kernel of the same signature (as ``decode_attend_for`` chooses)."""
+    from ..ops.pallas import paged_sparse_attn as kernel
+
+    if (mesh is None or mesh.size == 1) and kernel.is_available(
+            k_pool, n_head):
+        return kernel.paged_sparse_attn
+    return paged_sparse_attend_xla
+
+
+def lightning_chunk_for(q, mesh):
+    """The chunkwise form of a lightning layer: the kernel on one TPU at
+    shapes it can tile, else ``mixers.lightning_chunk_xla``."""
+    from ..models.mixers import lightning_chunk_xla
+    from ..ops.pallas import lightning_chunk as kernel
+
+    if (mesh is None or mesh.size == 1) and kernel.is_available(q):
+        return kernel.lightning_chunk
+    return lightning_chunk_xla
+
+
+def _own_token_init(q, k_row, v_row):
+    """(m0, l0, acc0) of rows that have attended over one key so far:
+    their own. q: (R, G, Dh); k_row, v_row: (R, Dh)."""
+    s = jnp.sum(q.astype(jnp.float32) * k_row.astype(jnp.float32)[:, None],
+                -1) / math.sqrt(q.shape[-1])
+    acc = jnp.broadcast_to(v_row.astype(jnp.float32)[:, None], q.shape)
+    return s, jnp.ones_like(s), acc
+
+
+def pooled_keys_of(kc_pool, layer, table, Hkv):
+    """A slot's pooled keys in window order: kc_pool (L, num_blocks, Hkv
+    * w, Dh), table (..., n) physical pages -> (..., Hkv, n * w, Dh)."""
+    kb = kc_pool[layer, table]                          # (..., n, Hkv w, Dh)
+    *lead, n, hw, Dh = kb.shape
+    kb = jnp.moveaxis(kb.reshape(*lead, n, Hkv, hw // Hkv, Dh), -3, -4)
+    return kb.reshape(*lead, Hkv, n * (hw // Hkv), Dh)
+
+
+def decode_write_indices(sp, tables, lengths):
+    """Where a decode step's new rows go, the same for every layer: the
+    page and row of the new token's key and value, and the page and
+    window (null page where none is completed) of the pooled key the new
+    token completes."""
+    bs, st, ks = sp.block_size, sp.kernel_stride, sp.kernel_size
+    t = lengths
+    one = lambda i: jnp.take_along_axis(tables, i[:, None], 1)[:, 0]
+    completes = ((t + 1) % st == 0) & (t + 1 >= ks)
+    j_new = jnp.maximum(t + 1 - ks, 0) // st
+    return {"page": one(t // bs), "row": t % bs,
+            "kc_page": jnp.where(completes, one(j_new * st // bs), NULL_BLOCK),
+            "kc_window": j_new % sp.windows_per_block,
+            "completes": completes, "j_new": j_new}
+
+
+def write_rows(pool, page, row, new):
+    """pool (L, num_blocks, ..., R, Dh) with ``new`` (L, N, ..., Dh) laid
+    over row ``row[n]`` of page ``page[n]``: whole pages are read, changed
+    and written back, so that nothing indexes inside a page of the pool
+    (XLA re-lays the WHOLE pool out for a scatter or a gather that does).
+    Rows of several slots on one page (idle slots, the null page) race;
+    what lands there is never read unmasked."""
+    cur = pool[:, page]                                 # (L, N, ..., R, Dh)
+    R = cur.shape[-2]
+    hit = (jnp.arange(R)[None, :] == row[:, None]).reshape(
+        (1, len(row)) + (1,) * (cur.ndim - 4) + (R, 1))
+    return pool.at[:, page].set(
+        jnp.where(hit, new[..., None, :].astype(pool.dtype), cur))
+
+
+def write_decode_rows(sp, k_pool, v_pool, kc_pool, at, k_rows, v_rows,
+                      pooled):
+    """A decode step's new keys, values (n, N, Hkv, Dh) and pooled keys
+    of all sparse layers into the slots' pages at ``at``
+    (``decode_write_indices``); a pooled key lands in the window its
+    token completes, the null page's where it completes none."""
+    k_pool = write_rows(k_pool, at["page"], at["row"], k_rows)
+    v_pool = write_rows(v_pool, at["page"], at["row"], v_rows)
+    n, N, Hkv, Dh = pooled.shape
+    w = sp.windows_per_block
+    cur = kc_pool[:, at["kc_page"]].reshape(n, N, Hkv, w, Dh)
+    hit = (jnp.arange(w)[None, :] == at["kc_window"][:, None])
+    kc_pool = kc_pool.at[:, at["kc_page"]].set(jnp.where(
+        hit[None, :, None, :, None], pooled[..., None, :], cur
+    ).reshape(n, N, -1, Dh))
+    return k_pool, v_pool, kc_pool
+
+
+def sparse_decode_attend(sp, k_pool, v_pool, kc_pool, layer, q, k_row,
+                         v_row, tables, lengths, at, attend_pages):
+    """One minicpm4 layer's decode attention for all slots. q: (N, 1, H,
+    Dh); k_row, v_row: (N, Hkv, Dh), the new token's, in the pool's dtype;
+    slot i's new token sits at position ``lengths[i]``; ``at`` is
+    ``decode_write_indices``. Scores the slot's pooled keys (the window
+    the new token completes among them), lists the pages under the one
+    causal rule (mixers.page_list) and reads those. Returns (ctx (N, 1, H,
+    Dh), that window's pooled key (N, Hkv, Dh))."""
+    from ..models import mixers as mx
+
+    N, _, H, Dh = q.shape
+    Hkv, bs = k_pool.shape[2], k_pool.shape[3]
+    bps = tables.shape[1]
+    w, ks = sp.windows_per_block, sp.kernel_size
+    t = lengths
+    # the window that ends at the new token: ks - 1 rows of the slot's own
+    # page and the one before it (whole pages gathered, rows picked after)
+    bt = t // bs
+    two = jnp.take_along_axis(
+        tables, jnp.stack([jnp.maximum(bt - 1, 0), bt], 1), axis=1)
+    pg = jnp.swapaxes(k_pool[layer, two], 1, 2).reshape(N, Hkv, 2 * bs, Dh)
+    idx = (t % bs)[:, None] + bs - (ks - 1) + jnp.arange(ks - 1)
+    prev = jnp.take_along_axis(pg, idx[:, None, :, None], axis=2)
+    window = jnp.concatenate([prev, k_row[:, :, None]], 2)  # (N, Hkv, ks, Dh)
+    kbar_new = mx.pool_windows(jnp.moveaxis(window, 2, 0), sp)[0]
+    kbar = pooled_keys_of(kc_pool, layer, tables, Hkv)      # (N, Hkv, J, Dh)
+    J = bps * w
+    new = (jnp.arange(J)[None, :] == at["j_new"][:, None]) \
+        & at["completes"][:, None]
+    kbar = jnp.where(new[:, None, :, None], kbar_new[:, :, None, :], kbar)
+    b = mx.block_scores(q[:, 0], kbar, mx.visible_windows(t, J, sp), sp)
+    blocks, valid = mx.select_blocks(b, bt, sp)
+    blk, n = mx.page_list(blocks, valid, t, sp, sp.list_blocks)
+    pages = jnp.take_along_axis(tables[:, None, :],
+                                jnp.minimum(blk, bps - 1), axis=2)
+    n_tokens = jnp.maximum(n - 1, 0) * bs + (t % bs)[:, None]
+    R, G, P = N * Hkv, H // Hkv, pages.shape[-1]
+    q_rows = q.reshape(R, G, Dh)
+    ctx = attend_pages(
+        k_pool, v_pool, layer, q_rows, jnp.tile(jnp.arange(Hkv), N),
+        pages.reshape(R, P), n_tokens.reshape(R),
+        *_own_token_init(q_rows, k_row.reshape(R, Dh), v_row.reshape(R, Dh)))
+    return ctx.reshape(N, 1, H, Dh), kbar_new
+
+
+def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
+                        table_row, offset, attend_pages):
+    """One minicpm4 layer's attention for a prompt chunk of C tokens at
+    positions ``offset ..`` (traced; a multiple of C) of one slot, whose
+    earlier pages are in the pool. q: (C, H, Dh); k, v: (C, Hkv, Dh) in
+    the pool's dtype. While the chunk ends inside ``dense_len`` every
+    query attends to all its past; beyond it every query selects: the
+    chunk's own keys (all inside each query's local window) densely, the
+    pages of the past through ``attend_pages``. Returns (ctx (C, H, Dh),
+    the pooled keys of the C / st windows this chunk completes, the first
+    of them starting st tokens before the chunk: (C / st, Hkv, Dh))."""
+    from ..models import mixers as mx
+
+    C, H, Dh = q.shape
+    Hkv, bs = k_pool.shape[2], k_pool.shape[3]
+    G = H // Hkv
+    bps = table_row.shape[0]
+    w, st = sp.windows_per_block, sp.kernel_stride
+    scale = 1.0 / math.sqrt(Dh)
+    q_pos = offset + jnp.arange(C, dtype=jnp.int32)
+    # the window that starts st tokens before the chunk ends inside it:
+    # those tokens are the last rows of the page before (a whole page read)
+    before = k_pool[layer, table_row[jnp.maximum(offset // bs - 1, 0)]]
+    kbar_new = mx.pool_windows(
+        jnp.concatenate([jnp.swapaxes(before, 0, 1)[bs - st:], k], 0), sp)
+    qg = q.reshape(C, Hkv, G, Dh)
+
+    def own_keys():
+        """(m, l, acc) of every query over the chunk's keys up to itself."""
+        s = jnp.einsum("qhgd,khd->qhgk", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        causal = jnp.arange(C)[None, :] <= jnp.arange(C)[:, None]
+        s = jnp.where(causal[:, None, None, :], s, -1e30)
+        m = jnp.max(s, -1)
+        p = jnp.exp(s - m[..., None])
+        acc = jnp.einsum("qhgk,khd->qhgd", p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+        return m, jnp.sum(p, -1), acc
+
+    def dense():
+        n_past = min(sp.dense_len // bs, bps)
+        past = lambda pool: jnp.swapaxes(
+            pool[layer, table_row[:n_past]], 0, 1).reshape(Hkv, n_past * bs, Dh)
+        kp, vp = past(k_pool), past(v_pool)
+        m0, l0, acc0 = own_keys()
+        live = jnp.arange(n_past * bs) < offset
+
+        def tile(a):
+            qt, m0, l0, acc0 = a
+            s = jnp.einsum("qhgd,hkd->qhgk", qt, kp,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(live, s, -1e30)
+            m = jnp.maximum(m0, jnp.max(s, -1))
+            p = jnp.exp(s - m[..., None])
+            alpha = jnp.exp(m0 - m)
+            acc = alpha[..., None] * acc0 + jnp.einsum(
+                "qhgk,hkd->qhgd", p.astype(vp.dtype), vp,
+                preferred_element_type=jnp.float32)
+            return acc / (alpha * l0 + jnp.sum(p, -1))[..., None]
+
+        tq = min(C, 256)
+        out = jax.lax.map(tile, jax.tree.map(
+            lambda a: a.reshape(C // tq, tq, *a.shape[1:]),
+            (qg, m0, l0, acc0)))
+        return out.reshape(C, H, Dh).astype(q.dtype)
+
+    def sparse():
+        J = bps * w
+        kbar = jax.lax.dynamic_update_slice(
+            pooled_keys_of(kc_pool, layer, table_row, Hkv),    # (Hkv, J, Dh)
+            jnp.swapaxes(kbar_new, 0, 1), (0, offset // st - 1, 0))
+        b = mx.block_scores(q, kbar, mx.visible_windows(q_pos, J, sp), sp)
+        blocks, valid = mx.select_blocks(b, q_pos // bs, sp)
+        ok = valid & (blocks < offset // bs)     # the rest: the chunk's own
+        order = jnp.argsort(~ok, axis=-1, stable=True)
+        blk = jnp.take_along_axis(blocks, order, -1)
+        pages = table_row[jnp.minimum(blk, bps - 1)]           # (C, Hkv, K)
+        n_tokens = jnp.sum(ok, -1).astype(jnp.int32) * bs
+        R = C * Hkv
+        m0, l0, acc0 = own_keys()
+        ctx = attend_pages(
+            k_pool, v_pool, layer, q.reshape(R, G, Dh),
+            jnp.tile(jnp.arange(Hkv), C), pages.reshape(R, -1),
+            n_tokens.reshape(R), m0.reshape(R, G), l0.reshape(R, G),
+            acc0.reshape(R, G, Dh))
+        return ctx.reshape(C, H, Dh)
+
+    ctx = jax.lax.cond(offset + C <= sp.dense_len, dense, sparse)
+    return ctx, kbar_new
+
+
+def write_chunk(sp, k_pool, v_pool, kc_pool, table_row, offset, kk, vv,
+                pooled):
+    """A prompt chunk's keys, values (n, C, Hkv, Dh) and pooled keys (n, C
+    / st, Hkv, Dh) of all sparse layers into the slot's pages, whole
+    pages at a time. ``table_row`` is padded past the slot's last page;
+    the pooled keys start at the last window of the page BEFORE the chunk
+    (the null page's at offset 0)."""
+    n, C, Hkv, Dh = kk.shape
+    bs, w = sp.block_size, sp.windows_per_block
+    pg = C // bs
+    ids = jax.lax.dynamic_slice(table_row, (offset // bs,), (pg,))
+    pages = lambda t: jnp.swapaxes(t.reshape(n, pg, bs, Hkv, Dh), 2, 3)
+    k_pool = k_pool.at[:, ids].set(pages(kk))
+    v_pool = v_pool.at[:, ids].set(pages(vv))
+    ids = jax.lax.dynamic_slice(
+        jnp.concatenate([jnp.zeros((1,), table_row.dtype), table_row]),
+        (offset // bs,), (pg + 1,))
+    cur = jnp.swapaxes(kc_pool[:, ids].reshape(n, pg + 1, Hkv, w, Dh), 1, 2)
+    cur = cur.reshape(n, Hkv, (pg + 1) * w, Dh).at[
+        :, :, w - 1:w - 1 + pooled.shape[1]].set(jnp.swapaxes(pooled, 1, 2))
+    cur = jnp.swapaxes(cur.reshape(n, Hkv, pg + 1, w, Dh), 1, 2)
+    return k_pool, v_pool, kc_pool.at[:, ids].set(
+        cur.reshape(n, pg + 1, Hkv * w, Dh))

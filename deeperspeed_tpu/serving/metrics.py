@@ -145,6 +145,15 @@ class ServingMetrics:
         # of every slot's whole view, summed over plain decode steps
         self.kv_live_pages = 0
         self.kv_view_pages = 0
+        # of the live pages, those a block-sparse layer's selection names
+        self.kv_selected_pages = 0
+        # tokens decoded, and those of them decoded in a step that first
+        # ran a prompt chunk (their gap held the chunk)
+        self.gaps = 0
+        self.chunk_gaps = 0
+        # bytes of per-slot recurrent state beside the pages (the engine
+        # sets it once; 0 for a model that keeps pages only)
+        self.state_bytes = 0
         self.prefills = 0
         self.preemptions = 0
         # prefix reuse / chunked prefill: admissions is every context
@@ -268,11 +277,15 @@ class ServingMetrics:
                 "Staged prompt-chunk forwards.").inc()
 
     def record_decode_step(self, n_active: int, queue_depth: int,
-                           now: float) -> None:
+                           now: float, held_chunk: bool = False) -> None:
+        """``held_chunk``: a prompt chunk ran in this step before the
+        decode, so each of the step's tokens came a chunk later."""
         if self._start_t is None:
             self._start_t = now
         self.decode_steps += 1
         self.total_generated += n_active
+        self.gaps += n_active
+        self.chunk_gaps += n_active if held_chunk else 0
         self.queue_depth.append(queue_depth)
         self.occupancy.append(n_active / self.num_slots)
         self._end_t = now
@@ -283,9 +296,13 @@ class ServingMetrics:
             self._g_active.set(n_active)
             self._g_occ.set(n_active / self.num_slots)
 
-    def record_kv_pages(self, live_pages: int, view_pages: int) -> None:
+    def record_kv_pages(self, live_pages: int, view_pages: int,
+                        selected_pages: int = 0) -> None:
+        """``selected_pages``: of the live pages, those one selection of
+        a block-sparse layer names (0 for a model that has none)."""
         self.kv_live_pages += live_pages
         self.kv_view_pages += view_pages
+        self.kv_selected_pages += selected_pages
 
     def record_preemption(self) -> None:
         self.preemptions += 1
@@ -389,6 +406,12 @@ class ServingMetrics:
             "slot_occupancy": float(occ.mean()) if occ.size else 0.0,
             "kv_live_page_frac": (self.kv_live_pages / self.kv_view_pages
                                   if self.kv_view_pages else 0.0),
+            "kv_selected_page_frac": (
+                self.kv_selected_pages / self.kv_live_pages
+                if self.kv_live_pages else 0.0),
+            "chunk_gap_share": (self.chunk_gaps / self.gaps
+                                if self.gaps else 0.0),
+            "state_bytes": int(self.state_bytes),
             "queue_depth_max": int(max(self.queue_depth, default=0)),
             "slo": self.slo_tracker.summary(),
             "prefix_reuse": {

@@ -196,7 +196,7 @@ def test_rows_written_are_the_old_forms_rows_bit_for_bit(dtype):
     lengths = [2 * bs, 3 * bs - 1, 0, bs]
     args = _step_args(cfg, params, lengths)
     want_k, want_v = _old_form_step(cfg)(*args[:6])
-    _, got_k, got_v = make_decode_step(cfg, SCFG)(*args)
+    _, got_k, got_v, _, _ = make_decode_step(cfg, SCFG)(*args)
     tables = np.asarray(args[3])
     for got, want in ((got_k, want_k), (got_v, want_v)):
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)

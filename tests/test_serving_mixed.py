@@ -1,0 +1,321 @@
+"""A stack of mixed layers (MiniCPM-SALA's kinds: ``minicpm4`` block-sparse
+attention over pages, ``lightning`` linear attention over a state row a
+slot) through ``ServingEngine``, at small widths on the CPU: 2 sparse + 6
+lightning layers, ``dense_len`` 64, block 8, top-4. What the engine serves
+(chunked prefill, then decode through the caches) is compared with the
+plain reference ``benchmark/refs/minicpm_sala.py`` on seeded weights, and
+the parts with each other."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.adapters import minicpm_sala as adapter
+from benchmark.refs import init as rinit
+from benchmark.refs import minicpm_sala as ref
+from deeperspeed_tpu.models import mixers
+from deeperspeed_tpu.models.gpt import GPTConfig, SparseAttnConfig, make_gpt
+from deeperspeed_tpu.ops.pallas.lightning_chunk import lightning_chunk
+from deeperspeed_tpu.ops.pallas.paged_sparse_attn import paged_sparse_attn
+from deeperspeed_tpu.serving.engine import prefill_chunk_for
+from deeperspeed_tpu.serving.kv_cache import paged_sparse_attend_xla
+
+PERIOD = ["minicpm4"] + ["lightning-attn"] * 3
+TOY = {
+    "family": "minicpm_sala", "hidden_size": 64, "intermediate_size": 128,
+    "vocab_size": 96, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "head_dim": 16, "rms_norm_eps": 1e-6, "rope_theta": 10000,
+    "num_hidden_layers": 8, "num_layers": 8, "mixer_types": PERIOD * 2,
+    "scale_emb": 12, "scale_depth": 1.4, "dim_model_base": 16,
+    "max_position_embeddings": 4096, "tie_word_embeddings": False,
+    "compute_dtype": "float32",
+    "sparse_config": {"block_size": 8, "topk": 4, "kernel_size": 4,
+                      "kernel_stride": 2, "init_blocks": 1, "window_size": 16,
+                      "dense_len": 64},
+    "weights": {"std": 0.05, "sparse_qkv_std": 0.2, "sparse_qk_gain": 1.5},
+}
+SERVING = {"num_slots": 3, "block_size": 8, "num_blocks": 121,
+           "max_seq_len": 256, "prefill_chunk": 16,
+           "prefill_token_budget": 16, "max_new_tokens": 32}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return rinit.init_tree(7, ref.leaf_specs(TOY), jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return ref.Forward(ref.make(TOY))
+
+
+def engine_for(params, **serving):
+    return adapter.serving_engine(TOY, params, {**SERVING, **serving})
+
+
+def prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 96, n).tolist() for n in lengths]
+
+
+def served(params, lengths, new=12, **serving):
+    eng = engine_for(params, **serving)
+    ps = prompts(lengths)
+    for i, p in enumerate(ps):
+        eng.submit(p, max_new_tokens=new, request_id=f"r{i}")
+    out = eng.run()
+    return eng, ps, [out[f"r{i}"] for i in range(len(ps))]
+
+
+# ------------------------------------------------------------------ #
+# the engine against the plain reference
+# ------------------------------------------------------------------ #
+
+
+@pytest.mark.parametrize("lengths", [
+    pytest.param((100, 37, 200), id="across_dense_len"),
+    pytest.param((5, 16, 63), id="the_dense_rule_alone"),
+    pytest.param((64, 65, 129), id="at_the_boundaries")])
+def test_prefill_chunks_and_decode_agree_with_the_reference(
+        params, reference, lengths):
+    """Chunked prefill, then decode through pages, pooled keys and state
+    rows: every served (greedy) token is the reference's best at its
+    position, within a rounding of the logits' spread."""
+    eng, ps, outs = served(params, lengths)
+    for p, o in zip(ps, outs):
+        logits = np.asarray(reference.logits(params, p + o, len(p)))
+        gap = logits.max(-1) - logits[np.arange(len(o)), o]
+        assert gap.max() <= 1e-4 * logits.std(), (len(p), gap.max())
+    assert eng.decode_compile_count == 1
+    assert eng._chunk_step._cache_size() == 1       # one lowering, every chunk
+    assert eng.prefill_compile_count == 0           # no bucketed prefill
+
+
+def test_first_token_logits_of_a_chunked_prompt(params, reference):
+    """The chunk program's own logits (the last real position of the last
+    chunk, a prompt that ends mid-chunk and beyond dense_len) against the
+    reference's."""
+    eng = engine_for(params)
+    (p,) = prompts((117,), seed=3)
+    cfg, scfg, kv = eng.cfg, eng.scfg, eng.kv
+    blocks = kv.allocator.alloc(-(-118 // 8))
+    table = jnp.asarray(blocks + [0] * (scfg.blocks_per_slot - len(blocks)),
+                        jnp.int32)
+    C = prefill_chunk_for(cfg, scfg)
+    for lo in range(0, len(p), C):
+        toks = np.zeros((1, C), np.int32)
+        n = min(C, len(p) - lo)
+        toks[0, :n] = p[lo:lo + n]
+        logits, kv.k, kv.v, kv.kc, kv.state = eng._chunk_step(
+            eng.params, kv.k, kv.v, kv.kc, kv.state, jnp.asarray(toks), table,
+            np.int32(1), np.int32(lo), np.int32(n))
+    want = np.asarray(reference.logits(params, p + [0], len(p)))[0]
+    np.testing.assert_allclose(np.asarray(logits), want, atol=2e-5)
+
+
+def test_the_whole_forward_agrees_with_the_reference(params, reference):
+    """mixers.forward (each mixer by its definition, no cache) is the
+    program's own statement of the model."""
+    cfg = adapter.model_config(TOY)
+    (p,) = prompts((136,), seed=5)
+    got = mixers.forward(cfg, params, jnp.asarray([p], jnp.int32))[0]
+    want = reference.logits(params, p + [0], 1)
+    np.testing.assert_allclose(np.asarray(got[:-1]), np.asarray(want)[:-1],
+                               atol=2e-5)
+
+
+# ------------------------------------------------------------------ #
+# chunked = unchunked; recurrence = chunkwise
+# ------------------------------------------------------------------ #
+
+
+def test_chunked_and_unchunked_prefill_leave_the_same_state_and_pages(params):
+    """One prompt in chunks of 16 and in chunks of 32 (a window of 32 and
+    top-8 allow both): the same first token, state rows, pages, pooled
+    keys."""
+    toy = dict(TOY, sparse_config=dict(TOY["sparse_config"], window_size=32,
+                                       topk=8))
+    (p,) = prompts((150,), seed=9)
+    got = []
+    for chunk in (16, 32):
+        eng = adapter.serving_engine(toy, params, {
+            **SERVING, "prefill_chunk": chunk, "prefill_token_budget": None})
+        eng.submit(p, max_new_tokens=3, request_id="r")
+        eng.step()                            # every chunk, one decode step
+        blocks = list(eng.sched.slot_blocks[0])
+        n = len(p) // 8                       # whole pages of the prompt
+        got.append((eng.get("r").output, np.asarray(eng.kv.state[:, 0]),
+                    np.asarray(eng.kv.k[:, blocks[:n]]),
+                    np.asarray(eng.kv.v[:, blocks[:n]]),
+                    # a page's last window ends in the next page
+                    np.asarray(eng.kv.kc[:, blocks[:n - 1]])))
+    assert got[0][0] == got[1][0]
+    for a, b in zip(got[0][1:], got[1][1:]):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+    assert np.abs(got[0][1]).max() > 0 and np.abs(got[0][4]).max() > 0
+
+
+@pytest.mark.parametrize("n_valid", [64, 37, 0])
+def test_decode_recurrence_is_the_chunkwise_form(n_valid):
+    C, H, Dh = 64, 4, 128
+    ks = jax.random.split(jax.random.PRNGKey(n_valid), 4)
+    q, k, v = (jax.random.normal(kk, (C, H, Dh)) for kk in ks[:3])
+    s_in = jax.random.normal(ks[3], (H, Dh, Dh)) * 0.1
+    slopes = mixers.lightning_slopes(H)
+    o, S = mixers.lightning_chunk_xla(q, k, v, s_in, slopes, n_valid, block=16)
+    state, outs = s_in[None], []
+    for t in range(n_valid):
+        o_t, state = mixers.lightning_step(q[t][None], k[t][None], v[t][None],
+                                           state, slopes)
+        outs.append(o_t[0])
+    np.testing.assert_allclose(np.asarray(S), np.asarray(state[0]), atol=1e-4)
+    if n_valid:
+        np.testing.assert_allclose(np.asarray(o[:n_valid]),
+                                   np.asarray(jnp.stack(outs)), atol=1e-4)
+
+
+# ------------------------------------------------------------------ #
+# the kernels, interpreted, against their XLA oracles
+# ------------------------------------------------------------------ #
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_selected_pages_kernel_against_its_oracle_at_two_key_heads(dtype):
+    L, nb, Hkv, bs, Dh, R, G, P = 2, 20, 2, 16, 128, 6, 4, 8
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    kp = jax.random.normal(ks[0], (L, nb, Hkv, bs, Dh)).astype(dtype)
+    vp = jax.random.normal(ks[1], (L, nb, Hkv, bs, Dh)).astype(dtype)
+    q = jax.random.normal(ks[2], (R, G, Dh)).astype(dtype)
+    pages = jnp.asarray(np.random.default_rng(0).integers(1, nb, (R, P)),
+                        jnp.int32)
+    # nothing to read, part of a page, whole pages, all of the list
+    n_tokens = jnp.asarray([0, 5, 16, 100, 128, 77], jnp.int32)
+    heads = jnp.asarray([0, 1, 0, 1, 0, 1], jnp.int32)
+    m0 = jax.random.normal(ks[3], (R, G))
+    l0 = jnp.full((R, G), 1.5)
+    acc0 = jax.random.normal(ks[4], (R, G, Dh))
+    args = (kp, vp, jnp.int32(1), q, heads, pages, n_tokens, m0, l0, acc0)
+    want = paged_sparse_attend_xla(*args).astype(jnp.float32)
+    got = paged_sparse_attn(*args, interpret=True).astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    # a row with nothing to read returns what it came with
+    np.testing.assert_allclose(np.asarray(got[0]),
+                               np.asarray((acc0 / l0[..., None])[0].astype(
+                                   dtype).astype(jnp.float32)), atol=1e-6)
+
+
+@pytest.mark.parametrize("n_valid", [64, 37])
+def test_lightning_kernel_against_its_oracle(n_valid):
+    C, H, Dh = 64, 4, 128
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q, k, v = (jax.random.normal(kk, (C, H, Dh)).astype(jnp.bfloat16)
+               for kk in ks[:3])
+    s_in = jax.random.normal(ks[3], (H, Dh, Dh)) * 0.1
+    slopes = mixers.lightning_slopes(H)
+    o1, S1 = mixers.lightning_chunk_xla(q, k, v, s_in, slopes, n_valid, block=16)
+    o2, S2 = lightning_chunk(q, k, v, s_in, slopes, n_valid, block=16,
+                             interpret=True)
+    np.testing.assert_allclose(np.asarray(o2[:n_valid]),
+                               np.asarray(o1[:n_valid]), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(S2), np.asarray(S1), atol=1e-4)
+
+
+# ------------------------------------------------------------------ #
+# the scheduler: a state row as well as blocks
+# ------------------------------------------------------------------ #
+
+
+def test_preempt_and_readmit_gives_identical_tokens(params):
+    """A pool too small for all three: the youngest is preempted while it
+    decodes, its state row is rebuilt by re-prefilling prompt + generated
+    (never resumed), and every request's tokens are those of a roomy pool."""
+    lengths = (90, 100, 80)
+    _, _, roomy = served(params, lengths, new=24)
+    eng, _, tight = served(params, lengths, new=24, num_blocks=40)
+    assert eng.metrics.summary()["preemptions"] >= 1
+    assert tight == roomy
+    assert eng.kv.allocator.num_allocated == 0
+
+
+def test_a_slot_is_cleared_by_who_enters_it(params, reference):
+    """The second request takes the slot (and state row) the first left."""
+    eng = engine_for(params, num_slots=1)
+    ps = prompts((70, 90), seed=4)
+    for i, p in enumerate(ps):
+        eng.submit(p, max_new_tokens=6, request_id=f"r{i}")
+    out = eng.run()
+    for i, p in enumerate(ps):
+        logits = np.asarray(reference.logits(params, p + out[f"r{i}"], len(p)))
+        assert (logits.argmax(-1) == np.asarray(out[f"r{i}"])).all()
+
+
+def test_prefix_caching_refuses_recurrent_layers(params):
+    with pytest.raises(ValueError, match="prefix_caching cannot serve"):
+        engine_for(params, prefix_caching=True)
+
+
+def test_a_chunk_size_the_model_cannot_take_is_refused(params):
+    with pytest.raises(ValueError, match="prefill_chunk 24"):
+        engine_for(params, prefill_chunk=24)
+    with pytest.raises(ValueError, match="a page is one selection block"):
+        engine_for(params, block_size=16, prefill_chunk=16)
+
+
+def test_training_this_stack_raises_and_a_classic_one_is_untouched():
+    cfg = adapter.model_config(TOY)
+    init_fn, apply_fn, loss_fn, specs = make_gpt(cfg)
+    assert set(init_fn(jax.random.PRNGKey(0))) == {
+        "embed", "final_norm", "lm_head", "sparse", "lightning"}
+    with pytest.raises(NotImplementedError, match="served only"):
+        loss_fn(None, None)
+    classic = GPTConfig(n_layer=2, n_head=2, d_model=32, vocab_size=64)
+    assert classic.classic and classic.layer_kinds == ("attention",) * 2
+    assert mixers.layer_runs(classic) == [("attention", 0, 2)]
+    # attention layers keep their own tree and pool layout: no mixing
+    with pytest.raises(ValueError, match="mixer_types must name"):
+        GPTConfig(n_layer=2, n_head=2, d_model=32, vocab_size=64,
+                  mixer_types=("attention", "lightning"))
+
+
+def test_layer_runs_and_specs_of_the_published_cut():
+    import json
+    import os
+
+    from benchmark import manifest as mf
+
+    config = mf.load_json(os.path.join(mf.BENCH_DIR, "configs",
+                                       "minicpm-sala.json"))
+    cfg = adapter.model_config(config)
+    assert mixers.layer_runs(cfg) == [
+        ("minicpm4", 0, 1), ("lightning", 0, 6), ("minicpm4", 1, 2),
+        ("lightning", 6, 4), ("minicpm4", 3, 1), ("lightning", 10, 2)]
+    assert cfg.count("lightning") == 12 and cfg.count("minicpm4") == 4
+    assert not cfg.classic and cfg.count("attention") == 0
+    assert cfg.residual_scale == pytest.approx(0.2475, abs=1e-4)
+    assert cfg.logit_scale == 1 / 16 and cfg.scale_emb == 12
+    assert dataclasses.asdict(cfg.sparse) == config["sparse_config"]
+    assert json.dumps(config["reduced"]) == '["num_layers", "mixer_types"]'
+
+
+def test_counters_of_a_served_window(params):
+    eng, _, _ = served(params, (100, 37, 200))
+    s = eng.metrics.summary()
+    assert 0 < s["chunk_gap_share"] < 1
+    assert 0 < s["kv_selected_page_frac"] <= 1
+    assert s["state_bytes"] == eng.kv.state.nbytes == 6 * 3 * 4 * 16 * 16 * 4
+    # beyond dense_len a chunk's selections name topk blocks less its own
+    assert eng.chunk_pages_read(0) == eng.chunk_pages_read(48) == 0
+    assert eng.chunk_pages_read(64) == 2 * 2 * (16 * 4 - 8 * 3)
+
+
+def test_sparse_config_is_validated():
+    with pytest.raises(ValueError, match="topk"):
+        SparseAttnConfig(block_size=8, topk=2, kernel_size=4, kernel_stride=2,
+                         window_size=16, dense_len=64)
+    with pytest.raises(ValueError, match="kernel_size"):
+        SparseAttnConfig(block_size=8, kernel_size=6, kernel_stride=2)
+    with pytest.raises(ValueError, match="mixer_types"):
+        GPTConfig(n_layer=2, mixer_types=("lightning",))

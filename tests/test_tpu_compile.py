@@ -97,3 +97,109 @@ def test_neox_1p3b_decode_step_holds_the_pool_once(one_chip, as_if_on_tpu):
     weights = sum(2 * math.prod(a.shape) for a in jax.tree.leaves(params))
     ask = extract_memory_analysis(compiled)["peak_bytes"]
     assert ask < 1.5 * pool + weights, (ask, pool, weights)
+
+
+# ------------------------------------------------------------------ #
+# MiniCPM-SALA: the kernels and the two serving programs at the cell's size
+# ------------------------------------------------------------------ #
+
+
+def _sala():
+    import os
+
+    from benchmark import manifest as mf
+    from benchmark.adapters import minicpm_sala as adapter
+    from deeperspeed_tpu.serving import ServingConfig
+
+    man = mf.Manifest()
+    cfg = adapter.model_config(man.config("minicpm-sala"))
+    scfg = ServingConfig.from_dict(
+        man.workload_file("minicpm-sala.serve-longdoc")["serving"])
+    return cfg, scfg
+
+
+@pytest.mark.parametrize("R,P", [(24, 128), (2048, 64)],
+                         ids=["a_decode_step", "a_prompt_chunk"])
+def test_paged_sparse_attn_compiles_at_the_longdoc_cells_geometry(
+        one_chip, R, P):
+    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import paged_sparse_attn
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    G, Dh = 16, 128
+    pool = sds((4, 6241, 2, 64, Dh))
+    f32 = jnp.float32
+    compiled = paged_sparse_attn.lower(
+        pool, pool, sds((), jnp.int32), sds((R, G, Dh)), sds((R,), jnp.int32),
+        sds((R, P), jnp.int32), sds((R,), jnp.int32), sds((R, G), f32),
+        sds((R, G), f32), sds((R, G, Dh), f32)).compile()
+    assert "paged_sparse_attn" in compiled.as_text()
+
+
+def test_lightning_chunk_compiles_at_the_longdoc_cells_geometry(one_chip):
+    from deeperspeed_tpu.ops.pallas.lightning_chunk import lightning_chunk
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    C, H, Dh = 1024, 32, 128
+    compiled = lightning_chunk.lower(
+        sds((C, H, Dh)), sds((C, H, Dh)), sds((C, H, Dh)),
+        sds((H, Dh, Dh), jnp.float32), sds((H,), jnp.float32),
+        sds((), jnp.int32)).compile()
+    assert "lightning_chunk" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_sala_programs_move_neither_the_pool_nor_a_weight_stack(
+        one_chip, as_if_on_tpu, program):
+    """The decode step and the prompt-chunk program of the long-document
+    cell (16 layers at the published widths, 12 slots, 399,360 tokens of
+    pages): the donated pools, pooled keys and state rows are outputs in
+    place; nothing copies a pool (XLA re-laid the WHOLE pool out for each
+    gather or scatter that indexed inside a page, 3 GiB and five passes a
+    step) or a stack of weights (it transposed the lightning layers' q, k
+    and v stacks every call while they were three projections); the
+    compiler's ask stays inside the chip."""
+    from deeperspeed_tpu.models import mixers
+    from deeperspeed_tpu.serving.engine import (make_chunk_step,
+                                                make_decode_step)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg, scfg = _sala()
+    params = jax.tree.map(
+        lambda a: sds(a.shape),
+        jax.eval_shape(lambda: mixers.init_params(jax.random.PRNGKey(0), cfg)))
+    N, nb, bps = scfg.num_slots, scfg.num_blocks, scfg.blocks_per_slot
+    i32 = jnp.int32
+    pool = sds((4, nb, 2, 64, 128))
+    kc = sds((4, nb, 8, 128))
+    state = sds((12, N, 32, 128, 128), jnp.float32)
+    if program == "decode":
+        compiled = make_decode_step(cfg, scfg).lower(
+            params, pool, pool, sds((N, bps), i32), sds((N,), i32),
+            sds((N,), i32), sds((N,), jnp.float32), sds((N,), i32),
+            sds((N,), i32), kc, state).compile()
+    else:
+        compiled = make_chunk_step(cfg, scfg).lower(
+            params, pool, pool, kc, state, sds((1, 1024), i32), sds((bps,), i32),
+            sds((), i32), sds((), i32), sds((), i32)).compile()
+    text = compiled.as_text()
+    assert "paged_sparse_attn" in text
+    assert ("lightning_chunk" in text) == (program == "chunk")
+    assert count_alias_pairs(text) == 4        # k, v, pooled keys, state rows
+    big = ("bf16[4,6241,2,64,128]", "bf16[4,6241,8,128]",
+           "bf16[12,4096,", "bf16[12,16384,", "bf16[4,4096,", "bf16[4,16384,")
+    moved = [ln.strip()[:140] for ln in text.splitlines()
+             if " copy(" in ln and ln.split(" = ")[1].startswith(big)]
+    assert moved == []
+    weights = sum(2 * math.prod(a.shape) for a in jax.tree.leaves(params))
+    caches = 2 * (2 * math.prod(pool.shape) + math.prod(kc.shape)) \
+        + 4 * math.prod(state.shape)
+    ask = extract_memory_analysis(compiled)["peak_bytes"]
+    assert weights + caches < ask < weights + caches + 2 ** 30, (
+        ask, weights, caches)
+    assert ask < 12.5 * 2 ** 30
