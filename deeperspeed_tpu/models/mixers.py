@@ -307,22 +307,76 @@ def block_scores(q, kbar, visible, sp: SparseAttnConfig):
     return jnp.maximum(own, before)
 
 
-def select_blocks(b, q_block, sp: SparseAttnConfig):
-    """The blocks a query attends to under the sparse rule. b: (R, Hkv,
-    M) block scores; q_block: (R,) the block of the query's own position.
-    The first ``init_blocks`` and the ``local_blocks`` that end at the
-    query's own are forced in, the best-scored of its past fill the rest
-    of ``topk``. -> (blocks (R, Hkv, topk) int32, valid (R, Hkv, topk))."""
-    M = b.shape[-1]
-    m = jnp.arange(M, dtype=jnp.int32)[None, None, :]
+def top_mask(score, k: int):
+    """The ``k`` largest of every row of ``score`` (..., M) float32 as a
+    mask, equal scores to the lower index: the set a stable descending
+    sort puts first, with no sort. The k-th largest value of a row is
+    found bit by bit over the order-preserving integer image of a float
+    (32 steps, each a compare and a row count), the ties at it taken by a
+    prefix count."""
+    M = score.shape[-1]
+    if k >= M:
+        return jnp.ones(score.shape, bool)
+    lead = score.shape[:-1]
+    # one row a (query, key head): two key heads alone would leave three
+    # quarters of every (8, 128) tile empty through the 32 steps
+    score = score.reshape(-1, M)
+    score = jnp.where(score == 0, 0.0, score)               # -0.0 is 0.0
+    bits = jax.lax.bitcast_convert_type(score, jnp.uint32)
+    # negative floats order backwards and below every positive one
+    u = jnp.where(score < 0, ~bits, bits | jnp.uint32(1 << 31))
+
+    def step(i, t):
+        cand = t | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = jnp.sum(u >= cand[:, None], -1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, t)
+
+    # the largest t that k entries reach: the k-th largest value
+    t = jax.lax.fori_loop(0, 32, step, jnp.zeros(u.shape[:1], jnp.uint32))
+    above, tie = u > t[:, None], u == t[:, None]
+    room = k - jnp.sum(above, -1, dtype=jnp.int32)
+    mask = above | (tie & (jnp.cumsum(tie, -1, dtype=jnp.int32)
+                           <= room[:, None]))
+    return mask.reshape(*lead, M)
+
+
+def selection_mask(b, q_block, sp: SparseAttnConfig):
+    """The blocks a query attends to under the sparse rule, as a mask. b:
+    (R, Hkv, M) block scores; q_block: (R,) the block of the query's own
+    position. The first ``init_blocks`` and the ``local_blocks`` that end
+    at the query's own are forced in; the best-scored of its past fill
+    the rest of ``topk``, a block counting only if its score is >= 0 (it
+    has a visible window), equal scores to the lower index. -> (R, Hkv,
+    M) bool."""
+    m = jnp.arange(b.shape[-1], dtype=jnp.int32)[None, None, :]
     bt = q_block[:, None, None]
     forced = (m < sp.init_blocks) | ((m <= bt) & (m > bt - sp.local_blocks))
     score = jnp.where(forced, 1e9, jnp.where(m <= bt, b, -1e9))
-    if M < sp.topk:
-        score = jnp.pad(score, ((0, 0), (0, 0), (0, sp.topk - M)),
-                        constant_values=-1e9)
-    vals, idx = jax.lax.top_k(score, sp.topk)
-    return idx.astype(jnp.int32), vals >= 0.0
+    return top_mask(score, sp.topk) & (score >= 0.0)
+
+
+def compact(mask, width: int):
+    """A mask over blocks (..., M) as a list: entry j is the j-th set
+    block in ASCENDING order (the blocks before it are those whose prefix
+    count is <= j), an entry past the count reads M. -> (blocks (...,
+    width) int32, n (...) how many count)."""
+    cum = jnp.cumsum(mask, -1, dtype=jnp.int32)
+    j = jnp.arange(width, dtype=jnp.int32)[:, None]
+    blocks = jnp.sum(cum[..., None, :] <= j, -1, dtype=jnp.int32)
+    return blocks, cum[..., -1]
+
+
+def select_blocks(b, q_block, sp: SparseAttnConfig):
+    """``selection_mask`` as a list ``topk`` wide, in ascending block
+    order, the entries that count first. Every selected block lies at or
+    before the query's own, so its own (partly filled) block is the last
+    that counts, and a rule that keeps the blocks below a bound
+    (``page_list``) keeps a prefix of the list. The kernel reads a row's
+    pages in list order into one online softmax: the order moves the
+    rounding of its sums and nothing else. -> (blocks (R, Hkv, topk)
+    int32, valid (R, Hkv, topk))."""
+    blocks, n = compact(selection_mask(b, q_block, sp), sp.topk)
+    return blocks, jnp.arange(sp.topk, dtype=jnp.int32) < n[..., None]
 
 
 def visible_windows(q_pos, J: int, sp: SparseAttnConfig):
@@ -332,25 +386,28 @@ def visible_windows(q_pos, J: int, sp: SparseAttnConfig):
     return j * sp.kernel_stride + sp.kernel_size - 1 <= q_pos[:, None]
 
 
-def page_list(blocks, valid, q_pos, sp: SparseAttnConfig, width: int):
+def page_list(blocks, valid, q_pos, sp: SparseAttnConfig, width: int,
+              before=None):
     """A query's list of blocks, ``width`` wide, under the one causal
     rule: the selection if it sees more than ``dense_len`` tokens, else
-    every block up to its own. The entries that count come first and the
-    query's own (partly filled) block last of them. blocks, valid: (R,
-    Hkv, topk) from ``select_blocks``; q_pos: (R,). -> (blocks (R, Hkv,
-    width) int32, n (R, Hkv) how many count)."""
-    R, Hkv, K = blocks.shape
-    bt = (q_pos // sp.block_size)[:, None, None]
-    pad = ((0, 0), (0, 0), (0, width - K))
-    sel_b, sel_v = jnp.pad(blocks, pad), jnp.pad(valid, pad)
+    every block up to its own; of either, the blocks below ``before``
+    only (a prompt chunk reads the pages before it and attends to its
+    own keys densely). Ascending, so the entries that count come first,
+    the query's own (partly filled) block last of them, and each rule
+    keeps a prefix: nothing is moved. An entry past the count may name a
+    block past the slot's last (``kv_cache.pages_of`` reads the null page
+    there: a page of the pool is all the kernel asks of such an entry).
+    blocks, valid: (R, Hkv, topk) from ``select_blocks``; q_pos: (R,). ->
+    (blocks (R, Hkv, width) int32, n (R, Hkv) how many count)."""
+    pad = ((0, 0), (0, 0), (0, width - blocks.shape[-1]))
     m = jnp.arange(width, dtype=jnp.int32)[None, None, :]
+    bt = (q_pos // sp.block_size)[:, None, None]
     dense = (q_pos + 1 <= sp.dense_len)[:, None, None]
-    blk = jnp.where(dense, jnp.broadcast_to(m, sel_b.shape), sel_b)
-    ok = jnp.where(dense, m <= bt, sel_v)
-    rank = jnp.where(ok, jnp.where(blk == bt, 1, 0), 2)
-    order = jnp.argsort(rank, axis=-1, stable=True)
-    blk = jnp.take_along_axis(blk, order, -1)
-    return blk, jnp.sum(ok, -1).astype(jnp.int32)
+    blk = jnp.where(dense, m, jnp.pad(blocks, pad))
+    ok = jnp.where(dense, m <= bt, jnp.pad(valid, pad))
+    if before is not None:
+        ok = ok & (blk < before)
+    return blk, jnp.sum(ok, -1, dtype=jnp.int32)
 
 
 # ------------------------------------------------------------------ #
@@ -372,10 +429,7 @@ def dense_sparse_attention(q, k, v, sp: SparseAttnConfig):
     kbar = jnp.pad(kbar, ((0, J - kbar.shape[0]), (0, 0), (0, 0)))
     b = block_scores(q, jnp.swapaxes(kbar, 0, 1),
                      visible_windows(pos, J, sp), sp)
-    blocks, valid = select_blocks(b, pos // bs, sp)
-    chosen = jnp.zeros((S, Hkv, S // bs), bool).at[
-        jnp.arange(S)[:, None, None], jnp.arange(Hkv)[None, :, None],
-        jnp.minimum(blocks, S // bs - 1)].max(valid)
+    chosen = selection_mask(b, pos // bs, sp)
     see = jnp.where((pos + 1 <= sp.dense_len)[:, None, None], True, chosen)
     see = jnp.repeat(see, bs, axis=-1) & (pos[None, None, :] <= pos[:, None, None])
     qg = q.reshape(S, Hkv, H // Hkv, Dh)

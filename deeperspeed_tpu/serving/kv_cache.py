@@ -659,6 +659,17 @@ def pooled_keys_of(kc_pool, layer, table, Hkv):
     return kb.reshape(*lead, Hkv, n * (hw // Hkv), Dh)
 
 
+def pages_of(table, blocks):
+    """``table[blocks]`` for a list of blocks: table (..., n) a slot's
+    physical pages by block, shaped to broadcast against blocks (..., P,
+    1). A compare and a sum over the table, since a gather of a prompt
+    chunk's 131,072 scalars takes the TPU five times as long; a block
+    past the table reads the null page, which is a page of the pool."""
+    m = jnp.arange(table.shape[-1], dtype=blocks.dtype)
+    return jnp.sum(jnp.where(blocks[..., None] == m, table, NULL_BLOCK), -1,
+                   dtype=table.dtype)
+
+
 def decode_write_indices(sp, tables, lengths):
     """Where a decode step's new rows go, the same for every layer: the
     page and row of the new token's key and value, and the page and
@@ -715,8 +726,9 @@ def sparse_decode_attend(sp, k_pool, v_pool, kc_pool, layer, q, k_row,
     slot i's new token sits at position ``lengths[i]``; ``at`` is
     ``decode_write_indices``. Scores the slot's pooled keys (the window
     the new token completes among them), lists the pages under the one
-    causal rule (mixers.page_list) and reads those. Returns (ctx (N, 1, H,
-    Dh), that window's pooled key (N, Hkv, Dh))."""
+    causal rule (mixers.page_list: ascending, so the slot's own partly
+    filled page is the last that counts) and reads those. Returns (ctx
+    (N, 1, H, Dh), that window's pooled key (N, Hkv, Dh))."""
     from ..models import mixers as mx
 
     N, _, H, Dh = q.shape
@@ -742,8 +754,7 @@ def sparse_decode_attend(sp, k_pool, v_pool, kc_pool, layer, q, k_row,
     b = mx.block_scores(q[:, 0], kbar, mx.visible_windows(t, J, sp), sp)
     blocks, valid = mx.select_blocks(b, bt, sp)
     blk, n = mx.page_list(blocks, valid, t, sp, sp.list_blocks)
-    pages = jnp.take_along_axis(tables[:, None, :],
-                                jnp.minimum(blk, bps - 1), axis=2)
+    pages = pages_of(tables[:, None, None, :], blk)
     n_tokens = jnp.maximum(n - 1, 0) * bs + (t % bs)[:, None]
     R, G, P = N * Hkv, H // Hkv, pages.shape[-1]
     q_rows = q.reshape(R, G, Dh)
@@ -762,7 +773,11 @@ def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
     the pool's dtype. While the chunk ends inside ``dense_len`` every
     query attends to all its past; beyond it every query selects: the
     chunk's own keys (all inside each query's local window) densely, the
-    pages of the past through ``attend_pages``. Returns (ctx (C, H, Dh),
+    pages of the past through ``attend_pages``, as ``mixers.page_list``
+    lists them: the selected blocks before the chunk in ascending order,
+    all of them whole. A row's pages meet one online softmax, so their
+    order decides the rounding of its sums and nothing else, and the
+    kernel asks only that every entry name a page. Returns (ctx (C, H, Dh),
     the pooled keys of the C / st windows this chunk completes, the first
     of them starting st tokens before the chunk: (C / st, Hkv, Dh))."""
     from ..models import mixers as mx
@@ -827,11 +842,10 @@ def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
             jnp.swapaxes(kbar_new, 0, 1), (0, offset // st - 1, 0))
         b = mx.block_scores(q, kbar, mx.visible_windows(q_pos, J, sp), sp)
         blocks, valid = mx.select_blocks(b, q_pos // bs, sp)
-        ok = valid & (blocks < offset // bs)     # the rest: the chunk's own
-        order = jnp.argsort(~ok, axis=-1, stable=True)
-        blk = jnp.take_along_axis(blocks, order, -1)
-        pages = table_row[jnp.minimum(blk, bps - 1)]           # (C, Hkv, K)
-        n_tokens = jnp.sum(ok, -1).astype(jnp.int32) * bs
+        blk, n = mx.page_list(blocks, valid, q_pos, sp, sp.topk,
+                              before=offset // bs)
+        pages = pages_of(table_row, blk)                       # (C, Hkv, K)
+        n_tokens = n * bs
         R = C * Hkv
         m0, l0, acc0 = own_keys()
         ctx = attend_pages(

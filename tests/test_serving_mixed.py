@@ -319,3 +319,207 @@ def test_sparse_config_is_validated():
         SparseAttnConfig(block_size=8, kernel_size=6, kernel_stride=2)
     with pytest.raises(ValueError, match="mixer_types"):
         GPTConfig(n_layer=2, mixer_types=("lightning",))
+
+
+# ------------------------------------------------------------------ #
+# the selector: a mask by a threshold, a list by a prefix count, no sort
+# ------------------------------------------------------------------ #
+
+SEL = SparseAttnConfig(block_size=8, topk=8, kernel_size=4, kernel_stride=2,
+                       init_blocks=1, window_size=16, dense_len=64)
+
+
+def sorted_selection(b, q_block, sp):
+    """The selector as PR 27 wrote it, kept here as the oracle: the forced
+    blocks at 1e9, a full ``lax.top_k``, validity from the value."""
+    M = b.shape[-1]
+    m = jnp.arange(M, dtype=jnp.int32)[None, None, :]
+    bt = q_block[:, None, None]
+    forced = (m < sp.init_blocks) | ((m <= bt) & (m > bt - sp.local_blocks))
+    score = jnp.where(forced, 1e9, jnp.where(m <= bt, b, -1e9))
+    if M < sp.topk:
+        score = jnp.pad(score, ((0, 0), (0, 0), (0, sp.topk - M)),
+                        constant_values=-1e9)
+    vals, idx = jax.lax.top_k(score, sp.topk)
+    return idx.astype(jnp.int32), vals >= 0.0
+
+
+def sorted_page_list(blocks, valid, q_pos, sp, width, before=None):
+    """PR 27's lists (``page_list`` for a decode step, ``before`` for a
+    prompt chunk's private copy): the entries that count moved to the
+    front by a stable argsort, the query's own block behind them."""
+    bt = (q_pos // sp.block_size)[:, None, None]
+    pad = ((0, 0), (0, 0), (0, width - blocks.shape[-1]))
+    sel_b, sel_v = jnp.pad(blocks, pad), jnp.pad(valid, pad)
+    m = jnp.arange(width, dtype=jnp.int32)[None, None, :]
+    dense = (q_pos + 1 <= sp.dense_len)[:, None, None]
+    blk = jnp.where(dense, jnp.broadcast_to(m, sel_b.shape), sel_b)
+    ok = jnp.where(dense, m <= bt, sel_v)
+    if before is not None:
+        ok = ok & (blk < before)
+    rank = jnp.where(ok, jnp.where(blk == bt, 1, 0), 2)
+    order = jnp.argsort(rank, axis=-1, stable=True)
+    return jnp.take_along_axis(blk, order, -1), jnp.sum(ok, -1)
+
+
+def _scores(kind, R, Hkv, M, rng):
+    b = rng.random((R, Hkv, M), dtype=np.float32)
+    if kind == "ties":          # four values: every cut falls inside a tie
+        b = rng.integers(0, 4, (R, Hkv, M)).astype(np.float32) / 4
+    elif kind == "equal":
+        b = np.full((R, Hkv, M), 0.25, np.float32)
+    elif kind == "unseen":      # most blocks have no visible window: -1
+        b = np.where(rng.random((R, Hkv, M)) < 0.8, -1.0, b).astype(np.float32)
+    elif kind == "tiny":        # zeros, the smallest normals, a huge one
+        b = rng.choice(np.array([0.0, 1.2e-38, 1.3e-38, 1e-30, 0.5, 2.5e8],
+                                np.float32), (R, Hkv, M))
+    return jnp.asarray(b)
+
+
+# name: (scores, blocks M, the queries' positions, a chunk's first block)
+SELECTOR_CASES = {
+    "random_scores": ("random", 40, [100, 163, 200, 255, 319], None),
+    "ties_at_the_cut": ("ties", 40, [100, 163, 200, 255, 319], None),
+    "all_scores_equal": ("equal", 40, [97, 180, 319], None),
+    "fewer_blocks_than_topk": ("random", 5, [0, 7, 8, 25, 39], None),
+    "fewer_seen_than_topk": ("unseen", 40, [100, 200, 319], None),
+    "zeros_and_tiny_scores": ("tiny", 40, [120, 250, 319], None),
+    "inside_and_past_dense_len": ("random", 24, [0, 30, 62, 63, 64, 65, 191],
+                                  None),
+    "first_and_last_position_of_a_block": ("ties", 24, [72, 79, 80, 184, 191],
+                                           None),
+    "a_prompt_chunk": ("random", 24, list(range(128, 144)), 16),
+    "a_chunk_with_ties": ("ties", 40, list(range(304, 320)), 38),
+}
+
+
+@pytest.mark.parametrize("case", SELECTOR_CASES)
+def test_the_selector_names_the_set_a_sort_would(case):
+    kind, M, positions, before = SELECTOR_CASES[case]
+    sp, Hkv = SEL, 2
+    rng = np.random.default_rng(sum(map(ord, case)))
+    q_pos = jnp.asarray(positions, jnp.int32)
+    R, bt = len(positions), np.asarray(positions) // sp.block_size
+    b = _scores(kind, R, Hkv, M, rng)
+
+    def as_sets(blocks, n_or_valid):
+        blocks, v = np.asarray(blocks), np.asarray(n_or_valid)
+        if v.dtype != bool:                     # a count: the first n
+            v = np.arange(blocks.shape[-1]) < v[..., None]
+        return [[sorted(blocks[r, h][v[r, h]]) for h in range(Hkv)]
+                for r in range(R)]
+
+    want_b, want_v = sorted_selection(b, q_pos // sp.block_size, sp)
+    blocks, valid = mixers.select_blocks(b, q_pos // sp.block_size, sp)
+    assert blocks.shape == valid.shape == (R, Hkv, sp.topk)
+    want = as_sets(want_b, want_v)
+    assert as_sets(blocks, valid) == want
+    mask = np.asarray(mixers.selection_mask(b, q_pos // sp.block_size, sp))
+    assert [[list(np.flatnonzero(mask[r, h])) for h in range(Hkv)]
+            for r in range(R)] == want
+    # the entries that count are a prefix, ascending; no set has a double
+    n_sel = np.asarray(valid).sum(-1)
+    assert (np.asarray(valid) == (np.arange(sp.topk) < n_sel[..., None])).all()
+    assert all(len(set(s)) == len(s) for row in want for s in row)
+
+    width = sp.topk if before is not None else sp.list_blocks
+    want_blk, want_n = sorted_page_list(want_b, want_v, q_pos, sp, width,
+                                        before)
+    blk, n = mixers.page_list(blocks, valid, q_pos, sp, width, before)
+    assert blk.shape == (R, Hkv, width) and n.dtype == jnp.int32
+    assert (np.asarray(n) == np.asarray(want_n)).all()
+    assert as_sets(blk, n) == as_sets(want_blk, want_n)
+    blk, n = np.asarray(blk), np.asarray(n)
+    for r in range(R):
+        for h in range(Hkv):
+            counted = blk[r, h, :n[r, h]]
+            assert (np.diff(counted) > 0).all()             # ascending
+            if before is None:      # its own block, and last of them
+                assert counted[-1] == bt[r] and n[r, h] >= 1
+            else:                   # whole blocks before the chunk only
+                assert (counted < before).all()
+    # an entry past the count: the callers clamp it from above only
+    assert (blk >= 0).all()
+
+
+def test_pages_of_is_the_table_lookup_and_reads_the_null_page_past_it():
+    from deeperspeed_tpu.serving.kv_cache import NULL_BLOCK, pages_of
+
+    rng = np.random.default_rng(3)
+    tables = jnp.asarray(rng.permutation(60)[:36].reshape(3, 12) + 1, jnp.int32)
+    blocks = jnp.asarray(rng.integers(0, 15, (3, 2, 8)), jnp.int32)
+    want = np.where(np.asarray(blocks) < 12, np.take_along_axis(
+        np.asarray(tables)[:, None, :], np.minimum(blocks, 11), 2), NULL_BLOCK)
+    got = pages_of(tables[:, None, None, :], blocks)        # a table a slot
+    assert got.dtype == tables.dtype and (np.asarray(got) == want).all()
+    got = pages_of(tables[1], blocks[1])                    # one table
+    assert (np.asarray(got) == want[1]).all()
+
+
+@pytest.mark.parametrize("program", ["chunk", "decode"])
+def test_no_sort_is_left_in_the_sparse_programs(program):
+    """``sparse_chunk_attend`` sorted 536 scores for each of 1,024 queries
+    (a ``lax.top_k`` is a full sort to XLA) and argsorted every list; a
+    fifth of a chunk step went there (PERF.md, PR 28)."""
+    from deeperspeed_tpu.analysis.hlo import iter_eqns
+    from deeperspeed_tpu.serving import kv_cache as kvc
+
+    sp, Hkv, H, Dh, L, nb, bps = SEL, 2, 4, 16, 2, 40, 24
+    w = sp.windows_per_block
+    pool = jnp.zeros((L, nb, Hkv, sp.block_size, Dh))
+    kc = jnp.zeros((L, nb, Hkv * w, Dh))
+    if program == "chunk":
+        C = 16
+        fn = lambda q, k, v, table, offset: kvc.sparse_chunk_attend(
+            sp, pool, pool, kc, 1, q, k, v, table, offset,
+            paged_sparse_attend_xla)
+        jaxpr = jax.make_jaxpr(fn)(
+            jnp.zeros((C, H, Dh)), jnp.zeros((C, Hkv, Dh)),
+            jnp.zeros((C, Hkv, Dh)), jnp.arange(bps), jnp.int32(128))
+    else:
+        N = 3
+        tables = jnp.tile(jnp.arange(bps), (N, 1))
+
+        def fn(q, k_row, v_row, lengths):
+            at = kvc.decode_write_indices(sp, tables, lengths)
+            return kvc.sparse_decode_attend(
+                sp, pool, pool, kc, 1, q, k_row, v_row, tables, lengths, at,
+                paged_sparse_attend_xla)
+
+        jaxpr = jax.make_jaxpr(fn)(
+            jnp.zeros((N, 1, H, Dh)), jnp.zeros((N, Hkv, Dh)),
+            jnp.zeros((N, Hkv, Dh)), jnp.asarray([5, 70, 150], jnp.int32))
+    names = {eqn.primitive.name for eqn in iter_eqns(jaxpr.jaxpr)}
+    assert "cumsum" in names                    # the walk reaches the selector
+    assert not [p for p in names if "sort" in p or "top_k" in p]
+
+
+def test_a_neox_decode_step_never_meets_the_selector(monkeypatch):
+    """The lowered ``ds_decode_step`` of a stack of attention layers is the
+    same text whatever the selector is: none of it is reached."""
+    from deeperspeed_tpu.models.gpt import init_params
+    from deeperspeed_tpu.serving import ServingConfig
+    from deeperspeed_tpu.serving.engine import make_decode_step
+
+    cfg = GPTConfig(vocab_size=96, n_layer=2, n_head=4, d_model=64,
+                    max_seq=64, rotary=True)
+    scfg = ServingConfig(num_slots=3, block_size=8, num_blocks=25,
+                         max_seq_len=64)
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    N, i32 = scfg.num_slots, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    pool = sds((cfg.n_layer, scfg.num_blocks, scfg.block_size, cfg.kv_heads,
+                cfg.head_dim), cfg.dtype)
+    args = (params, pool, pool, sds((N, scfg.blocks_per_slot), i32),
+            sds((N,), i32), sds((N,), i32), sds((N,), jnp.float32),
+            sds((N,), i32), sds((N,), i32))
+    text = make_decode_step(cfg, scfg).lower(*args).as_text()
+
+    def unreachable(*a, **k):
+        raise AssertionError("a NeoX program reached the sparse selector")
+
+    for name in ("top_mask", "selection_mask", "compact", "select_blocks",
+                 "page_list", "block_scores"):
+        monkeypatch.setattr(mixers, name, unreachable)
+    assert make_decode_step(cfg, scfg).lower(*args).as_text() == text
+    assert "ds_decode_step" in text and "stablehlo.sort" not in text
