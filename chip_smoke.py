@@ -123,7 +123,7 @@ def phase_trainer(args, say):
     # the file's 1000-step log warm-up starts at lr 0 and stays under 4e-5
     # for the steps taken here — less than half a bf16 ulp of a typical
     # weight, so the masterless weights would not move. A falling loss
-    # needs a plain lr; 1e-4 is bench.py's.
+    # needs a plain lr; 1e-4 is the benchmark cell's.
     ds_cfg.pop("scheduler", None)
     ds_cfg["optimizer"]["params"]["lr"] = 1e-4
     ds_cfg["steps_per_print"] = 10**9

@@ -52,8 +52,8 @@ def main(argv=None) -> int:
     p.add_argument("--json", default=None, metavar="PATH",
                    help="write the findings report JSON here")
     p.add_argument("--write-baseline", action="store_true",
-                   help="write <root>/ANALYSIS_BASELINE.json (the file "
-                        "monitor/ledger.py METRIC_SPECS gate on)")
+                   help="write <root>/ANALYSIS_BASELINE.json (the "
+                        "committed snapshot of the post-suppression counts)")
     p.add_argument("--suppressions", default=None, metavar="PATH",
                    help="suppression file (default: "
                         "<root>/ANALYSIS_SUPPRESSIONS.json)")

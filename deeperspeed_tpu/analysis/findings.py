@@ -138,7 +138,7 @@ def report(
     root: str = ".",
     extra: Optional[Dict[str, Any]] = None,
 ) -> Dict[str, Any]:
-    """The findings JSON the CLI writes (and the ledger baselines)."""
+    """The findings JSON the CLI writes (and ``--write-baseline`` snapshots)."""
     c = counts(findings)
     c["suppressed"] = len(suppressed)
     out = {

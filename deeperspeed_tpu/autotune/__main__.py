@@ -147,8 +147,8 @@ def _enumerate_space(args, model, budget):
 def _price_kernel_routes(routes, base_price, budget):
     """Kernel routes are priced analytically: off-TPU 'fused' forces
     interpret-mode Pallas launches (debug path, ~100x), 'auto' lowers to
-    the same XLA program as 'off'; on TPU the fused routes are the
-    measured winners (BENCH_kernels.json), modeled as a modest discount."""
+    the same XLA program as 'off'; on TPU the fused routes are modeled
+    as a modest discount (no benchmark cell has measured one: ROADMAP B6)."""
     from .costmodel import CandidatePrice
 
     on_tpu = budget["source"] not in ("cpu",)
@@ -247,7 +247,7 @@ def run_search(args, log=print):
     # tiers from the LAYOUT ranking (near-ties would only measure
     # scheduler noise, and comm variants are indistinguishable in
     # measured time on CPU where the collectives fuse into one program
-    # — see scripts/autotune_bench.py); the predicted-worst rides along
+    # — only layout spreads correlate); the predicted-worst rides along
     # so the correlation has range
     confirm_set = select_spread(ranked, k=max(1, args.top_k))
     confirmed, corr = [], None

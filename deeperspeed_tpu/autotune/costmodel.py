@@ -31,8 +31,8 @@ in the emitted ``components`` dict:
 
 CPU caveat (also in docs/tutorials/autotune.md): on the 8-virtual-device
 host the roofline peaks are nominal, so absolute predictions are
-meaningless — only the *ordering* is claimed, and
-``scripts/autotune_bench.py`` measures exactly that (Spearman).
+meaningless — only the *ordering* is claimed, and the CLI's confirm
+phase checks exactly that (Spearman of predicted against measured order).
 """
 
 import dataclasses
@@ -295,8 +295,8 @@ def price_layout(
     # ring attention circulates KV blocks ((sp-1) permute steps fwd,
     # ~2x for backward), every layer, every step. On a launch-bound
     # host the DISPATCH COUNT of these is what buries sp8 — the AOT
-    # flops alone would call it the cheapest layout while it measures
-    # slowest (cf. BENCH_mesh.json step times).
+    # flops alone would call it the cheapest layout, while each of
+    # its launches is a dispatch the host pays for.
     rows = effective_micro(layout, world, micro)
     act_bytes = 0.0
     act_launches = 0.0

@@ -9,7 +9,7 @@ engine keeps the legacy synchronous ``DeepSpeedDataLoader`` path.
 ::
 
     "datapipe": {
-        "source": "data/corpus_tokens.npy",  # .npy file or dir of shards
+        "source": "path/to/tokens.npy",  # your .npy file or dir of shards
         "seq_len": 1024,          # window length (tokens per sample - 1)
         "seed": 0,                # epoch-shuffle seed
         "shuffle": true,          # deterministic per-epoch permutation

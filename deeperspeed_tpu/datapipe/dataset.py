@@ -1,8 +1,8 @@
 """Streaming memory-mapped token-shard dataset + deterministic ordering.
 
 ``TokenShardDataset`` indexes fixed ``seq_len + 1``-token windows over a
-memory-mapped token corpus — a single ``.npy`` file (the bundled
-``data/corpus_tokens.npy``) or a directory of ``*.npy`` shards. Nothing
+memory-mapped token corpus — a single ``.npy`` file of token ids (the
+path the config names) or a directory of ``*.npy`` shards. Nothing
 is read until a window is fetched, so a multi-TB corpus costs a few
 mmap handles, and the page cache does the streaming.
 

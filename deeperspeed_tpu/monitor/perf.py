@@ -4,8 +4,8 @@ XLA already knows what every jitted entry point costs — the compiled
 executable carries a cost model (``compiled.cost_analysis()``: flops,
 bytes accessed, optimal seconds) and a memory breakdown
 (``compiled.memory_analysis()``: argument / output / peak-temp bytes).
-Until now that knowledge lived only in the offline flops profiler and
-``scripts/mfu_decomposition.py``; this module makes it a live layer:
+Until now that knowledge lived only in the offline flops profiler;
+this module makes it a live layer:
 
   * :func:`extract_cost_analysis` / :func:`extract_memory_analysis` —
     the ONE place the raw XLA structures are normalized (the CPU

@@ -59,7 +59,7 @@ scheduler's accrual (``serving/finish`` args). Costs aggregate per
 replica and per lifecycle weight-version (``lifecycle/repin`` /
 ``lifecycle/rollout``) into ``cost_per_1k_tokens`` gauges.
 
-Works on single-engine traces (scripts/serving_bench.py) and on merged
+Works on single-engine traces (one ``ServingEngine``'s) and on merged
 multi-source fleet traces (monitor/aggregate.py output, flight-recorder
 recoveries included) — serving-side spans are matched per process id,
 so one engine's decode is never charged to a request served elsewhere.

@@ -82,20 +82,20 @@ EVENT_ARG_SCHEMAS = {
     "goodput/report": ("wall_s", "goodput"),
     # comm overlap scheduling: per-bucket reduce launches must say
     # whether they were overlapped, and every drain must say how many
-    # buckets it waited on — overlap_fraction in BENCH_comm.json joins
-    # on exactly these spans
+    # buckets it waited on — scripts/comm_bench.py's overlap_fraction
+    # joins on exactly these spans
     "comm/reduce": ("bucket", "mode"),
     "comm/overlap_window": ("buckets",),
     # perf doctor: compiled-cost captures, live per-step MFU, and the
-    # device-memory watermark lane — ledger tooling and the
-    # roofline readout join on these
+    # device-memory watermark lane — the roofline readout joins
+    # on these
     "perf/compiled": ("entry", "flops", "bytes", "peak_hbm"),
     "perf/step": ("entry", "mfu", "wall_ms", "verdict"),
     "mem/watermark": ("phase", "bytes_in_use", "peak_bytes"),
     "mem/postmortem": ("reason", "bytes_in_use", "buffers"),
     "mem/buffer": ("rank", "shape", "dtype", "nbytes", "sharding"),
     # sharding substrate: every mesh build announces its layout, and the
-    # bench's placement audits record what actually sharded — BENCH_mesh
+    # bench's placement audits record what actually sharded — mesh_bench
     # and post-hoc layout debugging join on these
     "mesh/build": ("axes", "devices"),
     "mesh/audit": ("tree", "sharded_frac", "digest"),
@@ -116,7 +116,7 @@ EVENT_ARG_SCHEMAS = {
     "spec/accept": ("rid", "accepted", "k", "emitted"),
     # multi-host runtime (distributed/): every process stamps its
     # topology at jax.distributed init (the merged fleet timeline and
-    # BENCH_multihost join per-host lanes on these). Fleet-side
+    # multihost_drill join per-host lanes on these). Fleet-side
     # coordination — rendezvous, restart barriers, pool growth — is
     # recorded in the supervisor's restart JSONL and the rendezvous
     # records, not as trace events (the supervisor owns no trace lane)
@@ -129,8 +129,8 @@ EVENT_ARG_SCHEMAS = {
 KNOWN_EVENT_PREFIXES = (
     "engine/", "pipe/", "offload/", "comm/", "kernels/", "datapipe/",
     "resilience/", "serving/", "flight/", "run/", "goodput/", "trace/",
-    "perf/", "mem/", "mesh/", "ablation/", "lifecycle/", "req/", "slo/",
-    "kv/", "spec/", "dist/",
+    "perf/", "mem/", "mesh/", "lifecycle/", "req/", "slo/", "kv/",
+    "spec/", "dist/",
 )
 KNOWN_EVENT_NAMES = frozenset({
     "xla_compile", "recompile!", "process_name", "thread_name",

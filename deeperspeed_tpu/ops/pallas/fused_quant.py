@@ -3,8 +3,8 @@
 PR 6's reducer lowered its int8/compressed wire math as a chain of
 separate XLA ops (abs-max, scale, divide, round, cast, multiply, sum) —
 each a full pass over the gradient bucket, all serialized on the
-critical path after backward. BENCH_comm.json showed the cost: int8 cut
-wire bytes 3.69x and still LOST wall-clock to fp32. This module is the
+critical path after backward: int8 cuts the wire bytes 3.69x (the HLO
+audit of scripts/comm_bench.py) and pays for it in passes. This module is the
 EQuARX-style answer (PAPERS.md, arXiv 2506.17615): single-pass Pallas
 kernels that read each gradient block once and emit everything the wire
 needs —

@@ -1042,7 +1042,7 @@ def _bs_bwd_res(res, g, lut, lut_t, sm_scale, block, chunk, causal, srow,
 # ------------------------------------------------------------------ #
 
 
-# Measured on v5e (BENCH_EXTRA r3/r4): the streaming sparse kernels beat
+# Measured on v5e before the current installation: the streaming sparse kernels beat
 # DENSE flash only below ~12% effective density; above it, computing the
 # full S^2 on flash is faster than gathering the sparse blocks. auto CANNOT
 # route to flash — dense attention attends positions the layout masks out,

@@ -35,8 +35,8 @@ Proof, not promise: ``comm/reduce`` spans carry ``overlapped:
 true|false`` and the drain emits ``comm/overlap_window``;
 :func:`overlap_fraction` turns a pair of (merged) traces into the
 fraction of serialized comm time that the overlap schedule hid.
-scripts/comm_bench.py reports it as ``overlap_fraction`` in
-BENCH_comm.json.
+scripts/comm_bench.py reports it as ``overlap_fraction`` in the
+report its ``--out`` names.
 """
 
 from typing import Dict, List

@@ -553,8 +553,8 @@ class GradReducer:
         return out, {"e1": new_e1, "e2": new_e2}
 
     # ------------------------------------------------------------------ #
-    # wire model (feeds the comm_wire_bytes counter; BENCH_comm.json uses
-    # the real compiled-HLO audit in profiling/hlo_bytes.py instead)
+    # wire model (feeds the comm_wire_bytes counter; scripts/comm_bench.py
+    # uses the real compiled-HLO audit in profiling/hlo_bytes.py instead)
     # ------------------------------------------------------------------ #
 
     def bucket_wire_bytes(self, b: bucketing.Bucket) -> int:

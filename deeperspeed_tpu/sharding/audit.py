@@ -12,8 +12,8 @@ shardings into small JSON-able digests so benches and tests can assert
 * :func:`tree_digest` — a placed pytree → per-leaf digests keyed by
   flattened path.
 * :func:`audit_tree` — summary: total/sharded/replicated leaf counts,
-  bytes by axis usage — the number ``scripts/mesh_bench.py`` publishes
-  per layout in ``BENCH_mesh.json``.
+  bytes by axis usage — the number ``scripts/mesh_bench.py`` reports
+  per layout.
 """
 
 import hashlib

@@ -2,7 +2,7 @@
 
 One rule, one place. If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
 and the program sets nothing — whoever runs the program placed the cache.
-Otherwise the executables (``chip_smoke.py``, ``bench.py``,
+Otherwise the executables (``chip_smoke.py``,
 ``scripts/tpu_smoke.py``, ``python -m deeperspeed_tpu.autotune``,
 ``serving/replica_worker.py``) point ``jax_compilation_cache_dir`` at ONE
 fixed, git-ignored directory inside the checkout. The path is part of the

@@ -1,9 +1,11 @@
 #!/usr/bin/env bash
 # Pre-merge static gate: ruff -> analysis CLI -> strict trace
-# validation -> perf-ledger regression check. Run from anywhere; every
-# step must pass (ruff is skipped with a note on hosts that don't have
-# it — the [tool.ruff] config in pyproject.toml still applies wherever
-# ruff exists, e.g. CI).
+# validation -> request-path doctor -> autotune smoke -> multi-host
+# smoke. Run from anywhere; every step must pass (ruff is skipped with
+# a note on hosts that don't have it — the [tool.ruff] config in
+# pyproject.toml still applies wherever ruff exists, e.g. CI). No step
+# compares a time: a performance number comes from benchmark/run.py on
+# the chip (PERF.md).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -34,55 +36,10 @@ for trace in traces/serving_bench_trace.json traces/obs_drill_merged.json; do
         --max-residual 0.05 "$trace"
 done
 
-echo "== prefix-reuse smoke (shared-prefix bench, reuse must hit) =="
-# the reuse path end to end on a small trace: dual-pass bench (baseline
-# vs reuse+chunked), the reuse pass must actually hit the radix cache,
-# and the doctor must still explain the fresh trace's tail
-JAX_PLATFORMS=cpu python scripts/serving_bench.py --slo --shared-prefix \
-    --requests 12 --d-model 64 \
-    --out /tmp/reuse_smoke.json --trace /tmp/reuse_smoke_trace.json
-python - <<'EOF'
-import json
-out = json.load(open("/tmp/reuse_smoke.json"))
-pr = out["prefix_reuse"]
-assert pr["reuse_hit_rate"] > 0, pr
-assert pr["tokens_saved"] > 0, pr
-assert out["decode_compiles"] == 1, out
-print(f"  reuse_hit_rate={pr['reuse_hit_rate']} "
-      f"tokens_saved_frac={pr['tokens_saved_frac']}")
-EOF
-JAX_PLATFORMS=cpu python -m deeperspeed_tpu.monitor.slo \
-    --max-residual 0.05 /tmp/reuse_smoke_trace.json
-
-echo "== spec-decode smoke (dual-pass bench, drafts must land) =="
-# the speculative path end to end on a small trace: plain-vs-spec
-# dual-pass bench, the drafter must actually get tokens accepted, the
-# decode path must hold at exactly three compiled programs (plain
-# fallback + draft + verify), and the doctor must still explain the
-# fresh trace's tail
-JAX_PLATFORMS=cpu python scripts/serving_bench.py --speculative \
-    --requests 12 \
-    --out /tmp/spec_smoke.json --trace /tmp/spec_smoke_trace.json
-python - <<'EOF'
-import json
-out = json.load(open("/tmp/spec_smoke.json"))
-sp = out["speculative"]
-assert sp["accept_rate"] > 0, sp
-assert sp["rounds"] > 0, sp
-assert out["decode_compiles"] == 1, out
-assert out["draft_compiles"] == 1, out
-assert out["verify_compiles"] == 1, out
-print(f"  accept_rate={sp['accept_rate']} "
-      f"tokens_per_round={sp['tokens_per_round']} "
-      f"tpot_ms={sp['tpot_ms']} (baseline {sp['tpot_ms_baseline']})")
-EOF
-JAX_PLATFORMS=cpu python -m deeperspeed_tpu.monitor.slo \
-    --max-residual 0.05 /tmp/spec_smoke_trace.json
-
 echo "== autotune smoke (quick space, rank-only) =="
 # the config-search pipeline end to end on a small space: enumerate ->
-# AOT-price -> emit + provenance self-check (<60s; measured confirm
-# runs live in scripts/autotune_bench.py, not in the gate)
+# AOT-price -> emit + provenance self-check (<60s; the measured confirm
+# phase is the CLI's default and is left out of the gate)
 JAX_PLATFORMS=cpu python -m deeperspeed_tpu.autotune --devices 8 --quick \
     --no-confirm --out /tmp/autotune_smoke.json
 python - <<'EOF'
@@ -110,8 +67,5 @@ EOF
 else
     echo "  no multiprocess CPU collectives in this jaxlib — skipped"
 fi
-
-echo "== perf ledger =="
-JAX_PLATFORMS=cpu python -m deeperspeed_tpu.monitor.ledger check
 
 echo "check.sh: all gates passed"

@@ -48,7 +48,8 @@ Honesty notes baked into the output:
 
 Acceptance bar: int8 ``per_step_x`` >= 4 at gas=2 with loss delta < 1%,
 strict-valid traces, and ``overlap_fraction`` > 0.
-Results go to BENCH_comm.json at the repo root.
+Results go where ``--out`` says (default: the git-ignored BENCH_comm.json
+at the repo root).
 
 ``--onebit`` additionally regenerates ONEBIT_WIRE.json by delegating to
 scripts/onebit_wire_bytes.py (the 1-bit momentum-exchange audit is a

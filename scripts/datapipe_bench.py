@@ -18,7 +18,8 @@ end: ``datapipe/wait`` spans land in a Chrome trace which is validated
 with the ``monitor.validate`` CLI, and the ``datapipe_*`` gauges must
 show up in the metrics registry.
 
-Results go to BENCH_datapipe.json at the repo root. Runs anywhere (CI
+Results go where ``--out`` says (default: the git-ignored
+BENCH_datapipe.json at the repo root). Runs anywhere (CI
 included) in well under a minute on CPU; export JAX_PLATFORMS=tpu to
 measure real device staging.
 

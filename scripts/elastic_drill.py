@@ -16,7 +16,8 @@ to an uninterrupted 8-device reference run (canonical-slot reduction
 makes the loss world-size invariant), and the post-run datapipe batch
 digest matches (no token skipped or repeated).
 
-Writes BENCH_elastic.json: per-flip resume latency + loss delta.
+Writes its report where ``--out`` says (default: the git-ignored
+BENCH_elastic.json): per-flip resume latency + loss delta.
 
 Usage:
   python scripts/elastic_drill.py [--steps 24] [--out BENCH_elastic.json]

@@ -22,7 +22,8 @@ Acceptance, audited from router state (not replica claims):
     ``serving/retry``, ``serving/replica_down``, ``serving/finish``
     instants — passes ``python -m deeperspeed_tpu.monitor.validate``.
 
-Writes BENCH_fleet.json.
+Writes its report where ``--out`` says (default: the git-ignored
+BENCH_fleet.json).
 
 Usage:
   python scripts/fleet_drill.py [--quick] [--out BENCH_fleet.json]

@@ -2,7 +2,7 @@
 
 Runs every fused surface (fused LayerNorm / add+LayerNorm / bias+GeLU,
 fused Adam, dense super-tile flash, ragged-block streaming flash) over a
-grid of supported geometries — including the MFU_DECOMP.json bert128
+grid of supported geometries — including the BERT seq-128
 attention geometry (64, 16, 128, 64) that motivated the super-tile
 kernel — comparing against the plain XLA math, and prints a max-rel-err
 table. Errors are max |fused - ref| normalized by max |ref| (stable where
@@ -154,7 +154,7 @@ def run_sweep(quick=False):
     st_geoms = [((2, 2, 64, 16), True, True), ((8, 2, 128, 64), True, True),
                 ((4, 4, 96, 32), False, True)]
     if not quick:
-        # the MFU_DECOMP.json bert128 geometry, forward only (256 groups
+        # the BERT seq-128 geometry, forward only (256 groups
         # of (512, 512) scores in interpret mode; grads would double it)
         st_geoms.append(((64, 16, 128, 64), False, False))
     for shape, causal, with_grad in st_geoms:

@@ -28,7 +28,8 @@ Acceptance, audited from artifacts (not participant claims):
     lands in the new ``remesh`` bucket instead;
   * both Chrome traces (trainer + serving) pass the strict validator.
 
-Writes BENCH_lifecycle.json (paths match monitor/ledger.py specs).
+Writes its report where ``--out`` says (default: the git-ignored
+BENCH_lifecycle.json at the repo root).
 
 Usage:
   python scripts/lifecycle_drill.py [--quick] [--out BENCH_lifecycle.json]

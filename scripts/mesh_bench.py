@@ -35,9 +35,9 @@ substrate). On the virtual 8-device CPU mesh this measures, per layout:
     a ``mesh/audit`` instant into a Chrome trace that passes
     ``python -m deeperspeed_tpu.monitor.validate --strict``.
 
-Results go to BENCH_mesh.json at the repo root; the perf ledger reads
-``parity.max_loss_delta``, ``layouts.dp2_fsdp4.step_ms`` and
-``layouts.fsdp8_zero3.param_sharded_frac`` from it.
+Results go where ``--out`` says (default: the git-ignored
+BENCH_mesh.json at the repo root); the slow test of
+``tests/test_sharding.py`` asserts on them.
 
 Usage:
   python scripts/mesh_bench.py [--steps 12] [--out BENCH_mesh.json]

@@ -25,7 +25,8 @@ subsystem end to end:
     files merge (clock offsets from the rendezvous handshake) into ONE
     strict-validator-clean timeline.
 
-Writes BENCH_multihost.json (paths match monitor/ledger.py specs).
+Writes its report where ``--out`` says (default: the git-ignored
+BENCH_multihost.json at the repo root).
 
 Usage:
   python scripts/multihost_drill.py [--quick] [--out BENCH_multihost.json]

@@ -28,7 +28,8 @@ wall-clock from the restart log plus the per-incarnation traces (the
 killed incarnation contributes its flight file) and the drill audits
 that the buckets sum to the independently measured wall time within 5%.
 
-Writes BENCH_obs.json.
+Writes its report where ``--out`` says (default: the git-ignored
+BENCH_obs.json).
 
 Usage:
   python scripts/obs_drill.py [--quick] [--out BENCH_obs.json]

@@ -5,8 +5,8 @@
 Compiles the SAME data-parallel train step (GPT on a dp8 mesh) in the
 warmup phase (fp32 gradient pmean) and the compressed phase (1-bit
 two-phase momentum exchange, runtime/comm/onebit_spmd.py), audits every
-collective's result bytes in the compiled HLO, and writes
-ONEBIT_WIRE.json with the measured reduction factor. Runs on the virtual
+collective's result bytes in the compiled HLO, and writes the
+git-ignored ONEBIT_WIRE.json with the measured reduction factor. Runs on the virtual
 CPU mesh — the compiled program, not hardware, is the evidence.
 
 Scales: the default audits BOTH the tiny smoke model and GPT-125M
@@ -16,8 +16,7 @@ buffers only stress the design at real model sizes).
 
 Usage: run under the cleaned 8-device env (see tests/conftest.py), or let
 it re-exec itself.  ``scripts/comm_bench.py --onebit`` (the gradient-side
-wire bench for the "comm" config block) delegates here to refresh
-ONEBIT_WIRE.json alongside BENCH_comm.json.
+wire bench for the "comm" config block) delegates here.
 """
 
 import json

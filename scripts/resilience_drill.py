@@ -1,6 +1,7 @@
 """Resilience drill: save-stall benchmark + kill-and-resume exercise.
 
-Two measurements, written to BENCH_resilience.json at the repo root:
+Two measurements, written where ``--out`` says (default: the git-ignored
+BENCH_resilience.json at the repo root):
 
   1. Save stall: how long ``engine.save_checkpoint`` blocks the step
      loop for a ~tens-of-MB model under (a) the legacy inline writer,

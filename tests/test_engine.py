@@ -305,7 +305,7 @@ def test_onebit_adam_compression_phase():
 class TestMasterlessBf16:
     """Memory-lean bf16 mode (bf16.master_weights=false): no fp32 master,
     bf16-stored optimizer moments, bf16 grads — 4 bytes/param of state, the
-    mode that fits billion-param models on one chip (bench.py's 1.3B run)."""
+    mode that fits billion-param models on one chip (the cell neox-1.3b.train)."""
 
     CFG = {
         "train_micro_batch_size_per_gpu": 4,

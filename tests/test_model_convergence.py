@@ -104,10 +104,9 @@ def test_pld_trains():
 
 
 # ------------------------------------------------------------------ #
-# long-horizon convergence gate on the SHARDED 8-device mesh — the
-# in-suite companion of scripts/convergence_125m.py (which runs the
-# 124M model on real hardware). Here dp=8 so ZeRO 1/2/3 actually
-# shard masters/grads/params, and the curves must still agree.
+# long-horizon convergence gate on the SHARDED 8-device mesh. Here
+# dp=8 so ZeRO 1/2/3 actually shard masters/grads/params, and the
+# curves must still agree.
 # ------------------------------------------------------------------ #
 
 LONG_STEPS = 300
@@ -227,8 +226,7 @@ def test_long_horizon_masterless_bf16_tracks_fp32_master(long_baseline):
 
 
 def test_long_horizon_masterless_bf16_zero2(long_baseline):
-    """Masterless bf16 UNDER ZERO-2 — the exact configuration the BERT
-    headline bench reports (bert_sparse_bench masterless=True, stage 2):
+    """Masterless bf16 UNDER ZERO-2:
     sharded bf16 moments + grad partitioning with no fp32 master must
     track the fp32 baseline like the stage-1 case does."""
     losses = _long_losses({

@@ -7,7 +7,7 @@ Everything here runs single-process on the suite's 8 simulated CPU
 devices except the final slow test, which spawns a real 2-process
 localhost fleet (gloo collectives) and asserts its per-step losses are
 BIT-IDENTICAL to an equivalent single-process mesh — the property
-BENCH_multihost.json's max_loss_delta == 0.0 acceptance rides on.
+scripts/multihost_drill.py's max_loss_delta == 0.0 acceptance rides on.
 """
 
 import json

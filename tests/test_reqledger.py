@@ -294,11 +294,10 @@ def test_committed_drill_trace_token_exactness():
     not os.path.exists(os.path.join(TRACES, "serving_bench_trace.json")),
     reason="committed bench trace not present")
 def test_committed_bench_trace_p99_not_hol_dominated():
-    """The committed trace is the --shared-prefix bench's REUSE pass:
-    prefix reuse + chunked prefill exist to kill head-of-line blocking,
-    so the p99 victim must no longer be hol_blocking-dominated (the
-    baseline pass of the same traffic is — BENCH_serving.json carries
-    both hol_blocking totals), while attribution still explains the
+    """The committed trace (a fixture: the script that wrote it is gone)
+    is a shared-prefix run WITH prefix reuse + chunked prefill, which
+    exist to kill head-of-line blocking, so the p99 victim must not be
+    hol_blocking-dominated, while attribution still explains the
     tail."""
     report = build_ledger(
         os.path.join(TRACES, "serving_bench_trace.json"))

@@ -121,7 +121,11 @@ def test_plain_engine_without_spec_block_is_untouched(model):
 # ------------------------------------------------------------------ #
 
 
-def test_greedy_spec_identical_to_plain_cold(model):
+@pytest.mark.parametrize("drafter_layers", [1, 2],
+                         ids=["truncated", "target-as-drafter"])
+def test_greedy_spec_identical_to_plain_cold(model, drafter_layers):
+    """Depth 2 is the target itself as its own drafter: there drafts must
+    LAND, not only be proposed."""
     cfg, params = model
     prompts = [_prompt(9, 1), _prompt(17, 2), _prompt(30, 3)]
 
@@ -129,13 +133,16 @@ def test_greedy_spec_identical_to_plain_cold(model):
     refs = [plain.submit(p, max_new_tokens=20) for p in prompts]
     ref_out = plain.run()
 
-    eng = _engine(cfg, params)
+    eng = _engine(cfg, params, spec={"draft_k": 3, "drafter":
+                                     {"n_layer": drafter_layers}})
     rids = [eng.submit(p, max_new_tokens=20) for p in prompts]
     out = eng.run()
     for r, rr in zip(rids, refs):
         assert out[r] == ref_out[rr]
     assert eng.metrics.spec_rounds > 0
     assert eng.metrics.spec_drafted > 0
+    if drafter_layers == cfg.n_layer:
+        assert eng.metrics.spec_accepted > 0
 
 
 def test_greedy_spec_cache_hit_identical_to_miss(model):

@@ -41,7 +41,6 @@ from deeperspeed_tpu.autotune.costmodel import (
 )
 from deeperspeed_tpu.analysis.provenance import check_config_provenance
 from deeperspeed_tpu.monitor import Tracer, set_tracer, shutdown_monitor
-from deeperspeed_tpu.monitor.ledger import METRIC_SPECS
 from deeperspeed_tpu.monitor.perf import _cache_size
 from deeperspeed_tpu.monitor.watchdog import RecompileWatchdog
 from deeperspeed_tpu.runtime.comm import wiremodel
@@ -453,18 +452,8 @@ def test_repo_shipped_autotuned_config_verifies():
 
 
 # ------------------------------------------------------------------ #
-# ledger + ranking math
+# ranking math
 # ------------------------------------------------------------------ #
-
-
-def test_autotune_metrics_registered_in_ledger():
-    names = {s.name for s in METRIC_SPECS}
-    assert {"autotune.rank_correlation",
-            "autotune.best_predicted_cost"} <= names
-    spec = next(s for s in METRIC_SPECS
-                if s.name == "autotune.rank_correlation")
-    assert spec.file == "BENCH_autotune.json"
-    assert spec.path == ("confirm", "rank_correlation")
 
 
 def test_spearman_rank_correlation():
