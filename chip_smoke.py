@@ -217,7 +217,8 @@ def phase_server(args, say):
     from deeperspeed_tpu.models.generation import make_generator
     from deeperspeed_tpu.models.gpt import make_gpt
     from deeperspeed_tpu.monitor import shutdown_monitor
-    from deeperspeed_tpu.serving import ServingConfig, ServingEngine
+    from deeperspeed_tpu.serving import (ServingConfig, ServingEngine,
+                                         idle_slots)
 
     t0 = time.perf_counter()
     cfg = _model_cfg(args.tiny_cpu)
@@ -282,8 +283,10 @@ def phase_server(args, say):
     # so there the number is printed and not judged.
     from deeperspeed_tpu.monitor import extract_memory_analysis
 
-    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                          eng._last_dargs)
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+        (eng.params, eng.kv.k, eng.kv.v,
+         idle_slots(scfg.num_slots, scfg.blocks_per_slot)))
     ask = extract_memory_analysis(
         eng._decode_step.lower(*shapes).compile())["peak_bytes"]
     pool = eng.kv.k.nbytes + eng.kv.v.nbytes

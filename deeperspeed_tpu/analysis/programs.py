@@ -164,10 +164,9 @@ def _comm_specs(notes: List[str]) -> List[ProgramSpec]:
 def _serving_specs(notes: List[str]) -> List[ProgramSpec]:
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from ..models.gpt import GPTConfig, make_gpt
-    from ..serving import ServingConfig, ServingEngine
+    from ..serving import ServingConfig, ServingEngine, idle_slots
 
     cfg = GPTConfig(vocab_size=97, n_layer=2, n_head=2, d_model=32,
                     max_seq=64, remat=False, dtype=jnp.float32,
@@ -184,14 +183,8 @@ def _serving_specs(notes: List[str]) -> List[ProgramSpec]:
         name=f"serving/prefill_step[b{bucket}]", fn=eng._prefill_step,
         args=(eng.params, toks), hot=False)]
 
-    N = scfg.num_slots
-    dargs = (eng.params, eng.kv.k, eng.kv.v,
-             jnp.asarray(np.zeros((N, scfg.blocks_per_slot), np.int32)),
-             jnp.asarray(np.zeros(N, np.int32)),
-             jnp.asarray(np.zeros(N, np.int32)),
-             jnp.asarray(np.zeros(N, np.float32)),
-             jnp.asarray(np.zeros(N, np.int32)),
-             jnp.asarray(np.zeros(N, np.int32)))
+    dargs = (eng.params, eng.kv.k, eng.kv.v, jnp.asarray(
+        idle_slots(scfg.num_slots, scfg.blocks_per_slot)))
     specs.append(ProgramSpec(
         name="serving/decode_step", fn=eng._decode_step, args=dargs,
         hot=True))

@@ -17,8 +17,11 @@ from .engine import (
     PipelineServingBridge,
     ServingEngine,
     derive_request_seed,
+    idle_slots,
     make_decode_step,
+    pack_slots,
     request_sample_key,
+    unpack_slots,
 )
 from .fleet import (
     ReplicaUnavailableError,
@@ -50,6 +53,9 @@ __all__ = [
     "PipelineServingBridge",
     "EngineDrainingError",
     "make_decode_step",
+    "pack_slots",
+    "unpack_slots",
+    "idle_slots",
     "derive_request_seed",
     "request_sample_key",
     "BlockAllocator",

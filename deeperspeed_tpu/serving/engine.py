@@ -114,6 +114,48 @@ def request_sample_key(seed: int, count: int):
 # the jitted decode step
 # ------------------------------------------------------------------ #
 
+# columns of the packed slot array past a slot's page table
+SLOT_SCALARS = 5
+
+
+def pack_slots(tables, lengths, tokens, temps, seeds, counts) -> np.ndarray:
+    """The six per-slot inputs of a decode step as ONE int32 array
+    ``(num_slots, blocks_per_slot + 5)``, so that they cross to the device
+    in one transfer: columns ``[0, bps)`` a slot's page table, then its
+    length, pending token, sampling seed, sampled-token count, and its
+    temperature as the float32's BITS (it arrives bit for bit, and
+    ``temps[i] <= 0`` still means greedy). A fresh array every call: a
+    placement may still be reading the last one (on the CPU backend it
+    may alias host memory)."""
+    tables = np.asarray(tables, np.int32)
+    bps = tables.shape[1]
+    slots = np.empty((tables.shape[0], bps + SLOT_SCALARS), np.int32)
+    slots[:, :bps] = tables
+    slots[:, bps] = lengths
+    slots[:, bps + 1] = tokens
+    slots[:, bps + 2] = seeds
+    slots[:, bps + 3] = counts
+    slots[:, bps + 4] = np.ascontiguousarray(
+        temps, np.float32).view(np.int32)
+    return slots
+
+
+def idle_slots(num_slots: int, bps: int) -> np.ndarray:
+    """The packed array of a step with every slot idle: what a caller
+    that only lowers or audits the decode program hands it."""
+    idle = np.zeros(num_slots, np.int32)
+    return pack_slots(np.zeros((num_slots, bps), np.int32), idle, idle,
+                      np.zeros(num_slots, np.float32), idle, idle)
+
+
+def unpack_slots(slots, bps: int):
+    """``pack_slots`` undone inside a program (static slices and a
+    bitcast): ``(tables, lengths, tokens, temps, seeds, counts)``."""
+    lengths, tokens, seeds, counts, temp_bits = (
+        slots[:, bps + i] for i in range(SLOT_SCALARS))
+    temps = jax.lax.bitcast_convert_type(temp_bits, jnp.float32)
+    return slots[:, :bps], lengths, tokens, temps, seeds, counts
+
 
 def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
                  kind: str = "attention"):
@@ -147,9 +189,11 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
 def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     """Build the jitted all-slots decode step.
 
-    decode_step(params, k_pool, v_pool, tables, lengths, tokens, temps,
-    seeds, counts, kc_pool, state) -> (next_tokens (N,), k_pool', v_pool',
-    kc_pool', state'): one shape for every model. ``kc_pool`` and
+    decode_step(params, k_pool, v_pool, slots, kc_pool, state) ->
+    (next_tokens (N,), k_pool', v_pool', kc_pool', state'): one shape for
+    every model. ``slots`` is the step's six per-slot inputs as ONE int32
+    array (``pack_slots`` on the host, one transfer; the program's first
+    line takes it apart again, ``unpack_slots``). ``kc_pool`` and
     ``state`` (``PagedKVCache.kc``, ``.state``) are None, in and out, for
     a stack of attention layers; a model of mixed layers
     (``cfg.mixer_types``) passes its pooled keys and its state rows,
@@ -176,9 +220,11 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     kinds = set(cfg.layer_kinds)
     slopes = mixers.lightning_slopes(cfg.n_head)
 
-    @partial(jax.jit, donate_argnums=(1, 2, 9, 10))
-    def ds_decode_step(params, k_pool, v_pool, tables, lengths, tokens,
-                       temps, seeds, counts, kc_pool=None, state=None):
+    @partial(jax.jit, donate_argnums=(1, 2, 4, 5))
+    def ds_decode_step(params, k_pool, v_pool, slots, kc_pool=None,
+                       state=None):
+        tables, lengths, tokens, temps, seeds, counts = unpack_slots(
+            slots, scfg.blocks_per_slot)
         N = tokens.shape[0]
         positions = lengths[:, None]                        # (N, 1)
         with jax.named_scope("ds.embed"):
@@ -1040,7 +1086,8 @@ class ServingEngine(_ServingBase):
         The caller owns the surrounding span/metrics — this is both the
         whole decode phase (speculation off) and the fallback program
         for non-speculating slots (speculation on)."""
-        with trace_span("serving/decode/pack", lane="serving"):
+        with trace_span("serving/decode/pack", lane="serving",
+                        placements="1"):
             N = self.scfg.num_slots
             tables = np.zeros((N, self.scfg.blocks_per_slot), np.int32)
             lengths = np.zeros(N, np.int32)
@@ -1066,12 +1113,14 @@ class ServingEngine(_ServingBase):
                 temps[s] = req.temperature
                 seeds[s] = req.seed
                 counts[s] = len(req.generated)
-            _place = (self._place_slot_array if self.mesh is not None
-                      else jnp.asarray)
-            _dargs = (self.params, self.kv.k, self.kv.v, _place(tables),
-                      _place(lengths), _place(tokens),
-                      _place(temps), _place(seeds),
-                      _place(counts), self.kv.kc, self.kv.state)
+            # the step's ONE host-to-device placement
+            slots = pack_slots(tables, lengths, tokens, temps, seeds,
+                               counts)
+            slots = (self._place_slot_array(slots)
+                     if self.mesh is not None else jnp.asarray(slots))
+            _dargs = (self.params, self.kv.k, self.kv.v, slots,
+                      self.kv.kc, self.kv.state)
+        self.metrics.record_decode_placements(1)
         self.metrics.record_kv_pages(live_pages, tables.size,
                                      selected_pages)
         with trace_span("serving/decode/dispatch", lane="serving",

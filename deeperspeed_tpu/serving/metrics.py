@@ -147,6 +147,10 @@ class ServingMetrics:
         self.kv_view_pages = 0
         # of the live pages, those a block-sparse layer's selection names
         self.kv_selected_pages = 0
+        # host-to-device placements made for the plain decode program's
+        # slot inputs, and the dispatches they were made for
+        self.decode_placements = 0
+        self.decode_dispatches = 0
         # tokens decoded, and those of them decoded in a step that first
         # ran a prompt chunk (their gap held the chunk)
         self.gaps = 0
@@ -304,6 +308,12 @@ class ServingMetrics:
         self.kv_view_pages += view_pages
         self.kv_selected_pages += selected_pages
 
+    def record_decode_placements(self, placements: int) -> None:
+        """One dispatch of the plain decode program, and how many
+        host-to-device placements its slot inputs took."""
+        self.decode_placements += placements
+        self.decode_dispatches += 1
+
     def record_preemption(self) -> None:
         self.preemptions += 1
         if self.registry is not None:
@@ -409,6 +419,9 @@ class ServingMetrics:
             "kv_selected_page_frac": (
                 self.kv_selected_pages / self.kv_live_pages
                 if self.kv_live_pages else 0.0),
+            "decode_placements_per_step": (
+                self.decode_placements / self.decode_dispatches
+                if self.decode_dispatches else 0.0),
             "chunk_gap_share": (self.chunk_gaps / self.gaps
                                 if self.gaps else 0.0),
             "state_bytes": int(self.state_bytes),

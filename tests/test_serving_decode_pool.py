@@ -13,7 +13,8 @@ from deeperspeed_tpu.models.gpt import GPTConfig, make_gpt
 from deeperspeed_tpu.monitor import Tracer, set_tracer
 from deeperspeed_tpu.ops.pallas import paged_decode_attn as kernel
 from deeperspeed_tpu.serving import ServingConfig, ServingEngine
-from deeperspeed_tpu.serving.engine import _paged_block, make_decode_step
+from deeperspeed_tpu.serving.engine import (_paged_block, make_decode_step,
+                                            pack_slots)
 from deeperspeed_tpu.serving.kv_cache import (
     blocks_needed,
     paged_attend,
@@ -144,10 +145,16 @@ def _step_args(cfg, params, lengths, seed=3):
             jnp.zeros(N, jnp.float32), i32(np.arange(N)), i32(np.ones(N)))
 
 
+def _packed(args):
+    """The decode step's own arguments from the nine of ``_step_args``:
+    the six per-slot arrays as the one packed array."""
+    return args[:3] + (jnp.asarray(pack_slots(*args[3:])),)
+
+
 def test_decode_step_slices_no_layer_out_of_the_pool_and_aliases_both():
     cfg, params = _model()
     args = _step_args(cfg, params, [5, 0, 8, 3])
-    lowered = make_decode_step(cfg, SCFG).lower(*args)
+    lowered = make_decode_step(cfg, SCFG).lower(*_packed(args))
     nb, bs = SCFG.num_blocks, SCFG.block_size
     layer = f"{nb}x{bs}x{cfg.kv_heads}x{cfg.head_dim}x"
     moved = [ln.strip() for ln in lowered.as_text().splitlines()
@@ -196,7 +203,7 @@ def test_rows_written_are_the_old_forms_rows_bit_for_bit(dtype):
     lengths = [2 * bs, 3 * bs - 1, 0, bs]
     args = _step_args(cfg, params, lengths)
     want_k, want_v = _old_form_step(cfg)(*args[:6])
-    _, got_k, got_v, _, _ = make_decode_step(cfg, SCFG)(*args)
+    _, got_k, got_v, _, _ = make_decode_step(cfg, SCFG)(*_packed(args))
     tables = np.asarray(args[3])
     for got, want in ((got_k, want_k), (got_v, want_v)):
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)

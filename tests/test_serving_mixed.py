@@ -499,20 +499,19 @@ def test_a_neox_decode_step_never_meets_the_selector(monkeypatch):
     same text whatever the selector is: none of it is reached."""
     from deeperspeed_tpu.models.gpt import init_params
     from deeperspeed_tpu.serving import ServingConfig
-    from deeperspeed_tpu.serving.engine import make_decode_step
+    from deeperspeed_tpu.serving.engine import idle_slots, make_decode_step
 
     cfg = GPTConfig(vocab_size=96, n_layer=2, n_head=4, d_model=64,
                     max_seq=64, rotary=True)
     scfg = ServingConfig(num_slots=3, block_size=8, num_blocks=25,
                          max_seq_len=64)
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    N, i32 = scfg.num_slots, jnp.int32
+    N = scfg.num_slots
     sds = jax.ShapeDtypeStruct
     pool = sds((cfg.n_layer, scfg.num_blocks, scfg.block_size, cfg.kv_heads,
                 cfg.head_dim), cfg.dtype)
-    args = (params, pool, pool, sds((N, scfg.blocks_per_slot), i32),
-            sds((N,), i32), sds((N,), i32), sds((N,), jnp.float32),
-            sds((N,), i32), sds((N,), i32))
+    slots = idle_slots(N, scfg.blocks_per_slot)
+    args = (params, pool, pool, sds(slots.shape, slots.dtype))
     text = make_decode_step(cfg, scfg).lower(*args).as_text()
 
     def unreachable(*a, **k):

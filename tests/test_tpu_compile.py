@@ -58,6 +58,15 @@ def test_paged_decode_attn_compiles_at_the_serving_cells_geometry(
     assert "paged_decode_attn" in compiled.as_text()
 
 
+def _idle_slots(N, bps):
+    """Shape and dtype of the decode step's packed slot array, from the
+    function that packs it."""
+    from deeperspeed_tpu.serving.engine import idle_slots
+
+    slots = idle_slots(N, bps)
+    return slots.shape, slots.dtype
+
+
 def test_neox_1p3b_decode_step_holds_the_pool_once(one_chip, as_if_on_tpu):
     """The serving cell's decode program (NeoX-1.3B widths, 16 slots, a
     28,672-token pool): the donated pools are its outputs in place, no
@@ -82,9 +91,7 @@ def test_neox_1p3b_decode_step_holds_the_pool_once(one_chip, as_if_on_tpu):
              cfg.head_dim)
     compiled = make_decode_step(cfg, scfg).lower(
         params, sds(shape), sds(shape),
-        sds((N, scfg.blocks_per_slot), jnp.int32), sds((N,), jnp.int32),
-        sds((N,), jnp.int32), sds((N,), jnp.float32), sds((N,), jnp.int32),
-        sds((N,), jnp.int32)).compile()
+        sds(*_idle_slots(N, scfg.blocks_per_slot))).compile()
     text = compiled.as_text()
     assert "paged_decode_attn" in text
     assert count_alias_pairs(text) == 2
@@ -180,9 +187,8 @@ def test_sala_programs_move_neither_the_pool_nor_a_weight_stack(
     state = sds((12, N, 32, 128, 128), jnp.float32)
     if program == "decode":
         compiled = make_decode_step(cfg, scfg).lower(
-            params, pool, pool, sds((N, bps), i32), sds((N,), i32),
-            sds((N,), i32), sds((N,), jnp.float32), sds((N,), i32),
-            sds((N,), i32), kc, state).compile()
+            params, pool, pool, sds(*_idle_slots(N, bps)), kc,
+            state).compile()
     else:
         compiled = make_chunk_step(cfg, scfg).lower(
             params, pool, pool, kc, state, sds((1, 1024), i32), sds((bps,), i32),

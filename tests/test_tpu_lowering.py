@@ -219,7 +219,7 @@ def _decode_step_text(mesh=None, **cfg_kw):
     widths the kernel's gate admits), from shapes alone."""
     from deeperspeed_tpu.models.gpt import GPTConfig, make_gpt
     from deeperspeed_tpu.serving import ServingConfig
-    from deeperspeed_tpu.serving.engine import make_decode_step
+    from deeperspeed_tpu.serving.engine import idle_slots, make_decode_step
 
     cfg = GPTConfig(vocab_size=256, n_layer=2, n_head=16, d_model=2048,
                     max_seq=256, **cfg_kw)
@@ -229,10 +229,8 @@ def _decode_step_text(mesh=None, **cfg_kw):
     N = scfg.num_slots
     pool = _sds((cfg.n_layer, scfg.num_blocks, scfg.block_size,
                  cfg.kv_heads, cfg.head_dim))
-    args = (params, pool, pool, _sds((N, scfg.blocks_per_slot), jnp.int32),
-            _sds((N,), jnp.int32), _sds((N,), jnp.int32),
-            _sds((N,), jnp.float32), _sds((N,), jnp.int32),
-            _sds((N,), jnp.int32))
+    slots = idle_slots(N, scfg.blocks_per_slot)
+    args = (params, pool, pool, _sds(slots.shape, slots.dtype))
     return make_decode_step(cfg, scfg, mesh).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
 

@@ -40,7 +40,7 @@ from deeperspeed_tpu.monitor.metrics import (
 )
 from deeperspeed_tpu.monitor.validate import main as validate_main
 from deeperspeed_tpu.runtime.config import ConfigError, TrainingConfig
-from deeperspeed_tpu.serving import ServingEngine
+from deeperspeed_tpu.serving import ServingEngine, idle_slots
 from deeperspeed_tpu.utils.tensorboard import TensorBoardMonitor
 from deeperspeed_tpu.utils.timer import (
     SynchronizedWallClockTimer,
@@ -315,10 +315,7 @@ def test_watchdog_silent_across_serving_run_then_fires_on_injection():
     n2 = eng.scfg.num_slots + 1
     eng._decode_step(
         eng.params, jnp.array(eng.kv.k), jnp.array(eng.kv.v),
-        jnp.zeros((n2, eng.scfg.blocks_per_slot), jnp.int32),
-        jnp.zeros(n2, jnp.int32), jnp.zeros(n2, jnp.int32),
-        jnp.zeros(n2, jnp.float32), jnp.zeros(n2, jnp.int32),
-        jnp.zeros(n2, jnp.int32))
+        jnp.asarray(idle_slots(n2, eng.scfg.blocks_per_slot)))
     assert eng.telemetry.watchdog.observe() == ["serving/decode_step"]
     assert eng.decode_compile_count == 2
 
