@@ -86,6 +86,53 @@ class SparseAttnConfig:
         return max(self.topk, self.dense_len // self.block_size)
 
 
+@dataclasses.dataclass(frozen=True)
+class MambaAttnConfig:
+    """The constants of a ``mamba_attn`` layer (Falcon-H1's): the sizes of
+    its Mamba-2 state-space branch and the muP multiplier of every branch.
+    The branch has ``n_heads`` heads of ``head_dim`` channels, each with a
+    state of ``head_dim x d_state`` floats; B and C come in ``n_groups``
+    groups of ``d_state``; a depthwise causal convolution ``d_conv`` wide
+    runs over x, B and C before the recurrence; a prompt chunk computes
+    the recurrence chunkwise (SSD) in blocks of ``chunk`` positions.
+    ``ssm_mult`` scales the five segments of the input projection, z, x,
+    B, C and dt in that order."""
+    n_heads: int = 32
+    head_dim: int = 128
+    d_state: int = 256
+    n_groups: int = 2
+    d_conv: int = 4
+    chunk: int = 128
+    ssm_in: float = 1.0
+    ssm_mult: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out: float = 1.0
+    attn_in: float = 1.0
+    attn_out: float = 1.0
+    key: float = 1.0
+    mlp_gate: float = 1.0
+    mlp_out: float = 1.0
+
+    def __post_init__(self):
+        if self.n_heads % self.n_groups or len(self.ssm_mult) != 5:
+            raise ValueError(
+                "mamba_attn: n_heads must be a multiple of n_groups and "
+                f"ssm_mult name five segments (got {self})")
+
+    @property
+    def d_ssm(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: x, then B, then C."""
+        return self.d_ssm + 2 * self.n_groups * self.d_state
+
+    @property
+    def proj_dim(self) -> int:
+        """Width of the input projection: z, the convolved channels, dt."""
+        return self.d_ssm + self.conv_dim + self.n_heads
+
+
 # The kinds of layer a stack may hold, by the name of the mixer. The kind
 # settles the rest of the layer, so nothing else is configured: what its
 # mixer keeps between tokens (its cache), its norm and its feed-forward.
@@ -96,7 +143,11 @@ class SparseAttnConfig:
 #              gated SiLU feed-forward, no bias
 #   lightning  mixers.mixed_block: RMSNorm, decayed linear attention (one
 #              float32 state row a slot, no pages), gated SiLU, no bias
-LAYER_KINDS = ("attention", "minicpm4", "lightning")
+#   mamba_attn mixers.mamba_attn_block: RMSNorm, causal attention over every
+#              key (pages) AND a Mamba-2 state-space mixer (a float32 state
+#              row and a convolution tail a slot) on the same normed input,
+#              summed; gated SiLU; a muP multiplier a branch; no bias
+LAYER_KINDS = ("attention", "minicpm4", "lightning", "mamba_attn")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,10 +160,13 @@ class GPTConfig:
     # n_head/n_kv_head; attention repeats K/V heads to match Q
     n_kv_head: int = 0
     d_model: int = 768
+    # entries of one attention head; 0 => d_model // n_head
+    head_size: int = 0
     d_ff: int = 0  # 0 => 4 * d_model
     max_seq: int = 1024
     rotary: bool = True  # NeoX-style rotary; False => learned positions
     rotary_pct: float = 1.0
+    rope_theta: float = 10000.0   # read by the mamba_attn layers only
     parallel_residual: bool = True  # NeoX parallel attn+mlp
     layernorm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -160,6 +214,7 @@ class GPTConfig:
     residual_scale: float = 1.0
     logit_scale: float = 1.0
     sparse: Optional[SparseAttnConfig] = None   # the minicpm4 layers'
+    ssm: Optional[MambaAttnConfig] = None       # the mamba_attn layers'
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -207,11 +262,14 @@ class GPTConfig:
             if set(self.mixer_types) - mixable \
                     or len(self.mixer_types) != self.n_layer:
                 raise ValueError(
-                    f"mixer_types must name one of {sorted(mixable)} for "
-                    f"each of the {self.n_layer} layers (or be empty: a "
-                    f"stack of attention layers), got {self.mixer_types}")
+                    f"mixer_types must name one of {sorted(mixable)} (of "
+                    f"LAYER_KINDS {LAYER_KINDS}) for each of the "
+                    f"{self.n_layer} layers (or be empty: a stack of "
+                    f"attention layers), got {self.mixer_types}")
             if "minicpm4" in self.mixer_types and self.sparse is None:
                 raise ValueError("minicpm4 layers need cfg.sparse")
+            if "mamba_attn" in self.mixer_types and self.ssm is None:
+                raise ValueError("mamba_attn layers need cfg.ssm")
         if self.remat_policy not in ("full", "flash", "matmuls", "dots",
                                      "dots_all"):
             raise ValueError(
@@ -225,6 +283,8 @@ class GPTConfig:
 
     @property
     def head_dim(self):
+        if self.head_size:
+            return self.head_size
         assert self.d_model % self.n_head == 0
         return self.d_model // self.n_head
 
@@ -385,7 +445,7 @@ def layer_norm2(x, scale1, bias1, scale2, bias2, eps):
             (y * scale2 + bias2).astype(x.dtype))
 
 
-def rotary_embedding(x, positions, rotary_dims):
+def rotary_embedding(x, positions, rotary_dims, theta: float = 10000.0):
     """Apply rotary position embedding to the first rotary_dims of head_dim.
 
     x: (B, S, H, Dh); positions: (S,) shared across the batch, or (B, S)
@@ -395,7 +455,7 @@ def rotary_embedding(x, positions, rotary_dims):
     rot, rest = x[..., :rotary_dims], x[..., rotary_dims:]
     half = rotary_dims // 2
     freq = jnp.exp(
-        -math.log(10000.0) * jnp.arange(0, half, dtype=jnp.float32) / half
+        -math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
     )
     angles = positions[..., None].astype(jnp.float32) * freq  # (..., S, half)
     if positions.ndim == 1:

@@ -1,6 +1,8 @@
 """The layer kinds beside ``gpt.decoder_block``'s: RMSNorm, a gated SiLU
-feed-forward, muP scalings, and two mixers that keep something other than
-every key and value between tokens:
+feed-forward, muP scalings, and mixers that keep something other than
+every key and value between tokens. What a layer keeps has one of three
+shapes: pages (``minicpm4``), a state row a slot (``lightning``), or both
+(``mamba_attn``):
 
 ``lightning``  decayed linear attention (Lightning Attention): per head a
                state ``S_t = lam S_{t-1} + k_t^T v_t`` (Dh x Dh, float32),
@@ -9,13 +11,23 @@ every key and value between tokens:
 ``minicpm4``   InfLLM-v2 block-sparse attention: keys mean-pooled over
                windows, a query scores the pooled keys and attends over
                the tokens of ``topk`` blocks only.
+``mamba_attn`` causal attention over every key AND a Mamba-2 state-space
+               mixer, side by side on one normed input and summed
+               (Falcon-H1): per state-space head a state ``H_t = a_t
+               H_{t-1} + dt_t x_t (x) B_t`` (head_dim x d_state, float32),
+               ``y_t = H_t C_t + D x_t``, behind a depthwise causal
+               convolution whose last ``d_conv - 1`` inputs are state
+               too; a prompt computes the recurrence chunkwise (SSD), a
+               decode step is the recurrence.
 
-A model whose ``GPTConfig.mixer_types`` names them (MiniCPM-SALA) keeps
-its weights stacked BY KIND (``params["sparse"]``, ``params["lightning"]``)
-and is served only; the layer loop of every program goes run by run
-(``layer_runs``). ``mixed_block`` is the one layer the whole forward, the
-chunked prefill and the decode step share: a program hands it the
-cache-dependent ``core(q, k, v) -> (ctx, aux)`` alone.
+A model whose ``GPTConfig.mixer_types`` names them (MiniCPM-SALA,
+Falcon-H1) keeps its weights stacked BY KIND (``params["sparse"]``,
+``params["lightning"]``, ``params["mamba_attn"]``) and is served only; the
+layer loop of every program goes run by run (``layer_runs``).
+``mixed_block`` and ``mamba_attn_block`` are the layers the whole forward,
+the chunked prefill and the decode step share: a program hands them the
+cache-dependent cores alone (``core(q, k, v) -> (ctx, aux)``; for the
+state-space branch ``scan(xbc, dt) -> (y, aux)``).
 """
 
 import math
@@ -24,12 +36,13 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 
-from .gpt import GPTConfig, SparseAttnConfig, layer_norm, rotary_embedding
+from .gpt import (GPTConfig, SparseAttnConfig, _xla_causal_attention,
+                  expand_kv_heads, layer_norm, rotary_embedding)
 
 NEG = -1e30
 # where each kind's stacked weights live in the parameter tree
 STACK_KEY = {"attention": "layers", "minicpm4": "sparse",
-             "lightning": "lightning"}
+             "lightning": "lightning", "mamba_attn": "mamba_attn"}
 
 
 # ------------------------------------------------------------------ #
@@ -44,8 +57,14 @@ def rms_norm(x, scale, eps):
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def gated_ffn(u, p, cdt):
-    h = jax.nn.silu(u @ p["w_gate"].astype(cdt)) * (u @ p["w_up"].astype(cdt))
+def scaled(x, m: float):
+    """x times a model's constant multiplier (nothing where it is 1)."""
+    return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+
+
+def gated_ffn(u, p, cdt, gate_mult: float = 1.0):
+    h = jax.nn.silu(scaled(u @ p["w_gate"].astype(cdt), gate_mult)) \
+        * (u @ p["w_up"].astype(cdt))
     return h @ p["w_down"].astype(cdt)
 
 
@@ -130,6 +149,24 @@ def init_params(rng, cfg: GPTConfig):
         params["sparse"] = kind(cfg.count("minicpm4"), cfg.kv_heads, False)
     if cfg.count("lightning"):
         params["lightning"] = kind(cfg.count("lightning"), H, True)
+    if cfg.count("mamba_attn"):
+        n, m = cfg.count("mamba_attn"), cfg.ssm
+        params["mamba_attn"] = {
+            "ln1": jnp.ones((n, D)), "ln2": jnp.ones((n, D)),
+            "wqkv": w((n, D, cfg.qkv_dim), std),
+            "wo": w((n, H * Dh, D), out_std),
+            "ssm": {"w_in": w((n, D, m.proj_dim), std),
+                    # tap j meets the input d_conv - 1 - j positions back
+                    "conv_w": w((n, m.d_conv, m.conv_dim), 0.5),
+                    "conv_b": jnp.zeros((n, m.conv_dim)),
+                    "dt_bias": jnp.full((n, m.n_heads), -4.0),
+                    "A_log": jnp.tile(jnp.log(jnp.arange(
+                        1.0, m.n_heads + 1.0)), (n, 1)),
+                    "D": jnp.ones((n, m.n_heads)),
+                    "norm": jnp.ones((n, m.d_ssm)),
+                    "w_out": w((n, m.d_ssm, D), out_std)},
+            "mlp": {"w_gate": w((n, D, F), std), "w_up": w((n, D, F), std),
+                    "w_down": w((n, F, D), out_std)}}
     return params
 
 
@@ -170,6 +207,57 @@ def mixed_block(cfg: GPTConfig, kind: str, x, p, positions, core):
         m = gated_ffn(rms_norm(x, p["ln2"], eps), p["mlp"], cdt)
         x = x + (r * m).astype(cdt)
     return x, aux
+
+
+def mamba_attn_block(cfg: GPTConfig, x, p, positions, attend, scan):
+    """A ``mamba_attn`` layer: with u = RMSNorm(x), x + SSM(u) + Attn(u),
+    then x + FFN(RMSNorm(x)), every branch times its multiplier
+    (``cfg.ssm``). The two cores know the cache: ``attend(q, k, v) ->
+    (ctx (B, S, H, Dh), aux)`` with q and k rotated, q NOT yet scaled;
+    ``scan(xbc (B, S, conv_dim), dt (B, S, n_heads)) -> (y (B, S,
+    n_heads, head_dim) float32, aux)`` is the convolution, its SiLU and
+    the recurrence, ``D x`` included, over the projection's convolved
+    channels (x, B, C, before the convolution, in the compute dtype) and
+    its raw step sizes. Returns (x, (attention's aux, the scan's))."""
+    cdt, eps, m = cfg.dtype, cfg.layernorm_eps, cfg.ssm
+    B, S, _ = x.shape
+    H, Hkv, Dh = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    u = rms_norm(x, p["ln1"], eps)
+    with jax.named_scope("ds.ssm"):
+        sp = p["ssm"]
+        proj = scaled(u, m.ssm_in) @ sp["w_in"].astype(cdt)
+        d, g = m.d_ssm, m.n_groups * m.d_state
+        mz, mx, mb, mc, mdt = m.ssm_mult
+        z = scaled(proj[..., :d].astype(jnp.float32), mz)
+        xbc = jnp.concatenate(
+            [scaled(proj[..., d:2 * d], mx),
+             scaled(proj[..., 2 * d:2 * d + g], mb),
+             scaled(proj[..., 2 * d + g:2 * d + 2 * g], mc)], -1)
+        dt = scaled(proj[..., 2 * d + 2 * g:].astype(jnp.float32), mdt)
+        y, kept_ssm = scan(xbc, dt)
+        # gate, then an RMSNorm over each group's channels
+        y = y.reshape(B, S, m.n_groups, d // m.n_groups) \
+            * jax.nn.silu(z).reshape(B, S, m.n_groups, d // m.n_groups)
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                              + eps)
+        y = (y.reshape(B, S, d) * sp["norm"].astype(jnp.float32)).astype(cdt)
+        ssm = scaled(y @ sp["w_out"].astype(cdt), m.ssm_out)
+    with jax.named_scope("ds.attn"):
+        qkv = scaled(u, m.attn_in) @ p["wqkv"].astype(cdt)
+        q = qkv[..., :H * Dh].reshape(B, S, H, Dh)
+        k = scaled(qkv[..., H * Dh:(H + Hkv) * Dh], m.key).reshape(
+            B, S, Hkv, Dh)
+        v = qkv[..., (H + Hkv) * Dh:].reshape(B, S, Hkv, Dh)
+        q = rotary_embedding(q, positions, Dh, cfg.rope_theta)
+        k = rotary_embedding(k, positions, Dh, cfg.rope_theta)
+        ctx, kept_attn = attend(q, k, v)
+        attn = scaled(ctx.astype(cdt).reshape(B, S, H * Dh)
+                      @ p["wo"].astype(cdt), m.attn_out)
+    x = x + ssm + attn
+    with jax.named_scope("ds.mlp"):
+        mlp = gated_ffn(rms_norm(x, p["ln2"], eps), p["mlp"], cdt, m.mlp_gate)
+        x = x + scaled(mlp, m.mlp_out)
+    return x, (kept_attn, kept_ssm)
 
 
 def embed_tokens(cfg: GPTConfig, params, tokens, positions=None):
@@ -262,6 +350,133 @@ def lightning_step(q, k, v, S, slopes):
                    q.astype(jnp.float32) / math.sqrt(q.shape[-1]), S,
                    precision="highest")
     return o, S
+
+
+# ------------------------------------------------------------------ #
+# mamba_attn: the convolution, the chunkwise form and the recurrence
+# ------------------------------------------------------------------ #
+
+
+def ssm_inputs(m, sp, conv, dt, valid=None):
+    """What the recurrence takes, in float32, from the convolution's
+    output ``conv`` (..., conv_dim) (bias and SiLU here) and the raw step
+    sizes ``dt`` (..., n_heads): x (..., n_heads, head_dim), B, C (...,
+    n_groups, d_state), delta = softplus(dt + dt_bias) and delta * A
+    (..., n_heads), A = -exp(A_log). Where ``valid`` (...) is False delta
+    is 0: such a position neither decays the state nor adds to it."""
+    c = jax.nn.silu(conv + sp["conv_b"].astype(jnp.float32))
+    lead, d, g = c.shape[:-1], m.d_ssm, m.n_groups * m.d_state
+    x = c[..., :d].reshape(*lead, m.n_heads, m.head_dim)
+    Bm = c[..., d:d + g].reshape(*lead, m.n_groups, m.d_state)
+    Cm = c[..., d + g:].reshape(*lead, m.n_groups, m.d_state)
+    delta = jax.nn.softplus(dt.astype(jnp.float32)
+                            + sp["dt_bias"].astype(jnp.float32))
+    if valid is not None:
+        delta = jnp.where(valid[..., None], delta, 0.0)
+    dA = -delta * jnp.exp(sp["A_log"].astype(jnp.float32))
+    return x, Bm, Cm, delta, dA
+
+
+def ssd_chunk_xla(x, Bm, Cm, delta, dA, h_in, block: int):
+    """The recurrence ``H_t = exp(dA_t) H_{t-1} + delta_t x_t (x) B_t``,
+    ``y_t = H_t C_t`` chunkwise (SSD) in plain XLA: inside a block of
+    ``block`` positions a masked matrix of decays times C B^T, across
+    blocks the carried state. x: (T, Hs, P); Bm, Cm: (T, G, N); delta,
+    dA: (T, Hs); h_in: (Hs, P, N) float32, the state BEFORE position 0.
+    All float32. Returns (y (T, Hs, P), the state after position T-1);
+    a position whose delta is 0 leaves the state as it was."""
+    T, Hs, P = x.shape
+    G, N = Bm.shape[1:]
+    R = Hs // G
+    Q = min(block, T)
+    pad = -T % Q
+    if pad:     # delta 0: the state passes through
+        x, Bm, Cm, delta, dA = (jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                                for a in (x, Bm, Cm, delta, dA))
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def blk(h, xs):
+        xb, Bb, Cb, db, ab = xs
+        cs = jnp.cumsum(ab, 0)                              # (Q, Hs)
+        dx = (db[..., None] * xb).reshape(Q, G, R, P)
+        cb = jnp.einsum("tgn,sgn->gts", Cb, Bb, precision="highest")
+        seg = cs.T[:, :, None] - cs.T[:, None, :]           # (Hs, t, s)
+        decay = jnp.where(causal, jnp.exp(jnp.where(causal, seg, 0.0)), 0.0)
+        mat = decay.reshape(G, R, Q, Q) * cb[:, None]
+        y = jnp.einsum("grts,sgrp->tgrp", mat, dx, precision="highest")
+        hg = h.reshape(G, R, P, N)
+        y = y + jnp.exp(cs).reshape(Q, G, R, 1) * jnp.einsum(
+            "tgn,grpn->tgrp", Cb, hg, precision="highest")
+        rest = jnp.exp(cs[-1][None] - cs).reshape(Q, G, R, 1)
+        hg = jnp.exp(cs[-1]).reshape(G, R, 1, 1) * hg + jnp.einsum(
+            "sgrp,sgn->grpn", dx * rest, Bb, precision="highest")
+        return hg.reshape(Hs, P, N), y.reshape(Q, Hs, P)
+
+    split = lambda a: a.reshape((T + pad) // Q, Q, *a.shape[1:])
+    h, y = jax.lax.scan(blk, h_in.astype(jnp.float32),
+                        tuple(split(a) for a in (x, Bm, Cm, delta, dA)))
+    return y.reshape(T + pad, Hs, P)[:T], h
+
+
+def ssm_chunk(m, sp, xbc, dt, tail, h, n_valid):
+    """The state-space branch between its projections, for T positions of
+    one sequence whose first ``n_valid`` (traced) are real. xbc: (T,
+    conv_dim), the convolution's inputs; dt: (T, n_heads) raw; tail:
+    (d_conv - 1, conv_dim), the inputs just before position 0; h:
+    (n_heads, head_dim, d_state) float32, the state before it. Returns (y
+    (T, n_heads, head_dim) float32 with ``D x`` in it, the new tail: the
+    last d_conv - 1 inputs before ``n_valid``, reaching into the old tail
+    where n_valid is smaller, and the state after position n_valid - 1)."""
+    T, K = xbc.shape[0], m.d_conv
+    ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], 0)  # (T + K-1, ch)
+    w = sp["conv_w"].astype(jnp.float32)
+    conv = sum(w[j] * ext[j:j + T].astype(jnp.float32) for j in range(K))
+    x, Bm, Cm, delta, dA = ssm_inputs(
+        m, sp, conv, dt, jnp.arange(T, dtype=jnp.int32) < n_valid)
+    y, h = ssd_chunk_xla(x, Bm, Cm, delta, dA, h, m.chunk)
+    y = y + sp["D"].astype(jnp.float32)[:, None] * x
+    return y, jax.lax.dynamic_slice_in_dim(ext, n_valid, K - 1, 0), h
+
+
+def ssm_step_inputs(m, sp, xbc, dt, tail):
+    """One token a row up to the recurrence: the convolution over the
+    tail and the new input. xbc: (N, conv_dim); dt: (N, n_heads); tail:
+    (N, d_conv - 1, conv_dim). Returns ``ssm_inputs``' five and the new
+    tail."""
+    ext = jnp.concatenate([tail.astype(xbc.dtype), xbc[:, None]], 1)
+    conv = jnp.einsum("nkc,kc->nc", ext.astype(jnp.float32),
+                      sp["conv_w"].astype(jnp.float32), precision="highest")
+    return (*ssm_inputs(m, sp, conv, dt), ext[:, 1:])
+
+
+def ssm_rows_xla(rows, layer, decay, dx, Bm, Cm, live):
+    """The recurrence for one token a slot on the STACKED state rows, in
+    plain XLA, and the oracle of ops/pallas/ssm_row_update. rows: (L, N,
+    Hs, P, Nst) float32, the layers' rows of every slot; ``layer``
+    traced. decay: (N, Hs), a = exp(delta A); dx: (N, Hs, P), delta x; Bm,
+    Cm: (N, G, Nst); live: (N,) bool. ``H <- a H + dx (x) B``, ``y = H
+    C``; a slot that is not live keeps its row (its y means nothing).
+    Returns (rows with the layer's rows written in place, y (N, Hs, P))."""
+    R = rows.shape[2] // Bm.shape[1]
+    heads = lambda a: jnp.repeat(a, R, axis=1)[:, :, None, :]  # (N, Hs, 1, Nst)
+    h = rows[layer]
+    new = decay[..., None, None] * h + dx[..., None] * heads(Bm)
+    # a multiply and a sum, not a dot: one pass with the update above
+    y = jnp.sum(new * heads(Cm), -1)
+    rows = jax.lax.dynamic_update_index_in_dim(
+        rows, jnp.where(live[:, None, None, None], new, h), layer, 0)
+    return rows, y
+
+
+def ssm_step(m, sp, xbc, dt, tail, h):
+    """``ssm_chunk`` for one token a row: the recurrence itself. xbc: (N,
+    conv_dim); dt: (N, n_heads); tail: (N, d_conv - 1, conv_dim); h: (N,
+    n_heads, head_dim, d_state) float32. Returns (y (N, n_heads,
+    head_dim) float32, the new tail, the new state)."""
+    x, Bm, Cm, delta, dA, tail = ssm_step_inputs(m, sp, xbc, dt, tail)
+    rows, y = ssm_rows_xla(h[None], 0, jnp.exp(dA), delta[..., None] * x, Bm,
+                           Cm, jnp.ones(h.shape[:1], bool))
+    return y + sp["D"].astype(jnp.float32)[:, None] * x, tail, rows[0]
 
 
 # ------------------------------------------------------------------ #
@@ -443,13 +658,29 @@ def dense_sparse_attention(q, k, v, sp: SparseAttnConfig):
 
 def forward(cfg: GPTConfig, params, tokens):
     """tokens (1, S) -> logits (1, S, V): the mixed stack with no cache,
-    each mixer by its definition (S a multiple of the sparse block)."""
+    each mixer by its definition (S a multiple of the sparse block where
+    the stack has sparse layers)."""
     S = tokens.shape[1]
     slopes = lightning_slopes(cfg.n_head)
     x = embed_tokens(cfg, params, tokens)
     positions = jnp.arange(S, dtype=jnp.int32)
 
     def body(kind, x, p, _i):
+        if kind == "mamba_attn":
+            m = cfg.ssm
+
+            def attend(q, k, v):
+                return _xla_causal_attention(q, *expand_kv_heads(q, k, v)), None
+
+            def scan(xbc, dt):
+                y, _, _ = ssm_chunk(
+                    m, p["ssm"], xbc[0], dt[0],
+                    jnp.zeros((m.d_conv - 1, m.conv_dim), xbc.dtype),
+                    jnp.zeros((m.n_heads, m.head_dim, m.d_state)), S)
+                return y[None], None
+
+            return mamba_attn_block(cfg, x, p, positions, attend, scan)[0], ()
+
         def core(q, k, v):
             if kind == "lightning":
                 o, _ = lightning_chunk_xla(

@@ -65,14 +65,19 @@ from .kv_cache import (
     NULL_BLOCK,
     PagedKVCache,
     blocks_needed,
+    chunk_attend_all,
+    decode_attend_all,
     decode_attend_for,
     lightning_chunk_for,
     sparse_attend_for,
+    ssm_rows_for,
     decode_write_indices,
     sparse_chunk_attend,
     sparse_decode_attend,
     write_chunk,
+    write_chunk_pages,
     write_decode_rows,
+    write_rows,
 )
 from .metrics import ServingMetrics
 from .scheduler import Request, Scheduler
@@ -162,11 +167,16 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
     """One layer of the stack inside a serving program, by its ``kind``
     (one of ``cfg.layer_kinds``). The layer math is the model's own:
     gpt.decoder_block's for an ``attention`` layer (the block training
-    runs), mixers.mixed_block's for the others. Only the core differs
-    (mirrors generation._cached_block): ``attend(q, k, v) -> (ctx, kept)``
-    reads the layer's cache (pages, a state row), and ``kept`` (what the
-    caller keeps of the new tokens: keys and values, a new state) comes
-    back beside the layer's output."""
+    runs), mixers.mamba_attn_block's or mixers.mixed_block's for the
+    others. Only the core differs (mirrors generation._cached_block):
+    ``attend(q, k, v) -> (ctx, kept)`` reads the layer's cache (pages, a
+    state row), and ``kept`` (what the caller keeps of the new tokens:
+    keys and values, a new state) comes back beside the layer's output; a
+    ``mamba_attn`` layer has two caches and takes the pair of its cores,
+    (attention's, the state-space scan's)."""
+    if kind == "mamba_attn":
+        return mixers.mamba_attn_block(cfg, x, layer_params, positions,
+                                       *attend)
     if kind != "attention":
         return mixers.mixed_block(cfg, kind, x, layer_params, positions,
                                   attend)
@@ -199,8 +209,10 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     (``cfg.mixer_types``) passes its pooled keys and its state rows,
     donated like the pools: its sparse layers score the slot's pooled
     keys, pick pages and read only those, its lightning layers read and
-    write their state row, and the layer loop goes run by run of one kind
-    (``mixers.scan_runs``; a classic model is one run). Pools are
+    write their state row, its mamba_attn layers read every live page of
+    a slot AND read and write a state row and a convolution tail, and the
+    layer loop goes run by run of one kind (``mixers.scan_runs``; a
+    classic model is one run). Pools are
     donated — the caller's old handles die each step (no second pool in
     HBM) — and stay in place: the layer loop only READS them (each layer
     attends over the pool's positions below the slot's length plus the
@@ -233,10 +245,16 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         # what the kinds of layer in this stack need beside the pools
         if "attention" in kinds:
             attend_rows = decode_attend_for(k_pool, tables, cfg.n_head, mesh)
-        if "minicpm4" in kinds:
+        if kinds & {"minicpm4", "mamba_attn"}:
             attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
+        if "minicpm4" in kinds:
             at = decode_write_indices(sp, tables, lengths)
-        if "lightning" in kinds:
+        if "mamba_attn" in kinds:
+            bs = scfg.block_size
+            at = {"page": tables[jnp.arange(N), lengths // bs],
+                  "row": lengths % bs}
+            update_rows = ssm_rows_for(state["ssm"], cfg.ssm.n_groups, mesh)
+        if kinds & {"lightning", "mamba_attn"}:
             # a slot whose prompt is still being chunked in is idle here:
             # its state row is the chunks' to write
             live = (lengths > 0)[:, None, None, None]
@@ -267,10 +285,35 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                                                rows[layer], slopes)
                 return o[:, None], jnp.where(live, new, rows[layer])
 
+            def all_pages(q, k, v):
+                k_row = k[:, 0].astype(k_pool.dtype)
+                v_row = v[:, 0].astype(v_pool.dtype)
+                ctx = decode_attend_all(k_pool, v_pool, layer, q, k_row,
+                                        v_row, tables, lengths, attend_pages)
+                return ctx, (k_row, v_row)
+
+            def state_space(xbc, dt):
+                """The new token's convolution, then every slot's state
+                row through the recurrence, in place in the carry."""
+                sp_l, tail = layer_params["ssm"], rows["conv"][layer]
+                x, Bm, Cm, delta, dA, new_tail = mixers.ssm_step_inputs(
+                    cfg.ssm, sp_l, xbc[:, 0], dt[:, 0], tail)
+                ssm_rows, y = update_rows(
+                    rows["ssm"], layer, jnp.exp(dA), delta[..., None] * x,
+                    Bm, Cm, lengths > 0)
+                y = y + sp_l["D"].astype(jnp.float32)[:, None] * x
+                conv = jax.lax.dynamic_update_index_in_dim(
+                    rows["conv"], jnp.where(live[..., 0], new_tail, tail),
+                    layer, 0)
+                return y[:, None], {"conv": conv, "ssm": ssm_rows}
+
             core = {"attention": attention, "minicpm4": minicpm4,
-                    "lightning": lightning}[kind]
+                    "lightning": lightning,
+                    "mamba_attn": (all_pages, state_space)}[kind]
             x, kept = _paged_block(cfg, x, layer_params, positions, core,
                                    kind)
+            if kind == "mamba_attn":
+                kept, rows = kept
             if kind == "lightning":
                 rows = jax.lax.dynamic_update_index_in_dim(rows, kept, layer, 0)
                 kept = ()
@@ -291,6 +334,10 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
             if "minicpm4" in kept:
                 k_pool, v_pool, kc_pool = write_decode_rows(
                     sp, k_pool, v_pool, kc_pool, at, *kept["minicpm4"])
+            if "mamba_attn" in kept:
+                k_rows, v_rows = kept["mamba_attn"]     # (L, N, Hkv, Dh)
+                k_pool = write_rows(k_pool, at["page"], at["row"], k_rows)
+                v_pool = write_rows(v_pool, at["page"], at["row"], v_rows)
         with jax.named_scope("ds.decode/sample"):
             logits = mixers.head_logits(cfg, params, x)[:, 0]   # (N, V)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -340,10 +387,12 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     at the chunk's last real position, k_pool', v_pool', kc_pool',
     state'). ``offset`` (a multiple of C), ``slot`` and ``n_valid`` are
     TRACED: one lowering serves every chunk of every prompt. The chunk
-    carries the slot's lightning state in (zeros at offset 0: a row is
-    cleared by whoever enters it, never by who left) and out, attends
-    over the slot's pages (sparse layers) and writes its own keys,
-    values and pooled keys after the layer loop, in place."""
+    carries the slot's recurrent state in (zeros at offset 0: a row is
+    cleared by whoever enters it, never by who left) and out, a position
+    at or beyond ``n_valid`` leaving it as it was; it attends over the
+    slot's pages (the selected ones in a sparse layer, all of the past in
+    a mamba_attn layer) and writes its own keys, values and pooled keys
+    after the layer loop, in place."""
     C = prefill_chunk_for(cfg, scfg)
     bs = scfg.block_size
     sp = cfg.sparse
@@ -357,9 +406,11 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         # the last chunk may run past the table's end: null pages there
         table_row = jnp.pad(table_row, (0, C // bs))
         attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
-        carried = jnp.where(
-            offset == 0, 0.0,
-            jax.lax.dynamic_index_in_dim(state, slot, 1, keepdims=False))
+        carried = jax.tree.map(
+            lambda rows: jnp.where(
+                offset == 0, 0.0,
+                jax.lax.dynamic_index_in_dim(rows, slot, 1, keepdims=False)),
+            state)
 
         def layer_body(kind, x, layer_params, layer):
             def minicpm4(q, k, v):
@@ -374,7 +425,21 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                     q[0], k[0], v[0], carried[layer], slopes, n_valid)
                 return o[None], new
 
-            core = {"minicpm4": minicpm4, "lightning": lightning}[kind]
+            def all_past(q, k, v):
+                kk, vv = k[0].astype(k_pool.dtype), v[0].astype(v_pool.dtype)
+                ctx = chunk_attend_all(k_pool, v_pool, layer, q[0], kk, vv,
+                                       table_row, offset,
+                                       scfg.blocks_per_slot)
+                return ctx[None], (kk, vv)
+
+            def state_space(xbc, dt):
+                y, tail, h = mixers.ssm_chunk(
+                    cfg.ssm, layer_params["ssm"], xbc[0], dt[0],
+                    carried["conv"][layer], carried["ssm"][layer], n_valid)
+                return y[None], {"conv": tail, "ssm": h}
+
+            core = {"minicpm4": minicpm4, "lightning": lightning,
+                    "mamba_attn": (all_past, state_space)}[kind]
             return _paged_block(cfg, x, layer_params, positions, core, kind)
 
         x, kept = mixers.scan_runs(cfg, params, x, layer_body)
@@ -386,6 +451,14 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
             if "lightning" in kept:
                 state = jax.lax.dynamic_update_slice(
                     state, kept["lightning"][:, None], (0, slot, 0, 0, 0))
+            if "mamba_attn" in kept:
+                (kk, vv), new = kept["mamba_attn"]
+                k_pool, v_pool = write_chunk_pages(k_pool, v_pool, table_row,
+                                                   offset, kk, vv)
+                state = jax.tree.map(
+                    lambda rows, n: jax.lax.dynamic_update_slice(
+                        rows, n[:, None].astype(rows.dtype),
+                        (0, slot) + (0,) * (rows.ndim - 2)), state, new)
         last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0)
         return (mixers.head_logits(cfg, params, last)[0], k_pool, v_pool,
                 kc_pool, state)
@@ -613,12 +686,13 @@ class ServingEngine(_ServingBase):
         self.cfg = cfg
         if not cfg.classic:
             kinds = sorted(set(cfg.mixer_types))
-            if scfg.prefix_caching and cfg.count("lightning"):
+            if scfg.prefix_caching and \
+                    set(kinds) & {"lightning", "mamba_attn"}:
                 raise ValueError(
-                    "prefix_caching cannot serve a model with recurrent "
-                    f"layers ({kinds}): a cached prefix's pages say nothing "
-                    "of the lightning state after it, and no snapshot of "
-                    "that state is kept. Turn prefix_caching off")
+                    "prefix_caching cannot serve a model with a layer that "
+                    f"keeps recurrent state ({kinds}): a cached prefix's "
+                    "pages say nothing of the state row after it, and no "
+                    "snapshot of that row is kept. Turn prefix_caching off")
             if mesh is not None or scfg.speculative is not None:
                 raise NotImplementedError(
                     f"a stack of {kinds} layers is served on one device, "
@@ -664,8 +738,8 @@ class ServingEngine(_ServingBase):
         self._chunking: Dict[int, dict] = {}
         self._prefill_spent = 0   # prompt tokens prefilled this step
         self._chunk_ran = False   # ... of them any in a chunk
-        if self.kv.state is not None:
-            self.metrics.state_bytes = self.kv.state.nbytes
+        self.metrics.state_bytes = sum(
+            a.nbytes for a in jax.tree.leaves(self.kv.state))
         if self.telemetry is not None:
             # decode must stay one-compile forever; prefill legitimately
             # retraces per length bucket, so it is deliberately unwatched
@@ -994,6 +1068,9 @@ class ServingEngine(_ServingBase):
         self._prefill_spent += hi - lo
         self._chunk_ran = True
         self.metrics.record_prefill_chunk(hi - lo)
+        if lo == 0 and self.kv.state is not None:
+            # the first chunk entered the slot's state rows as zeros
+            self.metrics.record_state_reset()
         state["next"] += 1
         if final:
             del self._chunking[slot]

@@ -5,17 +5,21 @@ Layout: one pool per cache side, stacked over layers —
 
     k, v: (n_layer, num_blocks, block_size, n_kv_head, head_dim)
 
-A model of mixed layers (``GPTConfig.mixer_types``) keeps pages for its
-``minicpm4`` layers only, a page's ``(block_size, head_dim)`` last so that
-two key heads are not padded to a tile of sixteen, and beside them what its
-other layers keep between tokens:
+A model of mixed layers (``GPTConfig.mixer_types``) keeps, by the kind of
+each layer, one of three shapes of cache: pages (``minicpm4``), a state
+row a slot (``lightning``), or both for one layer (``mamba_attn``). Its
+pages hold the layers that have any, a page's ``(block_size, head_dim)``
+last so that two or four key heads are not padded to a tile of sixteen:
 
-    k, v:  (n_sparse, num_blocks, n_kv_head, block_size, head_dim)
+    k, v:  (n_paged, num_blocks, n_kv_head, block_size, head_dim)
     kc:    (n_sparse, num_blocks, n_kv_head * windows_per_block, head_dim)
            the selector's pooled keys: a page's row h * w + i is key head
            h's mean over the window that starts at the page's token i * st
-    state: (n_lightning, num_slots, n_head, head_dim, head_dim) float32,
-           one row a SLOT (not pages: it has one size whatever the length)
+    state: one row a SLOT (not pages: it has one size whatever the length),
+           float32: lightning (n_lightning, num_slots, n_head, head_dim,
+           head_dim); mamba_attn {"ssm": (n, num_slots, ssm heads, ssm
+           head_dim, d_state), "conv": (n, num_slots, d_conv - 1,
+           conv_dim) in the compute dtype: the convolution's last inputs}
 
 A request's cache lives in whichever blocks the allocator hands it; the
 per-slot BLOCK TABLE (``(num_slots, blocks_per_slot)`` int32) maps the
@@ -358,13 +362,30 @@ class PagedKVCache:
                     f"a page is one selection block: block_size must be "
                     f"{sp.block_size} (got {scfg.block_size}) and "
                     f"max_seq_len cover dense_len ({sp.dense_len})")
-            n_sp, n_li = cfg.count("minicpm4"), cfg.count("lightning")
-            shape = (n_sp, nb, cfg.kv_heads, scfg.block_size, cfg.head_dim)
-            w = sp.windows_per_block if sp is not None else 1
-            self.kc = jnp.zeros((n_sp, nb, cfg.kv_heads * w, cfg.head_dim),
-                                cfg.dtype)
-            self.state = jnp.zeros((n_li, scfg.num_slots, cfg.n_head,
-                                    cfg.head_dim, cfg.head_dim), jnp.float32)
+            n_sp, n_li, n_ma = (cfg.count(kind) for kind in (
+                "minicpm4", "lightning", "mamba_attn"))
+            if n_ma and (n_sp or n_li):
+                raise NotImplementedError(
+                    "mamba_attn layers share a stack with no other kind: "
+                    "the pool and the state rows are indexed by a layer's "
+                    "place among ONE kind")
+            shape = (n_sp + n_ma, nb, cfg.kv_heads, scfg.block_size,
+                     cfg.head_dim)
+            if sp is not None:
+                self.kc = jnp.zeros(
+                    (n_sp, nb, cfg.kv_heads * sp.windows_per_block,
+                     cfg.head_dim), cfg.dtype)
+            if n_li:
+                self.state = jnp.zeros(
+                    (n_li, scfg.num_slots, cfg.n_head, cfg.head_dim,
+                     cfg.head_dim), jnp.float32)
+            if n_ma:
+                m = cfg.ssm
+                self.state = {
+                    "ssm": jnp.zeros((n_ma, scfg.num_slots, m.n_heads,
+                                      m.head_dim, m.d_state), jnp.float32),
+                    "conv": jnp.zeros((n_ma, scfg.num_slots, m.d_conv - 1,
+                                       m.conv_dim), cfg.dtype)}
         self.k = jnp.zeros(shape, cfg.dtype)
         self.v = jnp.zeros(shape, cfg.dtype)
         self.allocator = BlockAllocator(nb)
@@ -641,6 +662,19 @@ def lightning_chunk_for(q, mesh):
     return lightning_chunk_xla
 
 
+def ssm_rows_for(rows, n_groups, mesh):
+    """A decode step's update of a mamba_attn layer's state rows: the
+    kernel (each row crosses HBM once each way) on one TPU at shapes it
+    can tile, else ``mixers.ssm_rows_xla``."""
+    from ..models import mixers
+    from ..ops.pallas import ssm_row_update as kernel
+
+    if (mesh is None or mesh.size == 1) and kernel.is_available(
+            rows, n_groups):
+        return kernel.ssm_row_update
+    return mixers.ssm_rows_xla
+
+
 def _own_token_init(q, k_row, v_row):
     """(m0, l0, acc0) of rows that have attended over one key so far:
     their own. q: (R, G, Dh); k_row, v_row: (R, Dh)."""
@@ -765,6 +799,80 @@ def sparse_decode_attend(sp, k_pool, v_pool, kc_pool, layer, q, k_row,
     return ctx.reshape(N, 1, H, Dh), kbar_new
 
 
+def _own_chunk_init(qg, k, v):
+    """(m, l, acc) of every query of a prompt chunk over the chunk's own
+    keys up to itself. qg: (C, Hkv, G, Dh); k, v: (C, Hkv, Dh)."""
+    C, Dh = qg.shape[0], qg.shape[-1]
+    s = jnp.einsum("qhgd,khd->qhgk", qg, k,
+                   preferred_element_type=jnp.float32) * (1.0 / math.sqrt(Dh))
+    causal = jnp.arange(C)[None, :] <= jnp.arange(C)[:, None]
+    s = jnp.where(causal[:, None, None, :], s, -1e30)
+    m = jnp.max(s, -1)
+    p = jnp.exp(s - m[..., None])
+    acc = jnp.einsum("qhgk,khd->qhgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return m, jnp.sum(p, -1), acc
+
+
+def chunk_attend_all(k_pool, v_pool, layer, q, k, v, table_row, offset,
+                     n_past: int):
+    """Causal attention of a prompt chunk's C queries at positions
+    ``offset ..`` (traced) over ALL their past: the slot's first
+    ``n_past`` pages, of which the positions below ``offset`` count (whole
+    pages gathered once for the chunk), and the chunk's own keys up to
+    each query, in one softmax. q: (C, H, Dh); k, v: (C, Hkv, Dh) in the
+    pool's dtype; pools in the mixed layout. -> (C, H, Dh) in q's dtype."""
+    C, H, Dh = q.shape
+    Hkv, bs = k_pool.shape[2], k_pool.shape[3]
+    scale = 1.0 / math.sqrt(Dh)
+    qg = q.reshape(C, Hkv, H // Hkv, Dh)
+    past = lambda pool: jnp.swapaxes(
+        pool[layer, table_row[:n_past]], 0, 1).reshape(Hkv, n_past * bs, Dh)
+    kp, vp = past(k_pool), past(v_pool)
+    m0, l0, acc0 = _own_chunk_init(qg, k, v)
+    live = jnp.arange(n_past * bs) < offset
+
+    def tile(a):
+        qt, m0, l0, acc0 = a
+        s = jnp.einsum("qhgd,hkd->qhgk", qt, kp,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(live, s, -1e30)
+        m = jnp.maximum(m0, jnp.max(s, -1))
+        p = jnp.exp(s - m[..., None])
+        alpha = jnp.exp(m0 - m)
+        acc = alpha[..., None] * acc0 + jnp.einsum(
+            "qhgk,hkd->qhgd", p.astype(vp.dtype), vp,
+            preferred_element_type=jnp.float32)
+        return acc / (alpha * l0 + jnp.sum(p, -1))[..., None]
+
+    tq = min(C, 256)
+    out = jax.lax.map(tile, jax.tree.map(
+        lambda a: a.reshape(C // tq, tq, *a.shape[1:]),
+        (qg, m0, l0, acc0)))
+    return out.reshape(C, H, Dh).astype(q.dtype)
+
+
+def decode_attend_all(k_pool, v_pool, layer, q, k_row, v_row, tables,
+                      lengths, attend_pages):
+    """One layer's decode attention for all slots over EVERY live page of
+    a slot, pools in the mixed layout: a row a (slot, key head), its page
+    list the slot's whole table, of which the ``lengths`` positions
+    already cached count, and its own new key as what it has attended
+    over already. q: (N, 1, H, Dh); k_row, v_row: (N, Hkv, Dh) in the
+    pool's dtype. ``attend_pages``: ``sparse_attend_for``'s. Returns ctx
+    (N, 1, H, Dh)."""
+    N, _, H, Dh = q.shape
+    Hkv = k_pool.shape[2]
+    R, P = N * Hkv, tables.shape[1]
+    q_rows = q.reshape(R, H // Hkv, Dh)
+    pages = jnp.broadcast_to(tables[:, None, :], (N, Hkv, P)).reshape(R, P)
+    ctx = attend_pages(
+        k_pool, v_pool, layer, q_rows, jnp.tile(jnp.arange(Hkv), N), pages,
+        jnp.repeat(lengths, Hkv),
+        *_own_token_init(q_rows, k_row.reshape(R, Dh), v_row.reshape(R, Dh)))
+    return ctx.reshape(N, 1, H, Dh)
+
+
 def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
                         table_row, offset, attend_pages):
     """One minicpm4 layer's attention for a prompt chunk of C tokens at
@@ -787,7 +895,6 @@ def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
     G = H // Hkv
     bps = table_row.shape[0]
     w, st = sp.windows_per_block, sp.kernel_stride
-    scale = 1.0 / math.sqrt(Dh)
     q_pos = offset + jnp.arange(C, dtype=jnp.int32)
     # the window that starts st tokens before the chunk ends inside it:
     # those tokens are the last rows of the page before (a whole page read)
@@ -796,44 +903,11 @@ def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
         jnp.concatenate([jnp.swapaxes(before, 0, 1)[bs - st:], k], 0), sp)
     qg = q.reshape(C, Hkv, G, Dh)
 
-    def own_keys():
-        """(m, l, acc) of every query over the chunk's keys up to itself."""
-        s = jnp.einsum("qhgd,khd->qhgk", qg, k,
-                       preferred_element_type=jnp.float32) * scale
-        causal = jnp.arange(C)[None, :] <= jnp.arange(C)[:, None]
-        s = jnp.where(causal[:, None, None, :], s, -1e30)
-        m = jnp.max(s, -1)
-        p = jnp.exp(s - m[..., None])
-        acc = jnp.einsum("qhgk,khd->qhgd", p.astype(v.dtype), v,
-                         preferred_element_type=jnp.float32)
-        return m, jnp.sum(p, -1), acc
+    own_keys = lambda: _own_chunk_init(qg, k, v)
 
     def dense():
-        n_past = min(sp.dense_len // bs, bps)
-        past = lambda pool: jnp.swapaxes(
-            pool[layer, table_row[:n_past]], 0, 1).reshape(Hkv, n_past * bs, Dh)
-        kp, vp = past(k_pool), past(v_pool)
-        m0, l0, acc0 = own_keys()
-        live = jnp.arange(n_past * bs) < offset
-
-        def tile(a):
-            qt, m0, l0, acc0 = a
-            s = jnp.einsum("qhgd,hkd->qhgk", qt, kp,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(live, s, -1e30)
-            m = jnp.maximum(m0, jnp.max(s, -1))
-            p = jnp.exp(s - m[..., None])
-            alpha = jnp.exp(m0 - m)
-            acc = alpha[..., None] * acc0 + jnp.einsum(
-                "qhgk,hkd->qhgd", p.astype(vp.dtype), vp,
-                preferred_element_type=jnp.float32)
-            return acc / (alpha * l0 + jnp.sum(p, -1))[..., None]
-
-        tq = min(C, 256)
-        out = jax.lax.map(tile, jax.tree.map(
-            lambda a: a.reshape(C // tq, tq, *a.shape[1:]),
-            (qg, m0, l0, acc0)))
-        return out.reshape(C, H, Dh).astype(q.dtype)
+        return chunk_attend_all(k_pool, v_pool, layer, q, k, v, table_row,
+                                offset, min(sp.dense_len // bs, bps))
 
     def sparse():
         J = bps * w
@@ -859,6 +933,18 @@ def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
     return ctx, kbar_new
 
 
+def write_chunk_pages(k_pool, v_pool, table_row, offset, kk, vv):
+    """A prompt chunk's keys and values (n, C, Hkv, Dh) of all paged
+    layers into the slot's pages from ``offset`` on, whole pages at a
+    time (``table_row`` padded past the slot's last page)."""
+    n, C, Hkv, Dh = kk.shape
+    bs = k_pool.shape[3]
+    pg = C // bs
+    ids = jax.lax.dynamic_slice(table_row, (offset // bs,), (pg,))
+    pages = lambda t: jnp.swapaxes(t.reshape(n, pg, bs, Hkv, Dh), 2, 3)
+    return k_pool.at[:, ids].set(pages(kk)), v_pool.at[:, ids].set(pages(vv))
+
+
 def write_chunk(sp, k_pool, v_pool, kc_pool, table_row, offset, kk, vv,
                 pooled):
     """A prompt chunk's keys, values (n, C, Hkv, Dh) and pooled keys (n, C
@@ -869,10 +955,8 @@ def write_chunk(sp, k_pool, v_pool, kc_pool, table_row, offset, kk, vv,
     n, C, Hkv, Dh = kk.shape
     bs, w = sp.block_size, sp.windows_per_block
     pg = C // bs
-    ids = jax.lax.dynamic_slice(table_row, (offset // bs,), (pg,))
-    pages = lambda t: jnp.swapaxes(t.reshape(n, pg, bs, Hkv, Dh), 2, 3)
-    k_pool = k_pool.at[:, ids].set(pages(kk))
-    v_pool = v_pool.at[:, ids].set(pages(vv))
+    k_pool, v_pool = write_chunk_pages(k_pool, v_pool, table_row, offset,
+                                       kk, vv)
     ids = jax.lax.dynamic_slice(
         jnp.concatenate([jnp.zeros((1,), table_row.dtype), table_row]),
         (offset // bs,), (pg + 1,))

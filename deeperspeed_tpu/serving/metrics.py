@@ -156,8 +156,13 @@ class ServingMetrics:
         self.gaps = 0
         self.chunk_gaps = 0
         # bytes of per-slot recurrent state beside the pages (the engine
-        # sets it once; 0 for a model that keeps pages only)
+        # sets it once; 0 for a model that keeps pages only), the bytes of
+        # it the plain decode steps read and wrote (every slot's rows,
+        # whatever the live count), and the slots whose rows a request's
+        # first prompt chunk entered as zeros
         self.state_bytes = 0
+        self.state_bytes_moved = 0
+        self.state_resets = 0
         self.prefills = 0
         self.preemptions = 0
         # prefix reuse / chunked prefill: admissions is every context
@@ -290,6 +295,7 @@ class ServingMetrics:
         self.total_generated += n_active
         self.gaps += n_active
         self.chunk_gaps += n_active if held_chunk else 0
+        self.state_bytes_moved += 2 * self.state_bytes
         self.queue_depth.append(queue_depth)
         self.occupancy.append(n_active / self.num_slots)
         self._end_t = now
@@ -299,6 +305,11 @@ class ServingMetrics:
             self._g_queue.set(queue_depth)
             self._g_active.set(n_active)
             self._g_occ.set(n_active / self.num_slots)
+
+    def record_state_reset(self) -> None:
+        """A request's first prompt chunk took its slot's state rows as
+        zeros (a row is cleared by whoever enters it)."""
+        self.state_resets += 1
 
     def record_kv_pages(self, live_pages: int, view_pages: int,
                         selected_pages: int = 0) -> None:
@@ -425,6 +436,10 @@ class ServingMetrics:
             "chunk_gap_share": (self.chunk_gaps / self.gaps
                                 if self.gaps else 0.0),
             "state_bytes": int(self.state_bytes),
+            "state_bytes_per_step": (self.state_bytes_moved
+                                     / self.decode_steps
+                                     if self.decode_steps else 0.0),
+            "state_resets": int(self.state_resets),
             "queue_depth_max": int(max(self.queue_depth, default=0)),
             "slo": self.slo_tracker.summary(),
             "prefix_reuse": {

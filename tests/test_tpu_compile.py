@@ -209,3 +209,104 @@ def test_sala_programs_move_neither_the_pool_nor_a_weight_stack(
     assert weights + caches < ask < weights + caches + 2 ** 30, (
         ask, weights, caches)
     assert ask < 12.5 * 2 ** 30
+
+
+# ------------------------------------------------------------------ #
+# Falcon-H1: the kernels and the two serving programs at the cell's size
+# ------------------------------------------------------------------ #
+
+
+def _falcon_h1():
+    from benchmark import manifest as mf
+    from benchmark.adapters import falcon_h1 as adapter
+    from deeperspeed_tpu.serving import ServingConfig
+
+    man = mf.Manifest()
+    cfg = adapter.model_config(man.config("falcon-h1-34b"))
+    scfg = ServingConfig.from_dict(
+        man.workload_file("falcon-h1-34b.serve-chat")["serving"])
+    return cfg, scfg
+
+
+def test_page_list_kernel_compiles_at_five_queries_a_key_head(one_chip):
+    """The chat cell's decode call: 48 slots x 4 key heads = 192 rows of 5
+    queries, each row's list the slot's whole table of 48 pages."""
+    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import paged_sparse_attn
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, G, P, Dh = 192, 5, 48, 128
+    pool = sds((6, 2305, 4, 64, Dh))
+    f32 = jnp.float32
+    compiled = paged_sparse_attn.lower(
+        pool, pool, sds((), jnp.int32), sds((R, G, Dh)), sds((R,), jnp.int32),
+        sds((R, P), jnp.int32), sds((R,), jnp.int32), sds((R, G), f32),
+        sds((R, G), f32), sds((R, G, Dh), f32)).compile()
+    assert "paged_sparse_attn" in compiled.as_text()
+
+
+def test_ssm_row_update_compiles_at_the_chat_cells_geometry(one_chip):
+    from deeperspeed_tpu.ops.pallas.ssm_row_update import ssm_row_update
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = ssm_row_update.lower(
+        sds((6, 48, 32, 128, 256)), sds((), jnp.int32), sds((48, 32)),
+        sds((48, 32, 128)), sds((48, 2, 256)), sds((48, 2, 256)),
+        sds((48,), jnp.bool_)).compile()
+    assert "ssm_row_update" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_falcon_h1_programs_move_neither_pool_rows_nor_a_weight_stack(
+        one_chip, as_if_on_tpu, program):
+    """The decode step and the prompt-chunk program of the chat cell (6
+    layers at the published widths, 48 slots, 147,520 tokens of pages,
+    1.13 GiB of state rows): the donated pools, state rows and convolution
+    tails are outputs in place, nothing copies a pool, the rows or a stack
+    of weights, and the compiler's ask stays inside the chip."""
+    from deeperspeed_tpu.models import mixers
+    from deeperspeed_tpu.serving.engine import (make_chunk_step,
+                                                make_decode_step)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg, scfg = _falcon_h1()
+    params = jax.tree.map(
+        lambda a: sds(a.shape),
+        jax.eval_shape(lambda: mixers.init_params(jax.random.PRNGKey(0), cfg)))
+    N, nb, bps = scfg.num_slots, scfg.num_blocks, scfg.blocks_per_slot
+    i32, m = jnp.int32, cfg.ssm
+    pool = sds((6, nb, 4, 64, 128))
+    state = {"ssm": sds((6, N, m.n_heads, m.head_dim, m.d_state), jnp.float32),
+             "conv": sds((6, N, m.d_conv - 1, m.conv_dim))}
+    if program == "decode":
+        compiled = make_decode_step(cfg, scfg).lower(
+            params, pool, pool, sds(*_idle_slots(N, bps)), None,
+            state).compile()
+    else:
+        compiled = make_chunk_step(cfg, scfg).lower(
+            params, pool, pool, None, state, sds((1, 512), i32),
+            sds((bps,), i32), sds((), i32), sds((), i32), sds((), i32)).compile()
+    text = compiled.as_text()
+    # the decode step reads pages and rows through kernels; the chunk
+    # attends over the slot's gathered pages and scans in XLA
+    assert ("paged_sparse_attn" in text) == (program == "decode")
+    assert ("ssm_row_update" in text) == (program == "decode")
+    assert count_alias_pairs(text) == 4        # k, v, state rows, tails
+    big = ("bf16[6,2305,4,64,128]", "f32[6,48,32,128,256]", "bf16[6,5120,",
+           "bf16[6,21504,", "bf16[6,4096,", "bf16[6,2560,")
+    moved = [ln.strip()[:140] for ln in text.splitlines()
+             if " copy(" in ln and ln.split(" = ")[1].startswith(big)]
+    assert moved == []
+    weights = sum(2 * math.prod(a.shape) for a in jax.tree.leaves(params))
+    assert weights == 2 * 5_254_594_112
+    caches = 2 * 2 * math.prod(pool.shape) + 4 * math.prod(
+        state["ssm"].shape) + 2 * math.prod(state["conv"].shape)
+    ask = extract_memory_analysis(compiled)["peak_bytes"]
+    assert weights + caches < ask < weights + caches + 2 ** 29, (
+        ask, weights, caches)
+    assert ask < 12.9 * 2 ** 30
