@@ -286,7 +286,7 @@ def phase_server(args, say):
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
         (eng.params, eng.kv.k, eng.kv.v,
-         idle_slots(scfg.num_slots, scfg.blocks_per_slot)))
+         idle_slots(scfg.num_slots, scfg.blocks_per_slot), eng._prev))
     ask = extract_memory_analysis(
         eng._decode_step.lower(*shapes).compile())["peak_bytes"]
     pool = eng.kv.k.nbytes + eng.kv.v.nbytes

@@ -184,7 +184,7 @@ def _serving_specs(notes: List[str]) -> List[ProgramSpec]:
         args=(eng.params, toks), hot=False)]
 
     dargs = (eng.params, eng.kv.k, eng.kv.v, jnp.asarray(
-        idle_slots(scfg.num_slots, scfg.blocks_per_slot)))
+        idle_slots(scfg.num_slots, scfg.blocks_per_slot)), eng._prev)
     specs.append(ProgramSpec(
         name="serving/decode_step", fn=eng._decode_step, args=dargs,
         hot=True))

@@ -12,8 +12,26 @@ The request lifecycle::
 One ``step()`` is: expire timeouts -> admit+prefill queued requests into
 free slots (length-bucketed, backpressure when the block pool is dry) ->
 grow block tables for the next write (preempting the youngest slot when
-the pool is exhausted) -> ONE jitted decode step over ALL slots -> append
-tokens, evict finished requests.
+the pool is exhausted) -> LAUNCH one jitted decode step over ALL slots ->
+COLLECT the step launched one call earlier: append its tokens, evict
+finished requests.
+
+The decode loop runs one step ahead. A step's tokens stay on the device
+and feed the next step there (``prev``), so step n+1 is packed from what
+the host knows without them (tables, lengths and sampling counts, all
+advanced at launch) and queued behind step n before step n is read: the
+host's work lies behind the device's. A caller sees a token, and a
+finish, in the ``step()`` that READS it. A request that ends by length
+is never launched beyond it (host arithmetic); one that ends on a token
+the host has not seen (EOS) gets one row too many, whose token is
+dropped (``decode_rows_discarded``) and whose write lands in a page the
+slot still holds. Whatever needs the host's truth about a slot first
+collects what is in flight (``_settle``: a cancel, a timeout, a
+preemption, the end of ``run()`` and ``drain()``), and while a step is
+in flight the host waits for nothing queued behind it: the first token
+of a prompt whose last chunk went out this step is picked first thing in
+the next. With speculation on a round needs the host's verdict, so its
+fallback rows launch and collect back to back: today's order.
 
 Static-shape discipline: the decode step closes over (num_slots,
 blocks_per_slot) and always runs the full slot array — idle slots carry
@@ -37,11 +55,13 @@ the reference fork's serving mode, kept as the compatibility path until
 pipelined KV caching lands.
 """
 
+import dataclasses
 import itertools
 import time
 import zlib
+from collections import deque
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Union
+from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -121,13 +141,18 @@ def request_sample_key(seed: int, count: int):
 
 # columns of the packed slot array past a slot's page table
 SLOT_SCALARS = 5
+# a slot's token in the packed array while the host has not read it: the
+# decode program takes the slot's entry of the last step's output instead
+TAKE_PREV = -1
 
 
 def pack_slots(tables, lengths, tokens, temps, seeds, counts) -> np.ndarray:
     """The six per-slot inputs of a decode step as ONE int32 array
     ``(num_slots, blocks_per_slot + 5)``, so that they cross to the device
     in one transfer: columns ``[0, bps)`` a slot's page table, then its
-    length, pending token, sampling seed, sampled-token count, and its
+    length, pending token (``TAKE_PREV``, a negative id, where the token
+    still lies on the device in the last step's output: the program then
+    reads it there), sampling seed, sampled-token count, and its
     temperature as the float32's BITS (it arrives bit for bit, and
     ``temps[i] <= 0`` still means greedy). A fresh array every call: a
     placement may still be reading the last one (on the CPU backend it
@@ -199,11 +224,16 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
 def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     """Build the jitted all-slots decode step.
 
-    decode_step(params, k_pool, v_pool, slots, kc_pool, state) ->
+    decode_step(params, k_pool, v_pool, slots, prev, kc_pool, state) ->
     (next_tokens (N,), k_pool', v_pool', kc_pool', state'): one shape for
     every model. ``slots`` is the step's six per-slot inputs as ONE int32
     array (``pack_slots`` on the host, one transfer; the program's first
-    line takes it apart again, ``unpack_slots``). ``kc_pool`` and
+    line takes it apart again, ``unpack_slots``). ``prev`` (N,) int32 is
+    the last step's ``next_tokens`` as the device handed them back (never
+    donated: the host may not have read them yet; zeros before any step
+    has run): a slot whose packed token is ``TAKE_PREV`` decodes its
+    entry of ``prev``, so a step can be launched before the host has the
+    last one's tokens. ``kc_pool`` and
     ``state`` (``PagedKVCache.kc``, ``.state``) are None, in and out, for
     a stack of attention layers; a model of mixed layers
     (``cfg.mixer_types``) passes its pooled keys and its state rows,
@@ -232,11 +262,12 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     kinds = set(cfg.layer_kinds)
     slopes = mixers.lightning_slopes(cfg.n_head)
 
-    @partial(jax.jit, donate_argnums=(1, 2, 4, 5))
-    def ds_decode_step(params, k_pool, v_pool, slots, kc_pool=None,
+    @partial(jax.jit, donate_argnums=(1, 2, 5, 6))
+    def ds_decode_step(params, k_pool, v_pool, slots, prev, kc_pool=None,
                        state=None):
         tables, lengths, tokens, temps, seeds, counts = unpack_slots(
             slots, scfg.blocks_per_slot)
+        tokens = jnp.where(tokens == TAKE_PREV, prev, tokens)
         N = tokens.shape[0]
         positions = lengths[:, None]                        # (N, 1)
         with jax.named_scope("ds.embed"):
@@ -559,6 +590,10 @@ class _ServingBase:
         when the rid is unknown or already finished. The router's
         deadline enforcement lands here."""
         req = self._requests.get(rid)
+        if req is not None and req.in_flight:
+            # tokens of its lie on the device unread: they count as they
+            # would have, and one of them may end the request by itself
+            self._settle()
         if req is None or req.state == "finished":
             return False
         self.sched.finish(req, reason)
@@ -574,14 +609,14 @@ class _ServingBase:
             now = self.clock()
             with trace_span("serving/schedule", lane="serving",
                             what="expire"):
-                expired = self.sched.expire_timeouts(now)
+                expired = self.sched.expire_timeouts(now, self._settle)
             for req in expired:
                 self.metrics.record_finish(req, now)
             self._prefill_phase()
             with trace_span("serving/schedule", lane="serving",
                             what="capacity"):
                 preempted = self.sched.ensure_decode_capacity(
-                    self._decode_window())
+                    self._decode_window(), self._settle)
             for _ in preempted:
                 self.metrics.record_preemption()
             trace_counter("serving/load", {
@@ -604,6 +639,7 @@ class _ServingBase:
             steps += 1
             if max_steps is not None and steps >= max_steps:
                 break
+        self._settle()
         return {r.rid: r.output for r in self.sched.finished}
 
     def drain(self, max_steps: Optional[int] = None) -> List[str]:
@@ -618,6 +654,7 @@ class _ServingBase:
             steps += 1
             if max_steps is not None and steps >= max_steps:
                 break
+        self._settle()
         return [r.rid for r in self.sched.queue]
 
     # -- helpers ------------------------------------------------------ #
@@ -649,6 +686,12 @@ class _ServingBase:
         (chunk-prefilling slots don't, until their final chunk lands)."""
         return self.sched.num_active > 0
 
+    def _settle(self) -> None:
+        """Bring the host up to date with the device: read and emit
+        whatever a subclass has launched and not yet read (the engine's
+        decode step ahead). Nothing to do for a loop that reads every
+        step before it returns."""
+
     def _decode_window(self) -> int:
         """Tokens of KV headroom each active slot needs for the next
         decode phase (1 for plain decode; draft_k + 1 with speculation
@@ -666,6 +709,16 @@ class _ServingBase:
             self.metrics.record_prefill(now, ttft)
         if self.sched.check_finished(req, now):
             self.metrics.record_finish(req, now)
+
+
+@dataclasses.dataclass
+class _Launched:
+    """A decode step launched whose tokens the host has not read."""
+    nxt: Any                            # (num_slots,) tokens, on the device
+    lanes: List[Tuple[int, Request]]    # the (slot, request) rows it ran
+    held_chunk: bool    # a prompt chunk ran before it in its step()
+    ahead: bool         # the step before it was unread at its launch
+    discarded: int = 0  # rows whose token was dropped when it was read
 
 
 class ServingEngine(_ServingBase):
@@ -712,6 +765,13 @@ class ServingEngine(_ServingBase):
         super().__init__(scfg, Scheduler(scfg, self.kv.allocator, clock),
                          clock, monitor, monitor_config)
         self._decode_step = make_decode_step(cfg, scfg, mesh)
+        # the last decode step's tokens as the device handed them back,
+        # the next step's ``prev`` (zeros until a step has run), and the
+        # steps launched and not yet read, oldest first: one between
+        # step() calls, two between a launch and the collect after it
+        self._prev = jnp.asarray(self._place_slot_array(
+            np.zeros(scfg.num_slots, np.int32)))
+        self._inflight: Deque[_Launched] = deque()
 
         # retraces once per prefill bucket (toks.shape[1] varies)
         def ds_prefill(params, toks):
@@ -883,6 +943,7 @@ class ServingEngine(_ServingBase):
         self._prefill_spent = 0
         self._chunk_ran = False
         self._sweep_chunk_states()
+        self._pick_deferred()
         for slot in sorted(self._chunking):
             if not self._budget_ok():
                 break
@@ -892,10 +953,6 @@ class ServingEngine(_ServingBase):
         while self._budget_ok() and \
                 (adm := self._pop_admissible()) is not None:
             self._admit_one(*adm)
-
-    def _has_decodable(self) -> bool:
-        return any(req is not None and s not in self._chunking
-                   for s, req in enumerate(self.sched.slots))
 
     def _sweep_chunk_states(self) -> None:
         """Drop chunk states whose request no longer holds the slot
@@ -961,10 +1018,44 @@ class ServingEngine(_ServingBase):
     def _pump_slot(self, slot: int, state: dict) -> None:
         """Forward prompt chunks for one slot while the step budget
         allows, each by the route its prompt entered on
-        (``state["forward"]``: staged or in place); the final chunk emits
-        the request's first token."""
+        (``state["forward"]``: staged or in place); the final chunk
+        yields the request's first token (``_end_prompt``)."""
         while state["next"] < state["n"] and self._budget_ok():
             state["forward"](slot, state)
+
+    def _end_prompt(self, slot: int, state: dict, logits) -> None:
+        """A prompt's last chunk is dispatched; ``logits`` (V,) are those
+        of its last position. Picking the first token waits that chunk
+        out. With a decode step in flight the wait would hold back tokens
+        already computed (the step is queued before the chunk, and its
+        tokens would be read a chunk late on top of whatever chunk ran
+        before IT: two chunks in one token gap), so the pick is then left
+        to the next ``step()``, which makes it before anything else
+        (``_pick_deferred``). With nothing in flight it is made at once."""
+        if self._inflight:
+            state["logits"] = logits
+        else:
+            self._pick_first(slot, state, logits)
+
+    def _pick_first(self, slot: int, state: dict, logits) -> None:
+        """The request's first token off its prompt's last logits; the
+        slot joins the decode steps."""
+        req = state["req"]
+        with trace_span("serving/prefill/pick", lane="serving"):
+            req.generated.append(self._pick_token(logits, req))
+        del self._chunking[slot]
+        self._record_emitted(req, prefill=True)
+
+    def _pick_deferred(self) -> None:
+        """The first tokens the last step left unpicked (``_end_prompt``).
+        Their chunks were queued before the decode step now in flight, so
+        this waits for nothing that step's tokens wait for."""
+        for slot, state in sorted(self._chunking.items()):
+            if "logits" in state:
+                with trace_span("serving/prefill", lane="serving",
+                                rid=state["req"].rid, slot=slot,
+                                ctx_len=state["L"], bucket=state["chunk"]):
+                    self._pick_first(slot, state, state.pop("logits"))
 
     def _forward_staged(self, slot: int, state: dict) -> None:
         """One chunk of a staged suffix through ``ds_suffix_prefill``; the
@@ -996,10 +1087,7 @@ class ServingEngine(_ServingBase):
                 with trace_span("serving/prefill/scatter",
                                 lane="serving"):
                     self._finish_staged(req, state)
-                with trace_span("serving/prefill/pick",
-                                lane="serving"):
-                    tok = self._pick_token(logits[0, hi - lo - 1], req)
-                req.generated.append(tok)
+                self._end_prompt(slot, state, logits[0, hi - lo - 1])
             tel = self.telemetry
             if tel is not None:
                 if tel.cost_index is not None:
@@ -1017,12 +1105,10 @@ class ServingEngine(_ServingBase):
         self.metrics.record_prefill_chunk(hi - lo)
         state["next"] += 1
         if final:
-            del self._chunking[slot]
             logger.debug(
                 "serving: admitted %s to slot %d (ctx=%d matched=%d "
                 "chunks=%d)", req.rid, slot, state["L"], state["m"],
                 state["n"])
-            self._record_emitted(req, prefill=True)
 
     def chunk_pages_read(self, offset: int) -> int:
         """Pool pages the sparse layers' selections name in one prompt
@@ -1063,8 +1149,7 @@ class ServingEngine(_ServingBase):
                 logits, kv.k, kv.v, kv.kc, kv.state = \
                     self._chunk_step(*_pargs)
             if final:
-                with trace_span("serving/prefill/pick", lane="serving"):
-                    req.generated.append(self._pick_token(logits, req))
+                self._end_prompt(slot, state, logits)
         self._prefill_spent += hi - lo
         self._chunk_ran = True
         self.metrics.record_prefill_chunk(hi - lo)
@@ -1073,9 +1158,7 @@ class ServingEngine(_ServingBase):
             self.metrics.record_state_reset()
         state["next"] += 1
         if final:
-            del self._chunking[slot]
             self.metrics.record_reuse(0, state["L"])
-            self._record_emitted(req, prefill=True)
 
     def _finish_staged(self, req: Request, state: dict) -> None:
         """Scatter the staged suffix into the slot's private blocks.
@@ -1149,20 +1232,34 @@ class ServingEngine(_ServingBase):
         self._record_emitted(req, prefill=True)
 
     def _active_decodable(self):
-        """(slot, request) pairs with a pending token this step.
+        """(slot, request) pairs that get a row in the step launched now.
         Chunk-prefilling slots have no pending token yet: their lane
         stays idle (all-null table, length 0), so the decode programs'
         shapes — and their single compiles — are untouched by
-        chunking."""
+        chunking. A request whose remaining tokens are all in flight
+        gets none either: it ends by length when they are read."""
         return [(s, req) for s, req in enumerate(self.sched.slots)
-                if req is not None and s not in self._chunking]
+                if req is not None and s not in self._chunking
+                and req.remaining > req.in_flight]
 
-    def _dispatch_plain(self, active) -> np.ndarray:
-        """Run the plain decode program with ``active`` lanes populated
-        (the rest idle); returns the host-synced next-token array (N,).
-        The caller owns the surrounding span/metrics — this is both the
-        whole decode phase (speculation off) and the fallback program
-        for non-speculating slots (speculation on)."""
+    def has_work(self) -> bool:
+        return bool(self._inflight) or self.sched.has_work()
+
+    def _has_decodable(self) -> bool:
+        return bool(self._inflight) or bool(self._active_decodable())
+
+    def _launch(self, lanes) -> None:
+        """Pack and dispatch the plain decode program with ``lanes``
+        populated (the rest idle), from what the host knows WITHOUT the
+        tokens in flight: a lane whose last token is still on the device
+        takes it there (``TAKE_PREV``), and its sampled-token count
+        includes the rows in flight, so ``request_sample_key`` sees the
+        pairs it would see reading every step. Lengths advance here: the
+        row a lane writes counts as cached from now on. The step joins
+        ``_inflight`` unread; ``_collect`` reads it. This is both the
+        decode phase's launch (speculation off) and, collected at once,
+        the fallback program for non-speculating slots (speculation
+        on)."""
         with trace_span("serving/decode/pack", lane="serving",
                         placements="1"):
             N = self.scfg.num_slots
@@ -1174,7 +1271,7 @@ class ServingEngine(_ServingBase):
             counts = np.zeros(N, np.int32)
             live_pages = selected_pages = 0
             sp = self.cfg.sparse
-            for s, req in active:
+            for s, req in lanes:
                 tables[s] = self.sched.slot_table_row(s)
                 lengths[s] = req.cached_len
                 # the pages that hold a live position of a live slot,
@@ -1186,69 +1283,109 @@ class ServingEngine(_ServingBase):
                     # what one selection of a sparse layer names of them
                     selected_pages += (live if req.cached_len + 1
                                        <= sp.dense_len else sp.topk)
-                tokens[s] = req.pending_token
+                tokens[s] = (TAKE_PREV if req.in_flight
+                             else req.pending_token)
                 temps[s] = req.temperature
                 seeds[s] = req.seed
-                counts[s] = len(req.generated)
+                counts[s] = len(req.generated) + req.in_flight
+                req.cached_len += 1
+                req.in_flight += 1
             # the step's ONE host-to-device placement
             slots = pack_slots(tables, lengths, tokens, temps, seeds,
                                counts)
             slots = (self._place_slot_array(slots)
                      if self.mesh is not None else jnp.asarray(slots))
-            _dargs = (self.params, self.kv.k, self.kv.v, slots,
+            _dargs = (self.params, self.kv.k, self.kv.v, slots, self._prev,
                       self.kv.kc, self.kv.state)
+        ahead = bool(self._inflight)
         self.metrics.record_decode_placements(1)
         self.metrics.record_kv_pages(live_pages, tables.size,
                                      selected_pages)
         with trace_span("serving/decode/dispatch", lane="serving",
                         live_pages=live_pages, view_pages=tables.size,
-                        selected_pages=selected_pages):
+                        selected_pages=selected_pages,
+                        ahead="1" if ahead else "0"):
             nxt, self.kv.k, self.kv.v, self.kv.kc, self.kv.state = \
                 self._decode_step(*_dargs)
+            # the read-back starts when the step ends, not when the host
+            # comes to ask for it
+            nxt.copy_to_host_async()
+        # under a mesh: laid out as the first step's zeros were, so the
+        # jit's cache keeps one entry
+        self._prev = self._place_slot_array(nxt)
+        self._inflight.append(_Launched(nxt, lanes, self._chunk_ran, ahead))
+        tel = self.telemetry
+        if tel is not None:
+            if tel.cost_index is not None:
+                # the AOT re-lower never touches the decode jit's cache
+                # (one-compile decode stays one-compile)
+                tel.cost_index.observe("serving/decode_step",
+                                       self._decode_step, _dargs)
+            tel.watchdog.observe("serving/decode_step", step=self._step_i)
+
+    def _collect(self) -> _Launched:
+        """Read the oldest launched step's tokens and emit them: append,
+        finish. A lane whose request no longer holds its slot (it ended
+        on the token before this one) ran a row nobody asked for: its
+        token is dropped; what it wrote went into a page the slot still
+        held, and its state row is the next occupant's to clear."""
+        step = self._inflight.popleft()
         with trace_span("serving/decode/wait", lane="serving"):
-            nxt = np.asarray(nxt)               # device sync
-        self._last_dargs = _dargs
-        return nxt
+            nxt = np.asarray(step.nxt)          # device sync
+        with trace_span("serving/decode/emit", lane="serving"):
+            for s, req in step.lanes:
+                req.in_flight -= 1
+                if self.sched.slots[s] is not req:
+                    step.discarded += 1
+                    continue
+                req.generated.append(int(nxt[s]))
+                self._record_emitted(req, prefill=False)
+        return step
+
+    def _settle(self, keep: int = 0) -> int:
+        """Collect the steps in flight, oldest first, until ``keep`` are
+        left, and count each as the decode step it was; returns how many
+        were read."""
+        read = 0
+        while len(self._inflight) > keep:
+            step = self._collect()
+            read += 1
+            self.metrics.record_decode_step(
+                len(step.lanes), len(self.sched.queue), self.clock(),
+                held_chunk=step.held_chunk, ahead=step.ahead,
+                discarded=step.discarded)
+        return read
 
     def _decode_all(self) -> None:
         """One decode phase over the full slot array: the speculative
-        round when enabled, else one jitted plain decode step."""
+        round when enabled, else the plain decode step one step ahead:
+        step n+1 is launched, THEN step n is collected (with nothing to
+        launch, whatever is in flight)."""
         if self._spec is not None:
             self._spec.decode_round()
             return
-        active = self._active_decodable()
+        lanes = self._active_decodable()
+        # the span's riders: the step launched, else the one read
+        riders = lanes or self._inflight[0].lanes
         with trace_span("serving/decode", lane="serving",
-                        n_active=len(active),
-                        rids=RID_SEP.join(r.rid for _, r in active)) as _sp:
-            nxt = self._dispatch_plain(active)
+                        n_active=len(riders),
+                        rids=RID_SEP.join(r.rid for _, r in riders)) as _sp:
+            if lanes:
+                self._launch(lanes)
+            read = self._settle(keep=1 if lanes else 0)
             tel = self.telemetry
             if tel is not None:
-                if tel.cost_index is not None:
-                    # the sync above already happened, so the span's
-                    # length so far is real; the AOT re-lower never
-                    # touches the decode jit's cache (one-compile decode
-                    # stays one-compile)
-                    _wall = _sp.elapsed_s()
-                    tel.cost_index.observe("serving/decode_step",
-                                           self._decode_step,
-                                           self._last_dargs)
+                if tel.cost_index is not None and read:
+                    # a step was read inside the span: with the loop one
+                    # step ahead the span is as long as the device took
+                    # for it, the launch of the next included
                     _stats = tel.cost_index.note_step(
-                        "serving/decode_step", _wall)
+                        "serving/decode_step", _sp.elapsed_s())
                     if _stats is not None:
                         _sp.note(mfu=round(_stats["mfu"], 6),
                                  verdict=_stats["verdict"])
                 if tel.memwatch is not None:
                     tel.memwatch.annotate(_sp, "decode")
-                tel.watchdog.observe("serving/decode_step",
-                                     step=self._step_i)
-            self.metrics.record_decode_step(
-                len(active), len(self.sched.queue), self.clock(),
-                held_chunk=self._chunk_ran)
-            with trace_span("serving/decode/emit", lane="serving"):
-                for s, req in active:
-                    req.cached_len += 1
-                    req.generated.append(int(nxt[s]))
-                    self._record_emitted(req, prefill=False)
 
 
 # ------------------------------------------------------------------ #

@@ -151,6 +151,12 @@ class ServingMetrics:
         # slot inputs, and the dispatches they were made for
         self.decode_placements = 0
         self.decode_dispatches = 0
+        # plain decode steps launched while the step before was still
+        # unread (the loop one step ahead), and rows a step ran for a slot
+        # whose request had ended before its token was read (EOS is seen
+        # a step late): their tokens were dropped
+        self.decode_steps_ahead = 0
+        self.decode_rows_discarded = 0
         # tokens decoded, and those of them decoded in a step that first
         # ran a prompt chunk (their gap held the chunk)
         self.gaps = 0
@@ -286,22 +292,30 @@ class ServingMetrics:
                 "Staged prompt-chunk forwards.").inc()
 
     def record_decode_step(self, n_active: int, queue_depth: int,
-                           now: float, held_chunk: bool = False) -> None:
-        """``held_chunk``: a prompt chunk ran in this step before the
-        decode, so each of the step's tokens came a chunk later."""
+                           now: float, held_chunk: bool = False,
+                           ahead: bool = False, discarded: int = 0) -> None:
+        """One decode step over ``n_active`` rows, recorded when its
+        tokens are read. ``held_chunk``: a prompt chunk ran before it in
+        the ``step()`` that launched it, so each of its tokens came a
+        chunk later. ``ahead``: it was launched while the step before it
+        was still unread. ``discarded``: of its rows, those whose token
+        was dropped (the request had ended meanwhile)."""
         if self._start_t is None:
             self._start_t = now
+        emitted = n_active - discarded
         self.decode_steps += 1
-        self.total_generated += n_active
-        self.gaps += n_active
-        self.chunk_gaps += n_active if held_chunk else 0
+        self.decode_steps_ahead += bool(ahead)
+        self.decode_rows_discarded += discarded
+        self.total_generated += emitted
+        self.gaps += emitted
+        self.chunk_gaps += emitted if held_chunk else 0
         self.state_bytes_moved += 2 * self.state_bytes
         self.queue_depth.append(queue_depth)
         self.occupancy.append(n_active / self.num_slots)
         self._end_t = now
         if self.registry is not None:
             self._c_decode.inc()
-            self._c_tokens.inc(n_active)
+            self._c_tokens.inc(emitted)
             self._g_queue.set(queue_depth)
             self._g_active.set(n_active)
             self._g_occ.set(n_active / self.num_slots)
@@ -433,6 +447,10 @@ class ServingMetrics:
             "decode_placements_per_step": (
                 self.decode_placements / self.decode_dispatches
                 if self.decode_dispatches else 0.0),
+            "decode_ahead_share": (self.decode_steps_ahead
+                                   / self.decode_steps
+                                   if self.decode_steps else 0.0),
+            "decode_rows_discarded": int(self.decode_rows_discarded),
             "chunk_gap_share": (self.chunk_gaps / self.gaps
                                 if self.gaps else 0.0),
             "state_bytes": int(self.state_bytes),

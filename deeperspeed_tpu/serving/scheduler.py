@@ -64,7 +64,12 @@ class Request:
     state: str = QUEUED
     generated: List[int] = dataclasses.field(default_factory=list)
     slot: int = -1
-    cached_len: int = 0           # tokens whose KV is written to the pool
+    # tokens whose KV is written to the pool, or will be by a decode step
+    # already launched (it advances at launch, not at read)
+    cached_len: int = 0
+    # decode rows launched for it whose tokens the host has not read yet
+    # (the engine's loop runs one step ahead)
+    in_flight: int = 0
     admissions: int = 0           # 1 + number of preemption re-admissions
     # cost-ledger accounting: integral of (blocks held × seconds held),
     # accrued at every block-count change point while the request holds
@@ -253,18 +258,25 @@ class Scheduler:
     # decode-time capacity
     # ---------------------------------------------------------------- #
 
-    def ensure_decode_capacity(self, tokens: int = 1) -> List[Request]:
+    def ensure_decode_capacity(self, tokens: int = 1,
+                               settle: Optional[Callable[[], None]] = None
+                               ) -> List[Request]:
         """Grow each active slot's block list to cover its next
         ``tokens`` writes (1 for plain decode; a speculative round asks
         for draft_k + 1, capped at the slot's table capacity); preempt
         most-recently-admitted slots when the pool runs dry. Returns the
-        preempted requests (already requeued)."""
+        preempted requests (already requeued). A request whose remaining
+        tokens are all in flight gets no further row and needs none.
+        ``settle``, if given, is called once before the first
+        preemption: an engine with a step's tokens unread appends them
+        first, so a preempted request is requeued with ``generated``
+        whole, and whatever that finishes gives its blocks back."""
         cap = self.scfg.blocks_per_slot * self.scfg.block_size
         preempted: List[Request] = []
         for slot in range(self.scfg.num_slots):
             while True:
                 req = self.slots[slot]
-                if req is None:
+                if req is None or req.remaining <= req.in_flight:
                     break
                 need = blocks_needed(min(req.cached_len + tokens, cap),
                                      self.scfg.block_size)
@@ -276,6 +288,10 @@ class Scheduler:
                     self._accrue_kv(slot)
                     self.slot_blocks[slot].extend(extra)
                     break
+                if settle is not None:
+                    settle()
+                    settle = None
+                    continue
                 victim = self._preempt_victim()
                 preempted.append(self._preempt(victim))
                 # if we preempted THIS slot, the inner while re-checks and
@@ -361,8 +377,14 @@ class Scheduler:
             return True
         return False
 
-    def expire_timeouts(self, now: float) -> List[Request]:
+    def expire_timeouts(self, now: float,
+                        settle: Optional[Callable[[], None]] = None
+                        ) -> List[Request]:
         """Evict requests that made no progress for request_timeout_s.
+        ``settle``, if given, is called before a request with rows in
+        flight is evicted: the engine appends their tokens first (the
+        verdict stands: it read the clocks of the last tokens the host
+        saw), and one of them may have ended the request by itself.
 
         Progress-based, not age-based: an ACTIVE request emitting tokens
         at a steady clip never expires here no matter how long it runs —
@@ -378,6 +400,9 @@ class Scheduler:
             if now - (r.last_token_t if r.last_token_t is not None
                       else r.arrival_t) >= timeout
         ]
+        if settle is not None and any(r.in_flight for r in expired):
+            settle()
+            expired = [r for r in expired if r.state != FINISHED]
         for r in expired:
             self.finish(r, FINISH_TIMEOUT, now)
         return expired

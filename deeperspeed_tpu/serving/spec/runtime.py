@@ -338,7 +338,7 @@ class SpecRuntime:
                 spec_lanes.append((s, req))
             else:
                 fallback.append((s, req))
-        drafts = n_acc = bonus = nxt = None
+        drafts = n_acc = bonus = None
         draft_s = verify_s = 0.0
         with trace_span("serving/decode", lane="serving",
                         n_active=len(active),
@@ -357,7 +357,11 @@ class SpecRuntime:
                               n_active=len(spec_lanes), k=K,
                               dur_us=round(verify_s * 1e6, 1))
             if fallback:
-                nxt = eng._dispatch_plain(fallback)
+                # the round needs these tokens before it can go on: the
+                # plain program launched and collected back to back (the
+                # collect appends them and finishes what they end)
+                eng._launch(fallback)
+                eng._collect()
             tel = eng.telemetry
             if tel is not None and tel.memwatch is not None:
                 tel.memwatch.annotate(_sp, "decode")
@@ -367,9 +371,6 @@ class SpecRuntime:
                 tel.watchdog.observe("serving/draft_step",
                                      step=eng._step_i)
                 tel.watchdog.observe("serving/verify_step",
-                                     step=eng._step_i)
-            if fallback:
-                tel.watchdog.observe("serving/decode_step",
                                      step=eng._step_i)
         eng.metrics.record_decode_step(len(active),
                                        len(eng.sched.queue), eng.clock())
@@ -392,10 +393,6 @@ class SpecRuntime:
             accepted += acc
             trace_instant("spec/accept", lane="serving", rid=req.rid,
                           accepted=acc, k=K, emitted=len(toks))
-            eng._record_emitted(req, prefill=False)
-        for s, req in fallback:
-            req.cached_len += 1
-            req.generated.append(int(nxt[s]))
             eng._record_emitted(req, prefill=False)
         eng.metrics.record_spec_round(
             n_spec=len(spec_lanes), n_fallback=len(fallback),

@@ -367,7 +367,7 @@ def test_thread_fleet_kill_retry_token_identity(model):
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 97, rng.integers(4, 12)).tolist()
                for _ in range(6)]
-    news = [40] * 6
+    news = [100] * 6
     temps = [0.0, 0.7] * 3
     rids = [f"q{i}" for i in range(6)]
     ref = _reference_outputs(factory, prompts, news, temps, rids)
@@ -379,7 +379,12 @@ def test_thread_fleet_kill_retry_token_identity(model):
             router.submit(p, max_new_tokens=n, temperature=t,
                           request_id=rid)
         router.step()                       # dispatch
-        time.sleep(0.05)                    # a few decode steps land
+        # a few decode steps land (a toy replica's whole share takes
+        # tens of milliseconds: watch its progress, not the clock)
+        seen = fleet[0].progress
+        deadline = time.monotonic() + 30.0
+        while fleet[0].progress < seen + 9 and time.monotonic() < deadline:
+            time.sleep(0.0005)
         fleet[0].kill()
         outcomes = router.run_until_idle(timeout_s=120)
         assert all(v in ("length", "eos") for v in outcomes.values()), \

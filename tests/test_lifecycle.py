@@ -463,10 +463,21 @@ def _request_trace(n, seed=0):
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(1, 97, int(rng.integers(4, 12))).tolist()
                for _ in range(n)]
-    news = [40] * n
+    news = [100] * n
     temps = [0.0, 0.7] * (n // 2) + [0.0] * (n % 2)
     rids = [f"v{i}" for i in range(n)]
     return prompts, news, temps, rids
+
+
+def _kill_mid_decode(replica):
+    """Kill once a few of its decode steps have landed. A toy replica's
+    whole share takes tens of milliseconds, so its progress is watched,
+    not the clock."""
+    seen = replica.progress
+    deadline = time.monotonic() + 30.0
+    while replica.progress < seen + 9 and time.monotonic() < deadline:
+        time.sleep(0.0005)
+    replica.kill()
 
 
 def test_mixed_version_fleet_failover_stays_pinned():
@@ -488,8 +499,7 @@ def test_mixed_version_fleet_failover_stays_pinned():
         router.step()                       # dispatch + pin
         pinned_v1 = [rid for rid in rids
                      if router.result(rid).version == 1]
-        time.sleep(0.05)                    # a few decode steps land
-        fleet[0].kill()                     # one v1 replica dies
+        _kill_mid_decode(fleet[0])          # one v1 replica dies
         outcomes = router.run_until_idle(timeout_s=120)
         assert sorted(outcomes) == sorted(rids)
         assert all(v in ("length", "eos") for v in outcomes.values()), \
@@ -528,8 +538,7 @@ def test_version_starvation_repins_with_full_regeneration():
         pinned_v1 = [rid for rid in rids
                      if router.result(rid).version == 1]
         assert pinned_v1                    # someone is on v1
-        time.sleep(0.05)                    # mid-decode
-        fleet[0].kill()                     # v1's ONLY replica dies
+        _kill_mid_decode(fleet[0])          # v1's ONLY replica dies
         outcomes = router.run_until_idle(timeout_s=120)
         assert sorted(outcomes) == sorted(rids)
         assert all(v in ("length", "eos") for v in outcomes.values()), \

@@ -81,27 +81,33 @@ def _six_array_engine(cfg, params, monkeypatch):
     placed by hand here."""
     eng = ServingEngine(cfg, params, SCFG)
 
-    def dispatch(active):
+    def launch(lanes):
         N = SCFG.num_slots
         tables = np.zeros((N, SCFG.blocks_per_slot), np.int32)
         lengths, tokens, seeds, counts = (np.zeros(N, np.int32)
                                           for _ in range(4))
         temps = np.zeros(N, np.float32)
-        for s, req in active:
+        for s, req in lanes:
             tables[s] = eng.sched.slot_table_row(s)
-            lengths[s], tokens[s] = req.cached_len, req.pending_token
+            lengths[s] = req.cached_len
+            tokens[s] = (engine_mod.TAKE_PREV if req.in_flight
+                         else req.pending_token)
             temps[s], seeds[s] = req.temperature, req.seed
-            counts[s] = len(req.generated)
+            counts[s] = len(req.generated) + req.in_flight
+            req.cached_len += 1
+            req.in_flight += 1
         six = tuple(map(jnp.asarray,
                         (tables, lengths, tokens, temps, seeds, counts)))
         with monkeypatch.context() as m:
             # the program's first line hands the six on as they came
             m.setattr(engine_mod, "unpack_slots", lambda six, bps: six)
             nxt, eng.kv.k, eng.kv.v, _, _ = eng._decode_step(
-                eng.params, eng.kv.k, eng.kv.v, six)
-        return np.asarray(nxt)
+                eng.params, eng.kv.k, eng.kv.v, six, eng._prev)
+        eng._inflight.append(engine_mod._Launched(
+            nxt, lanes, False, bool(eng._inflight)))
+        eng._prev = nxt
 
-    eng._dispatch_plain = dispatch
+    eng._launch = launch
     return eng
 
 
@@ -130,7 +136,7 @@ def test_served_tokens_are_the_six_array_programs(temperature, monkeypatch):
 
 
 def _count_placements(eng, monkeypatch):
-    """Count what ``_dispatch_plain`` places on the device by itself: calls
+    """Count what ``_launch`` places on the device by itself: calls
     of ``jnp.asarray`` and ``jax.device_put`` on a host array, by step."""
     calls, inside = [], []
 
@@ -143,17 +149,17 @@ def _count_placements(eng, monkeypatch):
 
     monkeypatch.setattr(jnp, "asarray", counting(jnp.asarray))
     monkeypatch.setattr(jax, "device_put", counting(jax.device_put))
-    plain = eng._dispatch_plain
+    plain = eng._launch
 
-    def dispatch(active):
+    def launch(lanes):
         calls.append(0)
         inside.append(True)
         try:
-            return plain(active)
+            return plain(lanes)
         finally:
             inside.pop()
 
-    eng._dispatch_plain = dispatch
+    eng._launch = launch
     return calls
 
 
@@ -170,10 +176,9 @@ def test_a_decode_step_makes_exactly_one_placement(mesh_shape, monkeypatch):
     assert len(calls) == eng.metrics.decode_steps >= 7
     assert set(calls) == {1}
     assert eng.metrics.summary()["decode_placements_per_step"] == 1.0
-    # and nothing is left to cross inside the call: every argument the
-    # program got was on the device already
-    assert all(isinstance(a, jax.Array)
-               for a in jax.tree.leaves(eng._last_dargs))
+    # and nothing is left to cross inside the call: the last step's
+    # tokens go in as the device handed them back
+    assert isinstance(eng._prev, jax.Array)
     assert eng.decode_compile_count == 1
 
 
@@ -235,11 +240,13 @@ def test_neox_decode_step_still_aliases_both_pools_and_never_sorts():
     pool = jnp.zeros((cfg.n_layer, SCFG.num_blocks, SCFG.block_size,
                       cfg.kv_heads, cfg.head_dim), cfg.dtype)
     lowered = make_decode_step(cfg, SCFG).lower(params, pool, pool,
-                                                idle_slots(N, bps))
+                                                idle_slots(N, bps),
+                                                np.zeros(N, np.int32))
     text = lowered.as_text()
     assert "ds_decode_step" in text and "stablehlo.sort" not in text
-    # ONE slot argument beside the parameters and the two pools
-    n_args = len(jax.tree.leaves(params)) + 3
+    # ONE slot argument, and the last step's tokens, beside the
+    # parameters and the two pools
+    n_args = len(jax.tree.leaves(params)) + 4
     assert len(jax.tree.leaves(lowered.args_info)) == n_args
     assert f"tensor<{N}x{bps + 5}xi32>" in text
     assert count_alias_pairs(lowered.compile().as_text()) == 2

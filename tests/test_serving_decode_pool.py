@@ -147,8 +147,10 @@ def _step_args(cfg, params, lengths, seed=3):
 
 def _packed(args):
     """The decode step's own arguments from the nine of ``_step_args``:
-    the six per-slot arrays as the one packed array."""
-    return args[:3] + (jnp.asarray(pack_slots(*args[3:])),)
+    the six per-slot arrays as the one packed array, and no token left
+    on the device (``prev`` zeros)."""
+    return args[:3] + (jnp.asarray(pack_slots(*args[3:])),
+                       jnp.zeros(SCFG.num_slots, jnp.int32))
 
 
 def test_decode_step_slices_no_layer_out_of_the_pool_and_aliases_both():

@@ -511,7 +511,8 @@ def test_a_neox_decode_step_never_meets_the_selector(monkeypatch):
     pool = sds((cfg.n_layer, scfg.num_blocks, scfg.block_size, cfg.kv_heads,
                 cfg.head_dim), cfg.dtype)
     slots = idle_slots(N, scfg.blocks_per_slot)
-    args = (params, pool, pool, sds(slots.shape, slots.dtype))
+    args = (params, pool, pool, sds(slots.shape, slots.dtype),
+            sds((N,), jnp.int32))
     text = make_decode_step(cfg, scfg).lower(*args).as_text()
 
     def unreachable(*a, **k):

@@ -41,6 +41,16 @@ def as_if_on_tpu(monkeypatch):
     monkeypatch.setattr(kernel_config, "on_tpu", lambda: True)
 
 
+def runs_kernel(text, name):
+    """Whether the compiled program CALLS the Mosaic kernel ``name``. Its
+    name anywhere in the text does not say so: the text lists the files
+    and functions of every trace it reuses, and a helper first traced
+    inside a kernel's module brings that module's name into a program
+    that never calls the kernel (which tests ran before decides it)."""
+    return any("tpu_custom_call" in ln and name in ln
+               for ln in text.splitlines())
+
+
 @pytest.mark.parametrize("H,Hkv", [(16, 16), (32, 16)])
 def test_paged_decode_attn_compiles_at_the_serving_cells_geometry(
         one_chip, H, Hkv):
@@ -91,7 +101,8 @@ def test_neox_1p3b_decode_step_holds_the_pool_once(one_chip, as_if_on_tpu):
              cfg.head_dim)
     compiled = make_decode_step(cfg, scfg).lower(
         params, sds(shape), sds(shape),
-        sds(*_idle_slots(N, scfg.blocks_per_slot))).compile()
+        sds(*_idle_slots(N, scfg.blocks_per_slot)),
+        sds((N,), jnp.int32)).compile()
     text = compiled.as_text()
     assert "paged_decode_attn" in text
     assert count_alias_pairs(text) == 2
@@ -187,15 +198,15 @@ def test_sala_programs_move_neither_the_pool_nor_a_weight_stack(
     state = sds((12, N, 32, 128, 128), jnp.float32)
     if program == "decode":
         compiled = make_decode_step(cfg, scfg).lower(
-            params, pool, pool, sds(*_idle_slots(N, bps)), kc,
+            params, pool, pool, sds(*_idle_slots(N, bps)), sds((N,), i32), kc,
             state).compile()
     else:
         compiled = make_chunk_step(cfg, scfg).lower(
             params, pool, pool, kc, state, sds((1, 1024), i32), sds((bps,), i32),
             sds((), i32), sds((), i32), sds((), i32)).compile()
     text = compiled.as_text()
-    assert "paged_sparse_attn" in text
-    assert ("lightning_chunk" in text) == (program == "chunk")
+    assert runs_kernel(text, "paged_sparse_attn")
+    assert runs_kernel(text, "lightning_chunk") == (program == "chunk")
     assert count_alias_pairs(text) == 4        # k, v, pooled keys, state rows
     big = ("bf16[4,6241,2,64,128]", "bf16[4,6241,8,128]",
            "bf16[12,4096,", "bf16[12,16384,", "bf16[4,4096,", "bf16[4,16384,")
@@ -285,8 +296,8 @@ def test_falcon_h1_programs_move_neither_pool_rows_nor_a_weight_stack(
              "conv": sds((6, N, m.d_conv - 1, m.conv_dim))}
     if program == "decode":
         compiled = make_decode_step(cfg, scfg).lower(
-            params, pool, pool, sds(*_idle_slots(N, bps)), None,
-            state).compile()
+            params, pool, pool, sds(*_idle_slots(N, bps)), sds((N,), i32),
+            None, state).compile()
     else:
         compiled = make_chunk_step(cfg, scfg).lower(
             params, pool, pool, None, state, sds((1, 512), i32),
@@ -294,8 +305,8 @@ def test_falcon_h1_programs_move_neither_pool_rows_nor_a_weight_stack(
     text = compiled.as_text()
     # the decode step reads pages and rows through kernels; the chunk
     # attends over the slot's gathered pages and scans in XLA
-    assert ("paged_sparse_attn" in text) == (program == "decode")
-    assert ("ssm_row_update" in text) == (program == "decode")
+    assert runs_kernel(text, "paged_sparse_attn") == (program == "decode")
+    assert runs_kernel(text, "ssm_row_update") == (program == "decode")
     assert count_alias_pairs(text) == 4        # k, v, state rows, tails
     big = ("bf16[6,2305,4,64,128]", "f32[6,48,32,128,256]", "bf16[6,5120,",
            "bf16[6,21504,", "bf16[6,4096,", "bf16[6,2560,")

@@ -230,7 +230,8 @@ def _decode_step_text(mesh=None, **cfg_kw):
     pool = _sds((cfg.n_layer, scfg.num_blocks, scfg.block_size,
                  cfg.kv_heads, cfg.head_dim))
     slots = idle_slots(N, scfg.blocks_per_slot)
-    args = (params, pool, pool, _sds(slots.shape, slots.dtype))
+    args = (params, pool, pool, _sds(slots.shape, slots.dtype),
+            _sds((N,), jnp.int32))
     return make_decode_step(cfg, scfg, mesh).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
 

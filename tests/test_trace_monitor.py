@@ -315,7 +315,8 @@ def test_watchdog_silent_across_serving_run_then_fires_on_injection():
     n2 = eng.scfg.num_slots + 1
     eng._decode_step(
         eng.params, jnp.array(eng.kv.k), jnp.array(eng.kv.v),
-        jnp.asarray(idle_slots(n2, eng.scfg.blocks_per_slot)))
+        jnp.asarray(idle_slots(n2, eng.scfg.blocks_per_slot)),
+        jnp.zeros(n2, jnp.int32))
     assert eng.telemetry.watchdog.observe() == ["serving/decode_step"]
     assert eng.decode_compile_count == 2
 
