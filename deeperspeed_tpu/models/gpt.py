@@ -133,6 +133,27 @@ class MambaAttnConfig:
         return self.d_ssm + self.conv_dim + self.n_heads
 
 
+@dataclasses.dataclass(frozen=True)
+class EvaAttnConfig:
+    """The constants of an ``eva`` layer (EVA attention as EvaByte applies
+    it): a query attends to the exact keys of its own aligned window of
+    ``window`` positions, and to ONE pooled key and value for every
+    ``chunk`` positions of the windows before it, in one softmax. The
+    pooling weights are learned, a pair of vectors a head."""
+    window: int = 2048
+    chunk: int = 16
+
+    def __post_init__(self):
+        if self.chunk < 1 or self.window % self.chunk:
+            raise ValueError(
+                f"eva: window must be a multiple of chunk (got {self})")
+
+    @property
+    def summaries(self) -> int:
+        """Pooled keys a window leaves behind."""
+        return self.window // self.chunk
+
+
 # The kinds of layer a stack may hold, by the name of the mixer. The kind
 # settles the rest of the layer, so nothing else is configured: what its
 # mixer keeps between tokens (its cache), its norm and its feed-forward.
@@ -147,7 +168,12 @@ class MambaAttnConfig:
 #              key (pages) AND a Mamba-2 state-space mixer (a float32 state
 #              row and a convolution tail a slot) on the same normed input,
 #              summed; gated SiLU; a muP multiplier a branch; no bias
-LAYER_KINDS = ("attention", "minicpm4", "lightning", "mamba_attn")
+#   eva        mixers.eva_block: RMSNorm, softmax attention over the exact
+#              keys of the query's own window (pages a slot reuses window
+#              after window) and one pooled key and value for every chunk
+#              of the windows behind it (pages of summaries), one query a
+#              key head; gated SiLU; no bias
+LAYER_KINDS = ("attention", "minicpm4", "lightning", "mamba_attn", "eva")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -166,7 +192,7 @@ class GPTConfig:
     max_seq: int = 1024
     rotary: bool = True  # NeoX-style rotary; False => learned positions
     rotary_pct: float = 1.0
-    rope_theta: float = 10000.0   # read by the mamba_attn layers only
+    rope_theta: float = 10000.0   # read by the mamba_attn and eva layers
     parallel_residual: bool = True  # NeoX parallel attn+mlp
     layernorm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -215,6 +241,16 @@ class GPTConfig:
     logit_scale: float = 1.0
     sparse: Optional[SparseAttnConfig] = None   # the minicpm4 layers'
     ssm: Optional[MambaAttnConfig] = None       # the mamba_attn layers'
+    eva: Optional[EvaAttnConfig] = None         # the eva layers'
+    # what a model states of its norms, its residual stream and its head
+    # (read by the eva layers, the final norm and the head): an RMSNorm
+    # scales by ``norm_offset + w``; the stream between layers and the
+    # logits stay float32 whatever ``dtype`` the matmuls run in; the head
+    # has ``n_pred * vocab_size`` columns, block p scoring the token p + 1
+    # positions on (block 0 is the served one)
+    norm_offset: float = 0.0
+    fp32_stream: bool = False
+    n_pred: int = 1
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -257,19 +293,23 @@ class GPTConfig:
             )
         if self.mixer_types:
             # attention layers keep their own weight tree and a pool laid
-            # out position by position: they do not mix with the others
+            # out position by position: they do not mix with the others,
+            # each of which keeps one of four shapes of cache (pages; a
+            # state row a slot; both; pages of two roles behind a window)
             mixable = set(LAYER_KINDS) - {"attention"}
             if set(self.mixer_types) - mixable \
                     or len(self.mixer_types) != self.n_layer:
                 raise ValueError(
-                    f"mixer_types must name one of {sorted(mixable)} (of "
-                    f"LAYER_KINDS {LAYER_KINDS}) for each of the "
-                    f"{self.n_layer} layers (or be empty: a stack of "
-                    f"attention layers), got {self.mixer_types}")
+                    f"mixer_types must name one of the four kinds "
+                    f"{sorted(mixable)} (of LAYER_KINDS {LAYER_KINDS}) for "
+                    f"each of the {self.n_layer} layers (or be empty: a "
+                    f"stack of attention layers), got {self.mixer_types}")
             if "minicpm4" in self.mixer_types and self.sparse is None:
                 raise ValueError("minicpm4 layers need cfg.sparse")
             if "mamba_attn" in self.mixer_types and self.ssm is None:
                 raise ValueError("mamba_attn layers need cfg.ssm")
+            if "eva" in self.mixer_types and self.eva is None:
+                raise ValueError("eva layers need cfg.eva")
         if self.remat_policy not in ("full", "flash", "matmuls", "dots",
                                      "dots_all"):
             raise ValueError(

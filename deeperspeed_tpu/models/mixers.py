@@ -1,8 +1,9 @@
 """The layer kinds beside ``gpt.decoder_block``'s: RMSNorm, a gated SiLU
 feed-forward, muP scalings, and mixers that keep something other than
-every key and value between tokens. What a layer keeps has one of three
-shapes: pages (``minicpm4``), a state row a slot (``lightning``), or both
-(``mamba_attn``):
+every key and value between tokens. What a layer keeps has one of four
+shapes: pages (``minicpm4``), a state row a slot (``lightning``), both
+(``mamba_attn``), or pages of two roles whose count stops following the
+length (``eva``):
 
 ``lightning``  decayed linear attention (Lightning Attention): per head a
                state ``S_t = lam S_{t-1} + k_t^T v_t`` (Dh x Dh, float32),
@@ -19,15 +20,25 @@ shapes: pages (``minicpm4``), a state row a slot (``lightning``), or both
                convolution whose last ``d_conv - 1`` inputs are state
                too; a prompt computes the recurrence chunkwise (SSD), a
                decode step is the recurrence.
+``eva``        softmax attention over the exact keys of the query's own
+               aligned window of ``window`` positions and, in the same
+               softmax, ONE pooled key and value for every ``chunk``
+               positions of the windows before it (EVA, as EvaByte
+               applies it): a chunk's pooled key is its keys' sum under
+               ``softmax_j(k_j . mu_h / sqrt(Dh))``, its pooled value its
+               values' sum under ``softmax_j(k_j . phi_h / sqrt(Dh))``,
+               ``mu``, ``phi`` learned, a pair a head; one query a key
+               head.
 
 A model whose ``GPTConfig.mixer_types`` names them (MiniCPM-SALA,
-Falcon-H1) keeps its weights stacked BY KIND (``params["sparse"]``,
-``params["lightning"]``, ``params["mamba_attn"]``) and is served only; the
-layer loop of every program goes run by run (``layer_runs``).
-``mixed_block`` and ``mamba_attn_block`` are the layers the whole forward,
-the chunked prefill and the decode step share: a program hands them the
-cache-dependent cores alone (``core(q, k, v) -> (ctx, aux)``; for the
-state-space branch ``scan(xbc, dt) -> (y, aux)``).
+Falcon-H1, EvaByte) keeps its weights stacked BY KIND (``params["sparse"]``,
+``params["lightning"]``, ``params["mamba_attn"]``, ``params["eva"]``) and
+is served only; the layer loop of every program goes run by run
+(``layer_runs``). ``mixed_block``, ``mamba_attn_block`` and ``eva_block``
+are the layers the whole forward, the chunked prefill and the decode step
+share: a program hands them the cache-dependent cores alone (``core(q, k,
+v) -> (ctx, aux)``; for the state-space branch ``scan(xbc, dt) -> (y,
+aux)``).
 """
 
 import math
@@ -42,7 +53,8 @@ from .gpt import (GPTConfig, SparseAttnConfig, _xla_causal_attention,
 NEG = -1e30
 # where each kind's stacked weights live in the parameter tree
 STACK_KEY = {"attention": "layers", "minicpm4": "sparse",
-             "lightning": "lightning", "mamba_attn": "mamba_attn"}
+             "lightning": "lightning", "mamba_attn": "mamba_attn",
+             "eva": "eva"}
 
 
 # ------------------------------------------------------------------ #
@@ -144,7 +156,7 @@ def init_params(rng, cfg: GPTConfig):
 
     params = {"embed": {"wte": w((V, D), std)},
               "final_norm": {"scale": jnp.ones((D,))},
-              "lm_head": w((D, V), std)}
+              "lm_head": w((D, cfg.n_pred * V), std)}
     if cfg.count("minicpm4"):
         params["sparse"] = kind(cfg.count("minicpm4"), cfg.kv_heads, False)
     if cfg.count("lightning"):
@@ -167,6 +179,18 @@ def init_params(rng, cfg: GPTConfig):
                     "w_out": w((n, m.d_ssm, D), out_std)},
             "mlp": {"w_gate": w((n, D, F), std), "w_up": w((n, D, F), std),
                     "w_down": w((n, F, D), out_std)}}
+    if cfg.count("eva"):
+        n = cfg.count("eva")
+        one = 1.0 - cfg.norm_offset     # the norms scale by 1 as they start
+        params["eva"] = {
+            "ln1": jnp.full((n, D), one), "ln2": jnp.full((n, D), one),
+            "wqkv": w((n, D, 3 * H * Dh), std),
+            "wo": w((n, H * Dh, D), out_std),
+            # the pooling vectors of a head: a chunk's keys, then its values
+            "mu": w((n, H, Dh), 1.0), "phi": w((n, H, Dh), 1.0),
+            "mlp": {"w_gate": w((n, D, F), std), "w_up": w((n, D, F), std),
+                    "w_down": w((n, F, D), out_std)}}
+        params["final_norm"]["scale"] = jnp.full((D,), one)
     return params
 
 
@@ -260,6 +284,50 @@ def mamba_attn_block(cfg: GPTConfig, x, p, positions, attend, scan):
     return x, (kept_attn, kept_ssm)
 
 
+def eva_summaries(k, v, mu, phi):
+    """One pooled key and one pooled value a head for every chunk of keys:
+    k, v (..., c, H, Dh), a chunk's c positions; mu, phi (H, Dh). The
+    pooled key is the keys' sum under ``softmax_j(k_j . mu_h / sqrt(Dh))``,
+    the pooled value the values' under ``softmax_j(k_j . phi_h /
+    sqrt(Dh))``, softmax and sums in float32. -> (..., H, Dh) each, in
+    k's and v's dtypes."""
+    with jax.named_scope("ds.eva.pool"):
+        k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+        scale = 1.0 / math.sqrt(k.shape[-1])
+
+        def weights(vec):
+            s = jnp.sum(k32 * vec.astype(jnp.float32), -1) * scale
+            return jax.nn.softmax(s, axis=-2)[..., None]    # over the chunk
+
+        return (jnp.sum(weights(mu) * k32, -3).astype(k.dtype),
+                jnp.sum(weights(phi) * v32, -3).astype(v.dtype))
+
+
+def eva_block(cfg: GPTConfig, x, p, positions, attend):
+    """An ``eva`` layer: x + Attn(RMSNorm(x)), then x + FFN(RMSNorm(x)),
+    the FFN gated SiLU, no bias; the norms scale by ``norm_offset + w``
+    and the stream ``x`` is float32 where the model says so
+    (``cfg.fp32_stream``). ``attend(q, k, v) -> (ctx (B, S, H, Dh), aux)``
+    knows the cache: q and k come rotated, q NOT yet scaled, one query
+    head a key head."""
+    cdt, eps = cfg.dtype, cfg.layernorm_eps
+    B, S, _ = x.shape
+    H, Dh = cfg.n_head, cfg.head_dim
+    norm = lambda x, w: rms_norm(x, w + cfg.norm_offset, eps).astype(cdt)
+    with jax.named_scope("ds.attn"):
+        qkv = norm(x, p["ln1"]) @ p["wqkv"].astype(cdt)
+        q, k, v = (qkv[..., i * H * Dh:(i + 1) * H * Dh].reshape(B, S, H, Dh)
+                   for i in range(3))
+        q = rotary_embedding(q, positions, Dh, cfg.rope_theta)
+        k = rotary_embedding(k, positions, Dh, cfg.rope_theta)
+        ctx, aux = attend(q, k, v)
+        y = ctx.astype(cdt).reshape(B, S, H * Dh) @ p["wo"].astype(cdt)
+        x = x + y.astype(x.dtype)
+    with jax.named_scope("ds.mlp"):
+        x = x + gated_ffn(norm(x, p["ln2"]), p["mlp"], cdt).astype(x.dtype)
+    return x, aux
+
+
 def embed_tokens(cfg: GPTConfig, params, tokens, positions=None):
     """Where the residual stream starts, for either parameter tree: the
     table's rows of ``tokens`` (any shape), times ``scale_emb`` where the
@@ -271,25 +339,42 @@ def embed_tokens(cfg: GPTConfig, params, tokens, positions=None):
         x = x * jnp.asarray(cfg.scale_emb, cfg.dtype)
     if "wpe" in emb:
         x = x + jnp.take(emb["wpe"], positions, axis=0).astype(cfg.dtype)
-    return x
+    return x.astype(jnp.float32) if cfg.fp32_stream else x
 
 
 def head_logits(cfg: GPTConfig, params, x):
     """The final norm and the head, for either parameter tree: a stack of
     attention layers ends in a LayerNorm (``final_ln``), a mixed one in an
-    RMSNorm (``final_norm``)."""
+    RMSNorm (``final_norm``). ``cfg.n_pred * vocab_size`` columns, block p
+    scoring the token p + 1 positions on (``served_logits`` takes block
+    0); float32 where the model keeps its stream so."""
     if "final_ln" in params:
         x = layer_norm(x, params["final_ln"]["scale"],
                        params["final_ln"]["bias"], cfg.layernorm_eps)
     else:
-        x = rms_norm(x, params["final_norm"]["scale"], cfg.layernorm_eps)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"]["wte"].astype(cfg.dtype).T
+        scale = params["final_norm"]["scale"]
+        if cfg.norm_offset:
+            scale = scale + cfg.norm_offset
+        x = rms_norm(x, scale, cfg.layernorm_eps)
+    if cfg.fp32_stream:
+        def dot(a, b):
+            return jnp.dot(a.astype(cfg.dtype), b,
+                           preferred_element_type=jnp.float32)
     else:
-        logits = x @ params["lm_head"].astype(cfg.dtype)
+        dot = jnp.matmul
+    if cfg.tie_embeddings:
+        logits = dot(x, params["embed"]["wte"].astype(cfg.dtype).T)
+    else:
+        logits = dot(x, params["lm_head"].astype(cfg.dtype))
     if cfg.logit_scale != 1.0:
         logits = logits * jnp.asarray(cfg.logit_scale, cfg.dtype)
     return logits
+
+
+def served_logits(cfg: GPTConfig, logits):
+    """Of ``head_logits``' columns, the block a served token is picked
+    from: the next position's (all of them where the head has one)."""
+    return logits if cfg.n_pred == 1 else logits[..., :cfg.vocab_size]
 
 
 # ------------------------------------------------------------------ #
@@ -656,6 +741,32 @@ def dense_sparse_attention(q, k, v, sp: SparseAttnConfig):
     return o.reshape(S, H, Dh)
 
 
+def dense_eva_attention(q, k, v, mu, phi, ev):
+    """eva over a whole sequence by its definition: every query attends
+    to the exact keys of its own window up to itself and to the pooled
+    key and value of every chunk of the windows before it, in one
+    softmax. q, k, v: (S, H, Dh), one query a key head; mu, phi: (H, Dh).
+    O(S^2) memory: a small-size form. -> (S, H, Dh) float32."""
+    S, H, Dh = q.shape
+    W, c = ev.window, ev.chunk
+    n = S // c                      # the chunks whose positions all exist
+    kbar, vbar = eva_summaries(k[:n * c].reshape(n, c, H, Dh),
+                               v[:n * c].reshape(n, c, H, Dh), mu, phi)
+    pos = jnp.arange(S, dtype=jnp.int32)
+    exact = (pos[None, :] <= pos[:, None]) \
+        & (pos[None, :] // W == pos[:, None] // W)
+    behind = (jnp.arange(n, dtype=jnp.int32)[None, :] * c) // W \
+        < pos[:, None] // W
+    keys = jnp.concatenate([k, kbar], 0)
+    s = jnp.einsum("qhd,khd->qhk", q, keys,
+                   preferred_element_type=jnp.float32) / math.sqrt(Dh)
+    see = jnp.concatenate([exact, behind], 1)[:, None, :]
+    pr = jax.nn.softmax(jnp.where(see, s, NEG), -1)
+    return jnp.einsum("qhk,khd->qhd", pr.astype(v.dtype),
+                      jnp.concatenate([v, vbar], 0),
+                      preferred_element_type=jnp.float32)
+
+
 def forward(cfg: GPTConfig, params, tokens):
     """tokens (1, S) -> logits (1, S, V): the mixed stack with no cache,
     each mixer by its definition (S a multiple of the sparse block where
@@ -666,6 +777,12 @@ def forward(cfg: GPTConfig, params, tokens):
     positions = jnp.arange(S, dtype=jnp.int32)
 
     def body(kind, x, p, _i):
+        if kind == "eva":
+            def attend(q, k, v):
+                return dense_eva_attention(q[0], k[0], v[0], p["mu"],
+                                           p["phi"], cfg.eva)[None], None
+
+            return eva_block(cfg, x, p, positions, attend)[0], ()
         if kind == "mamba_attn":
             m = cfg.ssm
 

@@ -20,6 +20,7 @@ Geometry:
     than once per prompt length.
 """
 
+import copy
 import dataclasses
 import math
 from typing import Optional, Tuple
@@ -250,6 +251,47 @@ class RouterConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class PageRule:
+    """How many pages a slot holds for ``n`` positions, by the role a page
+    plays: the cache's rule (``kv_cache.page_rule_for`` reads it off the
+    model), which admission, growth, the worst case and the table's width
+    all ask.
+
+    ``window == 0``: a position keeps its row of a page for ever, so the
+    count follows the length: ``ceil(n / block_size)`` pages of ONE role.
+
+    ``window > 0`` (a layer that keeps exact keys for the query's own
+    window of ``window`` positions and one summary row for every ``chunk``
+    positions): TWO roles. ``window / block_size`` pages at most of exact
+    keys, reused window after window (position ``i`` lives in row ``i mod
+    window``), and a page of summaries for every ``chunk * block_size``
+    positions: ``ceil((n // chunk) / block_size)`` pages, the rows of the
+    window being filled among them (written as chunks complete, seen once
+    the window is left behind). A slot's table holds the roles side by
+    side: ``[exact keys | summaries]``."""
+    window: int = 0
+    chunk: int = 0
+
+    def counts(self, n: int, block_size: int) -> Tuple[int, ...]:
+        """Pages of each role that ``n`` positions need."""
+        pages = math.ceil(n / block_size) if n > 0 else 0
+        if not self.window:
+            return (pages,)
+        return (min(pages, self.window // block_size),
+                math.ceil((max(n, 0) // self.chunk) / block_size))
+
+    def live(self, n: int, block_size: int) -> int:
+        """Of the pages ``n`` positions hold, those with a row the last
+        of them attends to (itself included): all of them, or the summary
+        pages of the windows left behind and the window's pages so far."""
+        if not self.window or n < 1:
+            return sum(self.counts(n, block_size))
+        w, r = divmod(n - 1, self.window)
+        return (w * (self.window // self.chunk // block_size)
+                + math.ceil((r + 1) / block_size))
+
+
+@dataclasses.dataclass(frozen=True)
 class ServingConfig:
     # slot pool: batch dimension of the one jitted decode step
     num_slots: int = 8
@@ -257,7 +299,9 @@ class ServingConfig:
     block_size: int = 16
     num_blocks: int = 128
     # hard cap on prompt_len + max_new_tokens per request (bounds the
-    # block-table width: ceil(max_seq_len / block_size) entries per slot)
+    # block-table width: what ``page_rule`` asks for max_seq_len positions,
+    # ceil(max_seq_len / block_size) entries per slot for a cache whose
+    # pages follow the length)
     max_seq_len: int = 512
     # default per-request generation budget (requests may pass their own)
     max_new_tokens: int = 64
@@ -297,6 +341,11 @@ class ServingConfig:
     # drafter-backed speculative decoding (serving/spec/); None = plain
     # one-program decode, the default path, untouched
     speculative: Optional[SpeculativeConfig] = None
+    # the cache's page rule: derived, not configured. No caller can pass
+    # it (``init=False``) and it is no key of the "serving" block: the
+    # engine reads it off the model it serves and ``for_cache`` alone sets
+    # it (``dataclasses.replace`` leaves it behind like any derived value)
+    page_rule: PageRule = dataclasses.field(default=PageRule(), init=False)
 
     def __post_init__(self):
         if isinstance(self.fleet, dict):
@@ -355,13 +404,41 @@ class ServingConfig:
         while b < self.max_seq_len:
             buckets.append(b)
             b *= 2
-        buckets.append(self.blocks_per_slot * self.block_size)
+        buckets.append(self.slot_positions)
         return tuple(buckets)
+
+    def for_cache(self, page_rule: PageRule) -> "ServingConfig":
+        """This configuration under the page rule of the cache it sizes."""
+        if page_rule == self.page_rule:
+            return self
+        sized = copy.copy(self)
+        object.__setattr__(sized, "page_rule", page_rule)
+        return sized
+
+    @property
+    def slot_positions(self) -> int:
+        """Positions a slot may reach: max_seq_len up to a whole page."""
+        return math.ceil(self.max_seq_len / self.block_size) * self.block_size
+
+    @property
+    def table_widths(self) -> Tuple[int, ...]:
+        """Entries of a slot's table by role, side by side in that order:
+        what the cache's rule asks for a maximally long request."""
+        return self.page_rule.counts(self.slot_positions, self.block_size)
 
     @property
     def blocks_per_slot(self) -> int:
-        """Block-table width: blocks a maximally long request occupies."""
-        return math.ceil(self.max_seq_len / self.block_size)
+        """Block-table width: the pages a maximally long request holds
+        under the cache's rule (``page_rule``)."""
+        return sum(self.table_widths)
+
+    def pages_by_role(self, n_tokens: int) -> Tuple[int, ...]:
+        """Pages of each role a slot holds for ``n_tokens`` positions."""
+        return self.page_rule.counts(n_tokens, self.block_size)
+
+    def pages_needed(self, n_tokens: int) -> int:
+        """Pages a slot holds for ``n_tokens`` positions, all roles."""
+        return sum(self.pages_by_role(n_tokens))
 
     @property
     def usable_blocks(self) -> int:

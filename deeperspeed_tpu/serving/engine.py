@@ -84,11 +84,14 @@ from .config import ServingConfig
 from .kv_cache import (
     NULL_BLOCK,
     PagedKVCache,
-    blocks_needed,
     chunk_attend_all,
     decode_attend_all,
     decode_attend_for,
+    eva_chunk_past,
+    eva_decode_indices,
+    eva_page_list,
     lightning_chunk_for,
+    page_rule_for,
     sparse_attend_for,
     ssm_rows_for,
     decode_write_indices,
@@ -97,6 +100,8 @@ from .kv_cache import (
     write_chunk,
     write_chunk_pages,
     write_decode_rows,
+    write_eva_chunk,
+    write_eva_decode,
     write_rows,
 )
 from .metrics import ServingMetrics
@@ -192,8 +197,9 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
     """One layer of the stack inside a serving program, by its ``kind``
     (one of ``cfg.layer_kinds``). The layer math is the model's own:
     gpt.decoder_block's for an ``attention`` layer (the block training
-    runs), mixers.mamba_attn_block's or mixers.mixed_block's for the
-    others. Only the core differs (mirrors generation._cached_block):
+    runs), mixers.mamba_attn_block's, mixers.eva_block's or
+    mixers.mixed_block's for the others. Only the core differs (mirrors
+    generation._cached_block):
     ``attend(q, k, v) -> (ctx, kept)`` reads the layer's cache (pages, a
     state row), and ``kept`` (what the caller keeps of the new tokens:
     keys and values, a new state) comes back beside the layer's output; a
@@ -202,6 +208,8 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
     if kind == "mamba_attn":
         return mixers.mamba_attn_block(cfg, x, layer_params, positions,
                                        *attend)
+    if kind == "eva":
+        return mixers.eva_block(cfg, x, layer_params, positions, attend)
     if kind != "attention":
         return mixers.mixed_block(cfg, kind, x, layer_params, positions,
                                   attend)
@@ -240,7 +248,9 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     donated like the pools: its sparse layers score the slot's pooled
     keys, pick pages and read only those, its lightning layers read and
     write their state row, its mamba_attn layers read every live page of
-    a slot AND read and write a state row and a convolution tail, and the
+    a slot AND read and write a state row and a convolution tail, its eva
+    layers read a list of two roles (the summary pages of the windows
+    left behind, then the window's own pages) under one count, and the
     layer loop goes run by run of one kind (``mixers.scan_runs``; a
     classic model is one run). Pools are
     donated — the caller's old handles die each step (no second pool in
@@ -276,7 +286,7 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         # what the kinds of layer in this stack need beside the pools
         if "attention" in kinds:
             attend_rows = decode_attend_for(k_pool, tables, cfg.n_head, mesh)
-        if kinds & {"minicpm4", "mamba_attn"}:
+        if kinds & {"minicpm4", "mamba_attn", "eva"}:
             attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
         if "minicpm4" in kinds:
             at = decode_write_indices(sp, tables, lengths)
@@ -285,6 +295,8 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
             at = {"page": tables[jnp.arange(N), lengths // bs],
                   "row": lengths % bs}
             update_rows = ssm_rows_for(state["ssm"], cfg.ssm.n_groups, mesh)
+        if "eva" in kinds:
+            at = eva_decode_indices(cfg.eva, scfg, tables, lengths)
         if kinds & {"lightning", "mamba_attn"}:
             # a slot whose prompt is still being chunked in is idle here:
             # its state row is the chunks' to write
@@ -323,6 +335,17 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                                         v_row, tables, lengths, attend_pages)
                 return ctx, (k_row, v_row)
 
+            def two_roles(q, k, v):
+                """Summaries first, the window's exact keys after, one
+                count of live rows, the new token's own key beside."""
+                k_row = k[:, 0].astype(k_pool.dtype)
+                v_row = v[:, 0].astype(v_pool.dtype)
+                with jax.named_scope("ds.eva.attn"):
+                    ctx = decode_attend_all(
+                        k_pool, v_pool, layer, q, k_row, v_row, at["pages"],
+                        at["count"], attend_pages)
+                return ctx, (k_row, v_row)
+
             def state_space(xbc, dt):
                 """The new token's convolution, then every slot's state
                 row through the recurrence, in place in the carry."""
@@ -339,7 +362,7 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 return y[:, None], {"conv": conv, "ssm": ssm_rows}
 
             core = {"attention": attention, "minicpm4": minicpm4,
-                    "lightning": lightning,
+                    "lightning": lightning, "eva": two_roles,
                     "mamba_attn": (all_pages, state_space)}[kind]
             x, kept = _paged_block(cfg, x, layer_params, positions, core,
                                    kind)
@@ -369,8 +392,13 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 k_rows, v_rows = kept["mamba_attn"]     # (L, N, Hkv, Dh)
                 k_pool = write_rows(k_pool, at["page"], at["row"], k_rows)
                 v_pool = write_rows(v_pool, at["page"], at["row"], v_rows)
+            if "eva" in kept:
+                k_pool, v_pool = write_eva_decode(
+                    cfg.eva, k_pool, v_pool, at, *kept["eva"],
+                    params["eva"]["mu"], params["eva"]["phi"])
         with jax.named_scope("ds.decode/sample"):
-            logits = mixers.head_logits(cfg, params, x)[:, 0]   # (N, V)
+            logits = mixers.served_logits(
+                cfg, mixers.head_logits(cfg, params, x)[:, 0])  # (N, V)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             l32 = logits.astype(jnp.float32) / jnp.maximum(
                 temps, 1e-6)[:, None]
@@ -393,19 +421,28 @@ def prefill_chunk_for(cfg: GPTConfig, scfg: ServingConfig) -> int:
     none). Whole pages, whole pooling strides; every query of a chunk
     falls on one side of ``dense_len`` and has the chunk's own keys
     inside its local window."""
-    sp = cfg.sparse
+    sp, ev = cfg.sparse, cfg.eva
     C = scfg.prefill_chunk or (sp.window_size if sp is not None else 1024)
     bad = C % scfg.block_size != 0
     if sp is not None:
         bad = bad or C > sp.window_size or sp.dense_len % C \
             or C % sp.kernel_stride
+    if ev is not None:
+        # its summaries are whole pages, or a part of one page
+        ns = C // ev.chunk
+        bad = bad or ev.window % C or C % ev.chunk \
+            or (ns % scfg.block_size and scfg.block_size % ns)
     if bad:
         raise ValueError(
             f"prefill_chunk {C} does not suit this model: it must be a "
             f"multiple of block_size ({scfg.block_size})"
             + (f" and of the pooling stride, at most window_size "
                f"({sp.window_size}), and divide dense_len ({sp.dense_len})"
-               if sp is not None else ""))
+               if sp is not None else "")
+            + (f" and divide the window ({ev.window}: a prompt chunk never "
+               f"straddles a window, whose pages are reused), its "
+               f"{ev.chunk}-position chunks filling whole pages of summaries "
+               f"or a part of one" if ev is not None else ""))
     return C
 
 
@@ -422,11 +459,12 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     cleared by whoever enters it, never by who left) and out, a position
     at or beyond ``n_valid`` leaving it as it was; it attends over the
     slot's pages (the selected ones in a sparse layer, all of the past in
-    a mamba_attn layer) and writes its own keys, values and pooled keys
-    after the layer loop, in place."""
+    a mamba_attn layer; in an eva layer the summaries of the windows left
+    behind and the window's pages before the chunk) and writes its own
+    keys, values and pooled keys after the layer loop, in place."""
     C = prefill_chunk_for(cfg, scfg)
     bs = scfg.block_size
-    sp = cfg.sparse
+    sp, ev = cfg.sparse, cfg.eva
     slopes = mixers.lightning_slopes(cfg.n_head)
 
     @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
@@ -437,6 +475,9 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         # the last chunk may run past the table's end: null pages there
         table_row = jnp.pad(table_row, (0, C // bs))
         attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
+        if ev is not None:
+            n_past = eva_chunk_past(ev, scfg, C)
+            past, n_seen = eva_page_list(ev, scfg, table_row, offset, n_past)
         carried = jax.tree.map(
             lambda rows: jnp.where(
                 offset == 0, 0.0,
@@ -469,7 +510,15 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                     carried["conv"][layer], carried["ssm"][layer], n_valid)
                 return y[None], {"conv": tail, "ssm": h}
 
+            def two_roles(q, k, v):
+                kk, vv = k[0].astype(k_pool.dtype), v[0].astype(v_pool.dtype)
+                with jax.named_scope("ds.eva.attn"):
+                    ctx = chunk_attend_all(k_pool, v_pool, layer, q[0], kk,
+                                           vv, past, n_seen, n_past)
+                return ctx[None], (kk, vv)
+
             core = {"minicpm4": minicpm4, "lightning": lightning,
+                    "eva": two_roles,
                     "mamba_attn": (all_past, state_space)}[kind]
             return _paged_block(cfg, x, layer_params, positions, core, kind)
 
@@ -490,6 +539,10 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                     lambda rows, n: jax.lax.dynamic_update_slice(
                         rows, n[:, None].astype(rows.dtype),
                         (0, slot) + (0,) * (rows.ndim - 2)), state, new)
+            if "eva" in kept:
+                k_pool, v_pool = write_eva_chunk(
+                    ev, scfg, k_pool, v_pool, table_row, offset, n_valid,
+                    *kept["eva"], params["eva"]["mu"], params["eva"]["phi"])
         last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0)
         return (mixers.head_logits(cfg, params, last)[0], k_pool, v_pool,
                 kc_pool, state)
@@ -737,15 +790,19 @@ class ServingEngine(_ServingBase):
                 f"model's learned-position table ({cfg.max_seq})"
             )
         self.cfg = cfg
+        # how many pages a length needs is the cache's to say
+        scfg = scfg.for_cache(page_rule_for(cfg))
         if not cfg.classic:
             kinds = sorted(set(cfg.mixer_types))
             if scfg.prefix_caching and \
-                    set(kinds) & {"lightning", "mamba_attn"}:
+                    set(kinds) & {"lightning", "mamba_attn", "eva"}:
                 raise ValueError(
                     "prefix_caching cannot serve a model with a layer that "
-                    f"keeps recurrent state ({kinds}): a cached prefix's "
-                    "pages say nothing of the state row after it, and no "
-                    "snapshot of that row is kept. Turn prefix_caching off")
+                    "keeps recurrent state, or pages that are overwritten "
+                    f"behind a window ({kinds}): a cached prefix's pages say "
+                    "nothing of the state row after it, and no snapshot of "
+                    "that row is kept; a reused page no longer holds the "
+                    "prefix. Turn prefix_caching off")
             if mesh is not None or scfg.speculative is not None:
                 raise NotImplementedError(
                     f"a stack of {kinds} layers is served on one device, "
@@ -918,6 +975,7 @@ class ServingEngine(_ServingBase):
         Greedy path is the same raw argmax make_generator uses; sampling
         keys off (req.seed, token index) exactly like the decode step,
         so a re-prefill after preemption or retry replays the stream."""
+        logits_1d = mixers.served_logits(self.cfg, logits_1d)
         if req.temperature <= 0.0:
             return int(jnp.argmax(logits_1d))
         top_k = self.scfg.top_k
@@ -1156,6 +1214,9 @@ class ServingEngine(_ServingBase):
         if lo == 0 and self.kv.state is not None:
             # the first chunk entered the slot's state rows as zeros
             self.metrics.record_state_reset()
+        if self.scfg.page_rule.window:
+            self.metrics.record_chunk_summaries(
+                (hi - lo) // self.scfg.page_rule.chunk)
         state["next"] += 1
         if final:
             self.metrics.record_reuse(0, state["L"])
@@ -1171,7 +1232,7 @@ class ServingEngine(_ServingBase):
         m, L, blocks = state["m"], state["L"], state["blocks"]
         first = m // bs
         page_to_block = [NULL_BLOCK] * (state["cache_len"] // bs)
-        for p in range(first, blocks_needed(L, bs)):
+        for p in range(first, self.scfg.pages_needed(L)):
             page_to_block[p] = blocks[p]
         self.kv.write_pages(state["k"], state["v"], page_to_block)
         if req.prefix_src is not None:
@@ -1185,7 +1246,7 @@ class ServingEngine(_ServingBase):
     def _index_prompt(self, req: Request, blocks: List[int]) -> None:
         if self.sched.prefix_cache is None:
             return
-        n = blocks_needed(len(req.prompt), self.scfg.block_size)
+        n = self.scfg.pages_needed(len(req.prompt))
         self.sched.prefix_cache.insert(req.prompt, blocks[:n])
 
     def _prefill_full(self, slot: int, req: Request,
@@ -1206,8 +1267,7 @@ class ServingEngine(_ServingBase):
             with trace_span("serving/prefill/scatter", lane="serving"):
                 # admission allocated headroom for the first decode
                 # write; only the context's own pages carry prefill data
-                data_blocks = blocks[:blocks_needed(L,
-                                                    self.scfg.block_size)]
+                data_blocks = blocks[:self.scfg.pages_needed(L)]
                 self.kv.write_prefill(cache["k"], cache["v"],
                                       data_blocks, L)
             with trace_span("serving/prefill/pick", lane="serving"):
@@ -1270,15 +1330,20 @@ class ServingEngine(_ServingBase):
             seeds = np.zeros(N, np.int32)
             counts = np.zeros(N, np.int32)
             live_pages = selected_pages = 0
-            sp = self.cfg.sparse
+            sp, rule = self.cfg.sparse, self.scfg.page_rule
+            held, summary_rows, wraps = [0, 0], 0, 0
             for s, req in lanes:
                 tables[s] = self.sched.slot_table_row(s)
                 lengths[s] = req.cached_len
                 # the pages that hold a live position of a live slot,
                 # the new token's included: all the pool a step need read
-                live = blocks_needed(req.cached_len + 1,
-                                     self.scfg.block_size)
+                live = rule.live(req.cached_len + 1, self.scfg.block_size)
                 live_pages += live
+                if rule.window:
+                    for role, n in enumerate(self.sched.slot_roles[s]):
+                        held[role] += n
+                    summary_rows += (req.cached_len + 1) % rule.chunk == 0
+                    wraps += req.cached_len % rule.window == 0
                 if sp is not None:
                     # what one selection of a sparse layer names of them
                     selected_pages += (live if req.cached_len + 1
@@ -1301,10 +1366,17 @@ class ServingEngine(_ServingBase):
         self.metrics.record_decode_placements(1)
         self.metrics.record_kv_pages(live_pages, tables.size,
                                      selected_pages)
+        roles = {}
+        if rule.window:
+            self.metrics.record_window_pages(len(lanes), *held,
+                                             summary_rows, wraps)
+            roles = {"window_pages": str(held[0]),
+                     "summary_pages": str(held[1]),
+                     "summary_rows": str(summary_rows), "wraps": str(wraps)}
         with trace_span("serving/decode/dispatch", lane="serving",
                         live_pages=live_pages, view_pages=tables.size,
                         selected_pages=selected_pages,
-                        ahead="1" if ahead else "0"):
+                        ahead="1" if ahead else "0", **roles):
             nxt, self.kv.k, self.kv.v, self.kv.kc, self.kv.state = \
                 self._decode_step(*_dargs)
             # the read-back starts when the step ends, not when the host

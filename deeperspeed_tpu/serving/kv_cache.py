@@ -6,8 +6,12 @@ Layout: one pool per cache side, stacked over layers —
     k, v: (n_layer, num_blocks, block_size, n_kv_head, head_dim)
 
 A model of mixed layers (``GPTConfig.mixer_types``) keeps, by the kind of
-each layer, one of three shapes of cache: pages (``minicpm4``), a state
-row a slot (``lightning``), or both for one layer (``mamba_attn``). Its
+each layer, one of four shapes of cache: pages (``minicpm4``), a state
+row a slot (``lightning``), both for one layer (``mamba_attn``), or pages
+of two roles (``eva``: the exact keys of the current window in pages the
+slot reuses window after window, and pages of pooled summaries, a row
+for every chunk of positions; ``page_rule_for`` is how many of each a
+length needs, and a slot's table holds them side by side). Its
 pages hold the layers that have any, a page's ``(block_size, head_dim)``
 last so that two or four key heads are not padded to a tile of sixteen:
 
@@ -59,7 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.gpt import GPTConfig
-from .config import ServingConfig
+from .config import PageRule, ServingConfig
 
 NULL_BLOCK = 0
 
@@ -150,7 +154,20 @@ class BlockAllocator:
 
 
 def blocks_needed(n_tokens: int, block_size: int) -> int:
-    return math.ceil(n_tokens / block_size) if n_tokens > 0 else 0
+    """Pages that hold ``n_tokens`` rows where a position keeps its row
+    for ever: ``PageRule()``'s count, spelled for callers that hold no
+    serving configuration (a configuration answers ``pages_needed``)."""
+    return PageRule().counts(n_tokens, block_size)[0]
+
+
+def page_rule_for(cfg: GPTConfig) -> PageRule:
+    """The rule by which this model's cache holds pages for a length:
+    every position a row for ever, or, for ``eva`` layers, the window's
+    pages reused and a page of summaries for every ``chunk * block_size``
+    positions."""
+    if cfg.count("eva"):
+        return PageRule(window=cfg.eva.window, chunk=cfg.eva.chunk)
+    return PageRule()
 
 
 # ------------------------------------------------------------------ #
@@ -362,14 +379,17 @@ class PagedKVCache:
                     f"a page is one selection block: block_size must be "
                     f"{sp.block_size} (got {scfg.block_size}) and "
                     f"max_seq_len cover dense_len ({sp.dense_len})")
-            n_sp, n_li, n_ma = (cfg.count(kind) for kind in (
-                "minicpm4", "lightning", "mamba_attn"))
-            if n_ma and (n_sp or n_li):
+            n_sp, n_li, n_ma, n_ev = (cfg.count(kind) for kind in (
+                "minicpm4", "lightning", "mamba_attn", "eva"))
+            if (n_ma or n_ev) and len(set(cfg.mixer_types)) > 1:
                 raise NotImplementedError(
-                    "mamba_attn layers share a stack with no other kind: "
-                    "the pool and the state rows are indexed by a layer's "
-                    "place among ONE kind")
-            shape = (n_sp + n_ma, nb, cfg.kv_heads, scfg.block_size,
+                    "mamba_attn and eva layers share a stack with no other "
+                    "kind: the pool and the state rows are indexed by a "
+                    "layer's place among ONE kind, and a slot's table is "
+                    "laid out by one page rule")
+            if n_ev:
+                check_eva_pages(cfg, scfg)
+            shape = (n_sp + n_ma + n_ev, nb, cfg.kv_heads, scfg.block_size,
                      cfg.head_dim)
             if sp is not None:
                 self.kc = jnp.zeros(
@@ -402,7 +422,8 @@ class PagedKVCache:
         allocated ``blocks``. ``bucket`` is a multiple of block_size;
         pages beyond ``blocks`` (prompt padding) go to the null block."""
         bs = self.scfg.block_size
-        assert len(blocks) == blocks_needed(length, bs), (blocks, length)
+        assert len(blocks) == self.scfg.pages_needed(length), \
+            (blocks, length)
         n_pages = k_dense.shape[2] // bs
         self.write_pages(k_dense, v_dense,
                          list(blocks) + [NULL_BLOCK] * (n_pages
@@ -720,6 +741,15 @@ def decode_write_indices(sp, tables, lengths):
             "completes": completes, "j_new": j_new}
 
 
+def lay_rows(cur, row, new):
+    """Pages ``cur`` (L, N, ..., R, Dh) with ``new`` (L, N, ..., Dh) laid
+    over row ``row[n]`` of page n."""
+    R = cur.shape[-2]
+    hit = (jnp.arange(R)[None, :] == row[:, None]).reshape(
+        (1, len(row)) + (1,) * (cur.ndim - 4) + (R, 1))
+    return jnp.where(hit, new[..., None, :].astype(cur.dtype), cur)
+
+
 def write_rows(pool, page, row, new):
     """pool (L, num_blocks, ..., R, Dh) with ``new`` (L, N, ..., Dh) laid
     over row ``row[n]`` of page ``page[n]``: whole pages are read, changed
@@ -727,12 +757,7 @@ def write_rows(pool, page, row, new):
     (XLA re-lays the WHOLE pool out for a scatter or a gather that does).
     Rows of several slots on one page (idle slots, the null page) race;
     what lands there is never read unmasked."""
-    cur = pool[:, page]                                 # (L, N, ..., R, Dh)
-    R = cur.shape[-2]
-    hit = (jnp.arange(R)[None, :] == row[:, None]).reshape(
-        (1, len(row)) + (1,) * (cur.ndim - 4) + (R, 1))
-    return pool.at[:, page].set(
-        jnp.where(hit, new[..., None, :].astype(pool.dtype), cur))
+    return pool.at[:, page].set(lay_rows(pool[:, page], row, new))
 
 
 def write_decode_rows(sp, k_pool, v_pool, kc_pool, at, k_rows, v_rows,
@@ -966,3 +991,143 @@ def write_chunk(sp, k_pool, v_pool, kc_pool, table_row, offset, kk, vv,
     cur = jnp.swapaxes(cur.reshape(n, Hkv, pg + 1, w, Dh), 1, 2)
     return k_pool, v_pool, kc_pool.at[:, ids].set(
         cur.reshape(n, pg + 1, Hkv * w, Dh))
+
+
+# ------------------------------------------------------------------ #
+# eva: the window's pages reused, and pages of summaries
+# ------------------------------------------------------------------ #
+
+
+def check_eva_pages(cfg: GPTConfig, scfg: ServingConfig) -> None:
+    """A slot's table is ``[window / bs pages of exact keys | pages of
+    summaries]``: a window is whole pages, a chunk never straddles a page,
+    and the summaries a window leaves behind fill whole pages."""
+    ev, bs = cfg.eva, scfg.block_size
+    if scfg.page_rule != page_rule_for(cfg):
+        raise ValueError(
+            f"the serving configuration's page rule ({scfg.page_rule}) is "
+            f"not this cache's ({page_rule_for(cfg)}): size it with "
+            "ServingConfig.for_cache(page_rule_for(cfg))")
+    if ev.window % bs or bs % ev.chunk or ev.summaries % bs:
+        raise ValueError(
+            f"eva pages: block_size ({bs}) must divide the window "
+            f"({ev.window}) and the summaries a window leaves behind "
+            f"({ev.summaries}), and be a multiple of the chunk ({ev.chunk})")
+
+
+def eva_page_list(ev, scfg: ServingConfig, table, n, width: int):
+    """The pages a query reads at ``n`` positions cached (traced, (...,)),
+    ``width`` entries, and how many rows of their concatenation count:
+    the summary pages of the ``n // window`` windows left behind, then the
+    window's own pages from its first, ``summaries * (n // window) + n
+    mod window`` rows in all. What lies beyond the count is there and not
+    seen: the rows of the window before in the reused pages, and the
+    summaries of the window being filled (their pages are not even
+    listed). table: (..., blocks_per_slot). Entries past the last that
+    counts name the null page."""
+    bs, ring = scfg.block_size, scfg.table_widths[0]
+    w, r = n // ev.window, n % ev.window
+    n_sum = ((ev.summaries // bs) * w)[..., None]     # summary pages listed
+    j = jnp.arange(width, dtype=jnp.int32)
+    entry = jnp.where(j < n_sum, ring + j, j - n_sum)
+    counts = j < n_sum + (r[..., None] + bs - 1) // bs
+    pages = jnp.take_along_axis(
+        table, jnp.clip(entry, 0, table.shape[-1] - 1), axis=-1)
+    return (jnp.where(counts, pages, NULL_BLOCK),
+            (ev.summaries * w + r).astype(jnp.int32))
+
+
+def eva_decode_indices(ev, scfg: ServingConfig, tables, lengths):
+    """Where a decode step reads and writes, the same for every layer.
+    Slot i's new token sits at position ``t = lengths[i]``: its key and
+    value go to row ``t mod window`` of the reused pages; it reads the
+    list of ``eva_page_list``; where it completes a chunk (``(t + 1) mod
+    chunk == 0``) the chunk's summary goes to row ``t // chunk`` of the
+    summary pages (the null page's where it completes none)."""
+    bs, ring = scfg.block_size, scfg.table_widths[0]
+    t = lengths
+    one = lambda i: jnp.take_along_axis(tables, i[:, None], 1)[:, 0]
+    r = t % ev.window
+    pages, count = eva_page_list(ev, scfg, tables, t, tables.shape[1])
+    completes = (t + 1) % ev.chunk == 0
+    s = t // ev.chunk
+    return {"page": one(r // bs), "row": r % bs, "group": (r % bs) // ev.chunk,
+            "pages": pages, "count": count,
+            "s_page": jnp.where(completes, one(ring + s // bs), NULL_BLOCK),
+            "s_row": s % bs}
+
+
+def write_eva_decode(ev, k_pool, v_pool, at, k_rows, v_rows, mu, phi):
+    """A decode step's new keys and values (n, N, H, Dh) of all eva
+    layers into the slots' reused pages at ``at`` (``eva_decode_indices``)
+    and, from the page as it then stands, the summary of the chunk the new
+    token lies in into the summary row it completes (the null page's where
+    it completes none). mu, phi: (n, H, Dh). Whole pages are read, changed
+    and written back (``write_rows``)."""
+    from ..models.mixers import eva_summaries
+
+    c = ev.chunk
+    N = len(at["row"])
+    pick = (jnp.arange(k_pool.shape[3] // c)[None, :]
+            == at["group"][:, None])[None, :, None, :, None, None]
+
+    def lay(pool, rows):
+        """The pool with the new rows in, and the new token's chunk: c
+        rows of its page, position-major (n, N, c, H, Dh)."""
+        page = lay_rows(pool[:, at["page"]], at["row"], rows)
+        n, _, H, bs, Dh = page.shape
+        grp = jnp.sum(jnp.where(pick, page.reshape(n, N, H, bs // c, c, Dh),
+                                0), 3)
+        return pool.at[:, at["page"]].set(page), jnp.moveaxis(grp, 3, 2)
+
+    k_pool, kc = lay(k_pool, k_rows)
+    v_pool, vc = lay(v_pool, v_rows)
+    sk, sv = eva_summaries(kc, vc, mu[:, None, None], phi[:, None, None])
+    return (write_rows(k_pool, at["s_page"], at["s_row"], sk),
+            write_rows(v_pool, at["s_page"], at["s_row"], sv))
+
+
+def eva_chunk_past(ev, scfg: ServingConfig, C: int) -> int:
+    """Entries of a prompt chunk's list of past pages: every summary page
+    a slot may hold and the window's pages before a chunk of C."""
+    return scfg.table_widths[1] + (ev.window - C) // scfg.block_size
+
+
+def write_eva_chunk(ev, scfg: ServingConfig, k_pool, v_pool, table_row,
+                    offset, n_valid, kk, vv, mu, phi):
+    """A prompt chunk's keys and values (n, C, H, Dh) of all eva layers
+    into the window's pages from row ``offset mod window`` on (whole
+    pages; a chunk never straddles a window) and the summaries of its C /
+    chunk chunks into the summary rows from ``offset / chunk`` on, zeros
+    for a chunk not wholly below ``n_valid`` (the decode steps that
+    complete it write its summary); ONE scatter of whole pages a pool
+    (a scatter of one page alone has the TPU compiler re-lay the whole
+    pool out). mu, phi: (n, H, Dh)."""
+    from ..models.mixers import eva_summaries
+
+    n, C, H, Dh = kk.shape
+    bs, ring, c = scfg.block_size, scfg.table_widths[0], ev.chunk
+    ns = C // c
+    chunks = lambda t: t.reshape(n, ns, c, H, Dh)
+    sk, sv = eva_summaries(chunks(kk), chunks(vv), mu[:, None, None],
+                           phi[:, None, None])               # (n, ns, H, Dh)
+    whole = (jnp.arange(ns) < n_valid // c)[None, :, None, None]
+    first = offset // c
+    pages = lambda t: jnp.swapaxes(t.reshape(n, -1, bs, H, Dh), 2, 3)
+    ids = jnp.concatenate([
+        jax.lax.dynamic_slice(table_row, (offset % ev.window // bs,),
+                              (C // bs,)),
+        jax.lax.dynamic_slice(table_row, (ring + first // bs,),
+                              (max(ns // bs, 1),))])
+
+    def write(pool, rows, summaries):
+        summaries = jnp.where(whole, summaries, 0).astype(pool.dtype)
+        if ns % bs == 0:
+            new = pages(summaries)
+        else:       # a part of one page: read, change, write back
+            new = jax.lax.dynamic_update_slice(
+                pool[:, ids[-1:]], jnp.swapaxes(summaries, 1, 2)[:, None],
+                (0, 0, 0, first % bs, 0))
+        return pool.at[:, ids].set(jnp.concatenate([pages(rows), new], 1))
+
+    return write(k_pool, kk, sk), write(v_pool, vv, sv)

@@ -147,6 +147,17 @@ class ServingMetrics:
         self.kv_view_pages = 0
         # of the live pages, those a block-sparse layer's selection names
         self.kv_selected_pages = 0
+        # a cache that reuses its pages behind a window: the pages the
+        # live slots HELD, summed over plain decode steps (exact keys of
+        # the window and summaries apart) and the rows those steps ran
+        # for them; summary rows written by decode steps and by prompt
+        # chunks; slots whose window's pages started over at a decode step
+        self.kv_window_pages = 0
+        self.kv_summary_pages = 0
+        self.kv_held_rows = 0
+        self.summary_rows_decode = 0
+        self.summary_rows_chunk = 0
+        self.window_wraps = 0
         # host-to-device placements made for the plain decode program's
         # slot inputs, and the dispatches they were made for
         self.decode_placements = 0
@@ -333,6 +344,24 @@ class ServingMetrics:
         self.kv_view_pages += view_pages
         self.kv_selected_pages += selected_pages
 
+    def record_window_pages(self, rows: int, window_pages: int,
+                            summary_pages: int, summary_rows: int,
+                            wraps: int) -> None:
+        """One plain decode step over a cache that reuses pages behind a
+        window: its ``rows`` live slots held ``window_pages`` pages of
+        exact keys and ``summary_pages`` of summaries; ``summary_rows``
+        of them completed a chunk (a summary row written) and ``wraps``
+        started their window's pages over."""
+        self.kv_held_rows += rows
+        self.kv_window_pages += window_pages
+        self.kv_summary_pages += summary_pages
+        self.summary_rows_decode += summary_rows
+        self.window_wraps += wraps
+
+    def record_chunk_summaries(self, rows: int) -> None:
+        """Summary rows a prompt chunk wrote (its whole chunks)."""
+        self.summary_rows_chunk += rows
+
     def record_decode_placements(self, placements: int) -> None:
         """One dispatch of the plain decode program, and how many
         host-to-device placements its slot inputs took."""
@@ -444,6 +473,14 @@ class ServingMetrics:
             "kv_selected_page_frac": (
                 self.kv_selected_pages / self.kv_live_pages
                 if self.kv_live_pages else 0.0),
+            "kv_pages_per_slot": {
+                "window": (self.kv_window_pages / self.kv_held_rows
+                           if self.kv_held_rows else 0.0),
+                "summary": (self.kv_summary_pages / self.kv_held_rows
+                            if self.kv_held_rows else 0.0)},
+            "summary_rows": {"decode": int(self.summary_rows_decode),
+                             "chunk": int(self.summary_rows_chunk)},
+            "window_wraps": int(self.window_wraps),
             "decode_placements_per_step": (
                 self.decode_placements / self.decode_dispatches
                 if self.decode_dispatches else 0.0),

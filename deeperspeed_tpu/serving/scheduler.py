@@ -9,10 +9,16 @@ greedy-parity test meaningful):
     when a slot is free AND the allocator can cover its context plus one
     decode write; otherwise admission stops (backpressure — the request
     STAYS QUEUED, nothing crashes).
+  * How many pages a number of positions needs is the CACHE's rule
+    (``ServingConfig.page_rule``, set by the engine from the model):
+    ``ceil(n / block_size)`` where a position keeps its row for ever, a
+    count that stops following the length where a layer reuses its pages
+    behind a window. Admission, growth, the worst case and the table's
+    width all ask it; a slot's pages are kept role by role.
   * Blocks are allocated incrementally: admission covers the prompt, and
-    each time a slot's next write would cross a block boundary the
-    scheduler allocates one more block. No request ever reserves
-    max_seq_len worth of cache up front.
+    each time a slot's next write needs a page the slot does not hold
+    the scheduler allocates it. No request ever reserves max_seq_len
+    worth of cache up front.
   * When the pool cannot cover a mid-decode extension, the MOST RECENTLY
     admitted slot is preempted: its blocks are freed and the request goes
     back to the FRONT of the queue carrying its generated tokens, so
@@ -32,8 +38,7 @@ from typing import Callable, Deque, List, Optional, Tuple
 from ..monitor.tracer import trace_instant
 from ..utils.logging import logger
 from .config import ServingConfig
-from .kv_cache import NULL_BLOCK, BlockAllocator, PrefixCache, \
-    blocks_needed
+from .kv_cache import NULL_BLOCK, BlockAllocator, PrefixCache
 
 QUEUED = "queued"
 ACTIVE = "active"
@@ -125,7 +130,14 @@ class Scheduler:
         self.clock = clock
         self.queue: Deque[Request] = deque()
         self.slots: List[Optional[Request]] = [None] * scfg.num_slots
+        # a slot's pages, role by role in the order of the cache's rule
+        # (one role for a cache whose pages follow the length), and how
+        # many of them each role has
         self.slot_blocks: List[List[int]] = [[] for _ in range(scfg.num_slots)]
+        self.slot_roles: List[List[int]] = [[] for _ in range(scfg.num_slots)]
+        # a slot's table: each role's stretch of entries, and their sum
+        self._widths = scfg.table_widths
+        self._table_width = scfg.blocks_per_slot
         self._admit_seq = itertools.count()   # admission order, for victims
         self._slot_admitted_at = [-1] * scfg.num_slots
         self.finished: List[Request] = []
@@ -151,7 +163,7 @@ class Scheduler:
         # worst-case footprint (full context + one decode-write of
         # headroom) must fit an EMPTY pool, else the request could never
         # admit and the engine would spin forever on backpressure
-        worst = blocks_needed(ctx_cap, self.scfg.block_size)
+        worst = self.scfg.pages_needed(ctx_cap)
         if worst > self.allocator.num_blocks - 1:
             raise ValueError(
                 f"request {req.rid}: worst-case footprint ({worst} blocks "
@@ -213,8 +225,8 @@ class Scheduler:
             self.allocator.ref(partial[0])
         # +1: headroom for the first decode write, so a freshly admitted
         # request cannot be preempted before its first step
-        need = blocks_needed(len(req.context) + 1, self.scfg.block_size)
-        private = self.allocator.alloc(need - len(full))
+        want = self.scfg.pages_by_role(len(req.context) + 1)
+        private = self.allocator.alloc(sum(want) - len(full))
         if private is None:
             if full:
                 self.allocator.free(full)
@@ -235,6 +247,7 @@ class Scheduler:
         req.prefix_src = partial
         self.slots[slot] = req
         self.slot_blocks[slot] = blocks
+        self.slot_roles[slot] = list(want)
         self._slot_admitted_at[slot] = next(self._admit_seq)
         trace_instant("serving/admit", lane="serving", rid=req.rid,
                       slot=slot, ctx_len=req.cached_len,
@@ -271,22 +284,23 @@ class Scheduler:
         preemption: an engine with a step's tokens unread appends them
         first, so a preempted request is requeued with ``generated``
         whole, and whatever that finishes gives its blocks back."""
-        cap = self.scfg.blocks_per_slot * self.scfg.block_size
+        cap = self.scfg.slot_positions
         preempted: List[Request] = []
         for slot in range(self.scfg.num_slots):
             while True:
                 req = self.slots[slot]
                 if req is None or req.remaining <= req.in_flight:
                     break
-                need = blocks_needed(min(req.cached_len + tokens, cap),
-                                     self.scfg.block_size)
-                short = need - len(self.slot_blocks[slot])
-                if short <= 0:
+                want = self.scfg.pages_by_role(
+                    min(req.cached_len + tokens, cap))
+                short = [max(w - h, 0)
+                         for w, h in zip(want, self.slot_roles[slot])]
+                if not any(short):
                     break
-                extra = self.allocator.alloc(short)
+                extra = self.allocator.alloc(sum(short))
                 if extra is not None:
                     self._accrue_kv(slot)
-                    self.slot_blocks[slot].extend(extra)
+                    self._extend(slot, short, extra)
                     break
                 if settle is not None:
                     settle()
@@ -297,6 +311,18 @@ class Scheduler:
                 # if we preempted THIS slot, the inner while re-checks and
                 # finds it empty; otherwise retry the alloc
         return preempted
+
+    def _extend(self, slot: int, short: List[int], extra: List[int]) -> None:
+        """Give the slot ``short[r]`` more pages of role r out of
+        ``extra``, each behind the pages of its role."""
+        blocks, roles = self.slot_blocks[slot], self.slot_roles[slot]
+        end = 0
+        for r, n in enumerate(short):
+            end += roles[r]
+            blocks[end:end] = extra[:n]
+            extra = extra[n:]
+            roles[r] += n
+            end += n
 
     def _preempt_victim(self) -> int:
         victims = [s for s in range(self.scfg.num_slots)
@@ -331,6 +357,7 @@ class Scheduler:
     def _release_slot(self, slot: int) -> None:
         self.allocator.free(self.slot_blocks[slot])
         self.slot_blocks[slot] = []
+        self.slot_roles[slot] = []
         self.slots[slot] = None
         self._slot_admitted_at[slot] = -1
 
@@ -412,7 +439,12 @@ class Scheduler:
     # ---------------------------------------------------------------- #
 
     def slot_table_row(self, slot: int) -> List[int]:
+        """The slot's table: each role's pages in its own stretch of
+        entries (``ServingConfig.table_widths``), null pages after."""
         blocks = self.slot_blocks[slot]
-        pad = self.scfg.blocks_per_slot - len(blocks)
-        assert pad >= 0, (slot, blocks)
-        return blocks + [NULL_BLOCK] * pad
+        row, at = [], 0
+        for have, width in zip(self.slot_roles[slot] or [0], self._widths):
+            assert have <= width, (slot, blocks, self.slot_roles[slot])
+            row += blocks[at:at + have] + [NULL_BLOCK] * (width - have)
+            at += have
+        return row + [NULL_BLOCK] * (self._table_width - len(row))

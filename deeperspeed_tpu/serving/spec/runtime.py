@@ -35,8 +35,7 @@ from ...models.generation import apply_with_cache
 from ...models.gpt import GPTConfig
 from ...utils.logging import logger
 from ..config import ServingConfig, SpeculativeConfig
-from ..kv_cache import NULL_BLOCK, PagedKVCache, PrefixCache, \
-    blocks_needed
+from ..kv_cache import NULL_BLOCK, PagedKVCache, PrefixCache
 from ...monitor.tracer import RID_SEP, trace_instant, trace_span
 from .steps import make_draft_step, make_verify_step
 
@@ -169,7 +168,7 @@ class SpecRuntime:
                 self._release(s)
 
     def _ensure_blocks(self, slot: int, want_tokens: int) -> bool:
-        need = blocks_needed(want_tokens, self.eng.scfg.block_size) \
+        need = self.eng.scfg.pages_needed(want_tokens) \
             - len(self.slot_blocks[slot])
         if need <= 0:
             return True
@@ -217,7 +216,7 @@ class SpecRuntime:
             start = m
         if not self._ensure_blocks(slot, c):
             return False
-        n_pages = blocks_needed(c, bs)
+        n_pages = scfg.pages_needed(c)
         if start < c:
             suf = ctx[start:c]
             pad = scfg.bucket_for(len(suf))
@@ -333,7 +332,7 @@ class SpecRuntime:
             if (req.cached_len + K + 1 <= cap
                     and req.remaining > 1
                     and len(eng.sched.slot_blocks[s])
-                    >= blocks_needed(req.cached_len + K + 1, bs)
+                    >= scfg.pages_needed(req.cached_len + K + 1)
                     and self._sync_slot(s, req)):
                 spec_lanes.append((s, req))
             else:

@@ -321,3 +321,90 @@ def test_falcon_h1_programs_move_neither_pool_rows_nor_a_weight_stack(
     assert weights + caches < ask < weights + caches + 2 ** 29, (
         ask, weights, caches)
     assert ask < 12.9 * 2 ** 30
+
+
+# ------------------------------------------------------------------ #
+# EvaByte: the page-list kernel at one query a key head, and the two
+# serving programs at the cell's size
+# ------------------------------------------------------------------ #
+
+
+def _evabyte():
+    from benchmark import manifest as mf
+    from benchmark.adapters import evabyte as adapter
+    from deeperspeed_tpu.serving import ServingConfig
+    from deeperspeed_tpu.serving.kv_cache import page_rule_for
+
+    man = mf.Manifest()
+    cfg = adapter.model_config(man.config("evabyte-6.5b"))
+    scfg = ServingConfig.from_dict(
+        man.workload_file("evabyte-6.5b.serve-bytes")["serving"])
+    return cfg, scfg.for_cache(page_rule_for(cfg))
+
+
+def test_page_list_kernel_compiles_at_one_query_a_key_head(one_chip):
+    """The byte cell's decode call: 16 slots x 32 key heads = 512 rows of
+    ONE query, each row's list the slot's 64 entries (summary pages, then
+    the window's): 128 KiB of lists in scalar memory."""
+    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import paged_sparse_attn
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    R, G, P, Dh = 512, 1, 64, 128
+    pool = sds((8, 1025, 32, 64, Dh))
+    f32 = jnp.float32
+    compiled = paged_sparse_attn.lower(
+        pool, pool, sds((), jnp.int32), sds((R, G, Dh)), sds((R,), jnp.int32),
+        sds((R, P), jnp.int32), sds((R,), jnp.int32), sds((R, G), f32),
+        sds((R, G), f32), sds((R, G, Dh), f32)).compile()
+    assert "paged_sparse_attn" in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_evabyte_programs_move_neither_the_pool_nor_a_weight_stack(
+        one_chip, as_if_on_tpu, program):
+    """The decode step and the prompt-chunk program of the byte cell (8
+    layers at the published widths, 16 slots, 1,025 pages of 64 rows x 32
+    heads): the donated pools are outputs in place, nothing copies a pool
+    or a stack of weights, the table is 64 entries wide, and the
+    compiler's ask stays inside the chip."""
+    from deeperspeed_tpu.models import mixers
+    from deeperspeed_tpu.serving.engine import (make_chunk_step,
+                                                make_decode_step)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg, scfg = _evabyte()
+    params = jax.tree.map(
+        lambda a: sds(a.shape),
+        jax.eval_shape(lambda: mixers.init_params(jax.random.PRNGKey(0), cfg)))
+    N, nb, bps = scfg.num_slots, scfg.num_blocks, scfg.blocks_per_slot
+    assert (N, nb, bps, scfg.table_widths) == (16, 1025, 64, (32, 32))
+    i32 = jnp.int32
+    pool = sds((8, nb, 32, 64, 128))
+    if program == "decode":
+        compiled = make_decode_step(cfg, scfg).lower(
+            params, pool, pool, sds(*_idle_slots(N, bps)), sds((N,), i32),
+            None, None).compile()
+    else:
+        compiled = make_chunk_step(cfg, scfg).lower(
+            params, pool, pool, None, None, sds((1, 1024), i32),
+            sds((bps,), i32), sds((), i32), sds((), i32), sds((), i32)).compile()
+    text = compiled.as_text()
+    # the decode step reads the two-role list through the page-list
+    # kernel; the chunk attends over its gathered past in XLA
+    assert runs_kernel(text, "paged_sparse_attn") == (program == "decode")
+    assert count_alias_pairs(text) == 2        # k, v
+    big = ("bf16[8,1025,32,64,128]", "bf16[8,4096,", "bf16[8,11008,")
+    moved = [ln.strip()[:140] for ln in text.splitlines()
+             if " copy(" in ln and ln.split(" = ")[1].startswith(big)]
+    assert moved == []
+    weights = sum(2 * math.prod(a.shape) for a in jax.tree.leaves(params))
+    assert weights == 2 * 1_630_932_992
+    caches = 2 * 2 * math.prod(pool.shape)
+    ask = extract_memory_analysis(compiled)["peak_bytes"]
+    assert weights + caches < ask < weights + caches + 2 ** 30, (
+        ask, weights, caches)
+    assert ask < 12.5 * 2 ** 30
