@@ -679,6 +679,59 @@ def select_blocks(b, q_block, sp: SparseAttnConfig):
     return blocks, jnp.arange(sp.topk, dtype=jnp.int32) < n[..., None]
 
 
+def forced_mask(M: int, q_block, sp: SparseAttnConfig):
+    """The blocks ``selection_mask`` takes whatever they score: the first
+    ``init_blocks`` and the ``local_blocks`` that end at the query's own.
+    q_block: (R,) -> (R, 1, M) bool."""
+    m = jnp.arange(M, dtype=jnp.int32)[None, None, :]
+    bt = q_block[:, None, None]
+    return (m < sp.init_blocks) | ((m <= bt) & (m > bt - sp.local_blocks))
+
+
+def forced_count(q_block: int, sp: SparseAttnConfig) -> int:
+    """How many blocks ``forced_mask`` sets for a query in block
+    ``q_block``: an initial block that is also local counts once."""
+    return len(set(range(sp.init_blocks)) | set(
+        range(max(q_block - sp.local_blocks + 1, 0), q_block + 1)))
+
+
+def chosen_width(sp: SparseAttnConfig) -> int:
+    """The most blocks a query beyond ``dense_len`` can select that are
+    not forced: ``topk`` less the fewest forced blocks such a query has
+    (its block is ``dense_len / block_size`` or later)."""
+    return sp.topk - forced_count(sp.dense_len // sp.block_size, sp)
+
+
+def select_chosen(b, q_block, sp: SparseAttnConfig, width: int):
+    """``selection_mask`` less the forced blocks, as a list ``width`` wide
+    (``chosen_width`` or more) in ascending order, the entries that count
+    first: what of a query's selection differs from its neighbours'. Every
+    one is a whole block, ``local_blocks`` or more before the query's own.
+    -> (blocks (R, Hkv, width) int32, n (R, Hkv) how many count)."""
+    return compact(selection_mask(b, q_block, sp)
+                   & ~forced_mask(b.shape[-1], q_block, sp), width)
+
+
+def forced_past(before, q_block, sp: SparseAttnConfig):
+    """The forced blocks of a prompt chunk's queries that lie before the
+    chunk, which starts at block ``before`` (traced) and holds at most
+    ``local_blocks`` blocks: the initial blocks and the ``local_blocks -
+    1`` that end at ``before - 1``, one run shared by every query. A
+    local block ``b`` counts for a query in block ``bt`` iff ``bt -
+    local_blocks < b``, an initial block if it lies before the chunk, a
+    block that is both as an initial one. q_block: (R,) -> (blocks (F,)
+    int32, negative where the run starts before the sequence; sees (R, F)
+    bool)."""
+    init = jnp.arange(sp.init_blocks, dtype=jnp.int32)
+    local = before - (sp.local_blocks - 1) \
+        + jnp.arange(sp.local_blocks - 1, dtype=jnp.int32)
+    bt = q_block[:, None]
+    sees = jnp.concatenate([
+        jnp.broadcast_to(init < before, (len(q_block), sp.init_blocks)),
+        (local >= sp.init_blocks) & (local > bt - sp.local_blocks)], 1)
+    return jnp.concatenate([init, local]), sees
+
+
 def visible_windows(q_pos, J: int, sp: SparseAttnConfig):
     """(R, J): window j (tokens j st .. j st + ks - 1) lies wholly at or
     before the query's position."""

@@ -1181,6 +1181,21 @@ class ServingEngine(_ServingBase):
         return (self.cfg.count("minicpm4") * self.cfg.kv_heads
                 * (C * sp.topk - sp.block_size * n * (n + 1) // 2))
 
+    def chunk_pages_listed(self, offset: int) -> int:
+        """Of the pages ``chunk_pages_read`` counts, those the rows' LISTS
+        name: the chosen ones. The forced pages before the chunk are the
+        same for all its queries and are gathered once
+        (``kv_cache.sparse_chunk_attend``)."""
+        sp = self.cfg.sparse
+        if sp is None or offset < sp.dense_len:
+            return 0
+        bs = sp.block_size
+        first = offset // bs
+        n = prefill_chunk_for(self.cfg, self.scfg) // bs
+        return (self.cfg.count("minicpm4") * self.cfg.kv_heads * bs
+                * sum(sp.topk - mixers.forced_count(bt, sp)
+                      for bt in range(first, first + n)))
+
     def _forward_chunk(self, slot: int, state: dict) -> None:
         """One chunk of a mixed stack's prompt through ``ds_prefill_chunk``:
         pages, pooled keys and the slot's state row are written in place;
@@ -1189,13 +1204,14 @@ class ServingEngine(_ServingBase):
         lo = c * C
         hi = min(lo + C, state["L"])
         final = (c + 1) == state["n"]
+        named, listed = self.chunk_pages_read(lo), self.chunk_pages_listed(lo)
         # serving/prefill: a request's prompt work inside one step, as for
         # every model; the chunk inside it says where in the prompt it is
         with trace_span("serving/prefill", lane="serving", rid=req.rid,
                         slot=slot, ctx_len=state["L"], bucket=C), \
                 trace_span("serving/prefill_chunk", lane="serving",
                            rid=req.rid, chunk=c, tokens=hi - lo, offset=lo,
-                           pages=self.chunk_pages_read(lo)):
+                           pages=named, listed_pages=f"{listed}/{named}"):
             with trace_span("serving/prefill/pack", lane="serving"):
                 toks = np.zeros((1, C), np.int32)
                 toks[0, :hi - lo] = state["ctx"][lo:hi]
@@ -1210,7 +1226,7 @@ class ServingEngine(_ServingBase):
                 self._end_prompt(slot, state, logits)
         self._prefill_spent += hi - lo
         self._chunk_ran = True
-        self.metrics.record_prefill_chunk(hi - lo)
+        self.metrics.record_prefill_chunk(hi - lo, named, listed)
         if lo == 0 and self.kv.state is not None:
             # the first chunk entered the slot's state rows as zeros
             self.metrics.record_state_reset()

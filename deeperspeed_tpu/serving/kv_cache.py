@@ -824,14 +824,16 @@ def sparse_decode_attend(sp, k_pool, v_pool, kc_pool, layer, q, k_row,
     return ctx.reshape(N, 1, H, Dh), kbar_new
 
 
-def _own_chunk_init(qg, k, v):
-    """(m, l, acc) of every query of a prompt chunk over the chunk's own
-    keys up to itself. qg: (C, Hkv, G, Dh); k, v: (C, Hkv, Dh)."""
-    C, Dh = qg.shape[0], qg.shape[-1]
+def _keys_init(qg, k, v, sees=None):
+    """(m, l, acc) of queries over the keys each one sees, at least one.
+    qg: (T, Hkv, G, Dh); k, v: (K, Hkv, Dh); sees: (T, K) bool, or None
+    for a prompt chunk over its own keys, each query up to itself."""
+    T, Dh = qg.shape[0], qg.shape[-1]
     s = jnp.einsum("qhgd,khd->qhgk", qg, k,
                    preferred_element_type=jnp.float32) * (1.0 / math.sqrt(Dh))
-    causal = jnp.arange(C)[None, :] <= jnp.arange(C)[:, None]
-    s = jnp.where(causal[:, None, None, :], s, -1e30)
+    if sees is None:
+        sees = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+    s = jnp.where(sees[:, None, None, :], s, -1e30)
     m = jnp.max(s, -1)
     p = jnp.exp(s - m[..., None])
     acc = jnp.einsum("qhgk,khd->qhgd", p.astype(v.dtype), v,
@@ -854,7 +856,7 @@ def chunk_attend_all(k_pool, v_pool, layer, q, k, v, table_row, offset,
     past = lambda pool: jnp.swapaxes(
         pool[layer, table_row[:n_past]], 0, 1).reshape(Hkv, n_past * bs, Dh)
     kp, vp = past(k_pool), past(v_pool)
-    m0, l0, acc0 = _own_chunk_init(qg, k, v)
+    m0, l0, acc0 = _keys_init(qg, k, v)
     live = jnp.arange(n_past * bs) < offset
 
     def tile(a):
@@ -898,21 +900,39 @@ def decode_attend_all(k_pool, v_pool, layer, q, k_row, v_row, tables,
     return ctx.reshape(N, 1, H, Dh)
 
 
+def chosen_list_width(sp) -> int:
+    """Width of a prompt-chunk row's list of chosen pages:
+    ``mixers.chosen_width`` (31 as published), taken up to whole copies of
+    the kernel, which moves a list the largest divisor of its width up to
+    512 tokens of pages at a time: 31 pages would cross one by one."""
+    from ..models.mixers import chosen_width
+    from ..ops.pallas.paged_sparse_attn import _CHUNK_TOKENS
+
+    most = max(1, _CHUNK_TOKENS // sp.block_size)
+    width = max(1, chosen_width(sp))
+    return width if width <= most else -(-width // most) * most
+
+
 def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
                         table_row, offset, attend_pages):
     """One minicpm4 layer's attention for a prompt chunk of C tokens at
-    positions ``offset ..`` (traced; a multiple of C) of one slot, whose
-    earlier pages are in the pool. q: (C, H, Dh); k, v: (C, Hkv, Dh) in
-    the pool's dtype. While the chunk ends inside ``dense_len`` every
-    query attends to all its past; beyond it every query selects: the
-    chunk's own keys (all inside each query's local window) densely, the
-    pages of the past through ``attend_pages``, as ``mixers.page_list``
-    lists them: the selected blocks before the chunk in ascending order,
-    all of them whole. A row's pages meet one online softmax, so their
-    order decides the rounding of its sums and nothing else, and the
-    kernel asks only that every entry name a page. Returns (ctx (C, H, Dh),
-    the pooled keys of the C / st windows this chunk completes, the first
-    of them starting st tokens before the chunk: (C / st, Hkv, Dh))."""
+    positions ``offset ..`` (traced; a multiple of C, which divides
+    ``dense_len``) of one slot, whose earlier pages are in the pool. q:
+    (C, H, Dh); k, v: (C, Hkv, Dh) in the pool's dtype. While the chunk
+    ends inside ``dense_len`` every query attends to all its past; beyond
+    it every query selects, and its selection is read in two parts. What
+    is FORCED is the same run of the slot's table for every query of the
+    chunk (``mixers.forced_past``: the initial blocks and the local
+    blocks before the chunk): those pages are gathered once and attended
+    densely beside the chunk's own keys (all inside each query's local
+    window), a tile of queries at a time. What is CHOSEN differs from row
+    to row and goes through ``attend_pages`` as ``mixers.select_chosen``
+    lists it: whole blocks before the chunk in ascending order. A row's
+    keys meet one online softmax, so their order decides the rounding of
+    its sums and nothing else, and the kernel asks only that every entry
+    name a page. Returns (ctx (C, H, Dh), the pooled keys of the C / st
+    windows this chunk completes, the first of them starting st tokens
+    before the chunk: (C / st, Hkv, Dh))."""
     from ..models import mixers as mx
 
     C, H, Dh = q.shape
@@ -928,11 +948,26 @@ def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
         jnp.concatenate([jnp.swapaxes(before, 0, 1)[bs - st:], k], 0), sp)
     qg = q.reshape(C, Hkv, G, Dh)
 
-    own_keys = lambda: _own_chunk_init(qg, k, v)
-
     def dense():
         return chunk_attend_all(k_pool, v_pool, layer, q, k, v, table_row,
                                 offset, min(sp.dense_len // bs, bps))
+
+    def forced_and_own():
+        """(m, l, acc) of every query over the forced pages before the
+        chunk and the chunk's own keys up to itself."""
+        blocks, sees = mx.forced_past(offset // bs, q_pos // bs, sp)
+        ids = table_row[jnp.maximum(blocks, 0)]
+        keys = lambda pool, own: jnp.concatenate(
+            [jnp.swapaxes(pool[layer, ids], 1, 2).reshape(-1, Hkv, Dh), own])
+        kf, vf = keys(k_pool, k), keys(v_pool, v)
+        sees = jnp.concatenate(
+            [jnp.repeat(sees, bs, axis=1),
+             jnp.arange(C)[None, :] <= jnp.arange(C)[:, None]], 1)
+        tq = min(C, 256)
+        return jax.tree.map(
+            lambda a: a.reshape(C, *a.shape[2:]),
+            jax.lax.map(lambda a: _keys_init(a[0], kf, vf, a[1]), jax.tree.map(
+                lambda a: a.reshape(C // tq, tq, *a.shape[1:]), (qg, sees))))
 
     def sparse():
         J = bps * w
@@ -940,17 +975,14 @@ def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
             pooled_keys_of(kc_pool, layer, table_row, Hkv),    # (Hkv, J, Dh)
             jnp.swapaxes(kbar_new, 0, 1), (0, offset // st - 1, 0))
         b = mx.block_scores(q, kbar, mx.visible_windows(q_pos, J, sp), sp)
-        blocks, valid = mx.select_blocks(b, q_pos // bs, sp)
-        blk, n = mx.page_list(blocks, valid, q_pos, sp, sp.topk,
-                              before=offset // bs)
-        pages = pages_of(table_row, blk)                       # (C, Hkv, K)
-        n_tokens = n * bs
+        blk, n = mx.select_chosen(b, q_pos // bs, sp, chosen_list_width(sp))
+        pages = pages_of(table_row, blk)                       # (C, Hkv, P)
         R = C * Hkv
-        m0, l0, acc0 = own_keys()
+        m0, l0, acc0 = forced_and_own()
         ctx = attend_pages(
             k_pool, v_pool, layer, q.reshape(R, G, Dh),
             jnp.tile(jnp.arange(Hkv), C), pages.reshape(R, -1),
-            n_tokens.reshape(R), m0.reshape(R, G), l0.reshape(R, G),
+            (n * bs).reshape(R), m0.reshape(R, G), l0.reshape(R, G),
             acc0.reshape(R, G, Dh))
         return ctx.reshape(C, H, Dh)
 

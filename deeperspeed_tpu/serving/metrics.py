@@ -192,6 +192,11 @@ class ServingMetrics:
         self.cow_splits = 0
         self.prefill_chunks = 0
         self.chunk_tokens = 0
+        # pool pages the selections of the sparse layers' prompt chunks
+        # named, and those of them the rows' lists named (the rest, forced
+        # for every query of a chunk, is gathered once a chunk)
+        self.chunk_named_pages = 0
+        self.chunk_listed_pages = 0
         # speculative decoding: per-round draft/accept accounting plus
         # the draft-vs-verify wall split (spec/runtime.decode_round)
         self.spec_rounds = 0
@@ -293,10 +298,15 @@ class ServingMetrics:
                 "serving_kv_cow_splits_total",
                 "Copy-on-write splits of shared boundary pages.").inc()
 
-    def record_prefill_chunk(self, tokens: int) -> None:
-        """One staged prompt-chunk forward (chunked/suffix prefill)."""
+    def record_prefill_chunk(self, tokens: int, named_pages: int = 0,
+                             listed_pages: int = 0) -> None:
+        """One staged prompt-chunk forward (chunked/suffix prefill);
+        ``named_pages``, ``listed_pages``: what its sparse layers'
+        selections and its rows' lists named of the pool."""
         self.prefill_chunks += 1
         self.chunk_tokens += tokens
+        self.chunk_named_pages += named_pages
+        self.chunk_listed_pages += listed_pages
         if self.registry is not None:
             self.registry.counter(
                 "serving_prefill_chunks_total",
@@ -490,6 +500,9 @@ class ServingMetrics:
             "decode_rows_discarded": int(self.decode_rows_discarded),
             "chunk_gap_share": (self.chunk_gaps / self.gaps
                                 if self.gaps else 0.0),
+            "chunk_listed_page_share": (
+                self.chunk_listed_pages / self.chunk_named_pages
+                if self.chunk_named_pages else 0.0),
             "state_bytes": int(self.state_bytes),
             "state_bytes_per_step": (self.state_bytes_moved
                                      / self.decode_steps

@@ -7,6 +7,7 @@ plain reference ``benchmark/refs/minicpm_sala.py`` on seeded weights, and
 the parts with each other."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -309,6 +310,13 @@ def test_counters_of_a_served_window(params):
     # beyond dense_len a chunk's selections name topk blocks less its own
     assert eng.chunk_pages_read(0) == eng.chunk_pages_read(48) == 0
     assert eng.chunk_pages_read(64) == 2 * 2 * (16 * 4 - 8 * 3)
+    # of those the rows' lists name the chosen ones: top-4 less 3 forced
+    assert eng.chunk_pages_listed(0) == eng.chunk_pages_listed(48) == 0
+    assert eng.chunk_pages_listed(64) == 2 * 2 * 16 * 1
+    sparse_chunks = (100 // 16 - 4 + 1) + (200 // 16 - 4 + 1)
+    assert eng.metrics.chunk_named_pages == sparse_chunks * 160
+    assert eng.metrics.chunk_listed_pages == sparse_chunks * 64
+    assert s["chunk_listed_page_share"] == pytest.approx(0.4)
 
 
 def test_sparse_config_is_validated():
@@ -390,6 +398,10 @@ SELECTOR_CASES = {
                                            None),
     "a_prompt_chunk": ("random", 24, list(range(128, 144)), 16),
     "a_chunk_with_ties": ("ties", 40, list(range(304, 320)), 38),
+    "a_chunk_whose_initial_block_is_local": ("random", 24,
+                                             list(range(8, 24)), 1),
+    "a_chunk_that_sees_few_windows": ("unseen", 40, list(range(304, 320)),
+                                      38),
 }
 
 
@@ -440,6 +452,140 @@ def test_the_selector_names_the_set_a_sort_would(case):
                 assert (counted < before).all()
     # an entry past the count: the callers clamp it from above only
     assert (blk >= 0).all()
+
+
+@pytest.mark.parametrize("case", SELECTOR_CASES)
+def test_forced_and_chosen_blocks_are_the_selection_split_in_two(case):
+    """``select_chosen`` lists what of ``selection_mask`` is not forced;
+    for a prompt chunk ``forced_past`` names the forced blocks before it,
+    the chunk's own blocks are the rest, and the three are the mask."""
+    kind, M, positions, before = SELECTOR_CASES[case]
+    sp, Hkv = SEL, 2
+    rng = np.random.default_rng(sum(map(ord, case)))
+    bt = np.asarray(positions) // sp.block_size
+    b = _scores(kind, len(positions), Hkv, M, rng)
+    width = sp.topk - sp.init_blocks - sp.local_blocks
+
+    @jax.jit
+    def split(b, bt):
+        return (mixers.selection_mask(b, bt, sp),
+                mixers.forced_mask(M, bt, sp)[:, 0],
+                mixers.select_chosen(b, bt, sp, width),
+                mixers.forced_past(0 if before is None else before, bt, sp))
+
+    mask, forced, (blocks, n), (fb, sees) = jax.tree.map(
+        np.asarray, split(b, jnp.asarray(bt)))
+    assert blocks.shape == (len(bt), Hkv, width) and n.dtype == np.int32
+    assert fb.shape == (sp.init_blocks + sp.local_blocks - 1,)
+    for r in range(len(bt)):
+        own = set(range(before, bt[r] + 1)) if before is not None else set()
+        for h in range(Hkv):
+            chosen = blocks[r, h, :n[r, h]]
+            assert (np.diff(chosen) > 0).all()              # ascending
+            assert not forced[r, chosen].any()              # disjoint
+            assert (blocks[r, h, n[r, h]:] == M).all()      # the null page
+            assert set(chosen) | set(np.flatnonzero(forced[r] & mask[r, h])) \
+                == set(np.flatnonzero(mask[r, h]))
+            assert n[r, h] == mask[r, h].sum() - (forced[r] & mask[r, h]).sum()
+            # every forced block that exists is selected, whatever it scores
+            assert (mask[r, h] | ~forced[r])[:bt[r] + 1].all()
+            if kind != "unseen" and bt[r] + 1 >= sp.topk + sp.local_blocks:
+                assert n[r, h] == width
+            if before is not None:
+                assert (chosen <= bt[r] - sp.local_blocks).all()
+                past = fb[sees[r]]
+                assert len(set(past)) == len(past) and (past >= 0).all()
+                assert set(past) | own == set(np.flatnonzero(
+                    forced[r, :bt[r] + 1]))
+                assert set(past) | own | set(chosen) == set(np.flatnonzero(
+                    mask[r, h, :bt[r] + 1]))
+    assert mixers.chosen_width(sp) == width == 5
+    assert mixers.chosen_width(WIDE) == sp.topk - 3      # blocks 0..2, once
+
+
+WIDE = dataclasses.replace(SEL, window_size=32, dense_len=16)
+# a chunk of 512 tokens is two tiles of queries to the forced pages' pass
+TILED = dataclasses.replace(SEL, topk=72, window_size=512, dense_len=512)
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk_world(sp, C, S, Hkv=2, H=4, Dh=16):
+    """A sequence's q, k, v, its attention by the definition, and the
+    chunk program's part at a traced offset over pools that hold EVERY
+    page and pooled key of the sequence on shuffled pages (what lies at
+    or after the chunk must not be read: it would count twice)."""
+    from deeperspeed_tpu.serving import kv_cache as kvc
+
+    bs, w, n = sp.block_size, sp.windows_per_block, S // sp.block_size
+    nb, L = n + 9, 2
+    table = jnp.asarray(np.random.default_rng(0).permutation(
+        np.arange(1, nb))[:n + 3], jnp.int32)
+
+    @jax.jit
+    def world(key):
+        ks = jax.random.split(key, 3)
+        q = jax.random.normal(ks[0], (S, H, Dh)) * 2.0
+        k = jax.random.normal(ks[1], (S, Hkv, Dh))
+        v = jax.random.normal(ks[2], (S, Hkv, Dh))
+        pages = lambda t: jnp.swapaxes(t.reshape(n, bs, Hkv, Dh), 1, 2)
+        pool = jnp.zeros((L, nb, Hkv, bs, Dh))
+        kbar = jnp.pad(mixers.pool_windows(k, sp), ((0, 1), (0, 0), (0, 0)))
+        kc = jnp.zeros((L, nb, Hkv * w, Dh)).at[1, table[:n]].set(jnp.swapaxes(
+            kbar.reshape(n, w, Hkv, Dh), 1, 2).reshape(n, -1, Dh))
+        return (q, k, v, pool.at[1, table[:n]].set(pages(k)),
+                pool.at[1, table[:n]].set(pages(v)), kc,
+                mixers.dense_sparse_attention(q, k, v, sp))
+
+    q, k, v, kp, vp, kc, want = world(jax.random.PRNGKey(0))
+    widths = []
+
+    def attend_pages(k_pool, v_pool, layer, q, row_head, pages, *rest):
+        widths.append(pages.shape)
+        return paged_sparse_attend_xla(k_pool, v_pool, layer, q, row_head,
+                                       pages, *rest)
+
+    @jax.jit
+    def chunk(offset):
+        cut = lambda t: jax.lax.dynamic_slice_in_dim(t, offset, C)
+        return kvc.sparse_chunk_attend(sp, kp, vp, kc, 1, cut(q), cut(k),
+                                       cut(v), table, offset, attend_pages)
+
+    return chunk, widths, k, want
+
+
+@pytest.mark.parametrize("sp,C,S,offset", [
+    pytest.param(SEL, 16, 192, 64, id="the_first_chunk_beyond_dense_len"),
+    pytest.param(SEL, 16, 192, 160, id="a_later_chunk"),
+    pytest.param(WIDE, 16, 192, 16, id="an_initial_block_that_is_local"),
+    pytest.param(WIDE, 16, 192, 96, id="a_wide_window_later"),
+    pytest.param(TILED, 512, 1536, 1024, id="two_tiles_of_queries")])
+def test_a_sparse_chunk_is_the_whole_sequence_form(sp, C, S, offset):
+    """``sparse_chunk_attend`` beyond ``dense_len`` (forced pages and own
+    keys densely, chosen pages through the lists) against the definition
+    over the whole sequence, and the width of the lists it hands on."""
+    from deeperspeed_tpu.serving.kv_cache import chosen_list_width
+
+    chunk, widths, k, want = _chunk_world(sp, C, S)
+    ctx, kbar_new = chunk(jnp.int32(offset))
+    np.testing.assert_allclose(np.asarray(ctx),
+                               np.asarray(want[offset:offset + C]), atol=2e-5)
+    st = sp.kernel_stride
+    np.testing.assert_allclose(
+        np.asarray(kbar_new),
+        np.asarray(mixers.pool_windows(k[offset - st:offset + C], sp)),
+        atol=1e-6)
+    assert widths == [(C * 2, chosen_list_width(sp))]       # one lowering
+    assert chosen_list_width(sp) == mixers.chosen_width(sp)
+
+
+def test_a_list_of_31_chosen_pages_is_32_wide():
+    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import _pages_per_chunk
+    from deeperspeed_tpu.serving.kv_cache import chosen_list_width
+
+    sp = SparseAttnConfig()
+    assert mixers.chosen_width(sp) == 31 and chosen_list_width(sp) == 32
+    assert _pages_per_chunk(32, sp.block_size) == 8
+    assert _pages_per_chunk(31, sp.block_size) == 1
 
 
 def test_pages_of_is_the_table_lookup_and_reads_the_null_page_past_it():

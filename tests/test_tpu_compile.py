@@ -136,11 +136,17 @@ def _sala():
     return cfg, scfg
 
 
-@pytest.mark.parametrize("R,P", [(24, 128), (2048, 64)],
-                         ids=["a_decode_step", "a_prompt_chunk"])
+@pytest.mark.parametrize("R,P", [(24, 128), (2048, 64), (2048, 32)],
+                         ids=["a_decode_step", "a_prompt_chunk_by_topk",
+                              "a_prompt_chunk_by_its_chosen_pages"])
 def test_paged_sparse_attn_compiles_at_the_longdoc_cells_geometry(
         one_chip, R, P):
-    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import paged_sparse_attn
+    """The chunk program lists a row's CHOSEN pages (32 wide, in calls of
+    1,024 rows); the 64-wide list is what it handed on until PR 36."""
+    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import (
+        paged_sparse_attn, rows_per_call)
+
+    assert rows_per_call(2048, 32) == 1024 and rows_per_call(2048, 64) == 512
 
     def sds(shape, dtype=BF16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
