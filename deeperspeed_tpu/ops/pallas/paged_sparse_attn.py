@@ -5,30 +5,43 @@ a slot, all key heads of a position side by side. A block-sparse layer
 (InfLLM-v2, the ``minicpm4`` mixer) names, for every query and key head, a
 list of pages that is the same size whatever the context, and has two key
 heads, which a pool with the heads last would pad to a tile of sixteen. So
-this kernel takes
+the pools here are ``(L, num_blocks, Hkv, bs, Dh)``, left in HBM
+(``pl.ANY``): one key head's page is one contiguous ``(bs, Dh)`` tile run,
+and a whole page, every key head of it, one contiguous ``(Hkv, bs, Dh)``
+run. A list is ``P`` physical pages, read in order, of which the first
+``n_tokens`` positions count; lists and counts are scalar prefetch, so a
+page's address is known before the page is needed. Whoever owns a list has
+attended over something already (the new token's own key in a decode step,
+the chunk's own keys in a prompt chunk) and brings it as a running
+maximum, sum and unnormalised output, so the result is one softmax over
+both. Two forms, by who owns a list:
 
-  * pools ``(L, num_blocks, Hkv, bs, Dh)``: one key head's page is one
-    contiguous ``(bs, Dh)`` tile run in HBM (``pl.ANY``);
-  * ROWS, not slots: a row is one query position's ``G`` heads that share
-    a key head (a decode step has ``slots * Hkv`` rows, a prompt chunk
-    ``tokens * Hkv``), with its own list of ``P`` physical pages, read in
-    order, of which the first ``n_tokens`` positions count; lists and
-    counts are scalar prefetch, so a page's address is known before the
-    page is needed;
-  * what each row has attended over already, as a running maximum, sum
-    and unnormalised output (the new token's own key in a decode step, the
-    chunk's own keys in a prompt chunk), so the result is one softmax over
-    both.
+  * ``paged_sparse_attn``, a ROW a list: a row is one query position's
+    ``G`` heads that share a key head (a decode step of a selecting layer
+    has ``slots * Hkv`` rows, a prompt chunk ``tokens * Hkv``). A copy is
+    one key head's page; a copy-chunk is ``pp`` of them, always whole, so
+    entries past the last that counts must still name a page of the pool.
+  * ``paged_sparse_attn_slots``, a SLOT a list: every key head of the slot
+    reads the same pages (``kv_cache.decode_attend_all``: a slot's whole
+    table, or its list of two roles), so a copy is a whole page, all key
+    heads in one DMA (512 KiB where the row form makes 32 copies of 16
+    KiB), and a page past the count is not copied at all. The slot's ``Hkv
+    * G`` queries meet a copy-chunk's keys in ONE product, every query
+    against every key head's keys, and a query keeps its own key head's
+    columns (the others are masked like the positions past the count, so
+    their probabilities are exact zeros): the MXU takes a key row a cycle
+    whichever query it is for, and at one query a key head this keeps the
+    products under the copy (stand-alone on a v5e at 16 slots x 32 key
+    heads: the copies alone 386 us a call, with the products 390).
 
-One grid step a row; a row's work is ``ceil(n_tokens / chunk)`` chunks of
-``pp`` whole pages copied to VMEM by double-buffered DMA, the next chunk
-(of this row, or the first of the next row that has any) in flight while
-this one is computed; K and V are read once, in the pool's dtype, into
-float32 accumulations (online softmax), the probabilities cast to the
-pool's dtype before they meet V, as the XLA form
-(serving/kv_cache.paged_sparse_attend_xla, this kernel's oracle) casts
-them. List entries past the last that counts must still name a page of
-the pool (a chunk is always copied whole).
+Both: one grid step a row or slot; its work is ``ceil(n_tokens / chunk)``
+copy-chunks brought to VMEM by double-buffered DMA, the next one (its own,
+or the first of the next row or slot that has any) in flight while this
+one is computed, so an idle one costs a grid step and no copy; K and V are
+read once, in the pool's dtype, into float32 accumulations (online
+softmax), the probabilities cast to the pool's dtype before they meet V,
+as the XLA form (serving/kv_cache.paged_sparse_attend_xla, the oracle of
+both) casts them.
 """
 
 import functools
@@ -71,26 +84,17 @@ def is_available(k_pool, n_head) -> bool:
             and n_head % Hkv == 0)
 
 
-def _kernel(layer_ref, head_ref, ntok_ref, pages_ref, q_ref, m_ref, l_ref,
-            acc_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, g_ref, *,
-            P, pp, sm_scale):
-    r = pl.program_id(0)
+def _walk_lists(r, ntok_ref, g_ref, for_each_copy, pos_of, q_ref, m_ref, l_ref,
+                acc_ref, o_ref, kbuf, vbuf, chunk, sm_scale):
+    """Grid step ``r``: the copy-chunks of row (or slot) ``r`` through the
+    online softmax. ``for_each_copy(row, c, buf, act)`` applies ``act`` to
+    every copy of row ``row``'s copy-chunk ``c`` into buffer ``buf``;
+    ``pos_of(Q)`` is, for each of the step's Q queries and each row of a
+    buffer, that row's position in the copy-chunk (int32 (Q, rows)). What
+    lies at ``n_tokens`` or beyond does not count. ``g_ref`` counts the
+    copy-chunks of the whole call: the buffers alternate across steps."""
     R = pl.num_programs(0)
-    bs = kbuf.shape[2]
-    chunk = pp * bs
-    layer = layer_ref[0]
-
-    def for_each_copy(row, c, buf, act):
-        head = head_ref[row]
-
-        def page_copies(p, _):
-            page = pages_ref[row * P + c * pp + p]
-            act(pltpu.make_async_copy(
-                k_hbm.at[layer, page, head], kbuf.at[buf, p], sems.at[0, buf]))
-            act(pltpu.make_async_copy(
-                v_hbm.at[layer, page, head], vbuf.at[buf, p], sems.at[1, buf]))
-
-        jax.lax.fori_loop(0, pp, page_copies, None)
+    Dh = kbuf.shape[-1]
 
     def start(row, c, buf):
         for_each_copy(row, c, buf, lambda cp: cp.start())
@@ -112,9 +116,8 @@ def _kernel(layer_ref, head_ref, ntok_ref, pages_ref, q_ref, m_ref, l_ref,
 
     n_tok = ntok_ref[r]
     n_chunks = pl.cdiv(n_tok, chunk)
-    q = q_ref[0]                                            # (G, Dh)
-    G = q.shape[0]
-    col = jax.lax.broadcasted_iota(jnp.int32, (G, chunk), 1)
+    q = q_ref[0]                                            # (Q, Dh)
+    pos = pos_of(q.shape[0])
 
     def chunk_body(c, carry):
         m, l, acc = carry
@@ -130,12 +133,12 @@ def _kernel(layer_ref, head_ref, ntok_ref, pages_ref, q_ref, m_ref, l_ref,
 
         for_each_copy(r, c, buf, lambda cp: cp.wait())
         g_ref[0] = g + 1
-        k = kbuf[buf].reshape(chunk, kbuf.shape[3])
-        v = vbuf[buf].reshape(chunk, vbuf.shape[3])
+        k = kbuf[buf].reshape(-1, Dh)
+        v = vbuf[buf].reshape(-1, Dh)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (G, chunk)
-        s = jnp.where(c * chunk + col < n_tok, s, NEG_INF)
+            preferred_element_type=jnp.float32) * sm_scale  # (Q, rows)
+        s = jnp.where(c * chunk + pos < n_tok, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -148,6 +151,31 @@ def _kernel(layer_ref, head_ref, ntok_ref, pages_ref, q_ref, m_ref, l_ref,
         0, n_chunks, chunk_body,
         (m_ref[0][:, :1], l_ref[0][:, :1], acc_ref[0]))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def _kernel(layer_ref, head_ref, ntok_ref, pages_ref, q_ref, m_ref, l_ref,
+            acc_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, g_ref, *,
+            P, sm_scale):
+    _, pp, bs, _ = kbuf.shape
+    chunk = pp * bs
+    layer = layer_ref[0]
+
+    def for_each_copy(row, c, buf, act):
+        head = head_ref[row]
+
+        def page_copies(p, _):
+            page = pages_ref[row * P + c * pp + p]
+            act(pltpu.make_async_copy(
+                k_hbm.at[layer, page, head], kbuf.at[buf, p], sems.at[0, buf]))
+            act(pltpu.make_async_copy(
+                v_hbm.at[layer, page, head], vbuf.at[buf, p], sems.at[1, buf]))
+
+        jax.lax.fori_loop(0, pp, page_copies, None)
+
+    _walk_lists(
+        pl.program_id(0), ntok_ref, g_ref, for_each_copy,
+        lambda G: jax.lax.broadcasted_iota(jnp.int32, (G, chunk), 1),
+        q_ref, m_ref, l_ref, acc_ref, o_ref, kbuf, vbuf, chunk, sm_scale)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -167,8 +195,7 @@ def paged_sparse_attn(k_pool, v_pool, layer, q, row_head, pages, n_tokens,
         row = lambda w: pl.BlockSpec((1, G, w), lambda r, *_: (r, 0, 0))
         hbm = pl.BlockSpec(memory_space=pl.ANY)
         return pl.pallas_call(
-            functools.partial(_kernel, P=P, pp=pp,
-                              sm_scale=1.0 / math.sqrt(Dh)),
+            functools.partial(_kernel, P=P, sm_scale=1.0 / math.sqrt(Dh)),
             name="paged_sparse_attn",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=4,
@@ -197,3 +224,114 @@ def paged_sparse_attn(k_pool, v_pool, layer, q, row_head, pages, n_tokens,
     out = jax.lax.map(call, jax.tree.map(
         lambda a: a.reshape(R // rows, rows, *a.shape[1:]), args))
     return out.reshape(R, G, Dh)
+
+
+# ------------------------------------------------------------------ #
+# a SLOT a list
+# ------------------------------------------------------------------ #
+
+_SLOT_VMEM = 4 * 2 ** 20       # K and V, two buffers each, of whole pages
+
+
+def _page_bytes(k_pool):
+    _, _, Hkv, bs, Dh = k_pool.shape
+    return Hkv * bs * Dh * k_pool.dtype.itemsize
+
+
+def _pages_per_slot_chunk(P, k_pool):
+    """Whole pages a copy-chunk of the slot form holds: what the four
+    buffers' room allows (2 pages of 512 KiB, 8 of 64 KiB), no more tokens
+    than the row form's, a divisor of the list."""
+    most = min(max(1, _SLOT_VMEM // (4 * _page_bytes(k_pool))),
+               max(1, _CHUNK_TOKENS // k_pool.shape[3]), P)
+    return next(p for p in range(most, 0, -1) if P % p == 0)
+
+
+def slots_available(k_pool, n_head, lists_shape) -> bool:
+    """Whether the compiled slot form can take this pool and lists of
+    ``lists_shape`` = (N, P): what ``is_available`` asks, a page that fits
+    a buffer, and every slot's list in scalar memory."""
+    return (is_available(k_pool, n_head)
+            and 4 * _page_bytes(k_pool) <= _SLOT_VMEM
+            and 4 * math.prod(lists_shape) <= _SMEM_BUDGET)
+
+
+def _slots_kernel(layer_ref, ntok_ref, pages_ref, q_ref, m_ref, l_ref,
+                  acc_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, g_ref, *,
+                  P, G, sm_scale):
+    _, pp, Hkv, bs, _ = kbuf.shape
+    chunk = pp * bs
+    layer = layer_ref[0]
+
+    def for_each_copy(slot, c, buf, act):
+        for p in range(pp):
+            @pl.when((c * pp + p) * bs < ntok_ref[slot])
+            def _():
+                page = pages_ref[slot * P + c * pp + p]
+                act(pltpu.make_async_copy(
+                    k_hbm.at[layer, page], kbuf.at[buf, p], sems.at[0, buf]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[layer, page], vbuf.at[buf, p], sems.at[1, buf]))
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        # a page that is not copied leaves what its buffer held: a
+        # probability of zero times that must be zero, so no buffer may
+        # start out holding what is not a number
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def pos_of(H):
+        """A buffer's rows are (page, key head, row of the page); those
+        of another key head than the query's lie past every count."""
+        shape = (H, pp * Hkv * bs)
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        head = (col // bs) % Hkv
+        own = (row >= head * G) & (row < (head + 1) * G)
+        return jnp.where(own, (col // (Hkv * bs)) * bs + col % bs, P * bs)
+
+    _walk_lists(
+        pl.program_id(0), ntok_ref, g_ref, for_each_copy, pos_of, q_ref,
+        m_ref, l_ref, acc_ref, o_ref, kbuf, vbuf, chunk, sm_scale)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_sparse_attn_slots(k_pool, v_pool, layer, q, pages, n_tokens,
+                            m0, l0, acc0, interpret=False):
+    """``paged_sparse_attend_xla`` for N slots whose key heads share the
+    slot's list: q (N, Hkv, G, Dh), pages (N, P), n_tokens (N,), m0 and l0
+    (N, Hkv, G), acc0 (N, Hkv, G, Dh) -> (N, Hkv, G, Dh), what the row
+    form gives for rows (slot, key head) that each carry the slot's list
+    and count."""
+    N, Hkv, G, Dh = q.shape
+    bs = k_pool.shape[3]
+    H, P = Hkv * G, pages.shape[1]
+    pp = _pages_per_slot_chunk(P, k_pool)
+    lanes = lambda a: jnp.broadcast_to(
+        a.astype(jnp.float32).reshape(N, H, 1), (N, H, LANES))
+    slot = lambda w: pl.BlockSpec((1, H, w), lambda n, *_: (n, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_slots_kernel, P=P, G=G,
+                          sm_scale=1.0 / math.sqrt(Dh)),
+        name="paged_sparse_attn_slots",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(N,),
+            in_specs=[slot(Dh), slot(LANES), slot(LANES), slot(Dh), hbm, hbm],
+            out_specs=slot(Dh),
+            scratch_shapes=[
+                pltpu.VMEM((2, pp, Hkv, bs, Dh), k_pool.dtype),
+                pltpu.VMEM((2, pp, Hkv, bs, Dh), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((N, H, Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), n_tokens.astype(jnp.int32),
+      pages.astype(jnp.int32).reshape(-1), q.reshape(N, H, Dh), lanes(m0),
+      lanes(l0), acc0.astype(jnp.float32).reshape(N, H, Dh), k_pool, v_pool)
+    return out.reshape(N, Hkv, G, Dh)

@@ -100,8 +100,10 @@ from .kv_cache import (
     eva_page_list,
     lightning_chunk_for,
     page_rule_for,
+    slot_attend_for,
     sparse_attend_for,
     ssm_rows_for,
+    takes_slot_form,
     decode_write_indices,
     sparse_chunk_attend,
     sparse_decode_attend,
@@ -237,6 +239,11 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
     return x, kv
 
 
+# the kinds of layer whose decode step reads ONE list a slot, the same for
+# all its key heads (``kv_cache.decode_attend_all``)
+SLOT_LIST_KINDS = frozenset({"mamba_attn", "eva"})
+
+
 def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     """Build the jitted all-slots decode step.
 
@@ -294,8 +301,11 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         # what the kinds of layer in this stack need beside the pools
         if "attention" in kinds:
             attend_rows = decode_attend_for(k_pool, tables, cfg.n_head, mesh)
-        if kinds & {"minicpm4", "mamba_attn", "eva"}:
+        if "minicpm4" in kinds:
             attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
+        if kinds & SLOT_LIST_KINDS:
+            attend_slots = slot_attend_for(k_pool, cfg.n_head, tables.shape,
+                                           mesh)
         if "minicpm4" in kinds:
             at = decode_write_indices(sp, tables, lengths)
         if "mamba_attn" in kinds:
@@ -340,7 +350,7 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 k_row = k[:, 0].astype(k_pool.dtype)
                 v_row = v[:, 0].astype(v_pool.dtype)
                 ctx = decode_attend_all(k_pool, v_pool, layer, q, k_row,
-                                        v_row, tables, lengths, attend_pages)
+                                        v_row, tables, lengths, attend_slots)
                 return ctx, (k_row, v_row)
 
             def two_roles(q, k, v):
@@ -351,7 +361,7 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 with jax.named_scope("ds.eva.attn"):
                     ctx = decode_attend_all(
                         k_pool, v_pool, layer, q, k_row, v_row, at["pages"],
-                        at["count"], attend_pages)
+                        at["count"], attend_slots)
                 return ctx, (k_row, v_row)
 
             def state_space(xbc, dt):
@@ -872,6 +882,13 @@ class ServingEngine(_ServingBase):
         super().__init__(scfg, Scheduler(scfg, self.kv.allocator, clock),
                          clock, monitor, monitor_config)
         self._decode_step = make_decode_step(cfg, scfg, mesh)
+        # whether that program's list-sharing layers copy a page once for
+        # all of a slot's key heads: chosen when it is traced, from the
+        # shapes and the mesh alone, so it is known here too
+        self._slot_rows = bool(
+            set(cfg.layer_kinds) & SLOT_LIST_KINDS) and takes_slot_form(
+            self.kv.k, cfg.n_head, (scfg.num_slots, scfg.blocks_per_slot),
+            mesh)
         # the last decode step's tokens as the device handed them back,
         # the next step's ``prev`` (zeros until a step has run), and the
         # steps launched and not yet read, oldest first: one between
@@ -1510,7 +1527,8 @@ class ServingEngine(_ServingBase):
         with trace_span("serving/decode/dispatch", lane="serving",
                         live_pages=live_pages, view_pages=tables.size,
                         selected_pages=selected_pages,
-                        ahead="1" if ahead else "0", **roles):
+                        ahead="1" if ahead else "0",
+                        slot_rows="1" if self._slot_rows else "0", **roles):
             nxt, self.kv.k, self.kv.v, self.kv.kc, self.kv.state = \
                 self._decode_step(*_dargs)
             # the read-back starts when the step ends, not when the host
@@ -1562,7 +1580,7 @@ class ServingEngine(_ServingBase):
             self.metrics.record_decode_step(
                 len(step.lanes), len(self.sched.queue), self.clock(),
                 held_chunk=step.held_chunk, ahead=step.ahead,
-                discarded=step.discarded)
+                discarded=step.discarded, slot_rows=self._slot_rows)
         return read
 
     def _decode_all(self) -> None:

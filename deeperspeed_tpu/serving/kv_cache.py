@@ -55,6 +55,7 @@ shared in place — admission copies its matched rows into a private block
 page machinery the prefill path uses.
 """
 
+import functools
 import itertools
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -672,6 +673,48 @@ def sparse_attend_for(k_pool, n_head, mesh):
     return paged_sparse_attend_xla
 
 
+def slots_as_rows(attend_pages, k_pool, v_pool, layer, q, pages, n_tokens,
+                  m0, l0, acc0):
+    """Slots whose key heads share the slot's list, through a form that
+    takes a list a ROW (``sparse_attend_for``'s): a row a (slot, key
+    head), each with a copy of the list and the count. q, acc0: (N, Hkv,
+    G, Dh); pages: (N, P); n_tokens: (N,); m0, l0: (N, Hkv, G). ->
+    (N, Hkv, G, Dh)."""
+    N, Hkv, G, Dh = q.shape
+    R, P = N * Hkv, pages.shape[1]
+    ctx = attend_pages(
+        k_pool, v_pool, layer, q.reshape(R, G, Dh),
+        jnp.tile(jnp.arange(Hkv), N),
+        jnp.broadcast_to(pages[:, None, :], (N, Hkv, P)).reshape(R, P),
+        jnp.repeat(n_tokens, Hkv), m0.reshape(R, G), l0.reshape(R, G),
+        acc0.reshape(R, G, Dh))
+    return ctx.reshape(N, Hkv, G, Dh)
+
+
+def takes_slot_form(k_pool, n_head, lists_shape, mesh) -> bool:
+    """Whether lists ``lists_shape`` = (slots, entries), one a slot for
+    all its key heads, go through the kernel whose row is a slot: one
+    TPU, shapes the kernel can tile, a page that fits its buffers."""
+    from ..ops.pallas import paged_sparse_attn as kernel
+
+    return (mesh is None or mesh.size == 1) and kernel.slots_available(
+        k_pool, n_head, lists_shape)
+
+
+def slot_attend_for(k_pool, n_head, lists_shape, mesh):
+    """The form of ``decode_attend_all``'s read (the signature of
+    ``slots_as_rows`` less its first argument): the kernel that copies a
+    page once for all of a slot's key heads where ``takes_slot_form``,
+    else a row a (slot, key head) through ``sparse_attend_for``'s choice
+    (the XLA form under a mesh and off the TPU)."""
+    from ..ops.pallas import paged_sparse_attn as kernel
+
+    if takes_slot_form(k_pool, n_head, lists_shape, mesh):
+        return kernel.paged_sparse_attn_slots
+    return functools.partial(slots_as_rows,
+                             sparse_attend_for(k_pool, n_head, mesh))
+
+
 def lightning_chunk_for(q, mesh):
     """The chunkwise form of a lightning layer: the kernel on one TPU at
     shapes it can tile, else ``mixers.lightning_chunk_xla``."""
@@ -698,10 +741,11 @@ def ssm_rows_for(rows, n_groups, mesh):
 
 def _own_token_init(q, k_row, v_row):
     """(m0, l0, acc0) of rows that have attended over one key so far:
-    their own. q: (R, G, Dh); k_row, v_row: (R, Dh)."""
-    s = jnp.sum(q.astype(jnp.float32) * k_row.astype(jnp.float32)[:, None],
+    their own. q: (..., G, Dh); k_row, v_row: (..., Dh)."""
+    s = jnp.sum(q.astype(jnp.float32)
+                * k_row.astype(jnp.float32)[..., None, :],
                 -1) / math.sqrt(q.shape[-1])
-    acc = jnp.broadcast_to(v_row.astype(jnp.float32)[:, None], q.shape)
+    acc = jnp.broadcast_to(v_row.astype(jnp.float32)[..., None, :], q.shape)
     return s, jnp.ones_like(s), acc
 
 
@@ -880,23 +924,21 @@ def chunk_attend_all(k_pool, v_pool, layer, q, k, v, table_row, offset,
 
 
 def decode_attend_all(k_pool, v_pool, layer, q, k_row, v_row, tables,
-                      lengths, attend_pages):
-    """One layer's decode attention for all slots over EVERY live page of
-    a slot, pools in the mixed layout: a row a (slot, key head), its page
-    list the slot's whole table, of which the ``lengths`` positions
-    already cached count, and its own new key as what it has attended
-    over already. q: (N, 1, H, Dh); k_row, v_row: (N, Hkv, Dh) in the
-    pool's dtype. ``attend_pages``: ``sparse_attend_for``'s. Returns ctx
-    (N, 1, H, Dh)."""
+                      lengths, attend_slots):
+    """One layer's decode attention for all slots over EVERY page a
+    slot's list names, pools in the mixed layout: ``tables`` (N, P) is a
+    list a SLOT (its whole table, or its list of two roles), of which the
+    first ``lengths`` positions count, the same for each of the slot's
+    key heads; every query has its own new key as what it has attended
+    over already. This is the one place where all of a slot's key heads
+    provably read the same pages, so the list goes on unbroadcast. q: (N,
+    1, H, Dh); k_row, v_row: (N, Hkv, Dh) in the pool's dtype.
+    ``attend_slots``: ``slot_attend_for``'s. Returns ctx (N, 1, H, Dh)."""
     N, _, H, Dh = q.shape
     Hkv = k_pool.shape[2]
-    R, P = N * Hkv, tables.shape[1]
-    q_rows = q.reshape(R, H // Hkv, Dh)
-    pages = jnp.broadcast_to(tables[:, None, :], (N, Hkv, P)).reshape(R, P)
-    ctx = attend_pages(
-        k_pool, v_pool, layer, q_rows, jnp.tile(jnp.arange(Hkv), N), pages,
-        jnp.repeat(lengths, Hkv),
-        *_own_token_init(q_rows, k_row.reshape(R, Dh), v_row.reshape(R, Dh)))
+    qg = q.reshape(N, Hkv, H // Hkv, Dh)
+    ctx = attend_slots(k_pool, v_pool, layer, qg, tables, lengths,
+                       *_own_token_init(qg, k_row, v_row))
     return ctx.reshape(N, 1, H, Dh)
 
 

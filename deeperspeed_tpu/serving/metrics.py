@@ -227,6 +227,9 @@ class ServingMetrics:
         # a step late): their tokens were dropped
         self.decode_steps_ahead = 0
         self.decode_rows_discarded = 0
+        # plain decode steps run by a program whose layers that read one
+        # list a slot took the kernel whose row is a slot
+        self.decode_steps_slot_rows = 0
         # tokens decoded, and those of them decoded in a step that first
         # ran a prompt chunk (their gap held the chunk, but where the step
         # was launched with nothing in flight: ``token_gaps`` files those
@@ -383,18 +386,22 @@ class ServingMetrics:
 
     def record_decode_step(self, n_active: int, queue_depth: int,
                            now: float, held_chunk: bool = False,
-                           ahead: bool = False, discarded: int = 0) -> None:
+                           ahead: bool = False, discarded: int = 0,
+                           slot_rows: bool = False) -> None:
         """One decode step over ``n_active`` rows, recorded when its
         tokens are read. ``held_chunk``: a prompt chunk ran before it in
         the ``step()`` that launched it, so each of its tokens came a
         chunk later. ``ahead``: it was launched while the step before it
         was still unread. ``discarded``: of its rows, those whose token
-        was dropped (the request had ended meanwhile)."""
+        was dropped (the request had ended meanwhile). ``slot_rows``: its
+        program read a slot's shared list through the kernel whose row is
+        a slot (``kv_cache.takes_slot_form``)."""
         if self._start_t is None:
             self._start_t = now
         emitted = n_active - discarded
         self.decode_steps += 1
         self.decode_steps_ahead += bool(ahead)
+        self.decode_steps_slot_rows += bool(slot_rows)
         self.decode_rows_discarded += discarded
         self.total_generated += emitted
         self.gaps += emitted
@@ -580,6 +587,9 @@ class ServingMetrics:
                                    / self.decode_steps
                                    if self.decode_steps else 0.0),
             "decode_rows_discarded": int(self.decode_rows_discarded),
+            "decode_slot_rows_share": (self.decode_steps_slot_rows
+                                       / self.decode_steps
+                                       if self.decode_steps else 0.0),
             "chunk_gap_share": (self.chunk_gaps / self.gaps
                                 if self.gaps else 0.0),
             "itl_ms": self.itl_ms(),

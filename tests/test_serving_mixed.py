@@ -208,6 +208,156 @@ def test_selected_pages_kernel_against_its_oracle_at_two_key_heads(dtype):
                                    dtype).astype(jnp.float32)), atol=1e-6)
 
 
+def _slot_world(Hkv, G, dtype, counts, carry="plain", seed=0):
+    """Pools of 24 pages of 64 rows, lists 16 wide (two copy-chunks at 4
+    and at 2 key heads, eight at 32), slots that hold ``counts`` rows;
+    entries past a count name the null page, page 0."""
+    L, nb, bs, Dh, P = 2, 24, 64, 128, 16
+    N = len(counts)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    kp = jax.random.normal(ks[0], (L, nb, Hkv, bs, Dh)).astype(dtype)
+    vp = jax.random.normal(ks[1], (L, nb, Hkv, bs, Dh)).astype(dtype)
+    q = jax.random.normal(ks[2], (N, Hkv, G, Dh)).astype(dtype)
+    n = np.asarray(counts)
+    pages = np.random.default_rng(seed).integers(1, nb, (N, P))
+    pages = np.where(np.arange(P)[None] * bs < n[:, None], pages, 0)
+    # a score over 128 entries of unit normals is a few units: what was
+    # attended over before holds all of the softmax, or none of it
+    m0 = jax.random.normal(ks[3], (N, Hkv, G)) + {
+        "plain": 0.0, "dominates": 60.0, "vanishes": -60.0}[carry]
+    l0 = jnp.full((N, Hkv, G), 1.5)
+    acc0 = jax.random.normal(ks[4], (N, Hkv, G, Dh))
+    return (kp, vp, jnp.int32(1), q, jnp.asarray(pages, jnp.int32),
+            jnp.asarray(n, jnp.int32), m0, l0, acc0)
+
+
+# ends mid-page in the second copy-chunk, nothing (between two live
+# slots), a page boundary that is a copy-chunk's too, the whole list, a
+# few rows of one page, nothing
+LIVE_AND_IDLE = (64 * 9 + 37, 0, 64 * 8, 64 * 16, 5, 0)
+SLOT_CASES = [
+    pytest.param(Hkv, G, dtype, LIVE_AND_IDLE, "plain",
+                 id=f"{Hkv}x{G}_{jnp.dtype(dtype).name}")
+    for Hkv, G in ((32, 1), (4, 5), (2, 16))
+    for dtype in (jnp.float32, jnp.bfloat16)
+] + [
+    pytest.param(Hkv, G, jnp.bfloat16, LIVE_AND_IDLE, carry,
+                 id=f"{Hkv}x{G}_what_came_before_{carry}")
+    for Hkv, G in ((32, 1), (4, 5), (2, 16))
+    for carry in ("dominates", "vanishes")
+] + [
+    pytest.param(Hkv, G, jnp.float32, (0, 0, 0), "plain",
+                 id=f"{Hkv}x{G}_every_slot_idle")
+    for Hkv, G in ((32, 1), (4, 5), (2, 16))
+]
+
+
+@pytest.mark.parametrize("Hkv,G,dtype,counts,carry", SLOT_CASES)
+def test_slot_form_against_the_oracle_over_the_broadcast_rows(
+        Hkv, G, dtype, counts, carry):
+    """``paged_sparse_attn_slots`` (a row a SLOT, a copy a whole page) is
+    ``paged_sparse_attend_xla`` over rows (slot, key head) that each carry
+    the slot's list and count."""
+    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import (
+        paged_sparse_attn_slots)
+    from deeperspeed_tpu.serving.kv_cache import slots_as_rows
+
+    args = _slot_world(Hkv, G, dtype, counts, carry)
+    want = slots_as_rows(paged_sparse_attend_xla, *args).astype(jnp.float32)
+    got = paged_sparse_attn_slots(*args, interpret=True).astype(jnp.float32)
+    assert got.shape == (len(counts), Hkv, G, 128)
+    # bf16: an output of a few units rounds to 2 ** -6, where two orders
+    # of one float32 sum fall on either side of a rounding
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5 if dtype == jnp.float32 else 2e-2)
+    *_, m0, l0, acc0 = args
+    came_with = (acc0 / l0[..., None]).astype(dtype).astype(jnp.float32)
+    idle = np.asarray(counts) == 0
+    # a slot with nothing to read returns what it came with, and so does
+    # one whose pages weigh nothing beside it
+    keeps = idle | (carry == "dominates")
+    np.testing.assert_allclose(np.asarray(got)[keeps],
+                               np.asarray(came_with)[keeps], atol=1e-6)
+    if carry == "vanishes":
+        assert np.abs(np.asarray(got - came_with)[~idle]).max() > 0.1
+
+
+@pytest.mark.parametrize("kind,Hkv,G", [("eva", 32, 1), ("mamba_attn", 4, 5)])
+def test_decode_attend_all_is_the_same_through_either_form(kind, Hkv, G):
+    """``decode_attend_all`` hands a slot's list on unbroadcast: through
+    the slot form (interpreted) and through the XLA form over the
+    broadcast rows it returns one context, the new token's own key
+    counted in both."""
+    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import (
+        paged_sparse_attn_slots)
+    from deeperspeed_tpu.serving import kv_cache as kvc
+
+    kp, vp, layer, qg, pages, n, *_ = _slot_world(
+        Hkv, G, jnp.bfloat16, LIVE_AND_IDLE, seed=3)
+    N, Dh = qg.shape[0], qg.shape[-1]
+    ks = jax.random.split(jax.random.PRNGKey(4), 2)
+    k_row, v_row = (jax.random.normal(k, (N, Hkv, Dh)).astype(kp.dtype)
+                    for k in ks)
+    q = qg.reshape(N, 1, Hkv * G, Dh)
+    shapes = []
+
+    def rows_xla(k_pool, v_pool, layer, q_rows, row_head, pages, *rest):
+        shapes.append((q_rows.shape, pages.shape))
+        return paged_sparse_attend_xla(k_pool, v_pool, layer, q_rows,
+                                       row_head, pages, *rest)
+
+    ctx = [kvc.decode_attend_all(kp, vp, layer, q, k_row, v_row, pages, n,
+                                 attend)
+           for attend in (
+               functools.partial(paged_sparse_attn_slots, interpret=True),
+               functools.partial(kvc.slots_as_rows, rows_xla))]
+    assert ctx[0].shape == (N, 1, Hkv * G, Dh)
+    assert shapes == [((N * Hkv, G, Dh), (N * Hkv, 16))]
+    np.testing.assert_allclose(np.asarray(ctx[0].astype(jnp.float32)),
+                               np.asarray(ctx[1].astype(jnp.float32)),
+                               atol=2e-2)
+
+
+def test_only_a_list_a_slot_takes_the_slot_form(monkeypatch):
+    """On one TPU ``decode_attend_all``'s read is the kernel whose row is
+    a slot, and a selecting layer's (``sparse_decode_attend``,
+    ``sparse_chunk_attend``: lists that differ by key head) stays the
+    kernel whose row is a (position, key head); under a mesh and off the
+    TPU both are the XLA form."""
+    from jax.sharding import Mesh
+
+    from deeperspeed_tpu.ops import kernel_config
+    from deeperspeed_tpu.ops.pallas import paged_sparse_attn as kernel
+    from deeperspeed_tpu.serving import kv_cache as kvc
+
+    sds = jax.ShapeDtypeStruct
+    eva = sds((8, 1025, 32, 64, 128), jnp.bfloat16)
+    h1 = sds((6, 2305, 4, 64, 128), jnp.bfloat16)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+
+    def xla_rows(attend):
+        return (attend.func is kvc.slots_as_rows
+                and attend.args == (paged_sparse_attend_xla,))
+
+    assert xla_rows(kvc.slot_attend_for(eva, 32, (16, 64), None))   # the CPU
+    assert not kvc.takes_slot_form(eva, 32, (16, 64), None)
+    monkeypatch.setattr(kernel_config, "on_tpu", lambda: True)
+    for pool, H, lists in ((eva, 32, (16, 64)), (h1, 20, (48, 48))):
+        assert kvc.slot_attend_for(pool, H, lists, None) \
+            is kernel.paged_sparse_attn_slots
+        assert kvc.sparse_attend_for(pool, H, None) is kernel.paged_sparse_attn
+        assert xla_rows(kvc.slot_attend_for(pool, H, lists, mesh))
+        assert not kvc.takes_slot_form(pool, H, lists, mesh)
+    # a page too large for the buffers (4 MiB of them) keeps a row a
+    # (slot, key head), through the row kernel
+    wide = sds((2, 65, 128, 64, 128), jnp.bfloat16)
+    attend = kvc.slot_attend_for(wide, 128, (16, 64), None)
+    assert attend.func is kvc.slots_as_rows
+    assert attend.args == (kernel.paged_sparse_attn,)
+    sala = sds((8, 6241, 2, 64, 128), jnp.bfloat16)
+    assert kvc.sparse_attend_for(sala, 32, None) is kernel.paged_sparse_attn
+
+
 @pytest.mark.parametrize("n_valid", [64, 37])
 def test_lightning_kernel_against_its_oracle(n_valid):
     C, H, Dh = 64, 4, 128

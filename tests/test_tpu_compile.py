@@ -312,6 +312,9 @@ def test_falcon_h1_programs_move_neither_pool_rows_nor_a_weight_stack(
     # the decode step reads pages and rows through kernels; the chunk
     # attends over the slot's gathered pages and scans in XLA
     assert runs_kernel(text, "paged_sparse_attn") == (program == "decode")
+    # ... in the form whose row is a slot: its key heads share the list
+    assert runs_kernel(text, "paged_sparse_attn_slots") == (
+        program == "decode")
     assert runs_kernel(text, "ssm_row_update") == (program == "decode")
     assert count_alias_pairs(text) == 4        # k, v, state rows, tails
     big = ("bf16[6,2305,4,64,128]", "f32[6,48,32,128,256]", "bf16[6,5120,",
@@ -367,6 +370,28 @@ def test_page_list_kernel_compiles_at_one_query_a_key_head(one_chip):
     assert "paged_sparse_attn" in compiled.as_text()
 
 
+@pytest.mark.parametrize("pool,N,G,P", [
+    pytest.param((8, 1025, 32, 64, 128), 16, 1, 64, id="the_byte_cell"),
+    pytest.param((6, 2305, 4, 64, 128), 48, 5, 48, id="the_chat_cell")])
+def test_page_list_kernel_compiles_with_a_slot_a_row(one_chip, pool, N, G, P):
+    """The decode call of the two cells whose key heads share a slot's
+    list: a row a SLOT, a copy a whole page (512 KiB of 32 key heads, 2 a
+    copy-chunk; 64 KiB of 4, 8 a copy-chunk)."""
+    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import (
+        paged_sparse_attn_slots, slots_available)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    Hkv, Dh = pool[2], pool[4]
+    f32 = jnp.float32
+    compiled = paged_sparse_attn_slots.lower(
+        sds(pool), sds(pool), sds((), jnp.int32), sds((N, Hkv, G, Dh)),
+        sds((N, P), jnp.int32), sds((N,), jnp.int32), sds((N, Hkv, G), f32),
+        sds((N, Hkv, G), f32), sds((N, Hkv, G, Dh), f32)).compile()
+    assert runs_kernel(compiled.as_text(), "paged_sparse_attn_slots")
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_evabyte_programs_move_neither_the_pool_nor_a_weight_stack(
         one_chip, as_if_on_tpu, program):
@@ -402,6 +427,9 @@ def test_evabyte_programs_move_neither_the_pool_nor_a_weight_stack(
     # the decode step reads the two-role list through the page-list
     # kernel; the chunk attends over its gathered past in XLA
     assert runs_kernel(text, "paged_sparse_attn") == (program == "decode")
+    # ... in the form whose row is a slot: its key heads share the list
+    assert runs_kernel(text, "paged_sparse_attn_slots") == (
+        program == "decode")
     assert count_alias_pairs(text) == 2        # k, v
     big = ("bf16[8,1025,32,64,128]", "bf16[8,4096,", "bf16[8,11008,")
     moved = [ln.strip()[:140] for ln in text.splitlines()
