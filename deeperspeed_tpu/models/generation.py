@@ -19,6 +19,7 @@ temperature=0 (default) is greedy argmax. The prompt is prefilled in one
 pass; decode steps attend to the cache only.
 """
 
+import dataclasses
 import math
 from functools import partial
 from typing import Optional
@@ -91,6 +92,10 @@ def _cached_block(cfg: GPTConfig, x, layer_params, k_cache, v_cache,
     moe_cfg = cfg.moe
     if moe_cfg is not None:
         from .moe import moe_ffn
+
+        # no capacity where tokens are served: a token training would
+        # drop over capacity would be a wrong token here
+        moe_cfg = dataclasses.replace(moe_cfg, dispatch_impl="dropless")
 
         def mlp_fn(mlp_in):
             return moe_ffn(layer_params["moe"], mlp_in, moe_cfg)

@@ -154,6 +154,68 @@ class EvaAttnConfig:
         return self.window // self.chunk
 
 
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """The rotary constants of one kind of layer: ``theta`` and, where
+    ``factor`` is not 1, YaRN's (Peng et al. 2023) as a config states
+    them: pair ``j`` of ``head_dim / 2`` turns at ``theta^(-2j/head_dim)``
+    below the pair ``low`` (``beta_fast`` turns over the
+    ``original_positions``), at that over ``factor`` from the pair ``high``
+    on (``beta_slow`` turns), a straight line between; cos and sin are
+    BOTH times ``attention_factor``, so a score carries its square. The
+    frequencies are static: the same at every length."""
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_positions: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self, head_dim: int):
+        """The ``head_dim / 2`` inverse frequencies, float32 (numpy: a
+        constant of the program)."""
+        import numpy as np
+
+        half = head_dim // 2
+        base = self.theta ** (-2.0 * np.arange(half, dtype=np.float64)
+                              / head_dim)
+        if self.factor == 1.0:
+            return base.astype(np.float32)
+
+        def pair(turns):     # the pair that makes ``turns`` turns
+            return (head_dim * math.log(self.original_positions
+                                        / (turns * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+
+        low = max(math.floor(pair(self.beta_fast)), 0)
+        high = min(math.ceil(pair(self.beta_slow)), head_dim - 1)
+        ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+        return ((1 - ramp) * base + ramp * base / self.factor).astype(
+            np.float32)
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedAttnConfig:
+    """The constants of the ``full_attn`` and ``window_attn`` layers:
+    grouped-query softmax attention, RMSNorm, no bias. A ``full_attn``
+    layer keeps every key; a ``window_attn`` layer's query at position
+    ``i`` sees the keys ``i - window < j <= i`` and the layer keeps the
+    last ``window`` keys alone (a ring a slot). Each kind turns q and k by
+    its own rotary constants; ``qk_norm``: q and k pass an RMSNorm over
+    each head's entries (one learned vector each a layer) before that."""
+    window: int = 1024
+    qk_norm: bool = True
+    full_rope: RopeScaling = RopeScaling()
+    window_rope: RopeScaling = RopeScaling()
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"window_attn: window >= 1 (got {self})")
+
+    def rope(self, kind: str) -> RopeScaling:
+        return self.window_rope if kind == "window_attn" else self.full_rope
+
+
 # The kinds of layer a stack may hold, by the name of the mixer. The kind
 # settles the rest of the layer, so nothing else is configured: what its
 # mixer keeps between tokens (its cache), its norm and its feed-forward.
@@ -173,7 +235,21 @@ class EvaAttnConfig:
 #              after window) and one pooled key and value for every chunk
 #              of the windows behind it (pages of summaries), one query a
 #              key head; gated SiLU; no bias
-LAYER_KINDS = ("attention", "minicpm4", "lightning", "mamba_attn", "eva")
+#   full_attn  mixers.grouped_attn_block: RMSNorm, grouped-query softmax
+#              attention over every key (pages that follow the length),
+#              q and k normed a head where the model says so, rotary by
+#              the kind's own constants (YaRN among them); no bias
+#   window_attn  the same block over the last ``window`` keys alone: a
+#              ring of ``window / block_size`` pages a slot, in a pool of
+#              its own beside the full layers' (two page rules, one stack)
+# The feed-forward of every kind but ``attention`` is a VALUE of the
+# configuration (``mixers.feed_forward``): dense gated SiLU, or, where
+# ``moe_num_experts`` is set, gated SiLU experts routed ``moe_top_k`` a
+# token with no token dropped (``moe.gated_experts``).
+LAYER_KINDS = ("attention", "minicpm4", "lightning", "mamba_attn", "eva",
+               "full_attn", "window_attn")
+# the kinds that share ``mixers.grouped_attn_block`` (and may share a stack)
+GROUPED_KINDS = frozenset({"full_attn", "window_attn"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -215,9 +291,17 @@ class GPTConfig:
     # 0 disables chunking (single fused logits+lse).
     ce_chunk: int = 128
     # Mixture-of-Experts: 0 = dense MLP; >0 replaces every layer's FFN with
-    # an expert-parallel MoE (models/moe.py) of this many experts, sharded
-    # over the 'expert' mesh axis. A capability BEYOND the reference, which
-    # predates DeepSpeed-MoE (SURVEY.md §2.3 lists EP as absent).
+    # experts (models/moe.py). In a stack of ``attention`` layers (the model
+    # that is trained): GeLU experts with biases, expert-parallel over the
+    # 'expert' mesh axis, dispatched as ``moe_dispatch_impl`` says, with
+    # the auxiliary losses; a capability BEYOND the reference, which
+    # predates DeepSpeed-MoE (SURVEY.md §2.3 lists EP as absent). In a
+    # stack of mixed layers (served only): gated SiLU experts of width
+    # ``d_ff``, no bias, a float32 softmax over all of them, the
+    # ``moe_top_k`` largest a token (renormalised to sum 1 where
+    # ``moe_normalize_gates``), every assignment computed whatever the
+    # imbalance (``moe.gated_experts``): of the keys below it reads
+    # ``moe_num_experts``, ``moe_top_k`` and ``moe_normalize_gates`` alone.
     moe_num_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
@@ -242,6 +326,8 @@ class GPTConfig:
     sparse: Optional[SparseAttnConfig] = None   # the minicpm4 layers'
     ssm: Optional[MambaAttnConfig] = None       # the mamba_attn layers'
     eva: Optional[EvaAttnConfig] = None         # the eva layers'
+    # the full_attn and window_attn layers'
+    gqa: Optional[GroupedAttnConfig] = None
     # what a model states of its norms, its residual stream and its head
     # (read by the eva layers, the final norm and the head): an RMSNorm
     # scales by ``norm_offset + w``; the stream between layers and the
@@ -251,6 +337,9 @@ class GPTConfig:
     norm_offset: float = 0.0
     fp32_stream: bool = False
     n_pred: int = 1
+    # the head's product accumulated and kept in float32 over a stream in
+    # ``dtype`` (``fp32_stream`` implies it)
+    fp32_logits: bool = False
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -294,13 +383,14 @@ class GPTConfig:
         if self.mixer_types:
             # attention layers keep their own weight tree and a pool laid
             # out position by position: they do not mix with the others,
-            # each of which keeps one of four shapes of cache (pages; a
-            # state row a slot; both; pages of two roles behind a window)
+            # each of which keeps one of five shapes of cache (pages; a
+            # state row a slot; both; pages of two roles behind a window;
+            # a ring of pages a slot, in a pool of its own)
             mixable = set(LAYER_KINDS) - {"attention"}
             if set(self.mixer_types) - mixable \
                     or len(self.mixer_types) != self.n_layer:
                 raise ValueError(
-                    f"mixer_types must name one of the four kinds "
+                    f"mixer_types must name one of the {len(mixable)} kinds "
                     f"{sorted(mixable)} (of LAYER_KINDS {LAYER_KINDS}) for "
                     f"each of the {self.n_layer} layers (or be empty: a "
                     f"stack of attention layers), got {self.mixer_types}")
@@ -310,6 +400,9 @@ class GPTConfig:
                 raise ValueError("mamba_attn layers need cfg.ssm")
             if "eva" in self.mixer_types and self.eva is None:
                 raise ValueError("eva layers need cfg.eva")
+            if set(self.mixer_types) & GROUPED_KINDS and self.gqa is None:
+                raise ValueError(
+                    "full_attn and window_attn layers need cfg.gqa")
         if self.remat_policy not in ("full", "flash", "matmuls", "dots",
                                      "dots_all"):
             raise ValueError(
@@ -485,23 +578,34 @@ def layer_norm2(x, scale1, bias1, scale2, bias2, eps):
             (y * scale2 + bias2).astype(x.dtype))
 
 
-def rotary_embedding(x, positions, rotary_dims, theta: float = 10000.0):
+def rotary_embedding(x, positions, rotary_dims, theta: float = 10000.0,
+                     rope: Optional[RopeScaling] = None):
     """Apply rotary position embedding to the first rotary_dims of head_dim.
 
     x: (B, S, H, Dh); positions: (S,) shared across the batch, or (B, S)
     per-row absolute positions (batched cache decode, where rows sit at
-    different offsets)."""
+    different offsets). ``rope``, where given, brings the frequencies (its
+    own ``theta``, YaRN's) and the factor on cos and sin."""
     dh = x.shape[-1]
     rot, rest = x[..., :rotary_dims], x[..., rotary_dims:]
     half = rotary_dims // 2
-    freq = jnp.exp(
-        -math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
-    )
+    if rope is not None:
+        freq = jnp.asarray(rope.inv_freq(rotary_dims))
+    else:
+        freq = jnp.exp(
+            -math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
+        )
     angles = positions[..., None].astype(jnp.float32) * freq  # (..., S, half)
     if positions.ndim == 1:
         angles = angles[None]
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+
+    def turn(f):
+        t = f(angles)
+        if rope is not None and rope.attention_factor != 1.0:
+            t = t * jnp.float32(rope.attention_factor)
+        return t[:, :, None, :].astype(x.dtype)
+
+    cos, sin = turn(jnp.cos), turn(jnp.sin)
     x1, x2 = rot[..., :half], rot[..., half:]
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     if rest.shape[-1]:

@@ -1,9 +1,11 @@
 """The layer kinds beside ``gpt.decoder_block``'s: RMSNorm, a gated SiLU
-feed-forward, muP scalings, and mixers that keep something other than
-every key and value between tokens. What a layer keeps has one of four
-shapes: pages (``minicpm4``), a state row a slot (``lightning``), both
-(``mamba_attn``), or pages of two roles whose count stops following the
-length (``eva``):
+feed-forward (dense, or experts: ``feed_forward``), muP scalings, and
+mixers most of which keep something other than every key and value
+between tokens. What a layer keeps has one of five shapes: pages
+(``minicpm4``, ``full_attn``), a state row a slot (``lightning``), both
+(``mamba_attn``), pages of two roles whose count stops following the
+length (``eva``), or a ring of pages that holds the last ``window`` keys
+(``window_attn``):
 
 ``lightning``  decayed linear attention (Lightning Attention): per head a
                state ``S_t = lam S_{t-1} + k_t^T v_t`` (Dh x Dh, float32),
@@ -29,16 +31,21 @@ length (``eva``):
                values' sum under ``softmax_j(k_j . phi_h / sqrt(Dh))``,
                ``mu``, ``phi`` learned, a pair a head; one query a key
                head.
+``full_attn``  grouped-query softmax attention over every key, q and k
+               normed a head where the model says so, rotary by the
+               kind's own constants (``GroupedAttnConfig``, YaRN among
+               them).
+``window_attn``  the same over the keys ``i - window < j <= i`` alone: the
+               window slides with the query, and the layer keeps the last
+               ``window`` keys (may share a stack with ``full_attn``).
 
-A model whose ``GPTConfig.mixer_types`` names them (MiniCPM-SALA,
-Falcon-H1, EvaByte) keeps its weights stacked BY KIND (``params["sparse"]``,
-``params["lightning"]``, ``params["mamba_attn"]``, ``params["eva"]``) and
-is served only; the layer loop of every program goes run by run
-(``layer_runs``). ``mixed_block``, ``mamba_attn_block`` and ``eva_block``
-are the layers the whole forward, the chunked prefill and the decode step
-share: a program hands them the cache-dependent cores alone (``core(q, k,
-v) -> (ctx, aux)``; for the state-space branch ``scan(xbc, dt) -> (y,
-aux)``).
+A model whose ``GPTConfig.mixer_types`` names them keeps its weights
+stacked BY KIND (``STACK_KEY``) and is served only; the layer loop of every
+program goes run by run (``layer_runs``). ``mixed_block``,
+``mamba_attn_block``, ``eva_block`` and ``grouped_attn_block`` are the
+layers the whole forward, the chunked prefill and the decode step share: a
+program hands them the cache-dependent cores alone (``core(q, k, v) ->
+(ctx, aux)``; for the state-space branch ``scan(xbc, dt) -> (y, aux)``).
 """
 
 import math
@@ -54,7 +61,8 @@ NEG = -1e30
 # where each kind's stacked weights live in the parameter tree
 STACK_KEY = {"attention": "layers", "minicpm4": "sparse",
              "lightning": "lightning", "mamba_attn": "mamba_attn",
-             "eva": "eva"}
+             "eva": "eva", "full_attn": "full_attn",
+             "window_attn": "window_attn"}
 
 
 # ------------------------------------------------------------------ #
@@ -78,6 +86,29 @@ def gated_ffn(u, p, cdt, gate_mult: float = 1.0):
     h = jax.nn.silu(scaled(u @ p["w_gate"].astype(cdt), gate_mult)) \
         * (u @ p["w_up"].astype(cdt))
     return h @ p["w_down"].astype(cdt)
+
+
+def feed_forward(cfg: GPTConfig, m, p, live=None, gate_mult: float = 1.0,
+                 layer=None):
+    """The feed-forward of a mixed layer, as the configuration says: the
+    dense gated SiLU one, or (``cfg.moe_num_experts``) gated SiLU experts
+    routed ``moe_top_k`` a token with nothing dropped
+    (``moe.gated_experts``). m: (B, S, D) normed; p: the layer's ``mlp``
+    tree; ``live`` (B, S) bool or None: the tokens that are real (an idle
+    lane, a chunk's padding is routed to no expert). With ``layer`` (a
+    traced index) ``p`` is the kind's whole STACK of experts and the
+    layer's are read where they lie (``moe.gated_experts``). -> (y (B, S,
+    D), the experts' counts (3,) int32, zeros for a dense feed-forward)."""
+    if not cfg.moe_num_experts:
+        return gated_ffn(m, p, cfg.dtype, gate_mult), \
+            jnp.zeros((3,), jnp.int32)
+    from .moe import gated_experts
+
+    B, S, D = m.shape
+    y, counts = gated_experts(
+        p, m.reshape(B * S, D), cfg.moe_top_k, cfg.moe_normalize_gates,
+        None if live is None else live.reshape(B * S), gate_mult, layer)
+    return y.reshape(B, S, D), counts
 
 
 def lightning_slopes(n_head: int):
@@ -141,6 +172,16 @@ def init_params(rng, cfg: GPTConfig):
     def w(shape, s):
         return jax.random.normal(next(keys), shape, jnp.float32) * s
 
+    def mlp(n):
+        """The feed-forward ``feed_forward`` reads: dense, or experts
+        (an expert axis behind the layers', and the router)."""
+        E = (cfg.moe_num_experts,) if cfg.moe_num_experts else ()
+        p = {"w_gate": w((n, *E, D, F), std), "w_up": w((n, *E, D, F), std),
+             "w_down": w((n, *E, F, D), out_std)}
+        if E:
+            p["router"] = w((n, D, *E), std)
+        return p
+
     def kind(n, kv_heads, o_norm):
         p = {"ln1": jnp.ones((n, D)), "ln2": jnp.ones((n, D)),
              # one projection: all query heads, then the key heads, then
@@ -148,8 +189,7 @@ def init_params(rng, cfg: GPTConfig):
              "wqkv": w((n, D, (H + 2 * kv_heads) * Dh), std),
              "wg": w((n, D, H * Dh), std), "wo": w((n, H * Dh, D), out_std),
              "q_norm": jnp.ones((n, Dh)), "k_norm": jnp.ones((n, Dh)),
-             "mlp": {"w_gate": w((n, D, F), std), "w_up": w((n, D, F), std),
-                     "w_down": w((n, F, D), out_std)}}
+             "mlp": mlp(n)}
         if o_norm:
             p["o_norm"] = jnp.ones((n, H * Dh))
         return p
@@ -177,8 +217,7 @@ def init_params(rng, cfg: GPTConfig):
                     "D": jnp.ones((n, m.n_heads)),
                     "norm": jnp.ones((n, m.d_ssm)),
                     "w_out": w((n, m.d_ssm, D), out_std)},
-            "mlp": {"w_gate": w((n, D, F), std), "w_up": w((n, D, F), std),
-                    "w_down": w((n, F, D), out_std)}}
+            "mlp": mlp(n)}
     if cfg.count("eva"):
         n = cfg.count("eva")
         one = 1.0 - cfg.norm_offset     # the norms scale by 1 as they start
@@ -188,9 +227,18 @@ def init_params(rng, cfg: GPTConfig):
             "wo": w((n, H * Dh, D), out_std),
             # the pooling vectors of a head: a chunk's keys, then its values
             "mu": w((n, H, Dh), 1.0), "phi": w((n, H, Dh), 1.0),
-            "mlp": {"w_gate": w((n, D, F), std), "w_up": w((n, D, F), std),
-                    "w_down": w((n, F, D), out_std)}}
+            "mlp": mlp(n)}
         params["final_norm"]["scale"] = jnp.full((D,), one)
+    for name in ("full_attn", "window_attn"):
+        n = cfg.count(name)
+        if n:
+            p = {"ln1": jnp.ones((n, D)), "ln2": jnp.ones((n, D)),
+                 "wqkv": w((n, D, cfg.qkv_dim), std),
+                 "wo": w((n, H * Dh, D), out_std), "mlp": mlp(n)}
+            if cfg.gqa.qk_norm:
+                p["q_norm"] = jnp.ones((n, Dh))
+                p["k_norm"] = jnp.ones((n, Dh))
+            params[name] = p
     return params
 
 
@@ -228,7 +276,7 @@ def mixed_block(cfg: GPTConfig, kind: str, x, p, positions, core):
         y = (ctx.reshape(B, S, H * Dh) * gate) @ p["wo"].astype(cdt)
         x = x + (r * y).astype(cdt)
     with jax.named_scope("ds.mlp"):
-        m = gated_ffn(rms_norm(x, p["ln2"], eps), p["mlp"], cdt)
+        m, _ = feed_forward(cfg, rms_norm(x, p["ln2"], eps), p["mlp"])
         x = x + (r * m).astype(cdt)
     return x, aux
 
@@ -279,7 +327,8 @@ def mamba_attn_block(cfg: GPTConfig, x, p, positions, attend, scan):
                       @ p["wo"].astype(cdt), m.attn_out)
     x = x + ssm + attn
     with jax.named_scope("ds.mlp"):
-        mlp = gated_ffn(rms_norm(x, p["ln2"], eps), p["mlp"], cdt, m.mlp_gate)
+        mlp, _ = feed_forward(cfg, rms_norm(x, p["ln2"], eps), p["mlp"],
+                              gate_mult=m.mlp_gate)
         x = x + scaled(mlp, m.mlp_out)
     return x, (kept_attn, kept_ssm)
 
@@ -324,8 +373,48 @@ def eva_block(cfg: GPTConfig, x, p, positions, attend):
         y = ctx.astype(cdt).reshape(B, S, H * Dh) @ p["wo"].astype(cdt)
         x = x + y.astype(x.dtype)
     with jax.named_scope("ds.mlp"):
-        x = x + gated_ffn(norm(x, p["ln2"]), p["mlp"], cdt).astype(x.dtype)
+        x = x + feed_forward(cfg, norm(x, p["ln2"]), p["mlp"])[0].astype(
+            x.dtype)
     return x, aux
+
+
+def grouped_attn_block(cfg: GPTConfig, kind: str, x, p, positions, attend,
+                       live=None, stacked=None):
+    """A ``full_attn`` or ``window_attn`` layer: x + Attn(RMSNorm(x)),
+    then x + FFN(RMSNorm(x)), no bias; ``n_head`` query heads over
+    ``kv_heads`` key heads of ``head_dim`` (whatever ``d_model`` is), q
+    and k normed a head where ``cfg.gqa.qk_norm``, then turned by the
+    kind's rotary constants; the feed-forward is the configuration's
+    (``feed_forward``), ``live`` (B, S) its real tokens. ``attend(q, k, v)
+    -> (ctx (B, S, H, Dh), kept)`` knows the cache and the window: q and
+    k come rotated, q NOT yet scaled. ``stacked``: (the kind's whole
+    stack of ``mlp`` trees, this layer's index in it) where a program
+    loops over the stack by a traced index: routed experts then read
+    their weights in the stack, where ``p["mlp"]`` would be a copy.
+    Returns (x, (kept, the experts' counts))."""
+    cdt, eps = cfg.dtype, cfg.layernorm_eps
+    B, S, _ = x.shape
+    H, Hkv, Dh = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    rope = cfg.gqa.rope(kind)
+    with jax.named_scope("ds.attn"):
+        qkv = rms_norm(x, p["ln1"], eps) @ p["wqkv"].astype(cdt)
+        q = qkv[..., :H * Dh].reshape(B, S, H, Dh)
+        k = qkv[..., H * Dh:(H + Hkv) * Dh].reshape(B, S, Hkv, Dh)
+        v = qkv[..., (H + Hkv) * Dh:].reshape(B, S, Hkv, Dh)
+        if cfg.gqa.qk_norm:
+            q = rms_norm(q, p["q_norm"], eps)
+            k = rms_norm(k, p["k_norm"], eps)
+        q = rotary_embedding(q, positions, Dh, rope=rope)
+        k = rotary_embedding(k, positions, Dh, rope=rope)
+        ctx, kept = attend(q, k, v)
+        x = x + ctx.astype(cdt).reshape(B, S, H * Dh) @ p["wo"].astype(cdt)
+    with jax.named_scope("ds.mlp"):
+        mlp, layer = (p["mlp"], None) if stacked is None \
+            or not cfg.moe_num_experts else stacked
+        y, counts = feed_forward(cfg, rms_norm(x, p["ln2"], eps), mlp, live,
+                                 layer=layer)
+        x = x + y
+    return x, (kept, counts)
 
 
 def embed_tokens(cfg: GPTConfig, params, tokens, positions=None):
@@ -356,7 +445,7 @@ def head_logits(cfg: GPTConfig, params, x):
         if cfg.norm_offset:
             scale = scale + cfg.norm_offset
         x = rms_norm(x, scale, cfg.layernorm_eps)
-    if cfg.fp32_stream:
+    if cfg.fp32_stream or cfg.fp32_logits:
         def dot(a, b):
             return jnp.dot(a.astype(cfg.dtype), b,
                            preferred_element_type=jnp.float32)
@@ -820,6 +909,24 @@ def dense_eva_attention(q, k, v, mu, phi, ev):
                       preferred_element_type=jnp.float32)
 
 
+def dense_windowed_attention(q, k, v, window: int = 0):
+    """Grouped-query causal attention over a whole sequence, a query at
+    ``i`` seeing the keys ``i - window < j <= i`` (every ``j <= i`` where
+    ``window`` is 0). q: (S, H, Dh); k, v: (S, Hkv, Dh). O(S^2) memory: a
+    small-size form. -> (S, H, Dh) float32."""
+    S, H, Dh = q.shape
+    Hkv = k.shape[1]
+    pos = jnp.arange(S, dtype=jnp.int32)
+    see = pos[None, :] <= pos[:, None]
+    if window:
+        see = see & (pos[None, :] > pos[:, None] - window)
+    s = jnp.einsum("qhgd,khd->qhgk", q.reshape(S, Hkv, H // Hkv, Dh), k,
+                   preferred_element_type=jnp.float32) / math.sqrt(Dh)
+    pr = jax.nn.softmax(jnp.where(see[:, None, None, :], s, NEG), -1)
+    return jnp.einsum("qhgk,khd->qhgd", pr.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).reshape(S, H, Dh)
+
+
 def forward(cfg: GPTConfig, params, tokens):
     """tokens (1, S) -> logits (1, S, V): the mixed stack with no cache,
     each mixer by its definition (S a multiple of the sparse block where
@@ -830,6 +937,14 @@ def forward(cfg: GPTConfig, params, tokens):
     positions = jnp.arange(S, dtype=jnp.int32)
 
     def body(kind, x, p, _i):
+        if kind in ("full_attn", "window_attn"):
+            def attend(q, k, v):
+                return dense_windowed_attention(
+                    q[0], k[0], v[0],
+                    cfg.gqa.window if kind == "window_attn" else 0)[None], ()
+
+            return grouped_attn_block(cfg, kind, x, p, positions,
+                                      attend)[0], ()
         if kind == "eva":
             def attend(q, k, v):
                 return dense_eva_attention(q[0], k[0], v[0], p["mu"],

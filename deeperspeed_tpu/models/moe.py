@@ -20,6 +20,25 @@ Public surface:
   init_moe_params / moe_param_specs — expert FFN + router pytrees
   moe_ffn(params, x, ...) -> (y, aux) — drop-in replacement for a dense FFN
   load_balancing_loss / router_z_loss
+
+All of that is TRAINING's (and the ``attention`` kind's in the serving
+programs, which take its dropless route: a token over capacity would be
+a wrong token served): GeLU experts with biases, four ``dispatch_impl``s,
+auxiliary losses. What a served stack of mixed layers takes as its
+feed-forward (``mixers.feed_forward``, where ``GPTConfig.moe_num_experts``
+is set) is the last section of this file:
+
+  init_gated_experts — router + ``w_gate``/``w_up``/``w_down`` with a
+      leading expert axis, no bias
+  gated_experts(p, m, top_k, ...) -> (y, counts) — gated SiLU experts,
+      routed as a config states it (a float32 softmax over all experts,
+      the ``top_k`` largest, renormalised to sum 1 where it says so), with
+      NO capacity: assignments sorted by expert feed three grouped
+      products (``ops/pallas/grouped_matmul``: the kernel on one TPU,
+      ``jax.lax.ragged_dot`` elsewhere), every one computed whatever the
+      imbalance; a token that is not
+      ``live`` (an idle lane of a decode step, a prompt chunk's padding)
+      is routed nowhere and touches no expert
 """
 
 import dataclasses
@@ -489,3 +508,102 @@ def moe_ffn(params, x, cfg: MoEConfig, mesh=None, activation=None):
 def moe_loss(aux, cfg: MoEConfig):
     """Total auxiliary loss term for one (or summed) moe_ffn aux dicts."""
     return cfg.aux_loss_coef * aux["aux_loss"] + cfg.z_loss_coef * aux["z_loss"]
+
+
+# ------------------------------------------------------------------ #
+# served: gated experts, routed as the config states, nothing dropped
+# ------------------------------------------------------------------ #
+
+# what ``gated_experts`` counts of one call, in this order
+EXPERT_COUNTS = ("experts_touched", "assignments", "max_load")
+
+
+def init_gated_experts(rng, d_model: int, d_ff: int, num_experts: int,
+                       std: float = 0.02, out_std: Optional[float] = None):
+    """Router and gated expert weights, float32, the expert axis first."""
+    E, D, F = num_experts, d_model, d_ff
+    k = jax.random.split(rng, 4)
+    out_std = std if out_std is None else out_std
+    w = lambda key, shape, s: jax.random.normal(key, shape, jnp.float32) * s
+    return {"router": w(k[0], (D, E), std),
+            "w_gate": w(k[1], (E, D, F), std), "w_up": w(k[2], (E, D, F), std),
+            "w_down": w(k[3], (E, F, D), out_std)}
+
+
+def route_top_k(m, router, top_k: int, normalize: bool):
+    """Which experts each token goes to, and with what weight. m: (T, D)
+    in the compute dtype; router: (D, E). The logits' products are exact
+    (both operands in m's dtype, sums in float32), the softmax over ALL
+    experts float32; the ``top_k`` largest probabilities win, equal ones
+    to the lower index; ``normalize`` divides the winners' by their sum.
+    -> (experts (T, top_k) int32, gates (T, top_k) float32)."""
+    logits = jnp.dot(m, router.astype(m.dtype),
+                     preferred_element_type=jnp.float32)
+    gate, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if normalize:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), gate
+
+
+def gated_experts(p, m, top_k: int, normalize: bool = True, live=None,
+                  gate_mult: float = 1.0, layer=None):
+    """``sum_{e in top-k} g_e W_down^e(SiLU(W_gate^e m) * (W_up^e m))``
+    for every token of m (T, D), with NO capacity: the T * top_k
+    assignments are sorted by expert and meet the experts' weights in
+    three grouped products (``ops/pallas/grouped_matmul``: the kernel on a
+    TPU, ``jax.lax.ragged_dot`` elsewhere), so the work follows the
+    assignments however they fall (all on one expert included) and none
+    is dropped. p: ``init_gated_experts``' tree, any float dtype (cast to
+    m's); with ``layer`` (a traced index) p is the STACK of many layers'
+    trees, a layer axis first, and layer ``layer`` is meant: the products
+    then take the whole stack as ``layers x experts`` groups with the
+    other layers' sizes zero, and read the layer's weights where they lie
+    (a slice by a traced index would be copied for them, every call).
+    ``live`` (T,) bool: a token that is not live is assigned to no expert
+    (it sorts past the last group, whose rows the product leaves alone)
+    and gets zeros. -> (y (T, D) in m's dtype, counts (3,) int32:
+    ``EXPERT_COUNTS``, the experts with any assignment, the assignments,
+    the largest expert's)."""
+    from ..ops.pallas.grouped_matmul import grouped_matmul_for
+
+    T, D = m.shape
+    E = p["router"].shape[-1]
+    cdt = m.dtype
+    router = p["router"] if layer is None else p["router"][layer]
+    with jax.named_scope("ds.moe.route"):
+        experts, gate = route_top_k(m, router, top_k, normalize)
+        flat = experts.reshape(-1)                  # token-major (T k,)
+        if live is not None:
+            flat = jnp.where(jnp.repeat(live, top_k), flat, E)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)[:E]
+        # where each assignment's row went, to bring its result home
+        home = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * top_k, dtype=order.dtype))
+    with jax.named_scope("ds.moe.experts"):
+        xs = m[order // top_k]                      # (T k, D) by expert
+        groups = sizes
+        if layer is not None:
+            n = p["w_gate"].shape[0]
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros((n * E,), jnp.int32), sizes, (layer * E,))
+
+        def product(x, w):
+            w = w.astype(cdt)
+            if layer is not None:
+                w = w.reshape(n * E, *w.shape[2:])
+            return grouped_matmul_for(x, w)(x, w, groups)
+
+        h = product(xs, p["w_gate"])
+        if gate_mult != 1.0:
+            h = h * jnp.asarray(gate_mult, h.dtype)
+        h = jax.nn.silu(h) * product(xs, p["w_up"])
+        out = product(h.astype(cdt), p["w_down"])
+        # a token's top_k results weighted and summed in float32
+        y = jnp.sum(out[home].reshape(T, top_k, D).astype(jnp.float32)
+                    * gate[..., None], axis=1)
+        if live is not None:
+            y = jnp.where(live[:, None], y, 0.0)
+    counts = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32),
+                        jnp.sum(sizes, dtype=jnp.int32), jnp.max(sizes)])
+    return y.astype(cdt), counts

@@ -268,13 +268,37 @@ class PageRule:
     positions: ``ceil((n // chunk) / block_size)`` pages, the rows of the
     window being filled among them (written as chunks complete, seen once
     the window is left behind). A slot's table holds the roles side by
-    side: ``[exact keys | summaries]``."""
+    side: ``[exact keys | summaries]``.
+
+    ``ring > 0`` (a stack that holds layers of TWO rules: layers that keep
+    every key beside layers that keep the last ``ring`` keys): TWO roles,
+    each the rule of one kind of layer, each in a POOL of its own as deep
+    as the layers that read it (``pools``): ``ceil(n / block_size)`` pages
+    of the first pool for the layers whose pages follow the length, and
+    ``min(ceil(n / block_size), ring / block_size)`` pages of the second
+    for the others, position ``i`` in row ``i mod ring``, a row overwritten
+    ``ring`` positions later. A slot's table: ``[every key | the ring]``,
+    each section's entries naming pages of its own pool."""
     window: int = 0
     chunk: int = 0
+    ring: int = 0
+
+    def __post_init__(self):
+        if self.ring and self.window:
+            raise ValueError(
+                "a ring beside pages of two roles is no cache this "
+                f"repository has (got {self})")
+
+    @property
+    def pools(self) -> Tuple[int, ...]:
+        """The pool each role's pages live in, role by role."""
+        return (0, 1) if self.ring else (0,) * (2 if self.window else 1)
 
     def counts(self, n: int, block_size: int) -> Tuple[int, ...]:
         """Pages of each role that ``n`` positions need."""
         pages = math.ceil(n / block_size) if n > 0 else 0
+        if self.ring:
+            return (pages, min(pages, self.ring // block_size))
         if not self.window:
             return (pages,)
         return (min(pages, self.window // block_size),
@@ -439,6 +463,16 @@ class ServingConfig:
     def pages_needed(self, n_tokens: int) -> int:
         """Pages a slot holds for ``n_tokens`` positions, all roles."""
         return sum(self.pages_by_role(n_tokens))
+
+    @property
+    def pool_blocks(self) -> Tuple[int, ...]:
+        """Pages of each pool of the cache, its null page among them:
+        ``num_blocks`` of the pool whose pages follow the length, and,
+        where the rule keeps a ring in a pool of its own, every slot's
+        whole ring there (no request ever waits for a page of it)."""
+        if not self.page_rule.ring:
+            return (self.num_blocks,)
+        return (self.num_blocks, self.num_slots * self.table_widths[1] + 1)
 
     @property
     def usable_blocks(self) -> int:

@@ -78,7 +78,7 @@ import numpy as np
 from ..models.generation import apply_with_cache, init_cache, \
     prep_sampling_logits
 from ..models import mixers
-from ..models.gpt import GPTConfig, decoder_block
+from ..models.gpt import GROUPED_KINDS, GPTConfig, decoder_block
 from ..models.speculative import engine_sample_key
 from ..monitor import get_monitor, init_monitor, install_compile_listener
 from ..monitor.tracer import (
@@ -93,6 +93,7 @@ from .kv_cache import (
     NULL_BLOCK,
     PagedKVCache,
     chunk_attend_all,
+    chunk_attend_past,
     decode_attend_all,
     decode_attend_for,
     eva_chunk_past,
@@ -100,6 +101,9 @@ from .kv_cache import (
     eva_page_list,
     lightning_chunk_for,
     page_rule_for,
+    ring_chunk_attend,
+    ring_decode_attend,
+    ring_decode_indices,
     slot_attend_for,
     sparse_attend_for,
     ssm_rows_for,
@@ -112,6 +116,7 @@ from .kv_cache import (
     write_decode_rows,
     write_eva_chunk,
     write_eva_decode,
+    write_ring_chunk,
     write_rows,
 )
 from .metrics import ServingMetrics
@@ -203,7 +208,7 @@ def unpack_slots(slots, bps: int):
 
 
 def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
-                 kind: str = "attention"):
+                 kind: str = "attention", live=None, stacked=None):
     """One layer of the stack inside a serving program, by its ``kind``
     (one of ``cfg.layer_kinds``). The layer math is the model's own:
     gpt.decoder_block's for an ``attention`` layer (the block training
@@ -214,7 +219,16 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
     state row), and ``kept`` (what the caller keeps of the new tokens:
     keys and values, a new state) comes back beside the layer's output; a
     ``mamba_attn`` layer has two caches and takes the pair of its cores,
-    (attention's, the state-space scan's)."""
+    (attention's, the state-space scan's). ``live``: the tokens that are
+    real, for a feed-forward of routed experts (a ``full_attn`` or
+    ``window_attn`` layer's ``kept`` comes paired with their counts, and
+    ``stacked`` is ``grouped_attn_block``'s: the experts' stack and the
+    layer's place in it). An
+    ``attention`` layer's experts go the dropless way here: what training
+    bounds by a capacity would be a wrong token served."""
+    if kind in GROUPED_KINDS:
+        return mixers.grouped_attn_block(cfg, kind, x, layer_params,
+                                         positions, attend, live, stacked)
     if kind == "mamba_attn":
         return mixers.mamba_attn_block(cfg, x, layer_params, positions,
                                        *attend)
@@ -226,6 +240,8 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
     moe_cfg = cfg.moe
     if moe_cfg is not None:
         from ..models.moe import moe_ffn
+
+        moe_cfg = dataclasses.replace(moe_cfg, dispatch_impl="dropless")
 
         def mlp_fn(mlp_in):
             return moe_ffn(layer_params["moe"], mlp_in, moe_cfg)
@@ -273,6 +289,14 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     attends over the pool's positions below the slot's length plus the
     new token's own row), yields the new rows, and one scatter after the
     loop writes all layers' rows into the donated buffers.
+    A stack of ``full_attn`` and ``window_attn`` layers hands ``k_pool``
+    and ``v_pool`` as PAIRS (the pool of every key, the rings' pool: two
+    page rules, ``kv_cache`` has the layout) and gets pairs back; where
+    its feed-forward is routed experts, ``next_tokens`` and ``prev`` carry
+    three more entries behind the slots': the experts touched (summed
+    over the layers), the assignments (summed) and the largest expert's
+    assignments (the largest of any layer) of this step, so that the one
+    read-back the loop makes brings them too.
     temps[i] <= 0 selects greedy argmax for slot i; > 0 samples at
     that temperature under the config's static top_k, keyed by
     ``request_sample_key(seeds[i], counts[i])`` so the sampled stream is
@@ -292,8 +316,10 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                        state=None):
         tables, lengths, tokens, temps, seeds, counts = unpack_slots(
             slots, scfg.blocks_per_slot)
-        tokens = jnp.where(tokens == TAKE_PREV, prev, tokens)
         N = tokens.shape[0]
+        if counts_experts(cfg):
+            prev = prev[:N]
+        tokens = jnp.where(tokens == TAKE_PREV, prev, tokens)
         positions = lengths[:, None]                        # (N, 1)
         with jax.named_scope("ds.embed"):
             x = mixers.embed_tokens(cfg, params, tokens,
@@ -319,6 +345,16 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
             # a slot whose prompt is still being chunked in is idle here:
             # its state row is the chunks' to write
             live = (lengths > 0)[:, None, None, None]
+        real = None
+        if kinds & GROUPED_KINDS:
+            (k_full, k_ring), (v_full, v_ring) = k_pool, v_pool
+            at = ring_decode_indices(scfg, tables, lengths)
+            attend_full = slot_attend_for(k_full, cfg.n_head,
+                                          at["full"].shape, mesh)
+            attend_ring = slot_attend_for(k_ring, cfg.n_head,
+                                          at["others"].shape, mesh)
+            # an idle lane is no token: it is routed to no expert
+            real = (lengths > 0)[:, None]
 
         def layer_body(kind, carry, layer_params, layer):
             """A layer by its kind: the new token's row cast to what the
@@ -364,6 +400,23 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                         at["count"], attend_slots)
                 return ctx, (k_row, v_row)
 
+            def every_key(q, k, v):
+                k_row = k[:, 0].astype(k_full.dtype)
+                v_row = v[:, 0].astype(v_full.dtype)
+                ctx = decode_attend_all(k_full, v_full, layer, q, k_row,
+                                        v_row, at["full"], lengths,
+                                        attend_full)
+                return ctx, (k_row, v_row)
+
+            def last_keys(q, k, v):
+                """The ring: its page that takes the new key in XLA, the
+                others through the page-list read."""
+                k_row = k[:, 0].astype(k_ring.dtype)
+                v_row = v[:, 0].astype(v_ring.dtype)
+                ctx = ring_decode_attend(k_ring, v_ring, layer, q, k_row,
+                                         v_row, at, attend_ring)
+                return ctx, (k_row, v_row)
+
             def state_space(xbc, dt):
                 """The new token's convolution, then every slot's state
                 row through the recurrence, in place in the carry."""
@@ -381,9 +434,11 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
 
             core = {"attention": attention, "minicpm4": minicpm4,
                     "lightning": lightning, "eva": two_roles,
+                    "full_attn": every_key, "window_attn": last_keys,
                     "mamba_attn": (all_pages, state_space)}[kind]
-            x, kept = _paged_block(cfg, x, layer_params, positions, core,
-                                   kind)
+            x, kept = _paged_block(
+                cfg, x, layer_params, positions, core, kind, real,
+                (params[mixers.STACK_KEY[kind]].get("mlp"), layer))
             if kind == "mamba_attn":
                 kept, rows = kept
             if kind == "lightning":
@@ -414,6 +469,19 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 k_pool, v_pool = write_eva_decode(
                     cfg.eva, k_pool, v_pool, at, *kept["eva"],
                     params["eva"]["mu"], params["eva"]["phi"])
+            experts = []
+            if "full_attn" in kept:
+                (k_rows, v_rows), n = kept["full_attn"]
+                experts.append(n)
+                k_full = write_rows(k_full, at["page"], at["row"], k_rows)
+                v_full = write_rows(v_full, at["page"], at["row"], v_rows)
+            if "window_attn" in kept:
+                (k_rows, v_rows), n = kept["window_attn"]
+                experts.append(n)
+                k_ring = write_rows(k_ring, at["ring_page"], at["row"], k_rows)
+                v_ring = write_rows(v_ring, at["ring_page"], at["row"], v_rows)
+            if kinds & GROUPED_KINDS:
+                k_pool, v_pool = (k_full, k_ring), (v_full, v_ring)
         with jax.named_scope("ds.decode/sample"):
             logits = mixers.served_logits(
                 cfg, mixers.head_logits(cfg, params, x)[:, 0])  # (N, V)
@@ -428,9 +496,26 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 lambda k, row: jax.random.categorical(k, row)
             )(keys, l32).astype(jnp.int32)
             nxt = jnp.where(temps > 0.0, sampled, greedy)
+        if counts_experts(cfg):
+            nxt = jnp.concatenate([nxt, sum_expert_counts(experts)])
         return nxt, k_pool, v_pool, kc_pool, state
 
     return ds_decode_step
+
+
+def counts_experts(cfg: GPTConfig) -> bool:
+    """Whether this stack's programs count what their routed experts did
+    (``moe.EXPERT_COUNTS``): the stacks whose layers hand the counts out."""
+    return bool(cfg.moe_num_experts) and bool(
+        set(cfg.layer_kinds) & GROUPED_KINDS)
+
+
+def sum_expert_counts(by_kind):
+    """One program's counts from its layers' (``(layers, 3)`` a kind):
+    experts touched and assignments summed over the layers, the largest
+    expert's load the largest of any layer. -> (3,) int32."""
+    n = jnp.concatenate(by_kind)
+    return jnp.stack([jnp.sum(n[:, 0]), jnp.sum(n[:, 1]), jnp.max(n[:, 2])])
 
 
 def prefill_chunk_for(cfg: GPTConfig, scfg: ServingConfig) -> int:
@@ -440,8 +525,10 @@ def prefill_chunk_for(cfg: GPTConfig, scfg: ServingConfig) -> int:
     falls on one side of ``dense_len`` and has the chunk's own keys
     inside its local window."""
     sp, ev = cfg.sparse, cfg.eva
+    ring = cfg.gqa.window if cfg.count("window_attn") else 0
     C = scfg.prefill_chunk or (sp.window_size if sp is not None else 1024)
-    bad = C % scfg.block_size != 0
+    # a chunk's keys go over whole pages of the ring and never past its end
+    bad = C % scfg.block_size != 0 or (ring and ring % C != 0)
     if sp is not None:
         bad = bad or C > sp.window_size or sp.dense_len % C \
             or C % sp.kernel_stride
@@ -460,7 +547,10 @@ def prefill_chunk_for(cfg: GPTConfig, scfg: ServingConfig) -> int:
             + (f" and divide the window ({ev.window}: a prompt chunk never "
                f"straddles a window, whose pages are reused), its "
                f"{ev.chunk}-position chunks filling whole pages of summaries "
-               f"or a part of one" if ev is not None else ""))
+               f"or a part of one" if ev is not None else "")
+            + (f" and divide the window ({ring}): a prompt chunk is written "
+               f"over the ring of the last {ring} keys from row offset mod "
+               f"{ring} on and may not run past its end" if ring else ""))
     return C
 
 
@@ -478,8 +568,13 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     at or beyond ``n_valid`` leaving it as it was; it attends over the
     slot's pages (the selected ones in a sparse layer, all of the past in
     a mamba_attn layer; in an eva layer the summaries of the windows left
-    behind and the window's pages before the chunk) and writes its own
-    keys, values and pooled keys after the layer loop, in place."""
+    behind and the window's pages before the chunk; all of the past in a
+    full_attn layer, a tile at a time as long as it is; in a window_attn
+    layer the ring's rows inside each query's band) and writes its own
+    keys, values and pooled keys after the layer loop, in place (over the
+    ring its valid rows alone). Where the stack counts its experts
+    (``counts_experts``) the first output is the pair (logits, the
+    chunk's counts (3,) int32)."""
     C = prefill_chunk_for(cfg, scfg)
     bs = scfg.block_size
     sp, ev = cfg.sparse, cfg.eva
@@ -492,7 +587,8 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         positions = offset + jnp.arange(C, dtype=jnp.int32)
         # the last chunk may run past the table's end: null pages there
         table_row = jnp.pad(table_row, (0, C // bs))
-        attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
+        if sp is not None:
+            attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
         if ev is not None:
             n_past = eva_chunk_past(ev, scfg, C)
             past, n_seen = eva_page_list(ev, scfg, table_row, offset, n_past)
@@ -501,6 +597,15 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 offset == 0, 0.0,
                 jax.lax.dynamic_index_in_dim(rows, slot, 1, keepdims=False)),
             state)
+        real = None
+        if cfg.count("full_attn") or cfg.count("window_attn"):
+            (k_full, k_ring), (v_full, v_ring) = k_pool, v_pool
+            n_full = scfg.table_widths[0]
+            full_row, ring_row = table_row[:n_full + C // bs], \
+                table_row[n_full:n_full + scfg.table_widths[1]]
+            full_row = full_row.at[n_full:].set(NULL_BLOCK)
+            # a chunk's padding is no token: it is routed to no expert
+            real = (jnp.arange(C) < n_valid)[None, :]
 
         def layer_body(kind, x, layer_params, layer):
             def minicpm4(q, k, v):
@@ -535,10 +640,25 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                                            vv, past, n_seen, n_past)
                 return ctx[None], (kk, vv)
 
+            def every_key(q, k, v):
+                kk, vv = k[0].astype(k_full.dtype), v[0].astype(v_full.dtype)
+                ctx = chunk_attend_past(k_full, v_full, layer, q[0], kk, vv,
+                                        full_row, offset)
+                return ctx[None], (kk, vv)
+
+            def last_keys(q, k, v):
+                kk, vv = k[0].astype(k_ring.dtype), v[0].astype(v_ring.dtype)
+                ctx = ring_chunk_attend(cfg.gqa.window, k_ring, v_ring, layer,
+                                        q[0], kk, vv, ring_row, offset)
+                return ctx[None], (kk, vv)
+
             core = {"minicpm4": minicpm4, "lightning": lightning,
-                    "eva": two_roles,
+                    "eva": two_roles, "full_attn": every_key,
+                    "window_attn": last_keys,
                     "mamba_attn": (all_past, state_space)}[kind]
-            return _paged_block(cfg, x, layer_params, positions, core, kind)
+            return _paged_block(
+                cfg, x, layer_params, positions, core, kind, real,
+                (params[mixers.STACK_KEY[kind]].get("mlp"), layer))
 
         x, kept = mixers.scan_runs(cfg, params, x, layer_body)
         with jax.named_scope("ds.prefill/kv_write"):
@@ -561,9 +681,25 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 k_pool, v_pool = write_eva_chunk(
                     ev, scfg, k_pool, v_pool, table_row, offset, n_valid,
                     *kept["eva"], params["eva"]["mu"], params["eva"]["phi"])
+            experts = []
+            if "full_attn" in kept:
+                (kk, vv), n = kept["full_attn"]
+                experts.append(n)
+                k_full, v_full = write_chunk_pages(k_full, v_full, full_row,
+                                                   offset, kk, vv)
+            if "window_attn" in kept:
+                (kk, vv), n = kept["window_attn"]
+                experts.append(n)
+                k_ring, v_ring = write_ring_chunk(
+                    cfg.gqa.window, k_ring, v_ring, ring_row, offset, n_valid,
+                    kk, vv)
+            if experts:
+                k_pool, v_pool = (k_full, k_ring), (v_full, v_ring)
         last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0)
-        return (mixers.head_logits(cfg, params, last)[0], k_pool, v_pool,
-                kc_pool, state)
+        logits = mixers.head_logits(cfg, params, last)[0]
+        if counts_experts(cfg):
+            logits = (logits, sum_expert_counts(experts))
+        return logits, k_pool, v_pool, kc_pool, state
 
     return ds_prefill_chunk
 
@@ -818,6 +954,9 @@ class _Launched:
     # launched it after one with nothing in flight, while a token was owed
     held_prefill: bool = False
     discarded: int = 0  # rows whose token was dropped when it was read
+    # the experts' counts of the prompt chunks dispatched before it (on
+    # the device: finished when this step is)
+    chunk_counts: Sequence[Any] = ()
 
     @property
     def held(self) -> str:
@@ -855,7 +994,8 @@ class ServingEngine(_ServingBase):
         if not cfg.classic:
             kinds = sorted(set(cfg.mixer_types))
             if scfg.prefix_caching and \
-                    set(kinds) & {"lightning", "mamba_attn", "eva"}:
+                    set(kinds) & {"lightning", "mamba_attn", "eva",
+                                  "window_attn"}:
                 raise ValueError(
                     "prefix_caching cannot serve a model with a layer that "
                     "keeps recurrent state, or pages that are overwritten "
@@ -879,7 +1019,7 @@ class ServingEngine(_ServingBase):
         self.kv = PagedKVCache(cfg, scfg)
         if mesh is not None:
             self._place_kv_pools()
-        super().__init__(scfg, Scheduler(scfg, self.kv.allocator, clock),
+        super().__init__(scfg, Scheduler(scfg, self.kv.allocators, clock),
                          clock, monitor, monitor_config)
         self._decode_step = make_decode_step(cfg, scfg, mesh)
         # whether that program's list-sharing layers copy a page once for
@@ -889,12 +1029,22 @@ class ServingEngine(_ServingBase):
             set(cfg.layer_kinds) & SLOT_LIST_KINDS) and takes_slot_form(
             self.kv.k, cfg.n_head, (scfg.num_slots, scfg.blocks_per_slot),
             mesh)
+        if cfg.count("full_attn"):      # its list: the table's first section
+            self._slot_rows = takes_slot_form(
+                self.kv.k[0], cfg.n_head,
+                (scfg.num_slots, scfg.table_widths[0]), mesh)
+        # whether the programs count their routed experts: the decode
+        # step's tokens then come with three counts behind them
+        self._counts_experts = counts_experts(cfg)
         # the last decode step's tokens as the device handed them back,
         # the next step's ``prev`` (zeros until a step has run), and the
         # steps launched and not yet read, oldest first: one between
         # step() calls, two between a launch and the collect after it
         self._prev = jnp.asarray(self._place_slot_array(
-            np.zeros(scfg.num_slots, np.int32)))
+            np.zeros(scfg.num_slots + 3 * self._counts_experts, np.int32)))
+        # the counts of the prompt chunks dispatched since the last launch,
+        # on the device: read with the decode step queued behind them
+        self._chunk_counts: List[Any] = []
         self._inflight: Deque[_Launched] = deque()
 
         # retraces once per prefill bucket (toks.shape[1] varies)
@@ -1271,6 +1421,20 @@ class ServingEngine(_ServingBase):
                 * sum(sp.topk - mixers.forced_count(bt, sp)
                       for bt in range(first, first + n)))
 
+    def _chunk_pages_by_rule(self, offset: int) -> dict:
+        """The pages a prompt chunk at ``offset`` lists, rule by rule, as
+        its span carries them (a stack of two page rules alone): the
+        pages of every key before it, and the ring's that hold a position
+        before it."""
+        rule = self.scfg.page_rule
+        if not rule.ring:
+            return {}
+        full, ring = rule.counts(offset, self.scfg.block_size)
+        n_full, n_ring = (self.cfg.count(k)
+                          for k in ("full_attn", "window_attn"))
+        return {"full_pages": str(full * n_full),
+                "window_pages": str(ring * n_ring)}
+
     def _forward_chunk(self, slot: int, state: dict) -> None:
         """One chunk of a mixed stack's prompt through ``ds_prefill_chunk``:
         pages, pooled keys and the slot's state row are written in place;
@@ -1283,7 +1447,8 @@ class ServingEngine(_ServingBase):
         # serving/prefill: a request's prompt work inside one step, as for
         # every model; the chunk inside it says where in the prompt it is
         with trace_span("serving/prefill", lane="serving", rid=req.rid,
-                        slot=slot, ctx_len=state["L"], bucket=C), \
+                        slot=slot, ctx_len=state["L"], bucket=C,
+                        **self._chunk_pages_by_rule(lo)), \
                 trace_span("serving/prefill_chunk", lane="serving",
                            rid=req.rid, chunk=c, tokens=hi - lo, offset=lo,
                            pages=named, listed_pages=f"{listed}/{named}"):
@@ -1297,6 +1462,9 @@ class ServingEngine(_ServingBase):
             with trace_span("serving/prefill/dispatch", lane="serving"):
                 logits, kv.k, kv.v, kv.kc, kv.state = \
                     self._chunk_step(*_pargs)
+            if self._counts_experts:
+                logits, counts = logits
+                self._chunk_counts.append(counts)
             if final:
                 self._end_prompt(slot, state, logits)
         self._prefill_spent += hi - lo
@@ -1483,6 +1651,7 @@ class ServingEngine(_ServingBase):
             live_pages = selected_pages = 0
             sp, rule = self.cfg.sparse, self.scfg.page_rule
             held, summary_rows, wraps = [0, 0], 0, 0
+            listed = [0, 0]     # a cache of two rules: pages a step lists
             for s, req in lanes:
                 tables[s] = self.sched.slot_table_row(s)
                 lengths[s] = req.cached_len
@@ -1490,11 +1659,20 @@ class ServingEngine(_ServingBase):
                 # the new token's included: all the pool a step need read
                 live = rule.live(req.cached_len + 1, self.scfg.block_size)
                 live_pages += live
-                if rule.window:
+                if rule.window or rule.ring:
                     for role, n in enumerate(self.sched.slot_roles[s]):
                         held[role] += n
+                if rule.window:
                     summary_rows += (req.cached_len + 1) % rule.chunk == 0
                     wraps += req.cached_len % rule.window == 0
+                if rule.ring:   # the new key goes over the ring's first row
+                    wraps += req.cached_len % rule.ring == 0
+                    # the pages of every key, and the ring's WHOLE pages
+                    # (the one that takes the new key is read beside them)
+                    full, ring = rule.counts(req.cached_len + 1,
+                                             self.scfg.block_size)
+                    listed[0] += full
+                    listed[1] += ring - 1
                 if sp is not None:
                     # what one selection of a sparse layer names of them
                     selected_pages += (live if req.cached_len + 1
@@ -1524,6 +1702,10 @@ class ServingEngine(_ServingBase):
             roles = {"window_pages": str(held[0]),
                      "summary_pages": str(held[1]),
                      "summary_rows": str(summary_rows), "wraps": str(wraps)}
+        if rule.ring:
+            self.metrics.record_ring_pages(len(lanes), *held, wraps)
+            roles = {"full_pages": str(listed[0]),
+                     "window_pages": str(listed[1]), "wraps": str(wraps)}
         with trace_span("serving/decode/dispatch", lane="serving",
                         live_pages=live_pages, view_pages=tables.size,
                         selected_pages=selected_pages,
@@ -1539,7 +1721,9 @@ class ServingEngine(_ServingBase):
         self._prev = self._place_slot_array(nxt)
         self._inflight.append(_Launched(
             nxt, lanes, self._chunk_ran, ahead,
-            held_prefill=self._prefill_ran and not ahead))
+            held_prefill=self._prefill_ran and not ahead,
+            chunk_counts=self._chunk_counts))
+        self._chunk_counts = []
         tel = self.telemetry
         if tel is not None:
             if tel.cost_index is not None:
@@ -1558,7 +1742,24 @@ class ServingEngine(_ServingBase):
         step = self._inflight.popleft()
         with trace_span("serving/decode/wait", lane="serving"):
             nxt = np.asarray(step.nxt)          # device sync
-        with trace_span("serving/decode/emit", lane="serving"):
+        experts = {}
+        if self._counts_experts:
+            # behind the slots' tokens: what the step's routed experts did
+            touched, assigned, most = (int(n) for n in nxt[-3:])
+            self.metrics.record_experts("decode", touched, assigned, most,
+                                        self.cfg.n_layer)
+            experts = {"experts": str(touched), "assignments": str(assigned),
+                       "max_load": str(most)}
+            if step.chunk_counts:       # finished before the step was
+                chunks = np.stack([np.asarray(c) for c in step.chunk_counts])
+                for c in chunks:
+                    self.metrics.record_experts("chunk", *(int(n) for n in c),
+                                                self.cfg.n_layer)
+                experts.update(
+                    chunks=str(len(chunks)),
+                    chunk_experts=str(int(chunks[:, 0].sum())),
+                    chunk_assignments=str(int(chunks[:, 1].sum())))
+        with trace_span("serving/decode/emit", lane="serving", **experts):
             for s, req in step.lanes:
                 req.in_flight -= 1
                 if self.sched.slots[s] is not req:

@@ -6,12 +6,21 @@ Layout: one pool per cache side, stacked over layers —
     k, v: (n_layer, num_blocks, block_size, n_kv_head, head_dim)
 
 A model of mixed layers (``GPTConfig.mixer_types``) keeps, by the kind of
-each layer, one of four shapes of cache: pages (``minicpm4``), a state
-row a slot (``lightning``), both for one layer (``mamba_attn``), or pages
-of two roles (``eva``: the exact keys of the current window in pages the
-slot reuses window after window, and pages of pooled summaries, a row
-for every chunk of positions; ``page_rule_for`` is how many of each a
-length needs, and a slot's table holds them side by side). Its
+each layer, one of five shapes of cache: pages (``minicpm4``,
+``full_attn``), a state row a slot (``lightning``), both for one layer
+(``mamba_attn``), pages of two roles (``eva``: the exact keys of the
+current window in pages the slot reuses window after window, and pages of
+pooled summaries, a row for every chunk of positions), or a ring of pages
+that holds the last ``window`` keys (``window_attn``). ``page_rule_for``
+is the RULE: how many pages of each role a length needs; a slot's table
+holds the roles side by side. The POOLS go by rule: one array a pool,
+each only as deep as the layers that read it. Every model but one has one
+pool; a stack of ``full_attn`` and ``window_attn`` layers has two (``k``
+and ``v`` are then PAIRS of arrays, the pool whose pages follow the
+length first, the rings' second, each with its own allocator and its own
+null page 0), so that no byte of a page belongs to a layer that never
+reads it: a page of the first is ``n_full`` layers deep, a page of the
+second ``n_window``. A mixed model's
 pages hold the layers that have any, a page's ``(block_size, head_dim)``
 last so that two or four key heads are not padded to a tile of sixteen:
 
@@ -63,7 +72,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.gpt import GPTConfig
+from ..models.gpt import GROUPED_KINDS, GPTConfig
 from .config import PageRule, ServingConfig
 
 NULL_BLOCK = 0
@@ -168,6 +177,10 @@ def page_rule_for(cfg: GPTConfig) -> PageRule:
     positions."""
     if cfg.count("eva"):
         return PageRule(window=cfg.eva.window, chunk=cfg.eva.chunk)
+    if cfg.count("window_attn"):
+        # beside the full layers' pages (none where the stack has no full
+        # layer: their pool is then no layer deep), a ring in its own pool
+        return PageRule(ring=cfg.gqa.window)
     return PageRule()
 
 
@@ -380,18 +393,25 @@ class PagedKVCache:
                     f"a page is one selection block: block_size must be "
                     f"{sp.block_size} (got {scfg.block_size}) and "
                     f"max_seq_len cover dense_len ({sp.dense_len})")
-            n_sp, n_li, n_ma, n_ev = (cfg.count(kind) for kind in (
-                "minicpm4", "lightning", "mamba_attn", "eva"))
-            if (n_ma or n_ev) and len(set(cfg.mixer_types)) > 1:
+            n_sp, n_li, n_ma, n_ev, n_fu, n_wi = (cfg.count(kind) for kind in (
+                "minicpm4", "lightning", "mamba_attn", "eva", "full_attn",
+                "window_attn"))
+            kinds = set(cfg.mixer_types)
+            if ((n_ma or n_ev) and len(kinds) > 1) \
+                    or ((n_fu or n_wi) and kinds - GROUPED_KINDS):
                 raise NotImplementedError(
                     "mamba_attn and eva layers share a stack with no other "
-                    "kind: the pool and the state rows are indexed by a "
-                    "layer's place among ONE kind, and a slot's table is "
-                    "laid out by one page rule")
+                    "kind, full_attn and window_attn layers with each other "
+                    "alone: a pool and the state rows are indexed by a "
+                    "layer's place among the kinds that share them, and "
+                    "only a ring has a pool beside the pages that follow "
+                    "the length")
             if n_ev:
                 check_eva_pages(cfg, scfg)
-            shape = (n_sp + n_ma + n_ev, nb, cfg.kv_heads, scfg.block_size,
-                     cfg.head_dim)
+            if n_wi:
+                check_ring_pages(cfg, scfg)
+            shape = (n_sp + n_ma + n_ev + n_fu, nb, cfg.kv_heads,
+                     scfg.block_size, cfg.head_dim)
             if sp is not None:
                 self.kc = jnp.zeros(
                     (n_sp, nb, cfg.kv_heads * sp.windows_per_block,
@@ -409,7 +429,16 @@ class PagedKVCache:
                                        m.conv_dim), cfg.dtype)}
         self.k = jnp.zeros(shape, cfg.dtype)
         self.v = jnp.zeros(shape, cfg.dtype)
-        self.allocator = BlockAllocator(nb)
+        # an allocator a pool, the one whose pages follow the length first
+        self.allocators = [BlockAllocator(nb)]
+        if scfg.page_rule.ring:
+            # the rings' pool beside it: as deep as the window layers
+            nr = scfg.pool_blocks[1]
+            ring = (cfg.count("window_attn"), nr) + shape[2:]
+            self.k = (self.k, jnp.zeros(ring, cfg.dtype))
+            self.v = (self.v, jnp.zeros(ring, cfg.dtype))
+            self.allocators.append(BlockAllocator(nr))
+        self.allocator = self.allocators[0]
         # both retrace once per page count of their dense side: the
         # scatter once a prefill bucket, the gather once a staging-cache
         # bucket (monitor.compile_account() counts them by name)
@@ -1205,3 +1234,168 @@ def write_eva_chunk(ev, scfg: ServingConfig, k_pool, v_pool, table_row,
         return pool.at[:, ids].set(jnp.concatenate([pages(rows), new], 1))
 
     return write(k_pool, kk, sk), write(v_pool, vv, sv)
+
+
+# ------------------------------------------------------------------ #
+# full_attn and window_attn: every key in one pool, a ring in another
+# ------------------------------------------------------------------ #
+
+
+def check_ring_pages(cfg: GPTConfig, scfg: ServingConfig) -> None:
+    """A slot's table is ``[a page for every block_size positions | window
+    / bs pages of the ring]``, each section naming pages of its own pool:
+    the ring is whole pages."""
+    if scfg.page_rule != page_rule_for(cfg):
+        raise ValueError(
+            f"the serving configuration's page rule ({scfg.page_rule}) is "
+            f"not this cache's ({page_rule_for(cfg)}): size it with "
+            "ServingConfig.for_cache(page_rule_for(cfg))")
+    if cfg.gqa.window % scfg.block_size:
+        raise ValueError(
+            f"ring pages: block_size ({scfg.block_size}) must divide the "
+            f"window ({cfg.gqa.window})")
+
+
+def pool_bytes(kv: "PagedKVCache") -> Tuple[int, ...]:
+    """Bytes of each pool of a cache, keys and values: what the rules'
+    arithmetic reckons (pages x layers that read them x a page's bytes)."""
+    pairs = zip(*(p if isinstance(p, tuple) else (p,) for p in (kv.k, kv.v)))
+    return tuple(k.nbytes + v.nbytes for k, v in pairs)
+
+
+def ring_decode_indices(scfg: ServingConfig, tables, lengths):
+    """Where a decode step reads and writes in a stack of full_attn and
+    window_attn layers, the same for every layer of a kind. Slot i's new
+    token sits at position ``t = lengths[i]``. Full layers: row ``t mod
+    bs`` of page ``t // bs`` of the table's first section, which they
+    read whole. Window layers: ring row ``t mod ring``, on the ring's
+    page ``own``, where position ``t - ring`` (just out of the window)
+    lies until the new key replaces it; every OTHER page of the ring that
+    holds a position of the window is whole inside it: all of them once
+    the ring has wrapped (listed from the one after ``own``, in ring
+    order), the pages before ``own`` until then. ``tables``: (N,
+    blocks_per_slot), ``[every key | the ring]``."""
+    bs = scfg.block_size
+    n_full, R = scfg.table_widths
+    t = lengths
+    full, ring = tables[:, :n_full], tables[:, n_full:]
+    one = lambda tab, i: jnp.take_along_axis(tab, i[:, None], 1)[:, 0]
+    own = (t // bs) % R
+    wrapped = t >= R * bs
+    j = jnp.arange(R, dtype=jnp.int32)[None, :]
+    entry = jnp.where(wrapped[:, None], (own[:, None] + 1 + j) % R, j)
+    return {"full": full, "page": one(full, t // bs), "row": t % bs,
+            "ring_page": one(ring, own), "wrapped": wrapped,
+            "others": jnp.take_along_axis(ring, entry, 1),
+            "others_rows": jnp.where(wrapped, R - 1, own) * bs}
+
+
+def ring_decode_attend(k_pool, v_pool, layer, q, k_row, v_row, at,
+                       attend_slots):
+    """One window_attn layer's decode attention for all slots: the new
+    token at position t over the keys ``t - ring < j <= t``. The ring's
+    page that takes the new key is read whole in XLA with the new row laid
+    over the one it replaces (the rows before it are the newest, those
+    after it the window's oldest, there once the ring has wrapped), and
+    seeds the softmax; the ring's other pages are whole inside the window
+    and go through the page-list read (``attend_slots``, a list a slot).
+    k_pool, v_pool: the rings' pool (n_window, blocks, Hkv, bs, Dh); q:
+    (N, 1, H, Dh); k_row, v_row: (N, Hkv, Dh) in the pool's dtype; ``at``:
+    ``ring_decode_indices``. Returns ctx (N, 1, H, Dh)."""
+    N, _, H, Dh = q.shape
+    Hkv, bs = k_pool.shape[2], k_pool.shape[3]
+    qg = q.reshape(N, Hkv, H // Hkv, Dh)
+    lay = lambda pool, new: lay_rows(
+        pool[layer, at["ring_page"]][None], at["row"], new[None])[0]
+    ko, vo = lay(k_pool, k_row), lay(v_pool, v_row)     # (N, Hkv, bs, Dh)
+    s = jnp.einsum("nhgd,nhkd->nhgk", qg, ko,
+                   preferred_element_type=jnp.float32) / math.sqrt(Dh)
+    r = jnp.arange(bs)[None, :]
+    sees = (r <= at["row"][:, None]) | at["wrapped"][:, None]
+    s = jnp.where(sees[:, None, None, :], s, -1e30)
+    m = jnp.max(s, -1)
+    pr = jnp.exp(s - m[..., None])
+    acc = jnp.einsum("nhgk,nhkd->nhgd", pr.astype(vo.dtype), vo,
+                     preferred_element_type=jnp.float32)
+    ctx = attend_slots(k_pool, v_pool, layer, qg, at["others"],
+                       at["others_rows"], m, jnp.sum(pr, -1), acc)
+    return ctx.reshape(N, 1, H, Dh)
+
+
+def chunk_attend_past(k_pool, v_pool, layer, q, k, v, table_row, offset):
+    """``chunk_attend_all`` for a slot whose past may be long: the C
+    queries at positions ``offset ..`` (traced, a multiple of C) over ALL
+    their past and the chunk's own keys up to each, the past read C
+    positions (whole pages) at a time through one online softmax, as many
+    times as the past is long (a traced trip count: a chunk at offset 0
+    reads nothing, one at 30,720 thirty tiles). -> (C, H, Dh)."""
+    C, H, Dh = q.shape
+    Hkv, bs = k_pool.shape[2], k_pool.shape[3]
+    scale = 1.0 / math.sqrt(Dh)
+    pg = C // bs
+    qg = q.reshape(C, Hkv, H // Hkv, Dh)
+
+    def tile(i, carry):
+        m0, l0, acc0 = carry
+        ids = jax.lax.dynamic_slice(table_row, (i * pg,), (pg,))
+        past = lambda pool: jnp.swapaxes(pool[layer, ids], 0, 1).reshape(
+            Hkv, C, Dh)
+        kp, vp = past(k_pool), past(v_pool)
+        s = jnp.einsum("qhgd,hkd->qhgk", qg, kp,
+                       preferred_element_type=jnp.float32) * scale
+        m = jnp.maximum(m0, jnp.max(s, -1))
+        p = jnp.exp(s - m[..., None])
+        alpha = jnp.exp(m0 - m)
+        acc = alpha[..., None] * acc0 + jnp.einsum(
+            "qhgk,hkd->qhgd", p.astype(vp.dtype), vp,
+            preferred_element_type=jnp.float32)
+        return m, alpha * l0 + jnp.sum(p, -1), acc
+
+    _, l, acc = jax.lax.fori_loop(0, offset // C, tile, _keys_init(qg, k, v))
+    return (acc / l[..., None]).reshape(C, H, Dh).astype(q.dtype)
+
+
+def ring_chunk_attend(window: int, k_pool, v_pool, layer, q, k, v,
+                      ring_row, offset):
+    """Attention of a prompt chunk's C queries at positions ``offset ..``
+    (traced, a multiple of C, which divides ``window``) in a window_attn
+    layer: query i over the keys ``i - window < j <= i``, a band that is
+    a different set of ring rows for every query. The ring (``ring_row``:
+    its ``window / bs`` pages, gathered once) holds, in row r, the last
+    position before the chunk that is r modulo ``window``; each query
+    sees the rows whose position lies inside its band, beside the chunk's
+    own keys up to itself, in one softmax. -> (C, H, Dh) in q's dtype."""
+    C, H, Dh = q.shape
+    Hkv = k_pool.shape[2]
+    qg = q.reshape(C, Hkv, H // Hkv, Dh)
+    ring = lambda pool: jnp.swapaxes(pool[layer, ring_row], 0, 1).reshape(
+        Hkv, window, Dh)
+    keys = lambda pool, own: jnp.concatenate(
+        [jnp.swapaxes(ring(pool), 0, 1), own])              # (window + C, ..)
+    r = jnp.arange(window, dtype=jnp.int32)
+    held = offset - 1 - (offset - 1 - r) % window           # row r's position
+    i = jnp.arange(C, dtype=jnp.int32)
+    sees = jnp.concatenate(
+        [(held[None, :] >= 0) & (held[None, :] > offset + i[:, None] - window),
+         i[None, :] <= i[:, None]], 1)
+    m, l, acc = _keys_init(qg, keys(k_pool, k), keys(v_pool, v), sees)
+    return (acc / l[..., None]).reshape(C, H, Dh).astype(q.dtype)
+
+
+def write_ring_chunk(window: int, k_pool, v_pool, ring_row, offset, n_valid,
+                     kk, vv):
+    """A prompt chunk's keys and values (n, C, Hkv, Dh) of all window_attn
+    layers over the ring's rows from ``offset mod window`` on, whole pages
+    at a time (C divides the window: a chunk never runs past the ring's
+    end). The rows at or beyond ``n_valid`` (a prompt's ragged last chunk)
+    keep what they held: those positions are still inside the window of
+    the tokens to come."""
+    n, C, Hkv, Dh = kk.shape
+    bs = k_pool.shape[3]
+    pg = C // bs
+    ids = jax.lax.dynamic_slice(ring_row, (offset % window // bs,), (pg,))
+    real = (jnp.arange(C) < n_valid).reshape(1, pg, 1, bs, 1)
+    pages = lambda t: jnp.swapaxes(t.reshape(n, pg, bs, Hkv, Dh), 2, 3)
+    write = lambda pool, t: pool.at[:, ids].set(
+        jnp.where(real, pages(t), pool[:, ids]))
+    return write(k_pool, kk), write(v_pool, vv)

@@ -217,6 +217,18 @@ class ServingMetrics:
         self.summary_rows_decode = 0
         self.summary_rows_chunk = 0
         self.window_wraps = 0
+        # a stack of two page rules: the pages of every key a live slot
+        # held (its ring's are kv_window_pages)
+        self.kv_full_pages = 0
+        # routed experts, by the program that ran them ("decode", "chunk"):
+        # over its calls, the experts with any assignment (summed over the
+        # layers), the assignments, the largest expert's assignments of a
+        # call (summed over the calls) and the calls x layers counted
+        self.moe_experts_touched = {"decode": 0, "chunk": 0}
+        self.moe_assignments = {"decode": 0, "chunk": 0}
+        self.moe_max_load = {"decode": 0, "chunk": 0}
+        self.moe_layer_calls = {"decode": 0, "chunk": 0}
+        self.moe_calls = {"decode": 0, "chunk": 0}
         # host-to-device placements made for the plain decode program's
         # slot inputs, and the dispatches they were made for
         self.decode_placements = 0
@@ -443,6 +455,27 @@ class ServingMetrics:
         self.kv_view_pages += view_pages
         self.kv_selected_pages += selected_pages
 
+    def record_ring_pages(self, rows: int, full_pages: int,
+                          window_pages: int, wraps: int) -> None:
+        """One launched decode step of a cache of two page rules: its
+        ``rows`` live slots held ``full_pages`` pages of every key and
+        ``window_pages`` of their rings; ``wraps`` of them write the
+        ring's first row."""
+        self.kv_held_rows += rows
+        self.kv_full_pages += full_pages
+        self.kv_window_pages += window_pages
+        self.window_wraps += wraps
+
+    def record_experts(self, program: str, touched: int, assignments: int,
+                       max_load: int, layers: int) -> None:
+        """What one call of ``program`` ("decode", "chunk") counted of its
+        routed experts over its ``layers`` layers."""
+        self.moe_experts_touched[program] += touched
+        self.moe_assignments[program] += assignments
+        self.moe_max_load[program] += max_load
+        self.moe_layer_calls[program] += layers
+        self.moe_calls[program] += 1
+
     def record_window_pages(self, rows: int, window_pages: int,
                             summary_pages: int, summary_rows: int,
                             wraps: int) -> None:
@@ -576,7 +609,19 @@ class ServingMetrics:
                 "window": (self.kv_window_pages / self.kv_held_rows
                            if self.kv_held_rows else 0.0),
                 "summary": (self.kv_summary_pages / self.kv_held_rows
-                            if self.kv_held_rows else 0.0)},
+                            if self.kv_held_rows else 0.0),
+                "full": (self.kv_full_pages / self.kv_held_rows
+                         if self.kv_held_rows else 0.0)},
+            # routed experts by program: a layer's experts with any
+            # assignment, and the largest expert's load, both a call
+            "moe": {prog: {
+                "experts_touched_per_layer": (
+                    self.moe_experts_touched[prog] / calls if calls else 0.0),
+                "assignments": int(self.moe_assignments[prog]),
+                "max_load_per_call": (
+                    self.moe_max_load[prog] / self.moe_calls[prog]
+                    if calls else 0.0)}
+                for prog, calls in self.moe_layer_calls.items()},
             "summary_rows": {"decode": int(self.summary_rows_decode),
                              "chunk": int(self.summary_rows_chunk)},
             "window_wraps": int(self.window_wraps),

@@ -33,7 +33,7 @@ import dataclasses
 import itertools
 import time
 from collections import deque
-from typing import Callable, Deque, List, Optional, Tuple
+from typing import Callable, Deque, List, Optional, Sequence, Tuple, Union
 
 from ..monitor.tracer import trace_instant
 from ..utils.logging import logger
@@ -118,10 +118,22 @@ class Request:
 class Scheduler:
     """Owns the slot array, the per-slot block lists, and the queue."""
 
-    def __init__(self, scfg: ServingConfig, allocator: BlockAllocator,
+    def __init__(self, scfg: ServingConfig,
+                 allocator: Union[BlockAllocator, Sequence[BlockAllocator]],
                  clock: Callable[[], float] = time.monotonic):
         self.scfg = scfg
-        self.allocator = allocator
+        # one allocator a pool of the cache's rule; the first is the pool
+        # whose pages follow the length (the prefix cache's)
+        self.allocators: List[BlockAllocator] = (
+            [allocator] if isinstance(allocator, BlockAllocator)
+            else list(allocator))
+        self._pools = scfg.page_rule.pools
+        if len(self.allocators) != max(self._pools) + 1:
+            raise ValueError(
+                f"the cache's rule ({scfg.page_rule}) keeps "
+                f"{max(self._pools) + 1} pools, got "
+                f"{len(self.allocators)} allocators")
+        allocator = self.allocator = self.allocators[0]
         # radix prompt index: admissions match their longest cached
         # prefix and share those blocks read-only (refcounted)
         self.prefix_cache: Optional[PrefixCache] = (
@@ -163,15 +175,56 @@ class Scheduler:
         # worst-case footprint (full context + one decode-write of
         # headroom) must fit an EMPTY pool, else the request could never
         # admit and the engine would spin forever on backpressure
-        worst = self.scfg.pages_needed(ctx_cap)
-        if worst > self.allocator.num_blocks - 1:
-            raise ValueError(
-                f"request {req.rid}: worst-case footprint ({worst} blocks "
-                f"of {self.scfg.block_size}) exceeds the pool "
-                f"({self.allocator.num_blocks - 1} usable blocks); raise "
-                f"num_blocks or lower max_new_tokens"
-            )
+        for alloc, worst in zip(self.allocators, self._by_pool(
+                self.scfg.pages_by_role(ctx_cap))):
+            if worst > alloc.num_blocks - 1:
+                raise ValueError(
+                    f"request {req.rid}: worst-case footprint ({worst} "
+                    f"blocks of {self.scfg.block_size}) exceeds the pool "
+                    f"({alloc.num_blocks - 1} usable blocks); raise "
+                    f"num_blocks or lower max_new_tokens"
+                )
         self.queue.append(req)
+
+    # ---------------------------------------------------------------- #
+    # pages by pool
+    # ---------------------------------------------------------------- #
+
+    def _by_pool(self, by_role: Sequence[int]) -> List[int]:
+        """Counts role by role summed pool by pool."""
+        out = [0] * len(self.allocators)
+        for pool, n in zip(self._pools, by_role):
+            out[pool] += n
+        return out
+
+    def _alloc(self, by_role: Sequence[int],
+               shared: int = 0) -> Optional[List[int]]:
+        """``by_role[r]`` pages of every role r, each from its pool's
+        allocator, role by role in one list (less the first ``shared`` of
+        the first pool, which the caller maps in itself); None, and
+        nothing taken, when ANY pool cannot give its share."""
+        got: List[List[int]] = []
+        for alloc, n in zip(self.allocators, self._by_pool(by_role)):
+            blocks = alloc.alloc(n - (shared if not got else 0))
+            if blocks is None:
+                for a, b in zip(self.allocators, got):
+                    a.free(b)
+                return None
+            got.append(blocks)
+        out: List[int] = []
+        for r, (pool, n) in enumerate(zip(self._pools, by_role)):
+            n -= shared if r == 0 else 0
+            out += got[pool][:n]
+            got[pool] = got[pool][n:]
+        return out
+
+    def _free(self, blocks: Sequence[int], by_role: Sequence[int]) -> None:
+        """Give a slot's pages, listed role by role, back each to its
+        pool."""
+        at = 0
+        for pool, n in zip(self._pools, by_role):
+            self.allocators[pool].free(list(blocks[at:at + n]))
+            at += n
 
     @property
     def num_active(self) -> int:
@@ -226,7 +279,7 @@ class Scheduler:
         # +1: headroom for the first decode write, so a freshly admitted
         # request cannot be preempted before its first step
         want = self.scfg.pages_by_role(len(req.context) + 1)
-        private = self.allocator.alloc(sum(want) - len(full))
+        private = self._alloc(want, shared=len(full))
         if private is None:
             if full:
                 self.allocator.free(full)
@@ -297,7 +350,7 @@ class Scheduler:
                          for w, h in zip(want, self.slot_roles[slot])]
                 if not any(short):
                     break
-                extra = self.allocator.alloc(sum(short))
+                extra = self._alloc(short)
                 if extra is not None:
                     self._accrue_kv(slot)
                     self._extend(slot, short, extra)
@@ -355,7 +408,7 @@ class Scheduler:
     # ---------------------------------------------------------------- #
 
     def _release_slot(self, slot: int) -> None:
-        self.allocator.free(self.slot_blocks[slot])
+        self._free(self.slot_blocks[slot], self.slot_roles[slot])
         self.slot_blocks[slot] = []
         self.slot_roles[slot] = []
         self.slots[slot] = None

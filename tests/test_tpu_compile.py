@@ -442,3 +442,99 @@ def test_evabyte_programs_move_neither_the_pool_nor_a_weight_stack(
     assert weights + caches < ask < weights + caches + 2 ** 30, (
         ask, weights, caches)
     assert ask < 12.5 * 2 ** 30
+
+
+# ------------------------------------------------------------------ #
+# Mellum 2: two pools, the page-list kernel at 8 queries a key head, the
+# routed experts' grouped product, and the two serving programs at the
+# cell's size
+# ------------------------------------------------------------------ #
+
+
+def _mellum():
+    from benchmark import manifest as mf
+    from benchmark.adapters import mellum as adapter
+    from deeperspeed_tpu.serving import ServingConfig
+    from deeperspeed_tpu.serving.kv_cache import page_rule_for
+
+    man = mf.Manifest()
+    cfg = adapter.model_config(man.config("mellum2-12b-a2.5b"))
+    scfg = ServingConfig.from_dict(
+        man.workload_file("mellum2-12b-a2.5b.serve-code")["serving"])
+    return cfg, scfg.for_cache(page_rule_for(cfg))
+
+
+@pytest.mark.parametrize("rows", [256, 8192])
+def test_grouped_matmul_compiles_at_the_experts_shapes(one_chip, rows):
+    """A layer's experts read in the layers' stack: 6 x 64 groups of which
+    one layer's 64 have rows, at a decode step's 256 rows and a prompt
+    chunk's 8,192, both products' shapes."""
+    from deeperspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                           tiling)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for k, n in ((2304, 896), (896, 2304)):
+        assert tiling(rows, k, n) == (128, k, n)
+        compiled = jax.jit(grouped_matmul).lower(
+            sds((rows, k)), sds((384, k, n)), sds((384,), jnp.int32)).compile()
+        assert runs_kernel(compiled.as_text(), "gmm")
+    assert tiling(100, 2304, 896) is None and tiling(256, 100, 896) is None
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_mellum_programs_move_neither_a_pool_nor_a_weight_stack(
+        one_chip, as_if_on_tpu, program):
+    """The decode step and the prompt-chunk program of the cell of two
+    cache rules (8 layers at the published widths, 32 slots, 16,385 pages
+    of every key 2 layers deep and 513 of the rings 6 deep): the donated
+    pools are outputs in place, nothing copies a pool or an experts'
+    stack (the grouped product reads a layer's experts where they lie),
+    and the compiler's ask stays inside the chip."""
+    from deeperspeed_tpu.models import mixers
+    from deeperspeed_tpu.serving.engine import (make_chunk_step,
+                                                make_decode_step)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg, scfg = _mellum()
+    params = jax.tree.map(
+        lambda a: sds(a.shape),
+        jax.eval_shape(lambda: mixers.init_params(jax.random.PRNGKey(0), cfg)))
+    N, bps = scfg.num_slots, scfg.blocks_per_slot
+    assert (N, scfg.pool_blocks, bps, scfg.table_widths) == (
+        32, (16385, 513), 528, (512, 16))
+    i32 = jnp.int32
+    pools = (sds((2, 16385, 4, 64, 128)), sds((6, 513, 4, 64, 128)))
+    if program == "decode":
+        compiled = make_decode_step(cfg, scfg).lower(
+            params, pools, pools, sds(*_idle_slots(N, bps)),
+            sds((N + 3,), i32), None, None).compile()
+    else:
+        compiled = make_chunk_step(cfg, scfg).lower(
+            params, pools, pools, None, None, sds((1, 1024), i32),
+            sds((bps,), i32), sds((), i32), sds((), i32), sds((), i32)).compile()
+    text = compiled.as_text()
+    # both kinds of layer read their lists through the kernel whose row is
+    # a slot in a decode step; a chunk attends in XLA; the experts' three
+    # products a layer are the grouped-matmul kernel's in both
+    assert runs_kernel(text, "paged_sparse_attn_slots") == (
+        program == "decode")
+    assert runs_kernel(text, "gmm")
+    assert count_alias_pairs(text) == 4        # k, v of both pools
+    big = ("bf16[2,16385,", "bf16[6,513,", "bf16[6,64,", "bf16[2,64,",
+           "bf16[64,2304,896]", "bf16[64,896,2304]")
+    moved = [ln.strip()[:140] for ln in text.splitlines()
+             if (" copy(" in ln or "dynamic-slice_bitcast_fusion" in ln)
+             and ln.split(" = ")[1].startswith(big)]
+    assert moved == []
+    weights = sum(2 * math.prod(a.shape) for a in jax.tree.leaves(params))
+    assert weights == 2 * 3_794_968_832
+    caches = 2 * 2 * sum(math.prod(p.shape) for p in pools)
+    assert caches == 2 * (16385 * 2 + 513 * 6) * 65536
+    ask = extract_memory_analysis(compiled)["peak_bytes"]
+    assert weights + caches < ask < weights + caches + 2 ** 29, (
+        ask, weights, caches)
+    assert ask < 11.8 * 2 ** 30
