@@ -93,7 +93,7 @@ from .kv_cache import (
     NULL_BLOCK,
     PagedKVCache,
     chunk_attend_all,
-    chunk_attend_past,
+    chunk_attend_for,
     decode_attend_all,
     decode_attend_for,
     eva_chunk_past,
@@ -101,7 +101,6 @@ from .kv_cache import (
     eva_page_list,
     lightning_chunk_for,
     page_rule_for,
-    ring_chunk_attend,
     ring_decode_attend,
     ring_decode_indices,
     slot_attend_for,
@@ -604,6 +603,9 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
             full_row, ring_row = table_row[:n_full + C // bs], \
                 table_row[n_full:n_full + scfg.table_widths[1]]
             full_row = full_row.at[n_full:].set(NULL_BLOCK)
+            attend = chunk_attend_for(k_full, cfg.n_head, C, mesh)
+            # the ring's pages as that form reads them, once for all layers
+            ring_pages = attend.ring_pages(cfg.gqa.window, ring_row, offset)
             # a chunk's padding is no token: it is routed to no expert
             real = (jnp.arange(C) < n_valid)[None, :]
 
@@ -642,14 +644,14 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
 
             def every_key(q, k, v):
                 kk, vv = k[0].astype(k_full.dtype), v[0].astype(v_full.dtype)
-                ctx = chunk_attend_past(k_full, v_full, layer, q[0], kk, vv,
-                                        full_row, offset)
+                ctx = attend.past(k_full, v_full, layer, q[0], kk, vv,
+                                  full_row, offset)
                 return ctx[None], (kk, vv)
 
             def last_keys(q, k, v):
                 kk, vv = k[0].astype(k_ring.dtype), v[0].astype(v_ring.dtype)
-                ctx = ring_chunk_attend(cfg.gqa.window, k_ring, v_ring, layer,
-                                        q[0], kk, vv, ring_row, offset)
+                ctx = attend.ring(cfg.gqa.window, k_ring, v_ring, layer, q[0],
+                                  kk, vv, ring_pages, offset)
                 return ctx[None], (kk, vv)
 
             core = {"minicpm4": minicpm4, "lightning": lightning,
@@ -1033,6 +1035,14 @@ class ServingEngine(_ServingBase):
             self._slot_rows = takes_slot_form(
                 self.kv.k[0], cfg.n_head,
                 (scfg.num_slots, scfg.table_widths[0]), mesh)
+        # how a prompt chunk of a stack of two cache rules attends over
+        # its past ("kernel" or "xla"), chosen like ``_slot_rows``; None
+        # for every other stack
+        self._chunk_attn = None
+        if cfg.count("full_attn") or cfg.count("window_attn"):
+            self._chunk_attn = chunk_attend_for(
+                self.kv.k[0], cfg.n_head, prefill_chunk_for(cfg, scfg),
+                None).name
         # whether the programs count their routed experts: the decode
         # step's tokens then come with three counts behind them
         self._counts_experts = counts_experts(cfg)
@@ -1451,7 +1461,9 @@ class ServingEngine(_ServingBase):
                         **self._chunk_pages_by_rule(lo)), \
                 trace_span("serving/prefill_chunk", lane="serving",
                            rid=req.rid, chunk=c, tokens=hi - lo, offset=lo,
-                           pages=named, listed_pages=f"{listed}/{named}"):
+                           pages=named, listed_pages=f"{listed}/{named}",
+                           **({"attn": self._chunk_attn}
+                              if self._chunk_attn else {})):
             with trace_span("serving/prefill/pack", lane="serving"):
                 toks = np.zeros((1, C), np.int32)
                 toks[0, :hi - lo] = state["ctx"][lo:hi]
@@ -1469,7 +1481,8 @@ class ServingEngine(_ServingBase):
                 self._end_prompt(slot, state, logits)
         self._prefill_spent += hi - lo
         self._chunk_ran = True
-        self.metrics.record_prefill_chunk(hi - lo, named, listed)
+        self.metrics.record_prefill_chunk(
+            hi - lo, named, listed, kernel_attn=self._chunk_attn == "kernel")
         if lo == 0 and self.kv.state is not None:
             # the first chunk entered the slot's state rows as zeros
             self.metrics.record_state_reset()

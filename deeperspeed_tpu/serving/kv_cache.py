@@ -67,7 +67,8 @@ page machinery the prefill path uses.
 import functools
 import itertools
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -1380,6 +1381,67 @@ def ring_chunk_attend(window: int, k_pool, v_pool, layer, q, k, v,
          i[None, :] <= i[:, None]], 1)
     m, l, acc = _keys_init(qg, keys(k_pool, k), keys(v_pool, v), sees)
     return (acc / l[..., None]).reshape(C, H, Dh).astype(q.dtype)
+
+
+def ring_oldest_first(window: int, ring_row, offset):
+    """The ring's pages in position order before a chunk at ``offset``:
+    from the page the chunk will be written over (``offset mod window``,
+    which holds the window's oldest keys, or nothing yet) on, wrapping;
+    list position p then holds position ``offset - window + p``."""
+    R = ring_row.shape[0]
+    bs = window // R
+    return ring_row[(offset % window // bs + jnp.arange(R, dtype=jnp.int32))
+                    % R]
+
+
+def chunk_attend_past_kernel(k_pool, v_pool, layer, q, k, v, table_row,
+                             offset):
+    """``chunk_attend_past`` through ops/pallas/chunk_past_attn: the list
+    is the slot's pages of every key, of which ``offset`` positions
+    count."""
+    from ..ops.pallas.chunk_past_attn import chunk_past_attn
+
+    return chunk_past_attn(k_pool, v_pool, layer, q, k, v, table_row, 0,
+                           offset)
+
+
+def ring_chunk_attend_kernel(window: int, k_pool, v_pool, layer, q, k, v,
+                             ring_list, offset):
+    """``ring_chunk_attend`` through ops/pallas/chunk_past_attn, the
+    ring's pages listed oldest first (``ring_oldest_first``): list
+    position p holds position ``offset - window + p``, nothing while that
+    is negative, and query i sees it while ``p > i``."""
+    from ..ops.pallas.chunk_past_attn import chunk_past_attn
+
+    return chunk_past_attn(k_pool, v_pool, layer, q, k, v, ring_list,
+                           jnp.maximum(window - offset, 0), window, band=True)
+
+
+class ChunkAttend(NamedTuple):
+    """How a prompt chunk of a stack of two cache rules attends: ``past``
+    has ``chunk_attend_past``'s signature and ``ring``
+    ``ring_chunk_attend``'s, but for the ring's pages, which ``ring``
+    takes as ``ring_pages(window, ring_row, offset)`` gives them, once a
+    chunk for all its layers."""
+    name: str               # "kernel" or "xla": the span's ``attn``
+    past: Callable
+    ring: Callable
+    ring_pages: Callable
+
+
+def chunk_attend_for(k_pool, n_head, C, mesh) -> ChunkAttend:
+    """The forms of a prompt chunk's attention in full_attn and
+    window_attn layers (both pools have one page shape): the kernel on
+    one TPU at shapes it can tile, else the XLA forms (as
+    ``decode_attend_for`` chooses)."""
+    from ..ops.pallas import chunk_past_attn as kernel
+
+    if (mesh is None or mesh.size == 1) and kernel.is_available(
+            k_pool, n_head, C):
+        return ChunkAttend("kernel", chunk_attend_past_kernel,
+                           ring_chunk_attend_kernel, ring_oldest_first)
+    return ChunkAttend("xla", chunk_attend_past, ring_chunk_attend,
+                       lambda window, ring_row, offset: ring_row)
 
 
 def write_ring_chunk(window: int, k_pool, v_pool, ring_row, offset, n_valid,

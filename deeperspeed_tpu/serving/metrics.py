@@ -275,6 +275,9 @@ class ServingMetrics:
         # for every query of a chunk, is gathered once a chunk)
         self.chunk_named_pages = 0
         self.chunk_listed_pages = 0
+        # prompt chunks whose attention over the past ran in the kernel
+        # (ops/pallas/chunk_past_attn), of a stack of two cache rules
+        self.prefill_chunks_kernel_attn = 0
         # speculative decoding: per-round draft/accept accounting plus
         # the draft-vs-verify wall split (spec/runtime.decode_round)
         self.spec_rounds = 0
@@ -383,11 +386,14 @@ class ServingMetrics:
                 "Copy-on-write splits of shared boundary pages.").inc()
 
     def record_prefill_chunk(self, tokens: int, named_pages: int = 0,
-                             listed_pages: int = 0) -> None:
+                             listed_pages: int = 0,
+                             kernel_attn: bool = False) -> None:
         """One staged prompt-chunk forward (chunked/suffix prefill);
         ``named_pages``, ``listed_pages``: what its sparse layers'
-        selections and its rows' lists named of the pool."""
+        selections and its rows' lists named of the pool; ``kernel_attn``:
+        its program attends over the past in the chunk kernel."""
         self.prefill_chunks += 1
+        self.prefill_chunks_kernel_attn += kernel_attn
         self.chunk_tokens += tokens
         self.chunk_named_pages += named_pages
         self.chunk_listed_pages += listed_pages
@@ -660,6 +666,8 @@ class ServingMetrics:
                                       if self.prefill_tokens else 0.0),
                 "cow_splits": int(self.cow_splits),
                 "prefill_chunks": int(self.prefill_chunks),
+                "prefill_chunks_kernel_attn": int(
+                    self.prefill_chunks_kernel_attn),
                 "chunk_tokens": int(self.chunk_tokens),
             },
             "speculative": {

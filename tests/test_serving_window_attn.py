@@ -505,6 +505,48 @@ def test_spans_carry_the_pages_by_rule_and_the_experts_counts(params):
     assert s["kv_pages_per_slot"]["full"] > 2.0
     assert s["window_wraps"] == m.window_wraps > 0
     assert 0 < s["moe"]["decode"]["experts_touched_per_layer"] <= 4.0
+    # how the chunks attended over their past: the XLA forms, on the CPU
+    chunks = named("serving/prefill_chunk")
+    assert [e["args"]["attn"] for e in chunks] == ["xla"] * 4
+    assert s["prefix_reuse"]["prefill_chunks"] == 4
+    assert s["prefix_reuse"]["prefill_chunks_kernel_attn"] == 0
+
+
+def test_the_span_and_the_count_say_when_the_chunks_kernel_engaged(
+        params, monkeypatch):
+    """``serving/prefill_chunk`` carries ``attn``, what
+    ``kv_cache.chunk_attend_for`` returned when the program was built, and
+    ``prefill_chunks_kernel_attn`` counts the chunks of a program that
+    attends in ops/pallas/chunk_past_attn. The toy pool is no shape the
+    kernel tiles, so the chooser is made to say so here; a stack of one
+    cache rule says nothing."""
+    from deeperspeed_tpu.monitor.tracer import Tracer, set_tracer
+    from deeperspeed_tpu.serving import engine as engine_mod
+    from deeperspeed_tpu.serving import kv_cache as kvc
+
+    said = []
+
+    def as_on_one_tpu(*args):
+        said.append(args[1:])
+        return kvc.chunk_attend_for(*args)._replace(name="kernel")
+
+    monkeypatch.setattr(engine_mod, "chunk_attend_for", as_on_one_tpu)
+    tracer = Tracer()
+    set_tracer(tracer)
+    try:
+        eng = engine_for(params)
+        eng.submit(prompts((21,))[0], max_new_tokens=3, request_id="r")
+        eng.run()
+    finally:
+        set_tracer(None)
+    chunks = [e for e in tracer.events()
+              if e["name"] == "serving/prefill_chunk"]
+    assert [e["args"]["attn"] for e in chunks] == ["kernel"] * 3
+    reuse = eng.metrics.summary()["prefix_reuse"]
+    assert reuse["prefill_chunks_kernel_attn"] == reuse["prefill_chunks"] == 3
+    # asked with the heads, the chunk's length and no mesh, by the engine
+    # and by the program it built
+    assert set(said) == {(4, 8, None)}
 
 
 def test_the_experts_scopes_are_in_the_programs(params):
@@ -561,6 +603,7 @@ def test_the_cells_the_benchmark_had_keep_their_rule_pool_and_programs(
     assert scfg.pool_blocks == (scfg.num_blocks,)
     assert eng.kv.k.shape == eng.kv.v.shape == pool
     assert eng.kv.allocators == [eng.kv.allocator]
+    assert eng._chunk_attn is None      # its chunks' spans carry no ``attn``
     assert pool_bytes(eng.kv) == (eng.kv.k.nbytes + eng.kv.v.nbytes,)
 
     def lowered():

@@ -483,6 +483,31 @@ def test_grouped_matmul_compiles_at_the_experts_shapes(one_chip, rows):
     assert tiling(100, 2304, 896) is None and tiling(256, 100, 896) is None
 
 
+@pytest.mark.parametrize("pool,P,band", [
+    pytest.param((2, 16385, 4, 64, 128), 528, False, id="every_key"),
+    pytest.param((6, 513, 4, 64, 128), 16, True, id="the_ring")])
+def test_chunk_past_attn_compiles_at_the_code_cells_geometry(
+        one_chip, as_if_on_tpu, pool, P, band):
+    """A prompt chunk's attention over its past in the cell of two cache
+    rules: 1,024 queries of 32 heads over 4 key heads, the list the
+    slot's 512 pages of every key and the chunk's own 16 (null), or the
+    ring's 16 pages under the band rule; a grid step 256 queries of all
+    heads, a step of the walk 16 whole pages of 64 KiB."""
+    from deeperspeed_tpu.ops.pallas import chunk_past_attn as kernel
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    assert kernel.is_available(sds(pool), 32, 1024)
+    assert kernel.tiles(1024, 64) == (256, 16)
+    compiled = kernel.chunk_past_attn.lower(
+        sds(pool), sds(pool), sds((), i32), sds((1024, 32, 128)),
+        sds((1024, 4, 128)), sds((1024, 4, 128)), sds((P,), i32),
+        sds((), i32), sds((), i32), band=band).compile()
+    assert runs_kernel(compiled.as_text(), "chunk_past_attn")
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_mellum_programs_move_neither_a_pool_nor_a_weight_stack(
         one_chip, as_if_on_tpu, program):
@@ -518,10 +543,12 @@ def test_mellum_programs_move_neither_a_pool_nor_a_weight_stack(
             sds((bps,), i32), sds((), i32), sds((), i32), sds((), i32)).compile()
     text = compiled.as_text()
     # both kinds of layer read their lists through the kernel whose row is
-    # a slot in a decode step; a chunk attends in XLA; the experts' three
-    # products a layer are the grouped-matmul kernel's in both
+    # a slot in a decode step; a chunk attends over its past and itself in
+    # the chunk kernel, under both band rules; the experts' three products
+    # a layer are the grouped-matmul kernel's in both
     assert runs_kernel(text, "paged_sparse_attn_slots") == (
         program == "decode")
+    assert runs_kernel(text, "chunk_past_attn") == (program == "chunk")
     assert runs_kernel(text, "gmm")
     assert count_alias_pairs(text) == 4        # k, v of both pools
     big = ("bf16[2,16385,", "bf16[6,513,", "bf16[6,64,", "bf16[2,64,",
