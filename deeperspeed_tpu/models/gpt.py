@@ -202,11 +202,16 @@ class GroupedAttnConfig:
     ``i`` sees the keys ``i - window < j <= i`` and the layer keeps the
     last ``window`` keys alone (a ring a slot). Each kind turns q and k by
     its own rotary constants; ``qk_norm``: q and k pass an RMSNorm over
-    each head's entries (one learned vector each a layer) before that."""
+    each head's entries (one learned vector each a layer) before that.
+    ``rotary`` False: q and k are not turned at all (a stack whose other
+    layers carry the position); ``out_gate``: the heads' output times
+    ``sigmoid(W_g m)`` entry by entry before the projection out."""
     window: int = 1024
     qk_norm: bool = True
     full_rope: RopeScaling = RopeScaling()
     window_rope: RopeScaling = RopeScaling()
+    rotary: bool = True
+    out_gate: bool = False
 
     def __post_init__(self):
         if self.window < 1:
@@ -214,6 +219,29 @@ class GroupedAttnConfig:
 
     def rope(self, kind: str) -> RopeScaling:
         return self.window_rope if kind == "window_attn" else self.full_rope
+
+
+@dataclasses.dataclass(frozen=True)
+class KdaConfig:
+    """The constants of a ``kda`` layer (Kimi Delta Attention, a gated
+    delta rule with a decay for every CHANNEL): ``n_heads`` heads, keys of
+    ``head_k`` and values of ``head_v`` entries, a state of ``head_k x
+    head_v`` float32 a head; q, k and v each pass a depthwise causal
+    convolution ``d_conv`` wide and a SiLU; the decay and the output gate
+    come through low-rank pairs ``d_model -> low_rank -> n_heads x head``;
+    ``beta = beta_scale sigmoid(.)`` (2 where the model allows a negative
+    eigenvalue)."""
+    n_heads: int = 64
+    head_k: int = 128
+    head_v: int = 128
+    d_conv: int = 4
+    low_rank: int = 128
+    beta_scale: float = 2.0
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: q, then k, then v."""
+        return self.n_heads * (2 * self.head_k + self.head_v)
 
 
 # The kinds of layer a stack may hold, by the name of the mixer. The kind
@@ -242,12 +270,15 @@ class GroupedAttnConfig:
 #   window_attn  the same block over the last ``window`` keys alone: a
 #              ring of ``window / block_size`` pages a slot, in a pool of
 #              its own beside the full layers' (two page rules, one stack)
+#   kda        mixers.kda_block: RMSNorm, a gated delta rule with a decay a
+#              channel (a float32 state row and three convolution tails a
+#              slot, no pages); shares a stack with ``full_attn`` layers
 # The feed-forward of every kind but ``attention`` is a VALUE of the
 # configuration (``mixers.feed_forward``): dense gated SiLU, or, where
 # ``moe_num_experts`` is set, gated SiLU experts routed ``moe_top_k`` a
 # token with no token dropped (``moe.gated_experts``).
 LAYER_KINDS = ("attention", "minicpm4", "lightning", "mamba_attn", "eva",
-               "full_attn", "window_attn")
+               "full_attn", "window_attn", "kda")
 # the kinds that share ``mixers.grouped_attn_block`` (and may share a stack)
 GROUPED_KINDS = frozenset({"full_attn", "window_attn"})
 
@@ -309,6 +340,20 @@ class GPTConfig:
     moe_z_coef: float = 1e-3
     moe_dispatch_impl: str = "auto"  # auto | dense | sorted | dropless
     moe_normalize_gates: bool = False
+    # a mixed stack's routed experts beyond the plain softmax rule, all
+    # read by ``moe.gated_experts`` alone. ``moe_rule``: how a token's
+    # scores choose its experts (``moe.route_top_k``: "softmax", or
+    # "sigmoid_bias": sigmoid scores, a learned selection bias, the gates
+    # the winners' unbiased scores). ``moe_held`` (first, count): this
+    # program holds the experts ``first .. first + count - 1`` of the
+    # ``moe_num_experts`` it routes over (one chip's share of a layer whose
+    # experts are spread over several): an assignment to another expert
+    # leaves and is counted, its result is some other chip's to add.
+    # ``moe_shared``: experts of the same shape every token passes, added
+    # once beside the routed ones
+    moe_rule: str = "softmax"
+    moe_held: Optional[Tuple[int, int]] = None
+    moe_shared: int = 0
     # EP-dropless receive-buffer headroom (see MoEConfig.ep_buffer_factor);
     # >= the 'expert' axis size guarantees zero drops under any skew
     moe_ep_buffer_factor: float = 2.0
@@ -328,6 +373,7 @@ class GPTConfig:
     eva: Optional[EvaAttnConfig] = None         # the eva layers'
     # the full_attn and window_attn layers'
     gqa: Optional[GroupedAttnConfig] = None
+    kda: Optional[KdaConfig] = None             # the kda layers'
     # what a model states of its norms, its residual stream and its head
     # (read by the eva layers, the final norm and the head): an RMSNorm
     # scales by ``norm_offset + w``; the stream between layers and the
@@ -383,9 +429,10 @@ class GPTConfig:
         if self.mixer_types:
             # attention layers keep their own weight tree and a pool laid
             # out position by position: they do not mix with the others,
-            # each of which keeps one of five shapes of cache (pages; a
-            # state row a slot; both; pages of two roles behind a window;
-            # a ring of pages a slot, in a pool of its own)
+            # each of which keeps one of six shapes of cache (pages; a
+            # state row a slot, with convolution tails in a kda layer;
+            # both; pages of two roles behind a window; a ring of pages a
+            # slot, in a pool of its own)
             mixable = set(LAYER_KINDS) - {"attention"}
             if set(self.mixer_types) - mixable \
                     or len(self.mixer_types) != self.n_layer:
@@ -403,6 +450,19 @@ class GPTConfig:
             if set(self.mixer_types) & GROUPED_KINDS and self.gqa is None:
                 raise ValueError(
                     "full_attn and window_attn layers need cfg.gqa")
+            if "kda" in self.mixer_types and self.kda is None:
+                raise ValueError("kda layers need cfg.kda")
+        if self.moe_rule not in ("softmax", "sigmoid_bias"):
+            raise ValueError(
+                f"moe_rule must be 'softmax' or 'sigmoid_bias', got "
+                f"{self.moe_rule!r}")
+        if self.moe_held is not None:
+            first, count = self.moe_held
+            if first < 0 or count < 1 \
+                    or first + count > self.moe_num_experts:
+                raise ValueError(
+                    f"moe_held {self.moe_held} must name experts among the "
+                    f"{self.moe_num_experts} routed over")
         if self.remat_policy not in ("full", "flash", "matmuls", "dots",
                                      "dots_all"):
             raise ValueError(

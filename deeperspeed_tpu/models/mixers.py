@@ -1,11 +1,12 @@
 """The layer kinds beside ``gpt.decoder_block``'s: RMSNorm, a gated SiLU
 feed-forward (dense, or experts: ``feed_forward``), muP scalings, and
 mixers most of which keep something other than every key and value
-between tokens. What a layer keeps has one of five shapes: pages
+between tokens. What a layer keeps has one of six shapes: pages
 (``minicpm4``, ``full_attn``), a state row a slot (``lightning``), both
 (``mamba_attn``), pages of two roles whose count stops following the
-length (``eva``), or a ring of pages that holds the last ``window`` keys
-(``window_attn``):
+length (``eva``), a ring of pages that holds the last ``window`` keys
+(``window_attn``), or a state row and three convolution tails a slot
+(``kda``):
 
 ``lightning``  decayed linear attention (Lightning Attention): per head a
                state ``S_t = lam S_{t-1} + k_t^T v_t`` (Dh x Dh, float32),
@@ -38,11 +39,19 @@ length (``eva``), or a ring of pages that holds the last ``window`` keys
 ``window_attn``  the same over the keys ``i - window < j <= i`` alone: the
                window slides with the query, and the layer keeps the last
                ``window`` keys (may share a stack with ``full_attn``).
+``kda``        a gated delta rule with a decay a CHANNEL (Kimi Delta
+               Attention): per head a state ``S_t = (I - b_t k_t k_t^T)
+               Diag(a_t) S_{t-1} + b_t k_t v_t^T`` (dk x dv, float32),
+               ``o_t = S_t^T q_t``, q, k and v each behind a depthwise
+               causal convolution and a SiLU, q and k of unit length; a
+               prompt computes the rule chunkwise, a decode step is the
+               recurrence (may share a stack with ``full_attn``).
 
 A model whose ``GPTConfig.mixer_types`` names them keeps its weights
 stacked BY KIND (``STACK_KEY``) and is served only; the layer loop of every
 program goes run by run (``layer_runs``). ``mixed_block``,
-``mamba_attn_block``, ``eva_block`` and ``grouped_attn_block`` are the
+``mamba_attn_block``, ``eva_block``, ``grouped_attn_block`` and
+``kda_block`` are the
 layers the whole forward, the chunked prefill and the decode step share: a
 program hands them the cache-dependent cores alone (``core(q, k, v) ->
 (ctx, aux)``; for the state-space branch ``scan(xbc, dt) -> (y, aux)``).
@@ -62,7 +71,7 @@ NEG = -1e30
 STACK_KEY = {"attention": "layers", "minicpm4": "sparse",
              "lightning": "lightning", "mamba_attn": "mamba_attn",
              "eva": "eva", "full_attn": "full_attn",
-             "window_attn": "window_attn"}
+             "window_attn": "window_attn", "kda": "kda"}
 
 
 # ------------------------------------------------------------------ #
@@ -89,7 +98,7 @@ def gated_ffn(u, p, cdt, gate_mult: float = 1.0):
 
 
 def feed_forward(cfg: GPTConfig, m, p, live=None, gate_mult: float = 1.0,
-                 layer=None):
+                 layer=None, shared=None):
     """The feed-forward of a mixed layer, as the configuration says: the
     dense gated SiLU one, or (``cfg.moe_num_experts``) gated SiLU experts
     routed ``moe_top_k`` a token with nothing dropped
@@ -97,18 +106,30 @@ def feed_forward(cfg: GPTConfig, m, p, live=None, gate_mult: float = 1.0,
     tree; ``live`` (B, S) bool or None: the tokens that are real (an idle
     lane, a chunk's padding is routed to no expert). With ``layer`` (a
     traced index) ``p`` is the kind's whole STACK of experts and the
-    layer's are read where they lie (``moe.gated_experts``). -> (y (B, S,
-    D), the experts' counts (3,) int32, zeros for a dense feed-forward)."""
+    layer's are read where they lie (``moe.gated_experts``); ``shared``
+    is then this layer's own shared expert (``cfg.moe_shared``), which a
+    call without ``layer`` finds in ``p``. -> (y (B, S, D), the experts'
+    counts int32 (``expert_counts_width`` of them), zeros for a dense
+    feed-forward)."""
     if not cfg.moe_num_experts:
         return gated_ffn(m, p, cfg.dtype, gate_mult), \
             jnp.zeros((3,), jnp.int32)
     from .moe import gated_experts
 
     B, S, D = m.shape
+    if cfg.moe_shared and layer is None:
+        shared = p["shared"]
     y, counts = gated_experts(
         p, m.reshape(B * S, D), cfg.moe_top_k, cfg.moe_normalize_gates,
-        None if live is None else live.reshape(B * S), gate_mult, layer)
+        None if live is None else live.reshape(B * S), gate_mult, layer,
+        cfg.moe_held, shared, cfg.moe_rule)
     return y.reshape(B, S, D), counts
+
+
+def expert_counts_width(cfg: GPTConfig) -> int:
+    """How many of ``moe.EXPERT_COUNTS`` this model's routed layers count:
+    the assignments that left only where it holds a share of the experts."""
+    return 4 if cfg.moe_held is not None else 3
 
 
 def lightning_slopes(n_head: int):
@@ -176,10 +197,20 @@ def init_params(rng, cfg: GPTConfig):
         """The feed-forward ``feed_forward`` reads: dense, or experts
         (an expert axis behind the layers', and the router)."""
         E = (cfg.moe_num_experts,) if cfg.moe_num_experts else ()
-        p = {"w_gate": w((n, *E, D, F), std), "w_up": w((n, *E, D, F), std),
-             "w_down": w((n, *E, F, D), out_std)}
+        # a program that holds a share of the experts holds their weights
+        # alone; the router scores all of them
+        Eh = (cfg.moe_held[1],) if cfg.moe_held is not None else E
+        p = {"w_gate": w((n, *Eh, D, F), std), "w_up": w((n, *Eh, D, F), std),
+             "w_down": w((n, *Eh, F, D), out_std)}
         if E:
             p["router"] = w((n, D, *E), std)
+        if E and cfg.moe_rule == "sigmoid_bias":
+            p["router_bias"] = w((n, *E), std)
+        if E and cfg.moe_shared:
+            Fs = cfg.moe_shared * F
+            p["shared"] = {"w_gate": w((n, D, Fs), std),
+                           "w_up": w((n, D, Fs), std),
+                           "w_down": w((n, Fs, D), out_std)}
         return p
 
     def kind(n, kv_heads, o_norm):
@@ -238,7 +269,25 @@ def init_params(rng, cfg: GPTConfig):
             if cfg.gqa.qk_norm:
                 p["q_norm"] = jnp.ones((n, Dh))
                 p["k_norm"] = jnp.ones((n, Dh))
+            if cfg.gqa.out_gate:
+                p["wg"] = w((n, D, H * Dh), std)
             params[name] = p
+    if cfg.count("kda"):
+        n, kc = cfg.count("kda"), cfg.kda
+        Hk, dk, dv, r = kc.n_heads, kc.head_k, kc.head_v, kc.low_rank
+        params["kda"] = {
+            "ln1": jnp.ones((n, D)), "ln2": jnp.ones((n, D)),
+            # one projection: every head's q, then the k's, then the v's
+            "wqkv": w((n, D, kc.conv_dim), std),
+            # tap j meets the input d_conv - 1 - j positions back; no bias
+            "conv_w": w((n, kc.d_conv, kc.conv_dim), 0.5),
+            "wf_down": w((n, D, r), std), "wf_up": w((n, r, Hk * dk), std),
+            "f_bias": jnp.zeros((n, Hk * dk)),
+            "A_log": jnp.zeros((n, Hk)),
+            "w_beta": w((n, D, Hk), std),
+            "wg_down": w((n, D, r), std), "wg_up": w((n, r, Hk * dv), std),
+            "o_norm": jnp.ones((n, dv)),
+            "wo": w((n, Hk * dv, D), out_std), "mlp": mlp(n)}
     return params
 
 
@@ -397,23 +446,80 @@ def grouped_attn_block(cfg: GPTConfig, kind: str, x, p, positions, attend,
     H, Hkv, Dh = cfg.n_head, cfg.kv_heads, cfg.head_dim
     rope = cfg.gqa.rope(kind)
     with jax.named_scope("ds.attn"):
-        qkv = rms_norm(x, p["ln1"], eps) @ p["wqkv"].astype(cdt)
+        u = rms_norm(x, p["ln1"], eps)
+        qkv = u @ p["wqkv"].astype(cdt)
         q = qkv[..., :H * Dh].reshape(B, S, H, Dh)
         k = qkv[..., H * Dh:(H + Hkv) * Dh].reshape(B, S, Hkv, Dh)
         v = qkv[..., (H + Hkv) * Dh:].reshape(B, S, Hkv, Dh)
         if cfg.gqa.qk_norm:
             q = rms_norm(q, p["q_norm"], eps)
             k = rms_norm(k, p["k_norm"], eps)
-        q = rotary_embedding(q, positions, Dh, rope=rope)
-        k = rotary_embedding(k, positions, Dh, rope=rope)
+        if cfg.gqa.rotary:
+            q = rotary_embedding(q, positions, Dh, rope=rope)
+            k = rotary_embedding(k, positions, Dh, rope=rope)
         ctx, kept = attend(q, k, v)
-        x = x + ctx.astype(cdt).reshape(B, S, H * Dh) @ p["wo"].astype(cdt)
+        ctx = ctx.astype(cdt).reshape(B, S, H * Dh)
+        if cfg.gqa.out_gate:
+            ctx = ctx * jax.nn.sigmoid(u @ p["wg"].astype(cdt))
+        x = x + ctx @ p["wo"].astype(cdt)
+    x, counts = routed_ffn(cfg, x, p, live, stacked)
+    return x, (kept, counts)
+
+
+def routed_ffn(cfg: GPTConfig, x, p, live, stacked):
+    """The second half of a ``grouped_attn_block`` or ``kda_block``: x +
+    FFN(RMSNorm(x)), the feed-forward the configuration's, its routed
+    experts read in the kind's stack where ``stacked`` (that stack of
+    ``mlp`` trees, the layer's index) is given. -> (x, the experts'
+    counts)."""
     with jax.named_scope("ds.mlp"):
         mlp, layer = (p["mlp"], None) if stacked is None \
             or not cfg.moe_num_experts else stacked
-        y, counts = feed_forward(cfg, rms_norm(x, p["ln2"], eps), mlp, live,
-                                 layer=layer)
+        y, counts = feed_forward(cfg, rms_norm(x, p["ln2"], cfg.layernorm_eps),
+                                 mlp, live, layer=layer,
+                                 shared=p["mlp"].get("shared"))
         x = x + y
+    return x, counts
+
+
+def kda_block(cfg: GPTConfig, x, p, scan, live=None, stacked=None):
+    """A ``kda`` layer: x + KDA(RMSNorm(x)), then x + FFN(RMSNorm(x)), no
+    bias, no rotary (the decay and the convolution carry the position).
+    With m the normed input: one projection to every head's q, k and v
+    (the convolution's inputs, in the compute dtype); the log-decay of
+    every channel ``g = -exp(A_h) softplus(W_up (W_down m) + b)`` and
+    ``beta = beta_scale sigmoid(W_beta m)`` a head, float32. ``scan(qkv
+    (B, S, conv_dim), g (B, S, H, dk), beta (B, S, H)) -> (o (B, S, H,
+    dv) float32, kept)`` knows the cache: the convolution over the tails,
+    its SiLU, the unit keys and the rule (``kda_inputs``, then the
+    chunkwise form or the recurrence). The output passes an RMSNorm over
+    each head's ``dv`` entries (one learned scale for all heads) and a
+    low-rank gate before the projection out. ``live``, ``stacked``:
+    ``grouped_attn_block``'s. Returns (x, (kept, the experts' counts))."""
+    cdt, eps, kc = cfg.dtype, cfg.layernorm_eps, cfg.kda
+    B, S, _ = x.shape
+    H, dk, dv = kc.n_heads, kc.head_k, kc.head_v
+    f32 = jnp.float32
+    with jax.named_scope("ds.kda"):
+        u = rms_norm(x, p["ln1"], eps)
+        with jax.named_scope("ds.kda.proj"):
+            qkv = u @ p["wqkv"].astype(cdt)
+            f = jnp.dot((u @ p["wf_down"].astype(cdt)),
+                        p["wf_up"].astype(cdt), preferred_element_type=f32)
+            g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+                (f + p["f_bias"].astype(f32)).reshape(B, S, H, dk))
+            beta = kc.beta_scale * jax.nn.sigmoid(jnp.dot(
+                u, p["w_beta"].astype(cdt), preferred_element_type=f32))
+        o, kept = scan(qkv, g, beta)
+        with jax.named_scope("ds.kda.out"):
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
+                                  + eps) * p["o_norm"].astype(f32)
+            gate = jax.nn.sigmoid(jnp.dot(
+                u @ p["wg_down"].astype(cdt), p["wg_up"].astype(cdt),
+                preferred_element_type=f32))
+            y = (o.reshape(B, S, H * dv) * gate).astype(cdt)
+            x = x + y @ p["wo"].astype(cdt)
+    x, counts = routed_ffn(cfg, x, p, live, stacked)
     return x, (kept, counts)
 
 
@@ -651,6 +757,126 @@ def ssm_step(m, sp, xbc, dt, tail, h):
     rows, y = ssm_rows_xla(h[None], 0, jnp.exp(dA), delta[..., None] * x, Bm,
                            Cm, jnp.ones(h.shape[:1], bool))
     return y + sp["D"].astype(jnp.float32)[:, None] * x, tail, rows[0]
+
+
+# ------------------------------------------------------------------ #
+# kda: the convolution, the chunkwise delta rule and the recurrence
+# ------------------------------------------------------------------ #
+
+
+def kda_inputs(kc, conv):
+    """What the rule takes, float32, from the convolution's output
+    ``conv`` (..., conv_dim): its SiLU, split into every head's q, k (...,
+    H, dk) and v (..., H, dv); q and k of unit length a head, q over
+    ``sqrt(dk)`` besides."""
+    c = jax.nn.silu(conv.astype(jnp.float32))
+    lead, H, dk, dv = c.shape[:-1], kc.n_heads, kc.head_k, kc.head_v
+    q = c[..., :H * dk].reshape(*lead, H, dk)
+    k = c[..., H * dk:2 * H * dk].reshape(*lead, H, dk)
+    v = c[..., 2 * H * dk:].reshape(*lead, H, dv)
+    unit = lambda a: a * jax.lax.rsqrt(
+        jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6)
+    return unit(q) * (1.0 / math.sqrt(dk)), unit(k), v
+
+
+def kda_recurrence(q, k, v, g, beta, s_in):
+    """The rule by its definition, a token at a time, and the oracle of
+    the chunkwise forms. q, k, g: (T, H, dk); v: (T, H, dv); beta: (T,
+    H); s_in: (H, dk, dv) float32. ``S <- (I - b k k^T) Diag(exp g) S + b
+    k v^T``, ``o = S^T q``. -> (o (T, H, dv), the state after)."""
+    def step(S, xs):
+        qt, kt, vt, gt, bt = xs
+        S = jnp.exp(gt)[..., None] * S
+        pred = jnp.einsum("hk,hkv->hv", kt, S, precision="highest")
+        S = S + kt[..., None] * (bt[:, None] * (vt - pred))[:, None, :]
+        return S, jnp.einsum("hk,hkv->hv", qt, S, precision="highest")
+
+    f32 = lambda a: a.astype(jnp.float32)
+    S, o = jax.lax.scan(step, f32(s_in), tuple(map(f32, (q, k, v, g, beta))))
+    return o, S
+
+
+def kda_chunk_xla(q, k, v, g, beta, s_in):
+    """The chunkwise rule in plain XLA: ``ops/pallas/kda_chunk.
+    block_rule`` a block and a head at a time (the heads side by side, the
+    blocks in order). Shapes as ``kda_recurrence``, T any length: padding
+    comes with g = 0 and beta = 0, which leaves the state as it was. ->
+    (o (T, H, dv) float32, the state after position T - 1)."""
+    from ..ops.pallas.kda_chunk import BLOCK, block_rule
+
+    T = q.shape[0]
+    pad = -T % BLOCK
+    f32 = lambda a: jnp.pad(a.astype(jnp.float32),
+                            ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+    q, k, v, g, beta = map(f32, (q, k, v, g, beta))
+    b = beta[..., None]
+    roll = lambda x, d: jnp.roll(x, d, 0)
+    dot = lambda a, c, dims: jax.lax.dot_general(
+        a, c, (dims, ((), ())), precision="highest",
+        preferred_element_type=jnp.float32)
+    rule = jax.vmap(lambda *a: block_rule(*a, roll, dot), in_axes=1,
+                    out_axes=(1, 0))
+
+    def blk(St, xs):
+        # the heads on axis 1 of every (B, H, .) operand, 0 of the state
+        o, St = rule(*xs, jnp.moveaxis(St, 0, 1))
+        return St, o
+
+    split = lambda a: a.reshape((T + pad) // BLOCK, BLOCK, *a.shape[1:])
+    St, o = jax.lax.scan(
+        blk, jnp.swapaxes(s_in.astype(jnp.float32), 1, 2),
+        tuple(map(split, (q, k, k * b, v * b, g))))
+    return o.reshape(T + pad, *o.shape[2:])[:T], jnp.swapaxes(St, 1, 2)
+
+
+def kda_chunk(kc, p, qkv, g, beta, tail, S, n_valid, rule=kda_chunk_xla):
+    """The mixer between its projections, for T positions of one sequence
+    whose first ``n_valid`` (traced) are real. qkv: (T, conv_dim), the
+    convolution's inputs; g: (T, H, dk); beta: (T, H); tail: (d_conv - 1,
+    conv_dim), the inputs just before position 0; S: (H, dk, dv) float32,
+    the state before it. ``rule``: the chunkwise form (``kda_chunk_xla``'s
+    signature). Returns (o (T, H, dv) float32, the new tail: the last
+    d_conv - 1 inputs before ``n_valid``, and the state after position
+    n_valid - 1)."""
+    T, K = qkv.shape[0], kc.d_conv
+    with jax.named_scope("ds.kda.conv"):
+        ext = jnp.concatenate([tail.astype(qkv.dtype), qkv], 0)
+        w = p["conv_w"].astype(jnp.float32)
+        conv = sum(w[j] * ext[j:j + T].astype(jnp.float32) for j in range(K))
+        q, k, v = kda_inputs(kc, conv)
+        real = jnp.arange(T, dtype=jnp.int32) < n_valid
+        g = jnp.where(real[:, None, None], g, 0.0)
+        beta = jnp.where(real[:, None], beta, 0.0)
+    with jax.named_scope("ds.kda.rule"):
+        o, S = rule(q, k, v, g, beta, S)
+    return o, jax.lax.dynamic_slice_in_dim(ext, n_valid, K - 1, 0), S
+
+
+def kda_step_inputs(kc, p, qkv, tail):
+    """One token a row up to the recurrence: the convolution over the
+    tail and the new input. qkv: (N, conv_dim); tail: (N, d_conv - 1,
+    conv_dim). Returns ``kda_inputs``' three and the new tail."""
+    ext = jnp.concatenate([tail.astype(qkv.dtype), qkv[:, None]], 1)
+    conv = jnp.sum(ext.astype(jnp.float32)
+                   * p["conv_w"].astype(jnp.float32), 1)
+    return (*kda_inputs(kc, conv), ext[:, 1:])
+
+
+def kda_rows_xla(rows, layer, q, k, v, g, beta, live):
+    """The recurrence for one token a slot on the STACKED state rows, in
+    plain XLA, and the oracle of ops/pallas/kda_row_update. rows: (L, N,
+    H, dk, dv) float32, the layers' rows of every slot; ``layer`` traced.
+    q, k, g: (N, H, dk); v: (N, H, dv); beta: (N, H); live: (N,) bool: a
+    slot that is not live keeps its row (its o means nothing). Returns
+    (rows with the layer's rows written in place, o (N, H, dv))."""
+    S = rows[layer]
+    Sd = jnp.exp(g)[..., None] * S
+    pred = jnp.sum(k[..., None] * Sd, -2)                   # (N, H, dv)
+    new = Sd + k[..., None] * (beta[..., None] * (v - pred))[..., None, :]
+    o = jnp.sum(q[..., None] * new, -2)
+    rows = jax.lax.dynamic_update_index_in_dim(
+        rows, jnp.where(live[:, None, None, None], new, S), layer, 0)
+    return rows, o
 
 
 # ------------------------------------------------------------------ #
@@ -945,6 +1171,17 @@ def forward(cfg: GPTConfig, params, tokens):
 
             return grouped_attn_block(cfg, kind, x, p, positions,
                                       attend)[0], ()
+        if kind == "kda":
+            kc = cfg.kda
+
+            def scan(qkv, g, beta):
+                o, _, _ = kda_chunk(
+                    kc, p, qkv[0], g[0], beta[0],
+                    jnp.zeros((kc.d_conv - 1, kc.conv_dim), qkv.dtype),
+                    jnp.zeros((kc.n_heads, kc.head_k, kc.head_v)), S)
+                return o[None], ()
+
+            return kda_block(cfg, x, p, scan)[0], ()
         if kind == "eva":
             def attend(q, k, v):
                 return dense_eva_attention(q[0], k[0], v[0], p["mu"],

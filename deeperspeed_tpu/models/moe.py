@@ -514,8 +514,9 @@ def moe_loss(aux, cfg: MoEConfig):
 # served: gated experts, routed as the config states, nothing dropped
 # ------------------------------------------------------------------ #
 
-# what ``gated_experts`` counts of one call, in this order
-EXPERT_COUNTS = ("experts_touched", "assignments", "max_load")
+# what ``gated_experts`` counts of one call, in this order; ``away`` only
+# where the program holds a share of the experts (``held``)
+EXPERT_COUNTS = ("experts_touched", "assignments", "max_load", "away")
 
 
 def init_gated_experts(rng, d_model: int, d_ff: int, num_experts: int,
@@ -530,23 +531,35 @@ def init_gated_experts(rng, d_model: int, d_ff: int, num_experts: int,
             "w_down": w(k[3], (E, F, D), out_std)}
 
 
-def route_top_k(m, router, top_k: int, normalize: bool):
+def route_top_k(m, router, top_k: int, normalize: bool,
+                rule: str = "softmax", bias=None):
     """Which experts each token goes to, and with what weight. m: (T, D)
     in the compute dtype; router: (D, E). The logits' products are exact
-    (both operands in m's dtype, sums in float32), the softmax over ALL
-    experts float32; the ``top_k`` largest probabilities win, equal ones
-    to the lower index; ``normalize`` divides the winners' by their sum.
-    -> (experts (T, top_k) int32, gates (T, top_k) float32)."""
+    (both operands in m's dtype, sums in float32). Two rules:
+    ``"softmax"``: a float32 softmax over ALL experts, the ``top_k``
+    largest probabilities win and are the gates. ``"sigmoid_bias"``: the
+    scores are ``sigmoid(logits)``, each expert on its own; the ``top_k``
+    largest of ``score + bias`` win (``bias`` (E,), a learned selection
+    bias), and the gates are the winners' UNBIASED scores. Under both,
+    equal ones go to the lower index and ``normalize`` divides the
+    winners' gates by their sum. -> (experts (T, top_k) int32, gates (T,
+    top_k) float32)."""
     logits = jnp.dot(m, router.astype(m.dtype),
                      preferred_element_type=jnp.float32)
-    gate, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if rule == "sigmoid_bias":
+        score = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(score + bias.astype(jnp.float32), top_k)
+        gate = jnp.take_along_axis(score, experts, axis=-1)
+    else:
+        gate, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
     if normalize:
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
     return experts.astype(jnp.int32), gate
 
 
 def gated_experts(p, m, top_k: int, normalize: bool = True, live=None,
-                  gate_mult: float = 1.0, layer=None):
+                  gate_mult: float = 1.0, layer=None, held=None,
+                  shared=None, rule: str = "softmax"):
     """``sum_{e in top-k} g_e W_down^e(SiLU(W_gate^e m) * (W_up^e m))``
     for every token of m (T, D), with NO capacity: the T * top_k
     assignments are sorted by expert and meet the experts' weights in
@@ -561,18 +574,32 @@ def gated_experts(p, m, top_k: int, normalize: bool = True, live=None,
     (a slice by a traced index would be copied for them, every call).
     ``live`` (T,) bool: a token that is not live is assigned to no expert
     (it sorts past the last group, whose rows the product leaves alone)
-    and gets zeros. -> (y (T, D) in m's dtype, counts (3,) int32:
-    ``EXPERT_COUNTS``, the experts with any assignment, the assignments,
-    the largest expert's)."""
+    and gets zeros. ``rule``: ``route_top_k``'s (``"sigmoid_bias"`` reads
+    ``p["router_bias"]``). ``held`` (first, count): the tree holds the
+    experts ``first .. first + count - 1`` alone of the E the router
+    scores: the routing is over all E, an assignment to an expert that is
+    not held sorts past the last group as a dead lane's does and adds
+    nothing HERE (its expert's chip adds it), and the groups are the
+    ``count`` held experts. ``shared``: a ``mixers.gated_ffn`` tree every
+    live token passes, added once. -> (y (T, D) in m's dtype, counts
+    int32: ``EXPERT_COUNTS``, the held experts with any assignment, the
+    assignments computed here, the largest expert's and, with ``held``
+    alone, the live assignments that left)."""
     from ..ops.pallas.grouped_matmul import grouped_matmul_for
 
     T, D = m.shape
-    E = p["router"].shape[-1]
+    E = p["router"].shape[-1] if held is None else held[1]
     cdt = m.dtype
-    router = p["router"] if layer is None else p["router"][layer]
+    pick = (lambda a: a) if layer is None else (lambda a: a[layer])
     with jax.named_scope("ds.moe.route"):
-        experts, gate = route_top_k(m, router, top_k, normalize)
+        experts, gate = route_top_k(
+            m, pick(p["router"]), top_k, normalize, rule,
+            pick(p["router_bias"]) if rule == "sigmoid_bias" else None)
         flat = experts.reshape(-1)                  # token-major (T k,)
+        if held is not None:
+            flat = flat - held[0]
+            here = (flat >= 0) & (flat < E)
+            flat = jnp.where(here, flat, E)
         if live is not None:
             flat = jnp.where(jnp.repeat(live, top_k), flat, E)
         order = jnp.argsort(flat, stable=True)
@@ -599,11 +626,24 @@ def gated_experts(p, m, top_k: int, normalize: bool = True, live=None,
             h = h * jnp.asarray(gate_mult, h.dtype)
         h = jax.nn.silu(h) * product(xs, p["w_up"])
         out = product(h.astype(cdt), p["w_down"])
+        if held is not None:
+            # a row past the groups holds whatever the buffer did
+            gate = jnp.where(here.reshape(T, top_k), gate, 0.0)
+            out = jnp.where(here[order][:, None], out, 0)
         # a token's top_k results weighted and summed in float32
         y = jnp.sum(out[home].reshape(T, top_k, D).astype(jnp.float32)
                     * gate[..., None], axis=1)
+        if shared is not None:
+            from .mixers import gated_ffn
+
+            with jax.named_scope("ds.moe.shared"):
+                y = y + gated_ffn(m, shared, cdt, gate_mult).astype(
+                    jnp.float32)
         if live is not None:
             y = jnp.where(live[:, None], y, 0.0)
-    counts = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32),
-                        jnp.sum(sizes, dtype=jnp.int32), jnp.max(sizes)])
-    return y.astype(cdt), counts
+    counts = [jnp.sum(sizes > 0, dtype=jnp.int32),
+              jnp.sum(sizes, dtype=jnp.int32), jnp.max(sizes)]
+    if held is not None:
+        real = T if live is None else jnp.sum(live, dtype=jnp.int32)
+        counts.append(real * top_k - counts[1])
+    return y.astype(cdt), jnp.stack(counts)

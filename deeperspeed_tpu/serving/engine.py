@@ -99,6 +99,8 @@ from .kv_cache import (
     eva_chunk_past,
     eva_decode_indices,
     eva_page_list,
+    kda_chunk_for,
+    kda_rows_for,
     lightning_chunk_for,
     page_rule_for,
     ring_decode_attend,
@@ -222,12 +224,15 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
     real, for a feed-forward of routed experts (a ``full_attn`` or
     ``window_attn`` layer's ``kept`` comes paired with their counts, and
     ``stacked`` is ``grouped_attn_block``'s: the experts' stack and the
-    layer's place in it). An
+    layer's place in it; a ``kda`` layer's likewise, its ``attend`` the
+    scan ``kda_block`` takes). An
     ``attention`` layer's experts go the dropless way here: what training
     bounds by a capacity would be a wrong token served."""
     if kind in GROUPED_KINDS:
         return mixers.grouped_attn_block(cfg, kind, x, layer_params,
                                          positions, attend, live, stacked)
+    if kind == "kda":
+        return mixers.kda_block(cfg, x, layer_params, attend, live, stacked)
     if kind == "mamba_attn":
         return mixers.mamba_attn_block(cfg, x, layer_params, positions,
                                        *attend)
@@ -317,7 +322,7 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
             slots, scfg.blocks_per_slot)
         N = tokens.shape[0]
         if counts_experts(cfg):
-            prev = prev[:N]
+            prev = prev[:N]     # behind the slots': the last step's counts
         tokens = jnp.where(tokens == TAKE_PREV, prev, tokens)
         positions = lengths[:, None]                        # (N, 1)
         with jax.named_scope("ds.embed"):
@@ -340,12 +345,22 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
             update_rows = ssm_rows_for(state["ssm"], cfg.ssm.n_groups, mesh)
         if "eva" in kinds:
             at = eva_decode_indices(cfg.eva, scfg, tables, lengths)
-        if kinds & {"lightning", "mamba_attn"}:
+        if kinds & {"lightning", "mamba_attn", "kda"}:
             # a slot whose prompt is still being chunked in is idle here:
             # its state row is the chunks' to write
             live = (lengths > 0)[:, None, None, None]
         real = None
-        if kinds & GROUPED_KINDS:
+        if "kda" in kinds:
+            # the full_attn layers' pages follow the length in the ONE
+            # pool; the kda layers keep rows and tails
+            k_full, v_full, bs = k_pool, v_pool, scfg.block_size
+            at = {"full": tables, "row": lengths % bs,
+                  "page": tables[jnp.arange(N), lengths // bs]}
+            attend_full = slot_attend_for(k_full, cfg.n_head, tables.shape,
+                                          mesh)
+            update_kda = kda_rows_for(state["kda"], mesh)
+            real = (lengths > 0)[:, None]
+        elif kinds & GROUPED_KINDS:
             (k_full, k_ring), (v_full, v_ring) = k_pool, v_pool
             at = ring_decode_indices(scfg, tables, lengths)
             attend_full = slot_attend_for(k_full, cfg.n_head,
@@ -431,15 +446,32 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                     layer, 0)
                 return y[:, None], {"conv": conv, "ssm": ssm_rows}
 
+            def delta_rule(qkv, g, beta):
+                """The new token's convolutions, then every slot's state
+                row through the rule, in place in the carry."""
+                tail = rows["conv"][layer]
+                q, k, v, new_tail = mixers.kda_step_inputs(
+                    cfg.kda, layer_params, qkv[:, 0], tail)
+                with jax.named_scope("ds.kda.rule"):
+                    kda_rows, o = update_kda(rows["kda"], layer, q, k, v,
+                                             g[:, 0], beta[:, 0], lengths > 0)
+                conv = jax.lax.dynamic_update_index_in_dim(
+                    rows["conv"], jnp.where(live[..., 0], new_tail, tail),
+                    layer, 0)
+                return o[:, None], {"conv": conv, "kda": kda_rows}
+
             core = {"attention": attention, "minicpm4": minicpm4,
                     "lightning": lightning, "eva": two_roles,
                     "full_attn": every_key, "window_attn": last_keys,
+                    "kda": delta_rule,
                     "mamba_attn": (all_pages, state_space)}[kind]
             x, kept = _paged_block(
                 cfg, x, layer_params, positions, core, kind, real,
                 (params[mixers.STACK_KEY[kind]].get("mlp"), layer))
             if kind == "mamba_attn":
                 kept, rows = kept
+            if kind == "kda":
+                rows, kept = kept
             if kind == "lightning":
                 rows = jax.lax.dynamic_update_index_in_dim(rows, kept, layer, 0)
                 kept = ()
@@ -479,7 +511,10 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 experts.append(n)
                 k_ring = write_rows(k_ring, at["ring_page"], at["row"], k_rows)
                 v_ring = write_rows(v_ring, at["ring_page"], at["row"], v_rows)
-            if kinds & GROUPED_KINDS:
+            if "kda" in kept:
+                experts.append(kept["kda"])
+                k_pool, v_pool = k_full, v_full
+            elif kinds & GROUPED_KINDS:
                 k_pool, v_pool = (k_full, k_ring), (v_full, v_ring)
         with jax.named_scope("ds.decode/sample"):
             logits = mixers.served_logits(
@@ -510,11 +545,14 @@ def counts_experts(cfg: GPTConfig) -> bool:
 
 
 def sum_expert_counts(by_kind):
-    """One program's counts from its layers' (``(layers, 3)`` a kind):
-    experts touched and assignments summed over the layers, the largest
-    expert's load the largest of any layer. -> (3,) int32."""
+    """One program's counts from its layers' (``(layers, 3 or 4)`` a
+    kind, ``moe.EXPERT_COUNTS``): experts touched and assignments summed
+    over the layers, the largest expert's load the largest of any layer,
+    the assignments that left (where counted) summed. -> (3 or 4,)
+    int32."""
     n = jnp.concatenate(by_kind)
-    return jnp.stack([jnp.sum(n[:, 0]), jnp.sum(n[:, 1]), jnp.max(n[:, 2])])
+    return jnp.stack([jnp.sum(n[:, 0]), jnp.sum(n[:, 1]), jnp.max(n[:, 2])]
+                     + [jnp.sum(n[:, 3])] * (n.shape[1] > 3))
 
 
 def prefill_chunk_for(cfg: GPTConfig, scfg: ServingConfig) -> int:
@@ -571,9 +609,10 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     full_attn layer, a tile at a time as long as it is; in a window_attn
     layer the ring's rows inside each query's band) and writes its own
     keys, values and pooled keys after the layer loop, in place (over the
-    ring its valid rows alone). Where the stack counts its experts
-    (``counts_experts``) the first output is the pair (logits, the
-    chunk's counts (3,) int32)."""
+    ring its valid rows alone); a kda layer carries its state row and
+    its convolutions' tails as a mamba_attn layer does. Where the stack
+    counts its experts (``counts_experts``) the first output is the pair
+    (logits, the chunk's counts (3,) int32, or (4,): ``sum_expert_counts``)."""
     C = prefill_chunk_for(cfg, scfg)
     bs = scfg.block_size
     sp, ev = cfg.sparse, cfg.eva
@@ -597,7 +636,14 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 jax.lax.dynamic_index_in_dim(rows, slot, 1, keepdims=False)),
             state)
         real = None
-        if cfg.count("full_attn") or cfg.count("window_attn"):
+        if cfg.count("kda"):
+            # ONE pool, the full_attn layers'; a chunk attends over its
+            # past as in a stack of two rules, with no ring
+            k_full, v_full, full_row = k_pool, v_pool, table_row
+            attend = chunk_attend_for(k_full, cfg.n_head, C, mesh)
+            kda_rule, _ = kda_chunk_for(C, cfg.kda, mesh)
+            real = (jnp.arange(C) < n_valid)[None, :]
+        elif cfg.count("full_attn") or cfg.count("window_attn"):
             (k_full, k_ring), (v_full, v_ring) = k_pool, v_pool
             n_full = scfg.table_widths[0]
             full_row, ring_row = table_row[:n_full + C // bs], \
@@ -654,9 +700,16 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                                   kk, vv, ring_pages, offset)
                 return ctx[None], (kk, vv)
 
+            def delta_rule(qkv, g, beta):
+                o, tail, S = mixers.kda_chunk(
+                    cfg.kda, layer_params, qkv[0], g[0], beta[0],
+                    carried["conv"][layer], carried["kda"][layer], n_valid,
+                    kda_rule)
+                return o[None], {"conv": tail, "kda": S}
+
             core = {"minicpm4": minicpm4, "lightning": lightning,
                     "eva": two_roles, "full_attn": every_key,
-                    "window_attn": last_keys,
+                    "window_attn": last_keys, "kda": delta_rule,
                     "mamba_attn": (all_past, state_space)}[kind]
             return _paged_block(
                 cfg, x, layer_params, positions, core, kind, real,
@@ -695,7 +748,15 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
                 k_ring, v_ring = write_ring_chunk(
                     cfg.gqa.window, k_ring, v_ring, ring_row, offset, n_valid,
                     kk, vv)
-            if experts:
+            if "kda" in kept:
+                new, n = kept["kda"]
+                experts.append(n)
+                k_pool, v_pool = k_full, v_full
+                state = jax.tree.map(
+                    lambda rows, n: jax.lax.dynamic_update_slice(
+                        rows, n[:, None].astype(rows.dtype),
+                        (0, slot) + (0,) * (rows.ndim - 2)), state, new)
+            elif experts:
                 k_pool, v_pool = (k_full, k_ring), (v_full, v_ring)
         last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0)
         logits = mixers.head_logits(cfg, params, last)[0]
@@ -997,7 +1058,7 @@ class ServingEngine(_ServingBase):
             kinds = sorted(set(cfg.mixer_types))
             if scfg.prefix_caching and \
                     set(kinds) & {"lightning", "mamba_attn", "eva",
-                                  "window_attn"}:
+                                  "window_attn", "kda"}:
                 raise ValueError(
                     "prefix_caching cannot serve a model with a layer that "
                     "keeps recurrent state, or pages that are overwritten "
@@ -1031,9 +1092,12 @@ class ServingEngine(_ServingBase):
             set(cfg.layer_kinds) & SLOT_LIST_KINDS) and takes_slot_form(
             self.kv.k, cfg.n_head, (scfg.num_slots, scfg.blocks_per_slot),
             mesh)
+        # the pool of every key: the first of two, or the only one
+        full_pool = self.kv.k[0] if isinstance(self.kv.k, tuple) \
+            else self.kv.k
         if cfg.count("full_attn"):      # its list: the table's first section
             self._slot_rows = takes_slot_form(
-                self.kv.k[0], cfg.n_head,
+                full_pool, cfg.n_head,
                 (scfg.num_slots, scfg.table_widths[0]), mesh)
         # how a prompt chunk of a stack of two cache rules attends over
         # its past ("kernel" or "xla"), chosen like ``_slot_rows``; None
@@ -1041,17 +1105,24 @@ class ServingEngine(_ServingBase):
         self._chunk_attn = None
         if cfg.count("full_attn") or cfg.count("window_attn"):
             self._chunk_attn = chunk_attend_for(
-                self.kv.k[0], cfg.n_head, prefill_chunk_for(cfg, scfg),
+                full_pool, cfg.n_head, prefill_chunk_for(cfg, scfg),
                 None).name
+        # how a prompt chunk's kda layers run the delta rule ("kernel" or
+        # "xla"), chosen alike; None for a stack without them
+        self._kda_scan = None
+        if cfg.count("kda"):
+            _, self._kda_scan = kda_chunk_for(
+                prefill_chunk_for(cfg, scfg), cfg.kda, None)
         # whether the programs count their routed experts: the decode
-        # step's tokens then come with three counts behind them
-        self._counts_experts = counts_experts(cfg)
+        # step's tokens then come with that many counts behind them
+        self._counts_experts = counts_experts(cfg) \
+            * mixers.expert_counts_width(cfg)
         # the last decode step's tokens as the device handed them back,
         # the next step's ``prev`` (zeros until a step has run), and the
         # steps launched and not yet read, oldest first: one between
         # step() calls, two between a launch and the collect after it
         self._prev = jnp.asarray(self._place_slot_array(
-            np.zeros(scfg.num_slots + 3 * self._counts_experts, np.int32)))
+            np.zeros(scfg.num_slots + self._counts_experts, np.int32)))
         # the counts of the prompt chunks dispatched since the last launch,
         # on the device: read with the decode step queued behind them
         self._chunk_counts: List[Any] = []
@@ -1463,7 +1534,9 @@ class ServingEngine(_ServingBase):
                            rid=req.rid, chunk=c, tokens=hi - lo, offset=lo,
                            pages=named, listed_pages=f"{listed}/{named}",
                            **({"attn": self._chunk_attn}
-                              if self._chunk_attn else {})):
+                              if self._chunk_attn else {}),
+                           **({"scan": self._kda_scan}
+                              if self._kda_scan else {})):
             with trace_span("serving/prefill/pack", lane="serving"):
                 toks = np.zeros((1, C), np.int32)
                 toks[0, :hi - lo] = state["ctx"][lo:hi]
@@ -1482,7 +1555,8 @@ class ServingEngine(_ServingBase):
         self._prefill_spent += hi - lo
         self._chunk_ran = True
         self.metrics.record_prefill_chunk(
-            hi - lo, named, listed, kernel_attn=self._chunk_attn == "kernel")
+            hi - lo, named, listed, kernel_attn=self._chunk_attn == "kernel",
+            kernel_scan=self._kda_scan == "kernel")
         if lo == 0 and self.kv.state is not None:
             # the first chunk entered the slot's state rows as zeros
             self.metrics.record_state_reset()
@@ -1719,6 +1793,13 @@ class ServingEngine(_ServingBase):
             self.metrics.record_ring_pages(len(lanes), *held, wraps)
             roles = {"full_pages": str(listed[0]),
                      "window_pages": str(listed[1]), "wraps": str(wraps)}
+        if self._kda_scan:
+            # the full_attn layers' pages the live slots list, and the
+            # state rows the step moves: every slot's, a kda layer each
+            self.metrics.record_full_pages(len(lanes), live_pages)
+            roles = {"full_pages": str(live_pages),
+                     "state_rows": str(self.scfg.num_slots
+                                       * self.cfg.count("kda"))}
         with trace_span("serving/decode/dispatch", lane="serving",
                         live_pages=live_pages, view_pages=tables.size,
                         selected_pages=selected_pages,
@@ -1758,16 +1839,20 @@ class ServingEngine(_ServingBase):
         experts = {}
         if self._counts_experts:
             # behind the slots' tokens: what the step's routed experts did
-            touched, assigned, most = (int(n) for n in nxt[-3:])
+            touched, assigned, most, *away = (
+                int(n) for n in nxt[-self._counts_experts:])
             self.metrics.record_experts("decode", touched, assigned, most,
-                                        self.cfg.n_layer)
+                                        self.cfg.n_layer, *away)
             experts = {"experts": str(touched), "assignments": str(assigned),
                        "max_load": str(most)}
+            if away:
+                experts["away"] = str(away[0])
             if step.chunk_counts:       # finished before the step was
                 chunks = np.stack([np.asarray(c) for c in step.chunk_counts])
                 for c in chunks:
-                    self.metrics.record_experts("chunk", *(int(n) for n in c),
-                                                self.cfg.n_layer)
+                    self.metrics.record_experts(
+                        "chunk", *(int(n) for n in c[:3]), self.cfg.n_layer,
+                        *(int(n) for n in c[3:]))
                 experts.update(
                     chunks=str(len(chunks)),
                     chunk_experts=str(int(chunks[:, 0].sum())),

@@ -6,12 +6,14 @@ Layout: one pool per cache side, stacked over layers —
     k, v: (n_layer, num_blocks, block_size, n_kv_head, head_dim)
 
 A model of mixed layers (``GPTConfig.mixer_types``) keeps, by the kind of
-each layer, one of five shapes of cache: pages (``minicpm4``,
+each layer, one of six shapes of cache: pages (``minicpm4``,
 ``full_attn``), a state row a slot (``lightning``), both for one layer
 (``mamba_attn``), pages of two roles (``eva``: the exact keys of the
 current window in pages the slot reuses window after window, and pages of
-pooled summaries, a row for every chunk of positions), or a ring of pages
-that holds the last ``window`` keys (``window_attn``). ``page_rule_for``
+pooled summaries, a row for every chunk of positions), a ring of pages
+that holds the last ``window`` keys (``window_attn``), or a state row and
+three convolution tails a slot and no pages (``kda``, in a stack whose
+``full_attn`` layers keep the pages). ``page_rule_for``
 is the RULE: how many pages of each role a length needs; a slot's table
 holds the roles side by side. The POOLS go by rule: one array a pool,
 each only as deep as the layers that read it. Every model but one has one
@@ -32,7 +34,10 @@ last so that two or four key heads are not padded to a tile of sixteen:
            float32: lightning (n_lightning, num_slots, n_head, head_dim,
            head_dim); mamba_attn {"ssm": (n, num_slots, ssm heads, ssm
            head_dim, d_state), "conv": (n, num_slots, d_conv - 1,
-           conv_dim) in the compute dtype: the convolution's last inputs}
+           conv_dim) in the compute dtype: the convolution's last inputs};
+           kda {"kda": (n_kda, num_slots, heads, head_k, head_v), "conv":
+           (n_kda, num_slots, d_conv - 1, conv_dim): the last inputs of
+           q's, k's and v's convolutions side by side}
 
 A request's cache lives in whichever blocks the allocator hands it; the
 per-slot BLOCK TABLE (``(num_slots, blocks_per_slot)`` int32) maps the
@@ -394,19 +399,25 @@ class PagedKVCache:
                     f"a page is one selection block: block_size must be "
                     f"{sp.block_size} (got {scfg.block_size}) and "
                     f"max_seq_len cover dense_len ({sp.dense_len})")
-            n_sp, n_li, n_ma, n_ev, n_fu, n_wi = (cfg.count(kind) for kind in (
-                "minicpm4", "lightning", "mamba_attn", "eva", "full_attn",
-                "window_attn"))
+            n_sp, n_li, n_ma, n_ev, n_fu, n_wi, n_kd = (
+                cfg.count(kind) for kind in (
+                    "minicpm4", "lightning", "mamba_attn", "eva", "full_attn",
+                    "window_attn", "kda"))
             kinds = set(cfg.mixer_types)
             if ((n_ma or n_ev) and len(kinds) > 1) \
-                    or ((n_fu or n_wi) and kinds - GROUPED_KINDS):
+                    or ((n_fu or n_wi or n_kd) and not (
+                        kinds <= GROUPED_KINDS
+                        or kinds == {"full_attn", "kda"})):
                 raise NotImplementedError(
                     "mamba_attn and eva layers share a stack with no other "
-                    "kind, full_attn and window_attn layers with each other "
-                    "alone: a pool and the state rows are indexed by a "
-                    "layer's place among the kinds that share them, and "
-                    "only a ring has a pool beside the pages that follow "
-                    "the length")
+                    "kind; full_attn layers share one with window_attn "
+                    "layers (two page rules, a pool each) or with kda "
+                    "layers (pages beside state rows and convolution "
+                    "tails), and kda layers with full_attn layers alone: a "
+                    "pool and the state rows are indexed by a layer's "
+                    "place among the kinds that share them, and only a "
+                    "ring has a pool beside the pages that follow the "
+                    "length")
             if n_ev:
                 check_eva_pages(cfg, scfg)
             if n_wi:
@@ -428,6 +439,13 @@ class PagedKVCache:
                                       m.head_dim, m.d_state), jnp.float32),
                     "conv": jnp.zeros((n_ma, scfg.num_slots, m.d_conv - 1,
                                        m.conv_dim), cfg.dtype)}
+            if n_kd:
+                kc = cfg.kda
+                self.state = {
+                    "kda": jnp.zeros((n_kd, scfg.num_slots, kc.n_heads,
+                                      kc.head_k, kc.head_v), jnp.float32),
+                    "conv": jnp.zeros((n_kd, scfg.num_slots, kc.d_conv - 1,
+                                       kc.conv_dim), cfg.dtype)}
         self.k = jnp.zeros(shape, cfg.dtype)
         self.v = jnp.zeros(shape, cfg.dtype)
         # an allocator a pool, the one whose pages follow the length first
@@ -767,6 +785,32 @@ def ssm_rows_for(rows, n_groups, mesh):
             rows, n_groups):
         return kernel.ssm_row_update
     return mixers.ssm_rows_xla
+
+
+def kda_rows_for(rows, mesh):
+    """A decode step's update of a kda layer's state rows: the kernel
+    (each row crosses HBM once each way) on one TPU at shapes it can
+    tile, else ``mixers.kda_rows_xla``."""
+    from ..models import mixers
+    from ..ops.pallas import kda_row_update as kernel
+
+    if (mesh is None or mesh.size == 1) and kernel.is_available(rows):
+        return kernel.kda_row_update
+    return mixers.kda_rows_xla
+
+
+def kda_chunk_for(C: int, kc, mesh):
+    """The chunkwise delta rule of a kda layer's prompt chunk of ``C``
+    positions (``kc``: the model's ``KdaConfig``): the kernel on one TPU
+    at shapes it can tile, else ``mixers.kda_chunk_xla``. -> (the form,
+    "kernel" or "xla")."""
+    from ..models.mixers import kda_chunk_xla
+    from ..ops.pallas import kda_chunk as kernel
+
+    if (mesh is None or mesh.size == 1) and kernel.is_available(
+            C, kc.head_k, kc.head_v):
+        return kernel.kda_chunk, "kernel"
+    return kda_chunk_xla, "xla"
 
 
 def _own_token_init(q, k_row, v_row):

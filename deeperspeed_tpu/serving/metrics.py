@@ -229,6 +229,11 @@ class ServingMetrics:
         self.moe_max_load = {"decode": 0, "chunk": 0}
         self.moe_layer_calls = {"decode": 0, "chunk": 0}
         self.moe_calls = {"decode": 0, "chunk": 0}
+        # ... and, where the program holds a share of the experts, the
+        # live assignments that went to experts it does not hold
+        self.moe_assignments_away = {"decode": 0, "chunk": 0}
+        # prompt chunks whose kda layers ran the chunkwise kernel
+        self.kda_chunks_kernel = 0
         # host-to-device placements made for the plain decode program's
         # slot inputs, and the dispatches they were made for
         self.decode_placements = 0
@@ -387,13 +392,16 @@ class ServingMetrics:
 
     def record_prefill_chunk(self, tokens: int, named_pages: int = 0,
                              listed_pages: int = 0,
-                             kernel_attn: bool = False) -> None:
+                             kernel_attn: bool = False,
+                             kernel_scan: bool = False) -> None:
         """One staged prompt-chunk forward (chunked/suffix prefill);
         ``named_pages``, ``listed_pages``: what its sparse layers'
         selections and its rows' lists named of the pool; ``kernel_attn``:
-        its program attends over the past in the chunk kernel."""
+        its program attends over the past in the chunk kernel;
+        ``kernel_scan``: its kda layers run the chunkwise kernel."""
         self.prefill_chunks += 1
         self.prefill_chunks_kernel_attn += kernel_attn
+        self.kda_chunks_kernel += kernel_scan
         self.chunk_tokens += tokens
         self.chunk_named_pages += named_pages
         self.chunk_listed_pages += listed_pages
@@ -473,14 +481,22 @@ class ServingMetrics:
         self.window_wraps += wraps
 
     def record_experts(self, program: str, touched: int, assignments: int,
-                       max_load: int, layers: int) -> None:
+                       max_load: int, layers: int, away: int = 0) -> None:
         """What one call of ``program`` ("decode", "chunk") counted of its
-        routed experts over its ``layers`` layers."""
+        routed experts over its ``layers`` layers; ``away``: assignments
+        to experts the program does not hold."""
         self.moe_experts_touched[program] += touched
         self.moe_assignments[program] += assignments
         self.moe_max_load[program] += max_load
         self.moe_layer_calls[program] += layers
         self.moe_calls[program] += 1
+        self.moe_assignments_away[program] += away
+
+    def record_full_pages(self, rows: int, full_pages: int) -> None:
+        """One launched decode step of a stack whose full_attn layers
+        alone keep pages: its ``rows`` live slots held ``full_pages``."""
+        self.kv_held_rows += rows
+        self.kv_full_pages += full_pages
 
     def record_window_pages(self, rows: int, window_pages: int,
                             summary_pages: int, summary_rows: int,
@@ -624,6 +640,7 @@ class ServingMetrics:
                 "experts_touched_per_layer": (
                     self.moe_experts_touched[prog] / calls if calls else 0.0),
                 "assignments": int(self.moe_assignments[prog]),
+                "assignments_away": int(self.moe_assignments_away[prog]),
                 "max_load_per_call": (
                     self.moe_max_load[prog] / self.moe_calls[prog]
                     if calls else 0.0)}
@@ -652,6 +669,7 @@ class ServingMetrics:
                                      / self.decode_steps
                                      if self.decode_steps else 0.0),
             "state_resets": int(self.state_resets),
+            "kda_chunks_kernel": int(self.kda_chunks_kernel),
             "queue_depth_max": int(max(self.queue_depth, default=0)),
             "slo": self.slo_tracker.summary(),
             "prefix_reuse": {

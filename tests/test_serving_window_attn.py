@@ -385,7 +385,7 @@ def test_what_cannot_be_served_is_refused_with_its_reason(params):
         PagedKVCache(cfg, ServingConfig.from_dict(SERVING))
     with pytest.raises(ValueError, match="need cfg.gqa"):
         GPTConfig(n_layer=1, mixer_types=("window_attn",))
-    with pytest.raises(NotImplementedError, match="each other alone"):
+    with pytest.raises(NotImplementedError, match="share a stack with no other"):
         PagedKVCache(
             GPTConfig(n_layer=2, n_head=2, d_model=32,
                       mixer_types=("window_attn", "lightning"),

@@ -565,3 +565,100 @@ def test_mellum_programs_move_neither_a_pool_nor_a_weight_stack(
     assert weights + caches < ask < weights + caches + 2 ** 29, (
         ask, weights, caches)
     assert ask < 11.8 * 2 ** 30
+
+
+def _solar():
+    from benchmark import manifest as mf
+    from benchmark.adapters import solar_open2 as adapter
+    from deeperspeed_tpu.serving import ServingConfig
+    from deeperspeed_tpu.serving.kv_cache import page_rule_for
+
+    man = mf.Manifest()
+    cfg = adapter.model_config(man.config("solar-open2-250b"))
+    scfg = ServingConfig.from_dict(
+        man.workload_file("solar-open2-250b.serve-reason")["serving"])
+    return cfg, scfg.for_cache(page_rule_for(cfg))
+
+
+def test_kda_kernels_compile_at_the_reasoning_cells_geometry(one_chip):
+    """The chunkwise delta rule over a 1,024-token chunk of 64 heads of
+    128 x 128 (a grid step a head and a block of 64 positions, the state
+    in VMEM between them) and a decode step's update of 48 slots' rows of
+    one of three layers, in place."""
+    from deeperspeed_tpu.ops.pallas.kda_chunk import kda_chunk
+    from deeperspeed_tpu.ops.pallas.kda_row_update import kda_row_update
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    C, H, N = 1024, 64, 48
+    compiled = kda_chunk.lower(
+        sds((C, H, 128)), sds((C, H, 128)), sds((C, H, 128)),
+        sds((C, H, 128)), sds((C, H)), sds((H, 128, 128))).compile()
+    assert runs_kernel(compiled.as_text(), "kda_chunk")
+    compiled = kda_row_update.lower(
+        sds((3, N, H, 128, 128)), sds((), jnp.int32), sds((N, H, 128)),
+        sds((N, H, 128)), sds((N, H, 128)), sds((N, H, 128)), sds((N, H)),
+        sds((N,), jnp.bool_)).compile()
+    assert runs_kernel(compiled.as_text(), "kda_row_update")
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_solar_programs_move_neither_the_pool_nor_the_rows_nor_a_weight_stack(
+        one_chip, as_if_on_tpu, program):
+    """The decode step and the prompt-chunk program of the cell of pages
+    and state rows (one period at the published widths: a GQA layer and
+    three KDA layers, 40 held experts of 320 and a shared one a layer, 48
+    slots, 15,361 pages 1 layer deep, 3 x 48 state rows of 4 MiB): the
+    donated pool, rows and tails are outputs in place, nothing copies
+    them or an experts' stack, every kernel of the path engages and the
+    compiler's ask stays inside the chip."""
+    from deeperspeed_tpu.models import mixers
+    from deeperspeed_tpu.serving.engine import (make_chunk_step,
+                                                make_decode_step)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg, scfg = _solar()
+    params = jax.tree.map(
+        lambda a: sds(a.shape),
+        jax.eval_shape(lambda: mixers.init_params(jax.random.PRNGKey(0), cfg)))
+    N, bps = scfg.num_slots, scfg.blocks_per_slot
+    assert (N, scfg.pool_blocks, bps, scfg.table_widths) == (
+        48, (15361,), 320, (320,))
+    i32 = jnp.int32
+    pool = sds((1, 15361, 8, 64, 128))
+    state = {"kda": sds((3, N, 64, 128, 128), jnp.float32),
+             "conv": sds((3, N, 3, 3 * 64 * 128))}
+    if program == "decode":
+        compiled = make_decode_step(cfg, scfg).lower(
+            params, pool, pool, sds(*_idle_slots(N, bps)),
+            sds((N + 4,), i32), None, state).compile()
+    else:
+        compiled = make_chunk_step(cfg, scfg).lower(
+            params, pool, pool, None, state, sds((1, 1024), i32),
+            sds((bps,), i32), sds((), i32), sds((), i32), sds((), i32)).compile()
+    text = compiled.as_text()
+    assert runs_kernel(text, "paged_sparse_attn_slots") == (
+        program == "decode")
+    assert runs_kernel(text, "kda_row_update") == (program == "decode")
+    assert runs_kernel(text, "chunk_past_attn") == (program == "chunk")
+    assert runs_kernel(text, "kda_chunk") == (program == "chunk")
+    assert runs_kernel(text, "gmm")
+    assert count_alias_pairs(text) == 4        # k, v, the rows, the tails
+    big = ("bf16[1,15361,", "f32[3,48,", "bf16[3,48,", "f32[48,64,128,128]",
+           "bf16[3,40,", "bf16[1,40,", "bf16[40,4096,1280]",
+           "bf16[40,1280,4096]")
+    moved = [ln.strip()[:140] for ln in text.splitlines()
+             if (" copy(" in ln or "dynamic-slice_bitcast_fusion" in ln)
+             and ln.split(" = ")[1].startswith(big)]
+    assert moved == []
+    weights = sum(2 * math.prod(a.shape) for a in jax.tree.leaves(params))
+    assert weights == 2 * 3_308_353_344
+    caches = 2 * 2 * math.prod(pool.shape) + 4 * math.prod(
+        state["kda"].shape) + 2 * math.prod(state["conv"].shape)
+    ask = extract_memory_analysis(compiled)["peak_bytes"]
+    assert weights + caches < ask < weights + caches + 2 ** 30, (
+        ask, weights, caches)
+    assert ask < 11.2 * 2 ** 30
