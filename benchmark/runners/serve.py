@@ -116,6 +116,24 @@ def build_engine(ctx):
     return ctx.adapter.serving_engine(ctx.config, params, ctx.cell_file["serving"])
 
 
+def schedule(mix, seed, seconds, vocab):
+    """The generator's schedule: in the seed's own order or, where the mix
+    deals one (``arrivals.deal``), the same lengths and gaps in that ONE
+    order for every seed, the seed drawing the token ids alone. The order
+    decides who decodes beside whom, and a decode step reads the pages of
+    whoever is live: dealt by each seed this cell's ``tpot_p95_ms`` moved
+    4.41 to 4.66 ms over nine seeds, two runs of one seed 0.01 apart
+    (PERF.md section 6, PR 44)."""
+    deal = mix["arrivals"].get("deal")
+    if deal is None:
+        return tg.serve_requests(mix, seed, seconds, vocab)
+    requests = tg.serve_requests(mix, deal, seconds, vocab)
+    tok = tg.rng_for(seed, 2)
+    for r in requests:
+        r["prompt"] = tok.integers(0, vocab, len(r["prompt"])).tolist()
+    return requests
+
+
 def warm(engine, requests, vocab, seed):
     """One request for every prompt shape of the schedule, then decode, so
     that every program the window uses is compiled or loaded. The program
@@ -177,15 +195,17 @@ def run(ctx) -> dict:
     import jax
 
     cfg, cell, mix, say = ctx.config, ctx.cell_file, ctx.traffic, ctx.say
-    requests = tg.serve_requests(mix, ctx.seed, ctx.seconds, cfg["vocab_size"])
+    requests = schedule(mix, ctx.seed, ctx.seconds, cfg["vocab_size"])
     engine = build_engine(ctx)
     shapes = warm(engine, requests, cfg["vocab_size"], ctx.seed)
     lowered = device.LoweringCounter.get()
     compiles = lowered.count
     n_occ = len(engine.metrics.occupancy)
+    deal = mix["arrivals"].get("deal")
+    order = "the seed" if deal is None else f"deal {deal}"
     say(f"warmed decode and {len(shapes)} prefill shapes in buckets "
         f"{sorted({b for b, _ in shapes})}; {len(requests)} requests "
-        f"offered over {ctx.seconds:g} s; in use "
+        f"offered over {ctx.seconds:g} s in the order of {order}; in use "
         f"{device.bytes_in_use(ctx.devices) / 2**30:.2f} GiB")
     ctx.spans.durations["serve_step"].clear()
     setup_s = time.perf_counter() - ctx.t_start

@@ -27,6 +27,7 @@ def main(argv=None) -> int:
 
     from benchmark import run as brun
     from benchmark import generator as tg
+    from benchmark import populations
     from benchmark.runners import serve
 
     ctx = brun.open_context(args.workload, args.seed, args.seconds, 0, print)
@@ -34,7 +35,7 @@ def main(argv=None) -> int:
     rates = [float(r) for r in args.rates.split(",")]
     engine = serve.build_engine(ctx)
     widest = tg.serve_requests(ctx.traffic, args.seed, args.seconds, vocab, max(rates))
-    serve.warm(engine, widest, vocab, args.seed)
+    populations.warm_cell(ctx, engine, widest)
     rows = []
     for rate in rates:
         reqs = tg.serve_requests(ctx.traffic, args.seed, args.seconds, vocab, rate,

@@ -320,9 +320,9 @@ def test_the_program_is_handed_the_published_sizes():
 def test_manifest_holds_the_cell_and_its_metrics():
     data = mf.load_json(os.path.join(mf.ROOT, "BENCHMARK.json"))
     assert mf.validate(data) == []
-    assert len(data["workloads"]) == 4 and len(data["configs"]) == 3
-    assert data["workloads"][-1]["name"] == REAL
-    assert data["configs"][-1]["name"] == "falcon-h1-34b"
+    # wherever they stand and whatever stands beside them
+    entry = next(c for c in data["configs"] if c["name"] == "falcon-h1-34b")
+    assert entry["file"] == "benchmark/configs/falcon-h1-34b.json"
     man = mf.Manifest()
     cell = man.cell(REAL)
     assert (cell["chips"], cell["config"], cell["traffic"]) == (

@@ -26,6 +26,23 @@ def test_manifest_holds_to_the_contract(man):
     assert mf.validate(man.data) == []
 
 
+SERVING = [w["name"] for w in mf.Manifest().data["workloads"]
+           if w["traffic"] != "train"]
+
+
+@pytest.mark.parametrize("cell", SERVING)
+def test_a_serving_cell_offers_whole_requests_at_a_share_of_a_stated_knee(man, cell):
+    """The rate is fixed in the mix's file, never searched: it offers a
+    whole number of requests in a run, and the mix's ``why`` and the
+    cell's both say what knee it is a share of."""
+    entry = man.cell(cell)
+    mix = man.traffic(entry["traffic"])
+    n = man.data["run_seconds"] * mix["arrivals"]["rate_per_s"]
+    assert n >= 8 and abs(n - round(n)) < 1e-9
+    assert "knee" in mix["why"] and "knee" in entry["why"]
+    assert f"{mix['arrivals']['rate_per_s']:g}" in entry["why"]
+
+
 @pytest.mark.parametrize("breach", [
     lambda d: d["end_to_end"][0].update(bound=0.2),
     lambda d: d["workloads"][0].update(name="has space"),

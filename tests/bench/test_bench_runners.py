@@ -140,3 +140,42 @@ def test_serving_control_in_fp8_is_not_correct():
     ctx = context("toy-neox.serve", 6, devices=jax.devices()[:1], seconds=0.5)
     ctx.control_numerics = [ctx.cell_file["check"]["control_numerics"]]
     assert control.control_serve(ctx)["correct"] is False
+
+
+SHAPE = lambda rs: [(r["rid"], r["due_s"], len(r["prompt"]), r["max_new_tokens"])
+                    for r in rs]
+
+
+@pytest.mark.parametrize("deal", [None, 4400000451])
+def test_schedule_follows_the_seed_or_the_mixs_one_deal(deal):
+    """Without ``arrivals.deal`` the schedule is the generator's for the
+    seed; with it every seed is offered the same lengths and gaps in the
+    deal's ONE order and draws its own token ids."""
+    from benchmark import generator as tg
+    from benchmark.runners import serve
+
+    mix = mf.Manifest().traffic("serve")
+    mix = dict(mix, arrivals={k: v for k, v in mix["arrivals"].items() if k != "deal"})
+    if deal is not None:
+        mix["arrivals"]["deal"] = deal
+    a = serve.schedule(mix, 3_100_000_411, 40.0, 50304)
+    b = serve.schedule(mix, 7, 40.0, 50304)
+    assert len(a) == len(b) == 48
+    assert sorted(len(r["prompt"]) for r in a) == sorted(len(r["prompt"]) for r in b)
+    assert all(x["prompt"] != y["prompt"] for x, y in zip(a, b))
+    tok = tg.rng_for(7, 2)     # the ids the generator would draw for the seed
+    assert b[0]["prompt"] == tok.integers(0, 50304, len(b[0]["prompt"])).tolist()
+    if deal is None:
+        assert SHAPE(a) != SHAPE(b)
+        assert b == tg.serve_requests(mix, 7, 40.0, 50304)
+    else:
+        assert SHAPE(a) == SHAPE(b) == SHAPE(tg.serve_requests(mix, deal, 40.0, 50304))
+        assert b == serve.schedule(mix, 7, 40.0, 50304)    # same seed, same inputs
+
+
+def test_the_neox_serving_cell_deals_one_order_and_says_why():
+    man = mf.Manifest()
+    mix = man.traffic("serve")
+    assert mix["arrivals"]["deal"] == 4400000451 and mix["arrivals"]["rate_per_s"] == 1.2
+    assert "arrivals.deal" in mix["why"] and "ONE order" in man.cell("neox-1.3b.serve")["why"]
+    assert man.workload_file("neox-1.3b.serve")["runner"] == "serve"

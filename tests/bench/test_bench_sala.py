@@ -68,13 +68,25 @@ def test_toy_cell_runs_and_agrees_with_its_reference():
     assert int(sel[2]) > 0 and int(sel[0]) <= int(sel[2]) // 20
 
 
-def test_both_controls_read_over_the_limit():
-    from benchmark.runners import serve_long
+def test_both_controls_read_over_the_limit(monkeypatch):
+    """On a sample taken by COUNT: the window is drained and every request
+    of the schedule is compared, so a slow host (a toy window is half a
+    second of wall clock) changes neither the sample nor the readings."""
+    from benchmark import generator as tg
+    from benchmark.runners import serve, serve_chat, serve_long
 
+    offer = serve.offer
+    monkeypatch.setattr(serve, "offer",
+                        lambda *a, **kw: offer(*a, **dict(kw, drain=True)))
+    monkeypatch.setattr(
+        serve, "sample_finished",
+        lambda done, seed, n: serve_chat.first_finished(done, float("inf")))
     ctx = context(7)
     out = serve_long.run(ctx, ctx.cell_file["check"]["controls"])
     limit = ctx.cell_file["check"]["limits"]["served_logit_gap"]
     assert out["correct"] is True
+    assert out["check"]["tokens"] == sum(
+        r["max_new_tokens"] for r in tg.serve_requests(ctx.traffic, 7, 0.5, 96))
     assert set(out["check"]["controls"]) == {"fp8", "noselect"}
     assert all(g > limit for g in out["check"]["controls"].values())
 
@@ -318,11 +330,52 @@ def test_the_cells_parameters_are_the_issues():
                             "num_blocks": 6241, "max_seq_len": 33280,
                             "max_new_tokens": 512, "prefill_chunk": 1024,
                             "prefill_token_budget": 1024}
-    assert t["prompt_tokens"] == {"dist": "lognormal", "median": 16384,
-                                  "sigma": 0.5, "min": 8448, "max": 32768}
+    # PR 44: the median is the smallest, in steps of 2,048 from 18,432,
+    # that keeps the 95th percentile of the gaps among the sparse chunks
+    # on every seed (PERF.md section 4), the shortest prompt half of it
+    p = t["prompt_tokens"]
+    assert (p["dist"], p["sigma"], p["max"]) == ("lognormal", 0.5, 32768)
+    assert p["median"] in range(18432, 32768, 2048)
+    assert p["min"] == max(8448, p["median"] // 2)
+    assert p["max"] + 512 <= w["serving"]["max_seq_len"]
+    n = 40 * t["arrivals"]["rate_per_s"]
+    assert abs(n - round(n)) < 1e-9 and "knee" in t["why"]
     assert t["output_tokens"] == {"dist": "lognormal", "median": 192,
                                   "sigma": 0.5, "min": 64, "max": 512}
     assert t["arrivals"]["stretches"] == 8 and t["first_token_cap_s"] == 20.0
     assert t["prompt_tokens"]["min"] > man.config("minicpm-sala")[
         "sparse_config"]["dense_len"]
     assert json.dumps(w["check"]["controls"]) == '["fp8", "noselect"]'
+
+
+def test_every_seed_offers_the_same_prompts_and_most_chunks_are_sparse():
+    """The seed orders the requests and draws their tokens; the work is
+    the file's. And the work is the selected pages: a chunk at or beyond
+    ``dense_len`` is what the cell exists for, so the set of prompts, and
+    the median prompt, hold more of those than of the dense ones."""
+    from benchmark import generator as tg
+
+    man = mf.Manifest()
+    t, cfg = man.traffic("serve-longdoc"), man.config("minicpm-sala")
+    dense = cfg["sparse_config"]["dense_len"]
+    chunk = man.workload_file(REAL)["serving"]["prefill_chunk"]
+    n = round(40 * t["arrivals"]["rate_per_s"])
+    dealt = {round(g, 6) for g in tg._gaps(t["arrivals"], n, 40.0)}
+    offered = []
+    for seed in (1, 4_400_000_101, 2**31 + 5):
+        reqs = tg.serve_requests(t, seed, 40.0, cfg["vocab_size"])
+        offered.append((sorted(len(r["prompt"]) for r in reqs),
+                        sorted(r["max_new_tokens"] for r in reqs)))
+        # the first request is due at 0: the gap dealt before it is not kept
+        gaps = {round(b["due_s"] - a["due_s"], 6) for a, b in zip(reqs, reqs[1:])}
+        assert len(gaps) == n - 1 and gaps < dealt
+    assert offered[0] == offered[1] == offered[2]
+    lengths = offered[0][0]
+    assert len(lengths) == n
+    assert min(lengths) > dense and max(lengths) <= 32768
+    below = [min(-(-m // chunk), dense // chunk) for m in lengths]
+    beyond = [-(-m // chunk) - b for m, b in zip(lengths, below)]
+    assert sum(beyond) > sum(below)
+    mid = len(lengths) // 2
+    assert beyond[mid] > below[mid]
+
