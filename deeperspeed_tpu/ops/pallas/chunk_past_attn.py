@@ -2,14 +2,16 @@
 
 A prompt chunk of a stack of two cache rules (``full_attn``: every key, a
 page for every ``bs`` positions; ``window_attn``: a ring of the last
-``window`` keys) has ``C`` queries at positions ``offset + i``. Each sees
-some of the slot's past, which lies in the pool's pages, and the chunk's
-own keys up to itself, which do not yet. The XLA forms
-(serving/kv_cache.chunk_attend_past, ring_chunk_attend: the oracles of
-this kernel, and the route under a mesh and off the TPU) gather the pages
-and hand float32 scores of ``(C, Hkv, G, keys)`` from one fusion to the
-next through HBM. Here the scores of one query tile against one step of
-keys live in VMEM and nowhere else:
+``window`` keys) or of pages of two roles (``eva``: the summary pages of
+the windows left behind, then the window's own pages, one list and a
+count of its rows) has ``C`` queries at positions ``offset + i``. Each
+sees some of the slot's past, which lies in the pool's pages, and the
+chunk's own keys up to itself, which do not yet. The XLA forms
+(serving/kv_cache.chunk_attend_past, ring_chunk_attend, chunk_attend_all:
+the oracles of this kernel, and the route under a mesh and off the TPU)
+gather the pages and hand float32 scores of ``(C, Hkv, G, keys)`` from
+one fusion to the next through HBM. Here the scores of one query tile
+against one step of keys live in VMEM and nowhere else:
 
   * the pools ``(L, num_blocks, Hkv, bs, Dh)`` stay in HBM (``pl.ANY``)
     and ``layer`` is scalar prefetch, as in ``paged_sparse_attn``: nothing
@@ -50,6 +52,20 @@ the chunk, whose own keys are walked in the same steps. At steps of 1,024
 a window layer computes its ring and its own keys whole (two squares of
 which the band and the diagonal keep half each: 0.40 ms a layer where
 steps of 512, which skip a quarter, read 0.42).
+
+At ONE query a key head (the eva layers': 32 heads over 32 key heads of
+128, a list of 48 pages of 512 KiB) a grid step's 8,192 rows would be 32
+blocks of 256 where the code cell's are 4 of 2,048, and each query tile
+reads the past again, 16 MiB a step: the tile is 512 positions there
+(``_HEAD_ROWS``; a grid step's VMEM reckons 77 MiB of the 96 asked for).
+Stand-alone on a v5e, ms a layer by the rows that count (0, 1,024, 1,920,
+2,944) against chunk_attend_all's 0.77-0.81 (0.16 of each reading is
+``q`` and the result crossing HBM transposed, which fuse with their
+neighbours in a program): tiles of 256 x 1,024 keys 0.383, 0.545, 0.682,
+0.843; 512 x 1,024 0.322, 0.454, 0.577, 0.695; 512 x 512 0.337, 0.552,
+0.753, 0.965. In the byte cell's chunk program a call reads 0.161 ms at
+count 0, 0.574 at most. A walk costs what its count asks, where the XLA
+form computes all 3,072 listed rows under a mask.
 """
 
 import functools
@@ -65,14 +81,17 @@ from .. import kernel_config
 NEG_INF = -1e30
 LANES = 128
 _Q_TILE = 256                  # query positions a grid step
+_HEAD_ROWS = 512               # ... and at least these rows a key head
 _STEP_KEYS = 1024              # keys a step of the walk: 16 pages of 64
 _VMEM_LIMIT = 96 * 2 ** 20
 
 
-def tiles(C, bs, q_tile=None, step_keys=None):
+def tiles(C, bs, G, q_tile=None, step_keys=None):
     """(query positions a grid step, pages a step of the walk): divisors
-    of the chunk and of its pages, as near the targets as they come."""
-    most_q = min(q_tile or _Q_TILE, C)
+    of the chunk and of its pages, as near the targets as they come. At
+    ``G`` queries a key head the tile is long enough to give a key head's
+    block ``_HEAD_ROWS`` rows (512 positions at ONE query a key head)."""
+    most_q = min(q_tile or max(_Q_TILE, _HEAD_ROWS // G), C)
     tq = next(t for t in range(most_q, 0, -1) if C % t == 0)
     n_own = C // bs
     most_p = min(max(1, (step_keys or _STEP_KEYS) // bs), n_own)
@@ -85,7 +104,7 @@ def _vmem_bytes(k_pool, n_head, C):
     included (scores, probabilities, their cast)."""
     _, _, Hkv, bs, Dh = k_pool.shape
     item = k_pool.dtype.itemsize
-    tq, pp = tiles(C, bs)
+    tq, pp = tiles(C, bs, n_head // Hkv)
     rows, kb = tq * n_head // Hkv, pp * bs
     q_and_out = 2 * 2 * Hkv * rows * Dh * item
     buffers = 2 * 2 * pp * Hkv * bs * Dh * item
@@ -106,9 +125,9 @@ def is_available(k_pool, n_head, C) -> bool:
     if item not in (2, 4) or bs % (32 // item) or Dh % LANES \
             or n_head % Hkv or C % bs:
         return False
-    tq, _ = tiles(C, bs)
+    tq, _ = tiles(C, bs, n_head // Hkv)
     return (tq * (n_head // Hkv)) % (32 // item) == 0 \
-        and _vmem_bytes(k_pool, n_head, C) <= _VMEM_LIMIT * 3 // 4
+        and _vmem_bytes(k_pool, n_head, C) <= _VMEM_LIMIT * 7 // 8
 
 
 def _kernel(layer_ref, pages_ref, lim_ref, q_ref, k_own, v_own, k_hbm, v_hbm,
@@ -220,8 +239,11 @@ def _kernel(layer_ref, pages_ref, lim_ref, q_ref, k_own, v_own, k_hbm, v_hbm,
             attend(x0, own, buf, False)
 
     jax.lax.fori_loop(0, n, step, None)
-    for h in range(Hkv):
+
+    def result(h, _):           # a loop: 32 key heads are 32 copies to trace
         o_ref[h, 0] = (acc_ref[h] / l_ref[h]).astype(o_ref.dtype)
+
+    jax.lax.fori_loop(0, Hkv, result, None)
 
 
 @functools.partial(jax.jit, static_argnames=("band", "q_tile", "step_keys",
@@ -239,7 +261,7 @@ def chunk_past_attn(k_pool, v_pool, layer, q, k, v, pages, first, count,
     C, H, Dh = q.shape
     _, _, Hkv, bs, _ = k_pool.shape
     G = H // Hkv
-    tq, pp = tiles(C, bs, q_tile, step_keys)
+    tq, pp = tiles(C, bs, G, q_tile, step_keys)
     nq, rows = C // tq, tq * G
     P = pages.shape[0]
     pages = jnp.pad(pages.astype(jnp.int32), (0, -P % pp))

@@ -630,6 +630,7 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         if ev is not None:
             n_past = eva_chunk_past(ev, scfg, C)
             past, n_seen = eva_page_list(ev, scfg, table_row, offset, n_past)
+            attend = chunk_attend_for(k_pool, cfg.n_head, C, mesh)
         carried = jax.tree.map(
             lambda rows: jnp.where(
                 offset == 0, 0.0,
@@ -684,8 +685,8 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
             def two_roles(q, k, v):
                 kk, vv = k[0].astype(k_pool.dtype), v[0].astype(v_pool.dtype)
                 with jax.named_scope("ds.eva.attn"):
-                    ctx = chunk_attend_all(k_pool, v_pool, layer, q[0], kk,
-                                           vv, past, n_seen, n_past)
+                    ctx = attend.listed(k_pool, v_pool, layer, q[0], kk, vv,
+                                        past, n_seen, n_past)
                 return ctx[None], (kk, vv)
 
             def every_key(q, k, v):
@@ -1099,11 +1100,12 @@ class ServingEngine(_ServingBase):
             self._slot_rows = takes_slot_form(
                 full_pool, cfg.n_head,
                 (scfg.num_slots, scfg.table_widths[0]), mesh)
-        # how a prompt chunk of a stack of two cache rules attends over
-        # its past ("kernel" or "xla"), chosen like ``_slot_rows``; None
-        # for every other stack
+        # how a prompt chunk of a stack of two cache rules, or of pages
+        # of two roles, attends over its past ("kernel" or "xla"), chosen
+        # like ``_slot_rows``; None for every other stack
         self._chunk_attn = None
-        if cfg.count("full_attn") or cfg.count("window_attn"):
+        if cfg.count("full_attn") or cfg.count("window_attn") \
+                or cfg.count("eva"):
             self._chunk_attn = chunk_attend_for(
                 full_pool, cfg.n_head, prefill_chunk_for(cfg, scfg),
                 None).name

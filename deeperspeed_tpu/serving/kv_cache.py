@@ -966,7 +966,12 @@ def chunk_attend_all(k_pool, v_pool, layer, q, k, v, table_row, offset,
     ``n_past`` pages, of which the positions below ``offset`` count (whole
     pages gathered once for the chunk), and the chunk's own keys up to
     each query, in one softmax. q: (C, H, Dh); k, v: (C, Hkv, Dh) in the
-    pool's dtype; pools in the mixed layout. -> (C, H, Dh) in q's dtype."""
+    pool's dtype; pools in the mixed layout. -> (C, H, Dh) in q's dtype.
+    The route of a mamba_attn layer's chunk and of a sparse layer's chunk
+    inside ``dense_len``; for an eva layer's list of two roles (the list
+    and its count of rows for ``table_row`` and ``offset``) the route
+    under a mesh and off the TPU, and the oracle of
+    ``chunk_attend_all_kernel``, which one TPU runs (``chunk_attend_for``)."""
     C, H, Dh = q.shape
     Hkv, bs = k_pool.shape[2], k_pool.shape[3]
     scale = 1.0 / math.sqrt(Dh)
@@ -1461,9 +1466,22 @@ def ring_chunk_attend_kernel(window: int, k_pool, v_pool, layer, q, k, v,
                            jnp.maximum(window - offset, 0), window, band=True)
 
 
+def chunk_attend_all_kernel(k_pool, v_pool, layer, q, k, v, table_row,
+                            offset, n_past: int):
+    """``chunk_attend_all`` through ops/pallas/chunk_past_attn: the list
+    is the row's first ``n_past`` entries, of which ``offset`` rows count
+    (an eva layer's list of two roles and its count, ``eva_page_list``:
+    what lies beyond the count names the null page)."""
+    from ..ops.pallas.chunk_past_attn import chunk_past_attn
+
+    return chunk_past_attn(k_pool, v_pool, layer, q, k, v,
+                           table_row[:n_past], 0, offset)
+
+
 class ChunkAttend(NamedTuple):
-    """How a prompt chunk of a stack of two cache rules attends: ``past``
-    has ``chunk_attend_past``'s signature and ``ring``
+    """How a prompt chunk attends over pages and itself: ``past`` has
+    ``chunk_attend_past``'s signature, ``listed`` ``chunk_attend_all``'s
+    (a list and a count: the eva layers') and ``ring``
     ``ring_chunk_attend``'s, but for the ring's pages, which ``ring``
     takes as ``ring_pages(window, ring_row, offset)`` gives them, once a
     chunk for all its layers."""
@@ -1471,11 +1489,12 @@ class ChunkAttend(NamedTuple):
     past: Callable
     ring: Callable
     ring_pages: Callable
+    listed: Callable
 
 
 def chunk_attend_for(k_pool, n_head, C, mesh) -> ChunkAttend:
-    """The forms of a prompt chunk's attention in full_attn and
-    window_attn layers (both pools have one page shape): the kernel on
+    """The forms of a prompt chunk's attention in full_attn, window_attn
+    and eva layers (a stack's pools have one page shape): the kernel on
     one TPU at shapes it can tile, else the XLA forms (as
     ``decode_attend_for`` chooses)."""
     from ..ops.pallas import chunk_past_attn as kernel
@@ -1483,9 +1502,11 @@ def chunk_attend_for(k_pool, n_head, C, mesh) -> ChunkAttend:
     if (mesh is None or mesh.size == 1) and kernel.is_available(
             k_pool, n_head, C):
         return ChunkAttend("kernel", chunk_attend_past_kernel,
-                           ring_chunk_attend_kernel, ring_oldest_first)
+                           ring_chunk_attend_kernel, ring_oldest_first,
+                           chunk_attend_all_kernel)
     return ChunkAttend("xla", chunk_attend_past, ring_chunk_attend,
-                       lambda window, ring_row, offset: ring_row)
+                       lambda window, ring_row, offset: ring_row,
+                       chunk_attend_all)
 
 
 def write_ring_chunk(window: int, k_pool, v_pool, ring_row, offset, n_valid,

@@ -281,7 +281,8 @@ class ServingMetrics:
         self.chunk_named_pages = 0
         self.chunk_listed_pages = 0
         # prompt chunks whose attention over the past ran in the kernel
-        # (ops/pallas/chunk_past_attn), of a stack of two cache rules
+        # (ops/pallas/chunk_past_attn), of a stack of two cache rules or
+        # of pages of two roles
         self.prefill_chunks_kernel_attn = 0
         # speculative decoding: per-round draft/accept accounting plus
         # the draft-vs-verify wall split (spec/runtime.decode_round)

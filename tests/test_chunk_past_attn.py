@@ -1,9 +1,10 @@
-"""``ops/pallas/chunk_past_attn`` interpreted on the CPU against the two
-XLA forms it replaces on one TPU (``kv_cache.chunk_attend_past`` for a
-layer that keeps every key, ``kv_cache.ring_chunk_attend`` for one that
-keeps a ring), on a toy pool: pages of 16 rows, chunks of 64 queries,
-tiles small enough that a chunk is several query tiles and a walk several
-steps. And the chooser between them."""
+"""``ops/pallas/chunk_past_attn`` interpreted on the CPU against the
+three XLA forms it replaces on one TPU (``kv_cache.chunk_attend_past`` for
+a layer that keeps every key, ``kv_cache.ring_chunk_attend`` for one that
+keeps a ring, ``kv_cache.chunk_attend_all`` for an eva layer's list of
+two roles and its count), on a toy pool: pages of 16 rows, chunks of 64
+queries, tiles small enough that a chunk is several query tiles and a walk
+several steps. And the chooser between them."""
 
 import jax
 import jax.numpy as jnp
@@ -96,18 +97,62 @@ def test_the_band_against_ring_chunk_attend(Hkv, G, window, offset, q_tile,
     close(got, want, dtype)
 
 
-@pytest.mark.parametrize("band", [False, True])
-def test_bfloat16_as_the_cell_runs_it(band):
+# the byte cell's list at a quarter of its page and a sixteenth of its
+# chunk: a window left behind leaves 2 pages of summaries (128 rows of 64
+# there, 32 of 16 here), the window's pages before the chunk are none or
+# one chunk's (1,024 rows there, 64 here), 48 entries in all
+SUMMARIES, LISTED = 2 * BS, 48
+
+
+def two_roles(w, r, seed=0):
+    """An eva layer's list before a chunk (``kv_cache.eva_page_list``):
+    the summary pages of ``w`` windows, then the window's pages holding
+    ``r`` rows; what lies beyond the count names the null page."""
+    count = SUMMARIES * w + r
+    row = jnp.concatenate([table(-(-count // BS), seed),
+                           jnp.zeros(LISTED, jnp.int32)])[:LISTED]
+    return row, jnp.int32(count)
+
+
+@pytest.mark.parametrize("q_tile,step_keys", TILES)
+@pytest.mark.parametrize("r", [0, C])
+@pytest.mark.parametrize("w", [0, 1, 7, 15])
+def test_a_list_of_two_roles_against_chunk_attend_all(w, r, q_tile,
+                                                      step_keys):
+    """ONE query a key head (``Hkv = H``), a list and a count of rows
+    ``2 pages x w + r``, no band: what ``make_chunk_step``'s eva layers
+    ask of the kernel on one TPU, against the XLA form they run
+    elsewhere, which computes all 48 pages under a mask."""
+    dtype = jnp.float32
+    kp, vp, q, k, v = world(4, 1, dtype, seed=11)
+    row, count = two_roles(w, r, seed=12)
+    layer = jnp.int32(2)
+    want = kvc.chunk_attend_all(kp, vp, layer, q, k, v, row, count, LISTED)
+    got = kernel.chunk_past_attn(kp, vp, layer, q, k, v, row, 0, count,
+                                 q_tile=q_tile, step_keys=step_keys,
+                                 interpret=True)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    close(got, want, dtype)
+
+
+@pytest.mark.parametrize("Hkv,G,band,first,count", [
+    pytest.param(4, 8, False, 0, 3 * C, id="every_key"),
+    pytest.param(4, 8, True, C // 2, C, id="the_band"),
+    pytest.param(4, 1, False, 0, 0, id="two_roles_at_offset_0"),
+    pytest.param(4, 1, False, 0, SUMMARIES + C,
+                 id="two_roles_one_window_behind"),
+    pytest.param(4, 1, False, 0, 7 * SUMMARIES,
+                 id="two_roles_seven_windows_behind")])
+def test_bfloat16_as_the_cell_runs_it(Hkv, G, band, first, count):
     """The pool's dtype all through: bfloat16 operands, float32 scores,
     probabilities cast to bfloat16 before they meet V. The CPU has no
     bfloat16 product for the XLA forms, so the oracle is the contract
     written out, in float32 on the same bfloat16 values."""
     dtype, f32 = jnp.bfloat16, jnp.float32
-    kp, vp, q, k, v = world(4, 8, dtype, seed=3)
+    kp, vp, q, k, v = world(Hkv, G, dtype, seed=3)
     layer = jnp.int32(0)
-    pages = jnp.concatenate([table(3 * C // BS, seed=4),
-                             jnp.zeros(4, jnp.int32)])
-    first, count = (C // 2, C) if band else (0, 3 * C)
+    # 15 pages hold the longest count; what lies beyond one is not seen
+    pages = jnp.concatenate([table(15, seed=4), jnp.zeros(4, jnp.int32)])
     pages = pages[:C // BS] if band else pages
     want = plain(*(a.astype(f32) for a in (kp, vp)), layer,
                  *(a.astype(f32) for a in (q, k, v)), pages, first, count,
@@ -181,29 +226,34 @@ def test_the_forms_the_chooser_returns(monkeypatch):
     sds = jax.ShapeDtypeStruct
     full = sds((2, 16385, 4, 64, 128), jnp.bfloat16)
     rings = sds((6, 513, 4, 64, 128), jnp.bfloat16)
+    roles = sds((8, 1025, 32, 64, 128), jnp.bfloat16)     # the byte cell's
     mesh = Mesh(np.asarray(jax.devices()[:2]), ("tp",))
 
     def is_xla(attend):
         row = jnp.arange(4)
         return (attend.name == "xla" and attend.past is kvc.chunk_attend_past
                 and attend.ring is kvc.ring_chunk_attend
-                and attend.ring_pages(64, row, 128) is row)
+                and attend.ring_pages(64, row, 128) is row
+                and attend.listed is kvc.chunk_attend_all)
 
-    assert is_xla(kvc.chunk_attend_for(full, 32, 1024, None))     # the CPU
+    for pool in (full, roles):                                    # the CPU
+        assert is_xla(kvc.chunk_attend_for(pool, 32, 1024, None))
     monkeypatch.setattr(kernel_config, "on_tpu", lambda: True)
-    for pool in (full, rings):
+    for pool in (full, rings, roles):
         attend = kvc.chunk_attend_for(pool, 32, 1024, None)
         assert attend.name == "kernel"
         assert attend.past is kvc.chunk_attend_past_kernel
         assert attend.ring is kvc.ring_chunk_attend_kernel
         assert attend.ring_pages is kvc.ring_oldest_first
+        assert attend.listed is kvc.chunk_attend_all_kernel
         assert is_xla(kvc.chunk_attend_for(pool, 32, 1024, mesh))
 
 
 @pytest.mark.parametrize("offset", [0, 64, 192])
 def test_the_kernel_forms_give_what_their_xla_twins_give(monkeypatch, offset):
-    """``chunk_attend_past_kernel`` and ``ring_chunk_attend_kernel`` with
-    the arguments ``make_chunk_step`` hands them."""
+    """``chunk_attend_past_kernel``, ``ring_chunk_attend_kernel`` and
+    ``chunk_attend_all_kernel`` with the arguments ``make_chunk_step``
+    hands them."""
     window = 128
     kp, vp, q, k, v = world(2, 4, jnp.float32, seed=9)
     compiled = kernel.chunk_past_attn
@@ -223,6 +273,14 @@ def test_the_kernel_forms_give_what_their_xla_twins_give(monkeypatch, offset):
         kvc.ring_oldest_first(window, ring_row, off), off),
         kvc.ring_chunk_attend(window, kp, vp, layer, q, k, v, ring_row, off),
         jnp.float32)
+    # a list of two roles wider than ``n_past``: one window behind, then
+    # ``offset`` rows of the window's own pages
+    listed, count = two_roles(1, offset, seed=13)
+    listed = jnp.concatenate([listed, table(4, seed=14)])
+    close(kvc.chunk_attend_all_kernel(kp, vp, layer, q, k, v, listed, count,
+                                      LISTED),
+          kvc.chunk_attend_all(kp, vp, layer, q, k, v, listed, count, LISTED),
+          jnp.float32)
 
 
 @pytest.mark.parametrize("pool,n_head,C_,why", [

@@ -412,6 +412,79 @@ def test_counters_and_cache_shapes_of_a_served_window(params):
     assert "kv_window_pages" in vars(m)
 
 
+def chunk_spans(params, reference, lengths=(70, 37), new=6):
+    """A served window under a tracer: the engine, its
+    ``serving/prefill_chunk`` spans, and how far the served bytes lie
+    under the reference's best, in units of the logits' spread."""
+    from deeperspeed_tpu.monitor.tracer import Tracer, set_tracer
+
+    tracer = Tracer()
+    set_tracer(tracer)
+    try:
+        eng, ps, outs = served(params, lengths, new)
+    finally:
+        set_tracer(None)
+    worst = 0.0
+    for p, o in zip(ps, outs):
+        gap, spread = gaps(reference, params, p, o)
+        worst = max(worst, gap.max() / spread)
+    return eng, [e for e in tracer.events()
+                 if e["name"] == "serving/prefill_chunk"], worst
+
+
+def test_the_span_and_the_count_say_the_xla_form_on_the_cpu(params,
+                                                            reference):
+    """Off the TPU an eva stack's chunk attends through
+    ``kv_cache.chunk_attend_all``: the engine, the span's ``attn`` and the
+    count of kernel chunks say so."""
+    eng, chunks, worst = chunk_spans(params, reference)
+    assert eng._chunk_attn == "xla"
+    assert [e["args"]["attn"] for e in chunks] == ["xla"] * 8   # 5 + 3
+    reuse = eng.metrics.summary()["prefix_reuse"]
+    assert reuse["prefill_chunks"] == 8
+    assert reuse["prefill_chunks_kernel_attn"] == 0
+    assert worst <= 1e-4
+
+
+def test_the_span_and_the_count_say_when_the_chunks_kernel_engaged(
+        params, reference, monkeypatch):
+    """On one TPU the chunk of an eva stack attends over its list of two
+    roles in ops/pallas/chunk_past_attn. The toy pool is no shape the
+    compiled kernel tiles, so the chooser is made to hand out the kernel's
+    forms here, interpreted: every chunk is counted, its span says
+    ``kernel``, and the served bytes are still the reference's best."""
+    from deeperspeed_tpu.ops.pallas import chunk_past_attn as kernel
+    from deeperspeed_tpu.serving import engine as engine_mod
+    from deeperspeed_tpu.serving import kv_cache as kvc
+
+    compiled, asked, lists = kernel.chunk_past_attn, [], []
+
+    def interpreted(*a, **kw):
+        lists.append(a[6].shape)
+        return compiled(*a, **kw, q_tile=8, interpret=True)
+
+    monkeypatch.setattr(kernel, "chunk_past_attn", interpreted)
+
+    def as_on_one_tpu(pool, *args):
+        asked.append(args)
+        return kvc.chunk_attend_for(pool, *args)._replace(
+            name="kernel", listed=kvc.chunk_attend_all_kernel)
+
+    monkeypatch.setattr(engine_mod, "chunk_attend_for", as_on_one_tpu)
+    eng, chunks, worst = chunk_spans(params, reference)
+    assert eng._chunk_attn == "kernel"
+    assert [e["args"]["attn"] for e in chunks] == ["kernel"] * 8
+    reuse = eng.metrics.summary()["prefix_reuse"]
+    assert reuse["prefill_chunks_kernel_attn"] == reuse["prefill_chunks"] == 8
+    assert worst <= 1e-4
+    # asked with the heads, the chunk's length and no mesh, by the engine
+    # and by the program it built
+    assert set(asked) == {(4, 16, None)}
+    # the program's layers (one trace for both) handed the kernel the list
+    # of ``eva_chunk_past``: 4 summary pages and the window's 2 before a chunk
+    assert set(lists) == {(6,)}
+
+
 def test_refusals_name_what_they_refuse(params):
     with pytest.raises(ValueError, match="recurrent state, or pages that are "
                                          "overwritten behind a window"):

@@ -603,7 +603,9 @@ def test_the_cells_the_benchmark_had_keep_their_rule_pool_and_programs(
     assert scfg.pool_blocks == (scfg.num_blocks,)
     assert eng.kv.k.shape == eng.kv.v.shape == pool
     assert eng.kv.allocators == [eng.kv.allocator]
-    assert eng._chunk_attn is None      # its chunks' spans carry no ``attn``
+    # only a stack whose chunk has a kernel form says how it attended (the
+    # pages of two roles since PR 45): the XLA form, on the CPU
+    assert eng._chunk_attn == ("xla" if rule.window else None)
     assert pool_bytes(eng.kv) == (eng.kv.k.nbytes + eng.kv.v.nbytes,)
 
     def lowered():

@@ -425,11 +425,13 @@ def test_evabyte_programs_move_neither_the_pool_nor_a_weight_stack(
             sds((bps,), i32), sds((), i32), sds((), i32), sds((), i32)).compile()
     text = compiled.as_text()
     # the decode step reads the two-role list through the page-list
-    # kernel; the chunk attends over its gathered past in XLA
+    # kernel; the chunk attends over the same list, a count of its rows
+    # and its own keys in the chunk kernel, the pages read where they lie
     assert runs_kernel(text, "paged_sparse_attn") == (program == "decode")
     # ... in the form whose row is a slot: its key heads share the list
     assert runs_kernel(text, "paged_sparse_attn_slots") == (
         program == "decode")
+    assert runs_kernel(text, "chunk_past_attn") == (program == "chunk")
     assert count_alias_pairs(text) == 2        # k, v
     big = ("bf16[8,1025,32,64,128]", "bf16[8,4096,", "bf16[8,11008,")
     moved = [ln.strip()[:140] for ln in text.splitlines()
@@ -485,25 +487,30 @@ def test_grouped_matmul_compiles_at_the_experts_shapes(one_chip, rows):
 
 @pytest.mark.parametrize("pool,P,band", [
     pytest.param((2, 16385, 4, 64, 128), 528, False, id="every_key"),
-    pytest.param((6, 513, 4, 64, 128), 16, True, id="the_ring")])
+    pytest.param((6, 513, 4, 64, 128), 16, True, id="the_ring"),
+    pytest.param((8, 1025, 32, 64, 128), 48, False, id="two_roles")])
 def test_chunk_past_attn_compiles_at_the_code_cells_geometry(
         one_chip, as_if_on_tpu, pool, P, band):
     """A prompt chunk's attention over its past in the cell of two cache
     rules: 1,024 queries of 32 heads over 4 key heads, the list the
     slot's 512 pages of every key and the chunk's own 16 (null), or the
     ring's 16 pages under the band rule; a grid step 256 queries of all
-    heads, a step of the walk 16 whole pages of 64 KiB."""
+    heads, a step of the walk 16 whole pages of 64 KiB. And in the byte
+    cell: ONE query a key head, 32 of them, the list of two roles 48
+    pages of 512 KiB; a grid step 512 queries, so that a key head's
+    block has 512 rows where the code cell's has 2,048."""
     from deeperspeed_tpu.ops.pallas import chunk_past_attn as kernel
 
     def sds(shape, dtype=BF16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    i32 = jnp.int32
+    i32, Hkv = jnp.int32, pool[2]
     assert kernel.is_available(sds(pool), 32, 1024)
-    assert kernel.tiles(1024, 64) == (256, 16)
+    assert kernel.tiles(1024, 64, 32 // Hkv) == (
+        (512, 16) if Hkv == 32 else (256, 16))
     compiled = kernel.chunk_past_attn.lower(
         sds(pool), sds(pool), sds((), i32), sds((1024, 32, 128)),
-        sds((1024, 4, 128)), sds((1024, 4, 128)), sds((P,), i32),
+        sds((1024, Hkv, 128)), sds((1024, Hkv, 128)), sds((P,), i32),
         sds((), i32), sds((), i32), band=band).compile()
     assert runs_kernel(compiled.as_text(), "chunk_past_attn")
 
