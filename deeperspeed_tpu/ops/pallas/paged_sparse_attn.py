@@ -19,8 +19,19 @@ both. Two forms, by who owns a list:
   * ``paged_sparse_attn``, a ROW a list: a row is one query position's
     ``G`` heads that share a key head (a decode step of a selecting layer
     has ``slots * Hkv`` rows, a prompt chunk ``tokens * Hkv``). A copy is
-    one key head's page; a copy-chunk is ``pp`` of them, always whole, so
-    entries past the last that counts must still name a page of the pool.
+    one key head's page, 16 KiB: too small to pay for a descriptor built
+    twice and a wait of its own (0.8 us for 8 of them where their bytes
+    take 0.3: PERF.md, PR 46), and a row's pages are scattered, so the
+    copies cannot be larger: they are made CHEAP and MANY. A copy-chunk
+    is as many pages as 2,048 positions and the buffers' room allow
+    (``_pages_per_row_chunk``: a prompt chunk's whole list of 32, a
+    quarter of a decode step's 128), always whole, so entries past the
+    last that counts must still name a page of the pool; its copies are
+    started from straight-line code, without Mosaic's bounds check of
+    each (the jitted wrapper clamps layer, page and key head into the
+    pool), and waited for ONCE a buffer and pool, on the bytes of the
+    whole buffer. On a v5e the copies then bind: 1,024 rows of 32 pages
+    take 1.58 ms where their copies alone take 1.54 (3.39 before).
   * ``paged_sparse_attn_slots``, a SLOT a list: every key head of the slot
     reads the same pages (``kv_cache.decode_attend_all``: a slot's whole
     table, or its list of two roles), so a copy is a whole page, all key
@@ -55,13 +66,25 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import kernel_config
 
 NEG_INF = -1e30
-_CHUNK_TOKENS = 512            # 8 pages of 64: 128 KiB of K, as much of V
+# a copy-chunk of the SLOT form: 8 pages of 64 (and what the width of a
+# prompt chunk's list of chosen pages is taken up to: kv_cache.
+# chosen_list_width)
+_CHUNK_TOKENS = 512
+# a copy-chunk of the ROW form: a prompt chunk's whole list, 32 pages of 64
+# (512 KiB of K, as much of V, two buffers each)
+_ROW_TOKENS = 2048
+_ROW_VMEM = 2 * 2 ** 20
 _SMEM_BUDGET = 192 * 2 ** 10   # one call's page lists
 LANES = 128
 
 
-def _pages_per_chunk(P, block_size):
-    most = min(max(1, _CHUNK_TOKENS // block_size), P)
+def _pages_per_row_chunk(P, k_pool):
+    """Pages of one key head a copy-chunk of the row form holds: a list's
+    pages up to ``_ROW_TOKENS`` positions, what the four buffers' room
+    allows (32 pages of 16 KiB, 16 of float32), a divisor of the list."""
+    _, _, _, bs, Dh = k_pool.shape
+    most = min(max(1, _ROW_TOKENS // bs),
+               max(1, _ROW_VMEM // (4 * bs * Dh * k_pool.dtype.itemsize)), P)
     return next(p for p in range(most, 0, -1) if P % p == 0)
 
 
@@ -84,20 +107,18 @@ def is_available(k_pool, n_head) -> bool:
             and n_head % Hkv == 0)
 
 
-def _walk_lists(r, ntok_ref, g_ref, for_each_copy, pos_of, q_ref, m_ref, l_ref,
+def _walk_lists(r, ntok_ref, g_ref, start, wait, pos_of, q_ref, m_ref, l_ref,
                 acc_ref, o_ref, kbuf, vbuf, chunk, sm_scale):
     """Grid step ``r``: the copy-chunks of row (or slot) ``r`` through the
-    online softmax. ``for_each_copy(row, c, buf, act)`` applies ``act`` to
-    every copy of row ``row``'s copy-chunk ``c`` into buffer ``buf``;
-    ``pos_of(Q)`` is, for each of the step's Q queries and each row of a
-    buffer, that row's position in the copy-chunk (int32 (Q, rows)). What
-    lies at ``n_tokens`` or beyond does not count. ``g_ref`` counts the
+    online softmax. ``start(row, c, buf)`` starts every copy of row
+    ``row``'s copy-chunk ``c`` into buffer ``buf`` and ``wait(row, c,
+    buf)`` returns when all of them have landed; ``pos_of(Q)`` is, for
+    each of the step's Q queries and each row of a buffer, that row's
+    position in the copy-chunk (int32 (Q, rows)). What lies at
+    ``n_tokens`` or beyond does not count. ``g_ref`` counts the
     copy-chunks of the whole call: the buffers alternate across steps."""
     R = pl.num_programs(0)
     Dh = kbuf.shape[-1]
-
-    def start(row, c, buf):
-        for_each_copy(row, c, buf, lambda cp: cp.start())
 
     def next_live(row):
         """The first row after ``row`` with anything to read, else R."""
@@ -131,7 +152,7 @@ def _walk_lists(r, ntok_ref, g_ref, for_each_copy, pos_of, q_ref, m_ref, l_ref,
         def _():
             start(nxt_row, nxt_c, 1 - buf)
 
-        for_each_copy(r, c, buf, lambda cp: cp.wait())
+        wait(r, c, buf)
         g_ref[0] = g + 1
         k = kbuf[buf].reshape(-1, Dh)
         v = vbuf[buf].reshape(-1, Dh)
@@ -160,20 +181,34 @@ def _kernel(layer_ref, head_ref, ntok_ref, pages_ref, q_ref, m_ref, l_ref,
     chunk = pp * bs
     layer = layer_ref[0]
 
-    def for_each_copy(row, c, buf, act):
-        head = head_ref[row]
+    def start(row, c, buf):
+        # straight-line code, the row's key head and place in the lists
+        # read once: a page's address arithmetic is scheduled beside its
+        # neighbours', where a loop a page ran them one after another. The
+        # loop is unrolled when it is LOWERED: traced a page at a time in
+        # Python, 64 descriptors cost 0.5 s a trace of the kernel
+        head, first = head_ref[row], row * P + c * pp
 
         def page_copies(p, _):
-            page = pages_ref[row * P + c * pp + p]
-            act(pltpu.make_async_copy(
-                k_hbm.at[layer, page, head], kbuf.at[buf, p], sems.at[0, buf]))
-            act(pltpu.make_async_copy(
-                v_hbm.at[layer, page, head], vbuf.at[buf, p], sems.at[1, buf]))
+            page = pages_ref[first + p]
+            pltpu.make_async_copy(
+                k_hbm.at[layer, page, head], kbuf.at[buf, p],
+                sems.at[0, buf]).start()
+            pltpu.make_async_copy(
+                v_hbm.at[layer, page, head], vbuf.at[buf, p],
+                sems.at[1, buf]).start()
 
-        jax.lax.fori_loop(0, pp, page_copies, None)
+        jax.lax.fori_loop(0, pp, page_copies, None, unroll=True)
+
+    def wait(row, c, buf):
+        # a DMA semaphore counts bytes: ONE wait for a whole buffer takes
+        # what its ``pp`` copies, all of them always started, signalled
+        for pages, s in ((kbuf, 0), (vbuf, 1)):
+            pltpu.make_async_copy(
+                pages.at[buf], pages.at[buf], sems.at[s, buf]).wait()
 
     _walk_lists(
-        pl.program_id(0), ntok_ref, g_ref, for_each_copy,
+        pl.program_id(0), ntok_ref, g_ref, start, wait,
         lambda G: jax.lax.broadcasted_iota(jnp.int32, (G, chunk), 1),
         q_ref, m_ref, l_ref, acc_ref, o_ref, kbuf, vbuf, chunk, sm_scale)
 
@@ -184,11 +219,18 @@ def paged_sparse_attn(k_pool, v_pool, layer, q, row_head, pages, n_tokens,
     """serving/kv_cache.paged_sparse_attend_xla as a kernel (its docstring
     has the contract)."""
     R, G, Dh = q.shape
-    _, _, _, bs, _ = k_pool.shape
+    L, nb, Hkv, bs, _ = k_pool.shape
     P = pages.shape[1]
-    pp = _pages_per_chunk(P, bs)
+    pp = _pages_per_row_chunk(P, k_pool)
     rows = rows_per_call(R, P)
     lanes = lambda a: jnp.broadcast_to(a[..., None], (R, G, LANES))
+    # a copy's source is (layer, page, key head) of the pool: each clamped
+    # into it, as the XLA form's gather clamps them
+    inside = lambda a, n: jnp.clip(a.astype(jnp.int32), 0, n - 1)
+    layer = inside(jnp.reshape(layer, (1,)), L)
+    args = (inside(row_head, Hkv), inside(pages, nb),
+            n_tokens.astype(jnp.int32), q, lanes(m0.astype(jnp.float32)),
+            lanes(l0.astype(jnp.float32)), acc0.astype(jnp.float32))
 
     def call(a):
         row_head, pages, n_tokens, q, m0, l0, acc0 = a
@@ -210,15 +252,16 @@ def paged_sparse_attn(k_pool, v_pool, layer, q, row_head, pages, n_tokens,
                 ],
             ),
             out_shape=jax.ShapeDtypeStruct((rows, G, Dh), q.dtype),
+            # Mosaic's own check of each copy's two ends is two thirds of
+            # the instructions a descriptor takes; the clamps above do its
+            # work
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",)),
+                dimension_semantics=("arbitrary",),
+                disable_bounds_checks=True),
             interpret=interpret,
-        )(jnp.reshape(layer, (1,)).astype(jnp.int32), row_head, n_tokens,
-          pages.reshape(-1), q, m0, l0, acc0, k_pool, v_pool)
+        )(layer, row_head, n_tokens, pages.reshape(-1), q, m0, l0, acc0,
+          k_pool, v_pool)
 
-    args = (row_head.astype(jnp.int32), pages.astype(jnp.int32),
-            n_tokens.astype(jnp.int32), q, lanes(m0.astype(jnp.float32)),
-            lanes(l0.astype(jnp.float32)), acc0.astype(jnp.float32))
     if rows == R:
         return call(args)
     out = jax.lax.map(call, jax.tree.map(
@@ -291,8 +334,10 @@ def _slots_kernel(layer_ref, ntok_ref, pages_ref, q_ref, m_ref, l_ref,
         return jnp.where(own, (col // (Hkv * bs)) * bs + col % bs, P * bs)
 
     _walk_lists(
-        pl.program_id(0), ntok_ref, g_ref, for_each_copy, pos_of, q_ref,
-        m_ref, l_ref, acc_ref, o_ref, kbuf, vbuf, chunk, sm_scale)
+        pl.program_id(0), ntok_ref, g_ref,
+        functools.partial(for_each_copy, act=lambda cp: cp.start()),
+        functools.partial(for_each_copy, act=lambda cp: cp.wait()), pos_of,
+        q_ref, m_ref, l_ref, acc_ref, o_ref, kbuf, vbuf, chunk, sm_scale)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -1023,9 +1023,10 @@ def decode_attend_all(k_pool, v_pool, layer, q, k_row, v_row, tables,
 
 def chosen_list_width(sp) -> int:
     """Width of a prompt-chunk row's list of chosen pages:
-    ``mixers.chosen_width`` (31 as published), taken up to whole copies of
-    the kernel, which moves a list the largest divisor of its width up to
-    512 tokens of pages at a time: 31 pages would cross one by one."""
+    ``mixers.chosen_width`` (31 as published), taken up to whole runs of
+    512 tokens of pages (8 of 64). The kernel moves a row's list in
+    copy-chunks that divide its width and scores a copy-chunk at once:
+    31 pages would be 1,984 positions, not whole lanes of scores."""
     from ..models.mixers import chosen_width
     from ..ops.pallas.paged_sparse_attn import _CHUNK_TOKENS
 

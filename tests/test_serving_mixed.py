@@ -208,6 +208,103 @@ def test_selected_pages_kernel_against_its_oracle_at_two_key_heads(dtype):
                                    dtype).astype(jnp.float32)), atol=1e-6)
 
 
+def _row_world(P, counts, dtype, bs=64, G=4, seed=0, stray=()):
+    """Pools of 40 pages of ``bs`` rows at 2 key heads; a row a count of
+    ``counts``, its key head by turns, its list ``P`` random pages of the
+    pool: what lies past a count names a real page, as a prompt chunk's
+    32nd entry does. The entries ``stray`` (row, place) name no page."""
+    L, nb, Hkv, Dh, R = 2, 40, 2, 128, len(counts)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    kp = jax.random.normal(ks[0], (L, nb, Hkv, bs, Dh)).astype(dtype)
+    vp = jax.random.normal(ks[1], (L, nb, Hkv, bs, Dh)).astype(dtype)
+    q = jax.random.normal(ks[2], (R, G, Dh)).astype(dtype)
+    pages = np.random.default_rng(seed).integers(1, nb, (R, P))
+    for at in stray:
+        pages[at] = nb + 3
+    return (kp, vp, jnp.int32(1), q, jnp.arange(R, dtype=jnp.int32) % Hkv,
+            jnp.asarray(pages, jnp.int32), jnp.asarray(counts, jnp.int32),
+            jax.random.normal(ks[3], (R, G)), jnp.full((R, G), 1.5),
+            jax.random.normal(ks[4], (R, G, Dh)))
+
+
+# ``pp``: the pages of a copy-chunk in bf16 and in float32
+ROW_CASES = {
+    # a prompt chunk's rows: ONE copy-chunk a row, the 32nd page not counted
+    "a_chunk_row_is_one_copy_chunk": dict(P=32, counts=(1984,) * 5, pp=(32, 16)),
+    # a decode step's width: four copy-chunks of 32 pages; counts that end
+    # inside a page, on a copy-chunk's edge, one row past it, with the list
+    "a_decode_row_is_up_to_four": dict(
+        P=128, counts=(5000, 2048, 2049, 8192, 7, 6143), pp=(32, 16)),
+    # the largest divisor of the width under the cap is not the cap
+    "a_width_the_cap_does_not_divide": dict(
+        P=48, counts=(3072, 1536, 1537, 100), pp=(24, 16)),
+    # the prefetch skips a row with nothing to read, wherever it stands
+    "idle_rows_first_inside_and_last": dict(
+        P=32, counts=(0, 0, 1984, 0, 70, 0, 0, 2048, 0), pp=(32, 16)),
+    # no copy leaves the pool: a page past its end reads as its last, as
+    # the oracle's gather clamps it (the kernel runs without Mosaic's own
+    # bounds checks)
+    "a_page_past_the_pool_is_its_last": dict(
+        P=32, counts=(1984, 2048, 640), pp=(32, 16),
+        stray=((0, 3), (1, 31), (2, 9), (2, 20))),
+    # more rows than one call's lists fit scalar memory (here: room for
+    # the lists of 4 rows): ``lax.map`` over 3 calls
+    "more_rows_than_a_call_takes": dict(
+        P=32, counts=(1984, 0, 517, 2048) * 3, pp=(32, 16), calls=3),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", ROW_CASES)
+def test_row_form_keeps_a_rows_list_in_flight_against_the_oracle(
+        case, dtype, monkeypatch):
+    """``paged_sparse_attn`` (a row a LIST of one key head's pages; a
+    copy-chunk as many pages as 2,048 positions and the buffers' room
+    allow, its copies waited for at once) against
+    ``paged_sparse_attend_xla``."""
+    from deeperspeed_tpu.ops.pallas import paged_sparse_attn as kernel
+
+    spec = dict(ROW_CASES[case])
+    pp, calls = spec.pop("pp"), spec.pop("calls", 1)
+    args = _row_world(dtype=dtype, **spec)
+    kp, pages, counts = args[0], args[5], np.asarray(args[6])
+    R, P = pages.shape
+    if calls > 1:       # no other case has 12 rows: the trace is this one's
+        monkeypatch.setattr(kernel, "_SMEM_BUDGET", 4 * P * 4)
+    # float32 pages are twice the bytes: half as many fit the buffers
+    assert kernel._pages_per_row_chunk(P, kp) == pp[dtype == jnp.float32]
+    assert R // kernel.rows_per_call(R, P) == calls
+    want = paged_sparse_attend_xla(*args).astype(jnp.float32)
+    got = paged_sparse_attn(*args, interpret=True).astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5 if dtype == jnp.float32 else 2e-2)
+    *_, m0, l0, acc0 = args
+    came_with = (acc0 / l0[..., None]).astype(dtype).astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got)[counts == 0],
+                               np.asarray(came_with)[counts == 0], atol=1e-6)
+
+
+def test_the_other_forms_copy_chunks_are_what_they_were():
+    """The row form's copy-chunk has a rule of its own: the slot form's
+    (four cells' decode steps) and the width of a chunk row's list still
+    follow ``_CHUNK_TOKENS``. The numbers are the parent's (PR 45)."""
+    from deeperspeed_tpu.ops.pallas import paged_sparse_attn as kernel
+    from deeperspeed_tpu.serving.kv_cache import chosen_list_width
+
+    pool = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    assert kernel._CHUNK_TOKENS == 512
+    for name, k_pool, P, pages in [
+            ("the byte cell", pool(8, 1025, 32, 64, 128), 64, 2),
+            ("the chat cell", pool(6, 2305, 4, 64, 128), 48, 8),
+            ("the code cell, every key", pool(2, 16385, 4, 64, 128), 512, 8),
+            ("the code cell, the ring", pool(6, 513, 4, 64, 128), 16, 8),
+            ("the reasoning cell", pool(1, 15361, 8, 64, 128), 320, 8),
+            ("the long-document cell", pool(4, 6241, 2, 64, 128), 128, 8)]:
+        assert kernel._pages_per_slot_chunk(P, k_pool) == pages, name
+    assert chosen_list_width(SparseAttnConfig()) == 32
+
+
 def _slot_world(Hkv, G, dtype, counts, carry="plain", seed=0):
     """Pools of 24 pages of 64 rows, lists 16 wide (two copy-chunks at 4
     and at 2 key heads, eight at 32), slots that hold ``counts`` rows;
@@ -729,13 +826,18 @@ def test_a_sparse_chunk_is_the_whole_sequence_form(sp, C, S, offset):
 
 
 def test_a_list_of_31_chosen_pages_is_32_wide():
-    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import _pages_per_chunk
+    from deeperspeed_tpu.ops.pallas.paged_sparse_attn import (
+        _pages_per_row_chunk)
     from deeperspeed_tpu.serving.kv_cache import chosen_list_width
 
     sp = SparseAttnConfig()
     assert mixers.chosen_width(sp) == 31 and chosen_list_width(sp) == 32
-    assert _pages_per_chunk(32, sp.block_size) == 8
-    assert _pages_per_chunk(31, sp.block_size) == 1
+    pool = jax.ShapeDtypeStruct((4, 6241, 2, sp.block_size, 128), jnp.bfloat16)
+    # one copy-chunk either way since PR 46, but only 32 pages of 64 are
+    # whole lanes of scores
+    assert _pages_per_row_chunk(32, pool) == 32
+    assert _pages_per_row_chunk(31, pool) == 31
+    assert (32 * sp.block_size) % 128 == 0 and (31 * sp.block_size) % 128
 
 
 def test_pages_of_is_the_table_lookup_and_reads_the_null_page_past_it():

@@ -144,7 +144,7 @@ def test_paged_sparse_attn_compiles_at_the_longdoc_cells_geometry(
     """The chunk program lists a row's CHOSEN pages (32 wide, in calls of
     1,024 rows); the 64-wide list is what it handed on until PR 36."""
     from deeperspeed_tpu.ops.pallas.paged_sparse_attn import (
-        paged_sparse_attn, rows_per_call)
+        _ROW_VMEM, _pages_per_row_chunk, paged_sparse_attn, rows_per_call)
 
     assert rows_per_call(2048, 32) == 1024 and rows_per_call(2048, 64) == 512
 
@@ -153,6 +153,11 @@ def test_paged_sparse_attn_compiles_at_the_longdoc_cells_geometry(
 
     G, Dh = 16, 128
     pool = sds((4, 6241, 2, 64, Dh))
+    # a copy-chunk is 32 pages of one key head at every width of the cell
+    # (a chunk row's whole list): K and V, two buffers each, fill the room
+    # the row form's rule names and no more
+    pp = _pages_per_row_chunk(P, pool)
+    assert pp == 32 and 2 * 2 * pp * 64 * Dh * 2 <= _ROW_VMEM
     f32 = jnp.float32
     compiled = paged_sparse_attn.lower(
         pool, pool, sds((), jnp.int32), sds((R, G, Dh)), sds((R,), jnp.int32),
