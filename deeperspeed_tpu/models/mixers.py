@@ -63,15 +63,15 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 
-from .gpt import (GPTConfig, SparseAttnConfig, _xla_causal_attention,
-                  expand_kv_heads, layer_norm, rotary_embedding)
+from .gpt import (LAYER_KINDS, GPTConfig, SparseAttnConfig,
+                  _xla_causal_attention, expand_kv_heads, layer_norm,
+                  rotary_embedding)
 
 NEG = -1e30
-# where each kind's stacked weights live in the parameter tree
-STACK_KEY = {"attention": "layers", "minicpm4": "sparse",
-             "lightning": "lightning", "mamba_attn": "mamba_attn",
-             "eva": "eva", "full_attn": "full_attn",
-             "window_attn": "window_attn", "kda": "kda"}
+# where each kind's stacked weights live in the parameter tree: under its
+# own name, but for the two kinds that came first
+STACK_KEY = {**{kind: kind for kind in LAYER_KINDS},
+             "attention": "layers", "minicpm4": "sparse"}
 
 
 # ------------------------------------------------------------------ #

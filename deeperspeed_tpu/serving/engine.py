@@ -78,7 +78,7 @@ import numpy as np
 from ..models.generation import apply_with_cache, init_cache, \
     prep_sampling_logits
 from ..models import mixers
-from ..models.gpt import GROUPED_KINDS, GPTConfig, decoder_block
+from ..models.gpt import GPTConfig
 from ..models.speculative import engine_sample_key
 from ..monitor import get_monitor, init_monitor, install_compile_listener
 from ..monitor.tracer import (
@@ -87,38 +87,23 @@ from ..monitor.tracer import (
     trace_instant,
     trace_span,
 )
+from ..utils.frames import on_one_stack_chunk
 from ..utils.logging import logger
 from .config import ServingConfig
-from .kv_cache import (
+# ``_paged_block``: serving/spec/steps.py takes the attention kind's body
+# from here; ``eva_page_list``: tests/bench patches it under this name too
+from .kinds import KINDS, _paged_block, counts_experts, \
+    split_expert_counts, sum_expert_counts  # noqa: F401
+from .kv_cache import (  # noqa: F401
     NULL_BLOCK,
     PagedKVCache,
-    chunk_attend_all,
-    chunk_attend_for,
-    decode_attend_all,
-    decode_attend_for,
-    eva_chunk_past,
-    eva_decode_indices,
+    chosen_forms,
+    chunk_view,
+    decode_view,
     eva_page_list,
-    kda_chunk_for,
-    kda_rows_for,
-    lightning_chunk_for,
     page_rule_for,
-    ring_decode_attend,
-    ring_decode_indices,
-    slot_attend_for,
-    sparse_attend_for,
-    ssm_rows_for,
-    takes_slot_form,
-    decode_write_indices,
-    sparse_chunk_attend,
-    sparse_decode_attend,
-    write_chunk,
-    write_chunk_pages,
-    write_decode_rows,
-    write_eva_chunk,
-    write_eva_decode,
-    write_ring_chunk,
-    write_rows,
+    write_decode_step,
+    write_prefill_chunk,
 )
 from .metrics import ServingMetrics
 from .scheduler import Request, Scheduler
@@ -208,64 +193,12 @@ def unpack_slots(slots, bps: int):
     return slots[:, :bps], lengths, tokens, temps, seeds, counts
 
 
-def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend,
-                 kind: str = "attention", live=None, stacked=None):
-    """One layer of the stack inside a serving program, by its ``kind``
-    (one of ``cfg.layer_kinds``). The layer math is the model's own:
-    gpt.decoder_block's for an ``attention`` layer (the block training
-    runs), mixers.mamba_attn_block's, mixers.eva_block's or
-    mixers.mixed_block's for the others. Only the core differs (mirrors
-    generation._cached_block):
-    ``attend(q, k, v) -> (ctx, kept)`` reads the layer's cache (pages, a
-    state row), and ``kept`` (what the caller keeps of the new tokens:
-    keys and values, a new state) comes back beside the layer's output; a
-    ``mamba_attn`` layer has two caches and takes the pair of its cores,
-    (attention's, the state-space scan's). ``live``: the tokens that are
-    real, for a feed-forward of routed experts (a ``full_attn`` or
-    ``window_attn`` layer's ``kept`` comes paired with their counts, and
-    ``stacked`` is ``grouped_attn_block``'s: the experts' stack and the
-    layer's place in it; a ``kda`` layer's likewise, its ``attend`` the
-    scan ``kda_block`` takes). An
-    ``attention`` layer's experts go the dropless way here: what training
-    bounds by a capacity would be a wrong token served."""
-    if kind in GROUPED_KINDS:
-        return mixers.grouped_attn_block(cfg, kind, x, layer_params,
-                                         positions, attend, live, stacked)
-    if kind == "kda":
-        return mixers.kda_block(cfg, x, layer_params, attend, live, stacked)
-    if kind == "mamba_attn":
-        return mixers.mamba_attn_block(cfg, x, layer_params, positions,
-                                       *attend)
-    if kind == "eva":
-        return mixers.eva_block(cfg, x, layer_params, positions, attend)
-    if kind != "attention":
-        return mixers.mixed_block(cfg, kind, x, layer_params, positions,
-                                  attend)
-    moe_cfg = cfg.moe
-    if moe_cfg is not None:
-        from ..models.moe import moe_ffn
-
-        moe_cfg = dataclasses.replace(moe_cfg, dispatch_impl="dropless")
-
-        def mlp_fn(mlp_in):
-            return moe_ffn(layer_params["moe"], mlp_in, moe_cfg)
-
-        x, (kv, _) = decoder_block(
-            cfg, None, x, layer_params, positions, attend, mlp_fn=mlp_fn
-        )
-    else:
-        x, kv = decoder_block(cfg, None, x, layer_params, positions,
-                              attend)
-    return x, kv
-
-
-# the kinds of layer whose decode step reads ONE list a slot, the same for
-# all its key heads (``kv_cache.decode_attend_all``)
-SLOT_LIST_KINDS = frozenset({"mamba_attn", "eva"})
-
-
 def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
-    """Build the jitted all-slots decode step.
+    """Build the jitted all-slots decode step: the FRAME of the program
+    (unpack, embed, the layer loop, the cache's write, the sampler). What
+    a layer reads, keeps and writes is its kind's to say (``kinds.KINDS``)
+    and the cache's (``kv_cache.decode_view``, ``write_decode_step``):
+    nothing here names a kind.
 
     decode_step(params, k_pool, v_pool, slots, prev, kc_pool, state) ->
     (next_tokens (N,), k_pool', v_pool', kc_pool', state'): one shape for
@@ -276,31 +209,25 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     donated: the host may not have read them yet; zeros before any step
     has run): a slot whose packed token is ``TAKE_PREV`` decodes its
     entry of ``prev``, so a step can be launched before the host has the
-    last one's tokens. ``kc_pool`` and
-    ``state`` (``PagedKVCache.kc``, ``.state``) are None, in and out, for
-    a stack of attention layers; a model of mixed layers
-    (``cfg.mixer_types``) passes its pooled keys and its state rows,
-    donated like the pools: its sparse layers score the slot's pooled
-    keys, pick pages and read only those, its lightning layers read and
-    write their state row, its mamba_attn layers read every live page of
-    a slot AND read and write a state row and a convolution tail, its eva
-    layers read a list of two roles (the summary pages of the windows
-    left behind, then the window's own pages) under one count, and the
-    layer loop goes run by run of one kind (``mixers.scan_runs``; a
-    classic model is one run). Pools are
-    donated — the caller's old handles die each step (no second pool in
-    HBM) — and stay in place: the layer loop only READS them (each layer
-    attends over the pool's positions below the slot's length plus the
-    new token's own row), yields the new rows, and one scatter after the
-    loop writes all layers' rows into the donated buffers.
-    A stack of ``full_attn`` and ``window_attn`` layers hands ``k_pool``
-    and ``v_pool`` as PAIRS (the pool of every key, the rings' pool: two
-    page rules, ``kv_cache`` has the layout) and gets pairs back; where
-    its feed-forward is routed experts, ``next_tokens`` and ``prev`` carry
-    three more entries behind the slots': the experts touched (summed
-    over the layers), the assignments (summed) and the largest expert's
-    assignments (the largest of any layer) of this step, so that the one
-    read-back the loop makes brings them too.
+    last one's tokens. ``kc_pool`` and ``state`` (``PagedKVCache.kc``,
+    ``.state``) are None, in and out, for a model that keeps neither
+    pooled keys nor state rows; one that does passes them, donated like
+    the pools, and the layer loop goes run by run of one kind
+    (``mixers.scan_runs``; a classic model is one run) with the state
+    rows in its carry, written in place. Pools are donated — the caller's
+    old handles die each step (no second pool in HBM) — and stay in
+    place: the layer loop only READS them (each layer reads what its
+    kind's rule lets the new token see, plus the new token's own row),
+    yields the new rows, and one write after the loop lays all layers'
+    rows into the donated buffers. A stack under two page rules hands
+    ``k_pool`` and ``v_pool`` as PAIRS (the pool of every key, the rings'
+    pool: ``kv_cache`` has the layout) and gets pairs back; where its
+    feed-forward is routed experts, ``next_tokens`` and ``prev`` carry
+    three or four more entries behind the slots': the experts touched
+    (summed over the layers), the assignments (summed), the largest
+    expert's assignments (the largest of any layer) and, where counted,
+    the assignments that left, of this step, so that the one read-back
+    the loop makes brings them too.
     temps[i] <= 0 selects greedy argmax for slot i; > 0 samples at
     that temperature under the config's static top_k, keyed by
     ``request_sample_key(seeds[i], counts[i])`` so the sampled stream is
@@ -310,12 +237,12 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     top_k = scfg.top_k
     if top_k is not None and top_k >= cfg.vocab_size:
         top_k = None  # full-vocab top-k is a no-op filter
+    view_of = decode_view(cfg, scfg, mesh)
 
-    sp = cfg.sparse
-    kinds = set(cfg.layer_kinds)
-    slopes = mixers.lightning_slopes(cfg.n_head)
-
+    # traced behind a frame with room for every frame below it: where the
+    # interpreter's 16 KiB chunks of frames end is then no part of set-up
     @partial(jax.jit, donate_argnums=(1, 2, 5, 6))
+    @on_one_stack_chunk
     def ds_decode_step(params, k_pool, v_pool, slots, prev, kc_pool=None,
                        state=None):
         tables, lengths, tokens, temps, seeds, counts = unpack_slots(
@@ -328,194 +255,20 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         with jax.named_scope("ds.embed"):
             x = mixers.embed_tokens(cfg, params, tokens,
                                     lengths)[:, None, :]    # (N, 1, D)
-        # what the kinds of layer in this stack need beside the pools
-        if "attention" in kinds:
-            attend_rows = decode_attend_for(k_pool, tables, cfg.n_head, mesh)
-        if "minicpm4" in kinds:
-            attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
-        if kinds & SLOT_LIST_KINDS:
-            attend_slots = slot_attend_for(k_pool, cfg.n_head, tables.shape,
-                                           mesh)
-        if "minicpm4" in kinds:
-            at = decode_write_indices(sp, tables, lengths)
-        if "mamba_attn" in kinds:
-            bs = scfg.block_size
-            at = {"page": tables[jnp.arange(N), lengths // bs],
-                  "row": lengths % bs}
-            update_rows = ssm_rows_for(state["ssm"], cfg.ssm.n_groups, mesh)
-        if "eva" in kinds:
-            at = eva_decode_indices(cfg.eva, scfg, tables, lengths)
-        if kinds & {"lightning", "mamba_attn", "kda"}:
-            # a slot whose prompt is still being chunked in is idle here:
-            # its state row is the chunks' to write
-            live = (lengths > 0)[:, None, None, None]
-        real = None
-        if "kda" in kinds:
-            # the full_attn layers' pages follow the length in the ONE
-            # pool; the kda layers keep rows and tails
-            k_full, v_full, bs = k_pool, v_pool, scfg.block_size
-            at = {"full": tables, "row": lengths % bs,
-                  "page": tables[jnp.arange(N), lengths // bs]}
-            attend_full = slot_attend_for(k_full, cfg.n_head, tables.shape,
-                                          mesh)
-            update_kda = kda_rows_for(state["kda"], mesh)
-            real = (lengths > 0)[:, None]
-        elif kinds & GROUPED_KINDS:
-            (k_full, k_ring), (v_full, v_ring) = k_pool, v_pool
-            at = ring_decode_indices(scfg, tables, lengths)
-            attend_full = slot_attend_for(k_full, cfg.n_head,
-                                          at["full"].shape, mesh)
-            attend_ring = slot_attend_for(k_ring, cfg.n_head,
-                                          at["others"].shape, mesh)
-            # an idle lane is no token: it is routed to no expert
-            real = (lengths > 0)[:, None]
+        view = view_of(params, k_pool, v_pool, kc_pool, state, tables,
+                       lengths, positions)
 
         def layer_body(kind, carry, layer_params, layer):
-            """A layer by its kind: the new token's row cast to what the
-            cache will hold, then the cache's read. The state rows ride
-            the carry and are written in place."""
             x, rows = carry
-
-            def attention(q, k, v):
-                k_row = k[:, 0].astype(k_pool.dtype)
-                v_row = v[:, 0].astype(v_pool.dtype)
-                ctx = attend_rows(k_pool, v_pool, layer, q, k_row, v_row,
-                                  tables, lengths)
-                return ctx, (k_row, v_row)
-
-            def minicpm4(q, k, v):
-                k_row = k[:, 0].astype(k_pool.dtype)
-                v_row = v[:, 0].astype(v_pool.dtype)
-                ctx, pooled = sparse_decode_attend(
-                    sp, k_pool, v_pool, kc_pool, layer, q, k_row, v_row,
-                    tables, lengths, at, attend_pages)
-                return ctx, (k_row, v_row, pooled)
-
-            def lightning(q, k, v):
-                o, new = mixers.lightning_step(q[:, 0], k[:, 0], v[:, 0],
-                                               rows[layer], slopes)
-                return o[:, None], jnp.where(live, new, rows[layer])
-
-            def all_pages(q, k, v):
-                k_row = k[:, 0].astype(k_pool.dtype)
-                v_row = v[:, 0].astype(v_pool.dtype)
-                ctx = decode_attend_all(k_pool, v_pool, layer, q, k_row,
-                                        v_row, tables, lengths, attend_slots)
-                return ctx, (k_row, v_row)
-
-            def two_roles(q, k, v):
-                """Summaries first, the window's exact keys after, one
-                count of live rows, the new token's own key beside."""
-                k_row = k[:, 0].astype(k_pool.dtype)
-                v_row = v[:, 0].astype(v_pool.dtype)
-                with jax.named_scope("ds.eva.attn"):
-                    ctx = decode_attend_all(
-                        k_pool, v_pool, layer, q, k_row, v_row, at["pages"],
-                        at["count"], attend_slots)
-                return ctx, (k_row, v_row)
-
-            def every_key(q, k, v):
-                k_row = k[:, 0].astype(k_full.dtype)
-                v_row = v[:, 0].astype(v_full.dtype)
-                ctx = decode_attend_all(k_full, v_full, layer, q, k_row,
-                                        v_row, at["full"], lengths,
-                                        attend_full)
-                return ctx, (k_row, v_row)
-
-            def last_keys(q, k, v):
-                """The ring: its page that takes the new key in XLA, the
-                others through the page-list read."""
-                k_row = k[:, 0].astype(k_ring.dtype)
-                v_row = v[:, 0].astype(v_ring.dtype)
-                ctx = ring_decode_attend(k_ring, v_ring, layer, q, k_row,
-                                         v_row, at, attend_ring)
-                return ctx, (k_row, v_row)
-
-            def state_space(xbc, dt):
-                """The new token's convolution, then every slot's state
-                row through the recurrence, in place in the carry."""
-                sp_l, tail = layer_params["ssm"], rows["conv"][layer]
-                x, Bm, Cm, delta, dA, new_tail = mixers.ssm_step_inputs(
-                    cfg.ssm, sp_l, xbc[:, 0], dt[:, 0], tail)
-                ssm_rows, y = update_rows(
-                    rows["ssm"], layer, jnp.exp(dA), delta[..., None] * x,
-                    Bm, Cm, lengths > 0)
-                y = y + sp_l["D"].astype(jnp.float32)[:, None] * x
-                conv = jax.lax.dynamic_update_index_in_dim(
-                    rows["conv"], jnp.where(live[..., 0], new_tail, tail),
-                    layer, 0)
-                return y[:, None], {"conv": conv, "ssm": ssm_rows}
-
-            def delta_rule(qkv, g, beta):
-                """The new token's convolutions, then every slot's state
-                row through the rule, in place in the carry."""
-                tail = rows["conv"][layer]
-                q, k, v, new_tail = mixers.kda_step_inputs(
-                    cfg.kda, layer_params, qkv[:, 0], tail)
-                with jax.named_scope("ds.kda.rule"):
-                    kda_rows, o = update_kda(rows["kda"], layer, q, k, v,
-                                             g[:, 0], beta[:, 0], lengths > 0)
-                conv = jax.lax.dynamic_update_index_in_dim(
-                    rows["conv"], jnp.where(live[..., 0], new_tail, tail),
-                    layer, 0)
-                return o[:, None], {"conv": conv, "kda": kda_rows}
-
-            core = {"attention": attention, "minicpm4": minicpm4,
-                    "lightning": lightning, "eva": two_roles,
-                    "full_attn": every_key, "window_attn": last_keys,
-                    "kda": delta_rule,
-                    "mamba_attn": (all_pages, state_space)}[kind]
-            x, kept = _paged_block(
-                cfg, x, layer_params, positions, core, kind, real,
-                (params[mixers.STACK_KEY[kind]].get("mlp"), layer))
-            if kind == "mamba_attn":
-                kept, rows = kept
-            if kind == "kda":
-                rows, kept = kept
-            if kind == "lightning":
-                rows = jax.lax.dynamic_update_index_in_dim(rows, kept, layer, 0)
-                kept = ()
+            x, rows, kept = KINDS[kind].block(view, KINDS[kind].decode, x,
+                                              layer_params, layer, rows)
             return (x, rows), kept
 
         (x, state), kept = mixers.scan_runs(cfg, params, (x, state),
                                             layer_body)
+        kept, experts = split_expert_counts(kept)
         with jax.named_scope("ds.decode/kv_write"):
-            # the layers' new rows into the donated pools, in place, by
-            # the kind that kept them; idle slots all target (null block,
-            # 0), never read unmasked
-            if "attention" in kept:
-                wblk = tables[jnp.arange(N), lengths // scfg.block_size]
-                woff = lengths % scfg.block_size
-                k_rows, v_rows = kept["attention"]      # (L, N, Hkv, Dh)
-                k_pool = k_pool.at[:, wblk, woff].set(k_rows)
-                v_pool = v_pool.at[:, wblk, woff].set(v_rows)
-            if "minicpm4" in kept:
-                k_pool, v_pool, kc_pool = write_decode_rows(
-                    sp, k_pool, v_pool, kc_pool, at, *kept["minicpm4"])
-            if "mamba_attn" in kept:
-                k_rows, v_rows = kept["mamba_attn"]     # (L, N, Hkv, Dh)
-                k_pool = write_rows(k_pool, at["page"], at["row"], k_rows)
-                v_pool = write_rows(v_pool, at["page"], at["row"], v_rows)
-            if "eva" in kept:
-                k_pool, v_pool = write_eva_decode(
-                    cfg.eva, k_pool, v_pool, at, *kept["eva"],
-                    params["eva"]["mu"], params["eva"]["phi"])
-            experts = []
-            if "full_attn" in kept:
-                (k_rows, v_rows), n = kept["full_attn"]
-                experts.append(n)
-                k_full = write_rows(k_full, at["page"], at["row"], k_rows)
-                v_full = write_rows(v_full, at["page"], at["row"], v_rows)
-            if "window_attn" in kept:
-                (k_rows, v_rows), n = kept["window_attn"]
-                experts.append(n)
-                k_ring = write_rows(k_ring, at["ring_page"], at["row"], k_rows)
-                v_ring = write_rows(v_ring, at["ring_page"], at["row"], v_rows)
-            if "kda" in kept:
-                experts.append(kept["kda"])
-                k_pool, v_pool = k_full, v_full
-            elif kinds & GROUPED_KINDS:
-                k_pool, v_pool = (k_full, k_ring), (v_full, v_ring)
+            k_pool, v_pool, kc_pool = write_decode_step(view, kept)
         with jax.named_scope("ds.decode/sample"):
             logits = mixers.served_logits(
                 cfg, mixers.head_logits(cfg, params, x)[:, 0])  # (N, V)
@@ -535,24 +288,6 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         return nxt, k_pool, v_pool, kc_pool, state
 
     return ds_decode_step
-
-
-def counts_experts(cfg: GPTConfig) -> bool:
-    """Whether this stack's programs count what their routed experts did
-    (``moe.EXPERT_COUNTS``): the stacks whose layers hand the counts out."""
-    return bool(cfg.moe_num_experts) and bool(
-        set(cfg.layer_kinds) & GROUPED_KINDS)
-
-
-def sum_expert_counts(by_kind):
-    """One program's counts from its layers' (``(layers, 3 or 4)`` a
-    kind, ``moe.EXPERT_COUNTS``): experts touched and assignments summed
-    over the layers, the largest expert's load the largest of any layer,
-    the assignments that left (where counted) summed. -> (3 or 4,)
-    int32."""
-    n = jnp.concatenate(by_kind)
-    return jnp.stack([jnp.sum(n[:, 0]), jnp.sum(n[:, 1]), jnp.max(n[:, 2])]
-                     + [jnp.sum(n[:, 3])] * (n.shape[1] > 3))
 
 
 def prefill_chunk_for(cfg: GPTConfig, scfg: ServingConfig) -> int:
@@ -593,7 +328,9 @@ def prefill_chunk_for(cfg: GPTConfig, scfg: ServingConfig) -> int:
 
 def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     """Build the jitted prompt-chunk program of a mixed stack: how every
-    prompt of such a model enters.
+    prompt of such a model enters. As ``make_decode_step``, the frame
+    alone: the kinds' chunk cores (``kinds.KINDS``) read the cache through
+    ``kv_cache.chunk_view``'s view and ``write_prefill_chunk`` writes.
 
     prefill_chunk(params, k_pool, v_pool, kc_pool, state, tokens (1, C),
     table_row (blocks_per_slot,), slot, offset, n_valid) -> (logits (V,)
@@ -603,162 +340,33 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     carries the slot's recurrent state in (zeros at offset 0: a row is
     cleared by whoever enters it, never by who left) and out, a position
     at or beyond ``n_valid`` leaving it as it was; it attends over the
-    slot's pages (the selected ones in a sparse layer, all of the past in
-    a mamba_attn layer; in an eva layer the summaries of the windows left
-    behind and the window's pages before the chunk; all of the past in a
-    full_attn layer, a tile at a time as long as it is; in a window_attn
-    layer the ring's rows inside each query's band) and writes its own
-    keys, values and pooled keys after the layer loop, in place (over the
-    ring its valid rows alone); a kda layer carries its state row and
-    its convolutions' tails as a mamba_attn layer does. Where the stack
-    counts its experts (``counts_experts``) the first output is the pair
-    (logits, the chunk's counts (3,) int32, or (4,): ``sum_expert_counts``)."""
+    slot's pages as each layer's kind rules and writes its own keys,
+    values and pooled keys after the layer loop, in place. Where the
+    stack counts its experts (``counts_experts``) the first output is the
+    pair (logits, the chunk's counts (3,) int32, or (4,):
+    ``sum_expert_counts``)."""
     C = prefill_chunk_for(cfg, scfg)
-    bs = scfg.block_size
-    sp, ev = cfg.sparse, cfg.eva
-    slopes = mixers.lightning_slopes(cfg.n_head)
+    view_of = chunk_view(cfg, scfg, mesh)
 
     @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
+    @on_one_stack_chunk
     def ds_prefill_chunk(params, k_pool, v_pool, kc_pool, state, tokens,
                          table_row, slot, offset, n_valid):
         x = mixers.embed_tokens(cfg, params, tokens)        # (1, C, D)
         positions = offset + jnp.arange(C, dtype=jnp.int32)
-        # the last chunk may run past the table's end: null pages there
-        table_row = jnp.pad(table_row, (0, C // bs))
-        if sp is not None:
-            attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
-        if ev is not None:
-            n_past = eva_chunk_past(ev, scfg, C)
-            past, n_seen = eva_page_list(ev, scfg, table_row, offset, n_past)
-            attend = chunk_attend_for(k_pool, cfg.n_head, C, mesh)
-        carried = jax.tree.map(
-            lambda rows: jnp.where(
-                offset == 0, 0.0,
-                jax.lax.dynamic_index_in_dim(rows, slot, 1, keepdims=False)),
-            state)
-        real = None
-        if cfg.count("kda"):
-            # ONE pool, the full_attn layers'; a chunk attends over its
-            # past as in a stack of two rules, with no ring
-            k_full, v_full, full_row = k_pool, v_pool, table_row
-            attend = chunk_attend_for(k_full, cfg.n_head, C, mesh)
-            kda_rule, _ = kda_chunk_for(C, cfg.kda, mesh)
-            real = (jnp.arange(C) < n_valid)[None, :]
-        elif cfg.count("full_attn") or cfg.count("window_attn"):
-            (k_full, k_ring), (v_full, v_ring) = k_pool, v_pool
-            n_full = scfg.table_widths[0]
-            full_row, ring_row = table_row[:n_full + C // bs], \
-                table_row[n_full:n_full + scfg.table_widths[1]]
-            full_row = full_row.at[n_full:].set(NULL_BLOCK)
-            attend = chunk_attend_for(k_full, cfg.n_head, C, mesh)
-            # the ring's pages as that form reads them, once for all layers
-            ring_pages = attend.ring_pages(cfg.gqa.window, ring_row, offset)
-            # a chunk's padding is no token: it is routed to no expert
-            real = (jnp.arange(C) < n_valid)[None, :]
+        view = view_of(params, k_pool, v_pool, kc_pool, state, table_row,
+                       slot, offset, n_valid, positions)
 
         def layer_body(kind, x, layer_params, layer):
-            def minicpm4(q, k, v):
-                kk, vv = k[0].astype(k_pool.dtype), v[0].astype(v_pool.dtype)
-                ctx, pooled = sparse_chunk_attend(
-                    sp, k_pool, v_pool, kc_pool, layer, q[0], kk, vv,
-                    table_row, offset, attend_pages)
-                return ctx[None], (kk, vv, pooled)
-
-            def lightning(q, k, v):
-                o, new = lightning_chunk_for(q[0], mesh)(
-                    q[0], k[0], v[0], carried[layer], slopes, n_valid)
-                return o[None], new
-
-            def all_past(q, k, v):
-                kk, vv = k[0].astype(k_pool.dtype), v[0].astype(v_pool.dtype)
-                ctx = chunk_attend_all(k_pool, v_pool, layer, q[0], kk, vv,
-                                       table_row, offset,
-                                       scfg.blocks_per_slot)
-                return ctx[None], (kk, vv)
-
-            def state_space(xbc, dt):
-                y, tail, h = mixers.ssm_chunk(
-                    cfg.ssm, layer_params["ssm"], xbc[0], dt[0],
-                    carried["conv"][layer], carried["ssm"][layer], n_valid)
-                return y[None], {"conv": tail, "ssm": h}
-
-            def two_roles(q, k, v):
-                kk, vv = k[0].astype(k_pool.dtype), v[0].astype(v_pool.dtype)
-                with jax.named_scope("ds.eva.attn"):
-                    ctx = attend.listed(k_pool, v_pool, layer, q[0], kk, vv,
-                                        past, n_seen, n_past)
-                return ctx[None], (kk, vv)
-
-            def every_key(q, k, v):
-                kk, vv = k[0].astype(k_full.dtype), v[0].astype(v_full.dtype)
-                ctx = attend.past(k_full, v_full, layer, q[0], kk, vv,
-                                  full_row, offset)
-                return ctx[None], (kk, vv)
-
-            def last_keys(q, k, v):
-                kk, vv = k[0].astype(k_ring.dtype), v[0].astype(v_ring.dtype)
-                ctx = attend.ring(cfg.gqa.window, k_ring, v_ring, layer, q[0],
-                                  kk, vv, ring_pages, offset)
-                return ctx[None], (kk, vv)
-
-            def delta_rule(qkv, g, beta):
-                o, tail, S = mixers.kda_chunk(
-                    cfg.kda, layer_params, qkv[0], g[0], beta[0],
-                    carried["conv"][layer], carried["kda"][layer], n_valid,
-                    kda_rule)
-                return o[None], {"conv": tail, "kda": S}
-
-            core = {"minicpm4": minicpm4, "lightning": lightning,
-                    "eva": two_roles, "full_attn": every_key,
-                    "window_attn": last_keys, "kda": delta_rule,
-                    "mamba_attn": (all_past, state_space)}[kind]
-            return _paged_block(
-                cfg, x, layer_params, positions, core, kind, real,
-                (params[mixers.STACK_KEY[kind]].get("mlp"), layer))
+            x, _, kept = KINDS[kind].block(view, KINDS[kind].chunk, x,
+                                           layer_params, layer, None)
+            return x, kept
 
         x, kept = mixers.scan_runs(cfg, params, x, layer_body)
+        kept, experts = split_expert_counts(kept)
         with jax.named_scope("ds.prefill/kv_write"):
-            if "minicpm4" in kept:
-                k_pool, v_pool, kc_pool = write_chunk(
-                    sp, k_pool, v_pool, kc_pool, table_row, offset,
-                    *kept["minicpm4"])
-            if "lightning" in kept:
-                state = jax.lax.dynamic_update_slice(
-                    state, kept["lightning"][:, None], (0, slot, 0, 0, 0))
-            if "mamba_attn" in kept:
-                (kk, vv), new = kept["mamba_attn"]
-                k_pool, v_pool = write_chunk_pages(k_pool, v_pool, table_row,
-                                                   offset, kk, vv)
-                state = jax.tree.map(
-                    lambda rows, n: jax.lax.dynamic_update_slice(
-                        rows, n[:, None].astype(rows.dtype),
-                        (0, slot) + (0,) * (rows.ndim - 2)), state, new)
-            if "eva" in kept:
-                k_pool, v_pool = write_eva_chunk(
-                    ev, scfg, k_pool, v_pool, table_row, offset, n_valid,
-                    *kept["eva"], params["eva"]["mu"], params["eva"]["phi"])
-            experts = []
-            if "full_attn" in kept:
-                (kk, vv), n = kept["full_attn"]
-                experts.append(n)
-                k_full, v_full = write_chunk_pages(k_full, v_full, full_row,
-                                                   offset, kk, vv)
-            if "window_attn" in kept:
-                (kk, vv), n = kept["window_attn"]
-                experts.append(n)
-                k_ring, v_ring = write_ring_chunk(
-                    cfg.gqa.window, k_ring, v_ring, ring_row, offset, n_valid,
-                    kk, vv)
-            if "kda" in kept:
-                new, n = kept["kda"]
-                experts.append(n)
-                k_pool, v_pool = k_full, v_full
-                state = jax.tree.map(
-                    lambda rows, n: jax.lax.dynamic_update_slice(
-                        rows, n[:, None].astype(rows.dtype),
-                        (0, slot) + (0,) * (rows.ndim - 2)), state, new)
-            elif experts:
-                k_pool, v_pool = (k_full, k_ring), (v_full, v_ring)
+            k_pool, v_pool, kc_pool, state = write_prefill_chunk(
+                view, state, kept)
         last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0)
         logits = mixers.head_logits(cfg, params, last)[0]
         if counts_experts(cfg):
@@ -1057,9 +665,8 @@ class ServingEngine(_ServingBase):
         scfg = scfg.for_cache(page_rule_for(cfg))
         if not cfg.classic:
             kinds = sorted(set(cfg.mixer_types))
-            if scfg.prefix_caching and \
-                    set(kinds) & {"lightning", "mamba_attn", "eva",
-                                  "window_attn", "kda"}:
+            if scfg.prefix_caching and not all(
+                    KINDS[kind].prefix_reuse for kind in kinds):
                 raise ValueError(
                     "prefix_caching cannot serve a model with a layer that "
                     "keeps recurrent state, or pages that are overwritten "
@@ -1086,35 +693,15 @@ class ServingEngine(_ServingBase):
         super().__init__(scfg, Scheduler(scfg, self.kv.allocators, clock),
                          clock, monitor, monitor_config)
         self._decode_step = make_decode_step(cfg, scfg, mesh)
-        # whether that program's list-sharing layers copy a page once for
-        # all of a slot's key heads: chosen when it is traced, from the
-        # shapes and the mesh alone, so it is known here too
-        self._slot_rows = bool(
-            set(cfg.layer_kinds) & SLOT_LIST_KINDS) and takes_slot_form(
-            self.kv.k, cfg.n_head, (scfg.num_slots, scfg.blocks_per_slot),
-            mesh)
-        # the pool of every key: the first of two, or the only one
-        full_pool = self.kv.k[0] if isinstance(self.kv.k, tuple) \
-            else self.kv.k
-        if cfg.count("full_attn"):      # its list: the table's first section
-            self._slot_rows = takes_slot_form(
-                full_pool, cfg.n_head,
-                (scfg.num_slots, scfg.table_widths[0]), mesh)
-        # how a prompt chunk of a stack of two cache rules, or of pages
-        # of two roles, attends over its past ("kernel" or "xla"), chosen
-        # like ``_slot_rows``; None for every other stack
-        self._chunk_attn = None
-        if cfg.count("full_attn") or cfg.count("window_attn") \
-                or cfg.count("eva"):
-            self._chunk_attn = chunk_attend_for(
-                full_pool, cfg.n_head, prefill_chunk_for(cfg, scfg),
-                None).name
-        # how a prompt chunk's kda layers run the delta rule ("kernel" or
-        # "xla"), chosen alike; None for a stack without them
-        self._kda_scan = None
-        if cfg.count("kda"):
-            _, self._kda_scan = kda_chunk_for(
-                prefill_chunk_for(cfg, scfg), cfg.kda, None)
+        # what the cache's choosers pick when the two programs are traced,
+        # from shapes, mesh and platform alone, so known here too, for the
+        # host's accounting: whether the decode step's list-sharing layers
+        # copy a page once for all of a slot's key heads; how a prompt
+        # chunk attends over pages of two rules or two roles, and how it
+        # runs a delta rule ("kernel", "xla", None where it does neither)
+        self._slot_rows, self._chunk_attn, self._kda_scan = chosen_forms(
+            cfg, scfg, self.kv.k, mesh,
+            None if cfg.classic else prefill_chunk_for(cfg, scfg))
         # whether the programs count their routed experts: the decode
         # step's tokens then come with that many counts behind them
         self._counts_experts = counts_experts(cfg) \
@@ -1144,8 +731,8 @@ class ServingEngine(_ServingBase):
             return apply_with_cache(
                 cfg, params, toks, {"k": kc, "v": vc}, offset)
 
-        self._prefill_step = jax.jit(ds_prefill)
-        self._suffix_prefill = jax.jit(ds_suffix_prefill,
+        self._prefill_step = jax.jit(on_one_stack_chunk(ds_prefill))
+        self._suffix_prefill = jax.jit(on_one_stack_chunk(ds_suffix_prefill),
                                        donate_argnums=(2, 3))
         # a mixed stack's prompts all enter chunk by chunk, straight into
         # the pool and the slot's state row (no staging cache)

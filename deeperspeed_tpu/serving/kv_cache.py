@@ -67,17 +67,27 @@ reads. The partially filled boundary block of a matched prefix is never
 shared in place — admission copies its matched rows into a private block
 (the CoW split, exactly once per admission) via the same gather/scatter
 page machinery the prefill path uses.
+
+What ONE program sees of all this is built here too, at the end of the
+file: ``decode_view`` and ``chunk_view`` (the pools by role, the lists, the
+write indexes and the forms the choosers picked, each reckoned once before
+the layer loop: what the kinds' cores in ``serving/kinds.py`` read) and
+the writes after the loop, ``write_decode_step`` and
+``write_prefill_chunk``. ``serving/engine.py`` builds the frame of a
+program round them and names no kind.
 """
 
 import functools
 import itertools
 import math
+from types import SimpleNamespace
 from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
                     Tuple)
 
 import jax
 import jax.numpy as jnp
 
+from ..models import mixers as mx
 from ..models.gpt import GROUPED_KINDS, GPTConfig
 from .config import PageRule, ServingConfig
 
@@ -418,6 +428,12 @@ class PagedKVCache:
                     "place among the kinds that share them, and only a "
                     "ring has a pool beside the pages that follow the "
                     "length")
+            if scfg.page_rule != page_rule_for(cfg):
+                raise ValueError(
+                    f"the serving configuration's page rule "
+                    f"({scfg.page_rule}) is not this cache's "
+                    f"({page_rule_for(cfg)}): size it with "
+                    "ServingConfig.for_cache(page_rule_for(cfg))")
             if n_ev:
                 check_eva_pages(cfg, scfg)
             if n_wi:
@@ -766,37 +782,34 @@ def slot_attend_for(k_pool, n_head, lists_shape, mesh):
 def lightning_chunk_for(q, mesh):
     """The chunkwise form of a lightning layer: the kernel on one TPU at
     shapes it can tile, else ``mixers.lightning_chunk_xla``."""
-    from ..models.mixers import lightning_chunk_xla
     from ..ops.pallas import lightning_chunk as kernel
 
     if (mesh is None or mesh.size == 1) and kernel.is_available(q):
         return kernel.lightning_chunk
-    return lightning_chunk_xla
+    return mx.lightning_chunk_xla
 
 
 def ssm_rows_for(rows, n_groups, mesh):
     """A decode step's update of a mamba_attn layer's state rows: the
     kernel (each row crosses HBM once each way) on one TPU at shapes it
     can tile, else ``mixers.ssm_rows_xla``."""
-    from ..models import mixers
     from ..ops.pallas import ssm_row_update as kernel
 
     if (mesh is None or mesh.size == 1) and kernel.is_available(
             rows, n_groups):
         return kernel.ssm_row_update
-    return mixers.ssm_rows_xla
+    return mx.ssm_rows_xla
 
 
 def kda_rows_for(rows, mesh):
     """A decode step's update of a kda layer's state rows: the kernel
     (each row crosses HBM once each way) on one TPU at shapes it can
     tile, else ``mixers.kda_rows_xla``."""
-    from ..models import mixers
     from ..ops.pallas import kda_row_update as kernel
 
     if (mesh is None or mesh.size == 1) and kernel.is_available(rows):
         return kernel.kda_row_update
-    return mixers.kda_rows_xla
+    return mx.kda_rows_xla
 
 
 def kda_chunk_for(C: int, kc, mesh):
@@ -804,13 +817,12 @@ def kda_chunk_for(C: int, kc, mesh):
     positions (``kc``: the model's ``KdaConfig``): the kernel on one TPU
     at shapes it can tile, else ``mixers.kda_chunk_xla``. -> (the form,
     "kernel" or "xla")."""
-    from ..models.mixers import kda_chunk_xla
     from ..ops.pallas import kda_chunk as kernel
 
     if (mesh is None or mesh.size == 1) and kernel.is_available(
             C, kc.head_k, kc.head_v):
         return kernel.kda_chunk, "kernel"
-    return kda_chunk_xla, "xla"
+    return mx.kda_chunk_xla, "xla"
 
 
 def _own_token_init(q, k_row, v_row):
@@ -878,14 +890,20 @@ def write_rows(pool, page, row, new):
     return pool.at[:, page].set(lay_rows(pool[:, page], row, new))
 
 
+def write_kv_rows(k_pool, v_pool, page, row, k_rows, v_rows):
+    """``write_rows`` for a pool of keys and its pool of values."""
+    return (write_rows(k_pool, page, row, k_rows),
+            write_rows(v_pool, page, row, v_rows))
+
+
 def write_decode_rows(sp, k_pool, v_pool, kc_pool, at, k_rows, v_rows,
                       pooled):
     """A decode step's new keys, values (n, N, Hkv, Dh) and pooled keys
     of all sparse layers into the slots' pages at ``at``
     (``decode_write_indices``); a pooled key lands in the window its
     token completes, the null page's where it completes none."""
-    k_pool = write_rows(k_pool, at["page"], at["row"], k_rows)
-    v_pool = write_rows(v_pool, at["page"], at["row"], v_rows)
+    k_pool, v_pool = write_kv_rows(k_pool, v_pool, at["page"], at["row"],
+                                   k_rows, v_rows)
     n, N, Hkv, Dh = pooled.shape
     w = sp.windows_per_block
     cur = kc_pool[:, at["kc_page"]].reshape(n, N, Hkv, w, Dh)
@@ -906,8 +924,6 @@ def sparse_decode_attend(sp, k_pool, v_pool, kc_pool, layer, q, k_row,
     causal rule (mixers.page_list: ascending, so the slot's own partly
     filled page is the last that counts) and reads those. Returns (ctx
     (N, 1, H, Dh), that window's pooled key (N, Hkv, Dh))."""
-    from ..models import mixers as mx
-
     N, _, H, Dh = q.shape
     Hkv, bs = k_pool.shape[2], k_pool.shape[3]
     bps = tables.shape[1]
@@ -1027,11 +1043,10 @@ def chosen_list_width(sp) -> int:
     512 tokens of pages (8 of 64). The kernel moves a row's list in
     copy-chunks that divide its width and scores a copy-chunk at once:
     31 pages would be 1,984 positions, not whole lanes of scores."""
-    from ..models.mixers import chosen_width
     from ..ops.pallas.paged_sparse_attn import _CHUNK_TOKENS
 
     most = max(1, _CHUNK_TOKENS // sp.block_size)
-    width = max(1, chosen_width(sp))
+    width = max(1, mx.chosen_width(sp))
     return width if width <= most else -(-width // most) * most
 
 
@@ -1055,8 +1070,6 @@ def sparse_chunk_attend(sp, k_pool, v_pool, kc_pool, layer, q, k, v,
     name a page. Returns (ctx (C, H, Dh), the pooled keys of the C / st
     windows this chunk completes, the first of them starting st tokens
     before the chunk: (C / st, Hkv, Dh))."""
-    from ..models import mixers as mx
-
     C, H, Dh = q.shape
     Hkv, bs = k_pool.shape[2], k_pool.shape[3]
     G = H // Hkv
@@ -1157,11 +1170,6 @@ def check_eva_pages(cfg: GPTConfig, scfg: ServingConfig) -> None:
     summaries]``: a window is whole pages, a chunk never straddles a page,
     and the summaries a window leaves behind fill whole pages."""
     ev, bs = cfg.eva, scfg.block_size
-    if scfg.page_rule != page_rule_for(cfg):
-        raise ValueError(
-            f"the serving configuration's page rule ({scfg.page_rule}) is "
-            f"not this cache's ({page_rule_for(cfg)}): size it with "
-            "ServingConfig.for_cache(page_rule_for(cfg))")
     if ev.window % bs or bs % ev.chunk or ev.summaries % bs:
         raise ValueError(
             f"eva pages: block_size ({bs}) must divide the window "
@@ -1218,8 +1226,6 @@ def write_eva_decode(ev, k_pool, v_pool, at, k_rows, v_rows, mu, phi):
     token lies in into the summary row it completes (the null page's where
     it completes none). mu, phi: (n, H, Dh). Whole pages are read, changed
     and written back (``write_rows``)."""
-    from ..models.mixers import eva_summaries
-
     c = ev.chunk
     N = len(at["row"])
     pick = (jnp.arange(k_pool.shape[3] // c)[None, :]
@@ -1236,9 +1242,8 @@ def write_eva_decode(ev, k_pool, v_pool, at, k_rows, v_rows, mu, phi):
 
     k_pool, kc = lay(k_pool, k_rows)
     v_pool, vc = lay(v_pool, v_rows)
-    sk, sv = eva_summaries(kc, vc, mu[:, None, None], phi[:, None, None])
-    return (write_rows(k_pool, at["s_page"], at["s_row"], sk),
-            write_rows(v_pool, at["s_page"], at["s_row"], sv))
+    sk, sv = mx.eva_summaries(kc, vc, mu[:, None, None], phi[:, None, None])
+    return write_kv_rows(k_pool, v_pool, at["s_page"], at["s_row"], sk, sv)
 
 
 def eva_chunk_past(ev, scfg: ServingConfig, C: int) -> int:
@@ -1257,13 +1262,11 @@ def write_eva_chunk(ev, scfg: ServingConfig, k_pool, v_pool, table_row,
     complete it write its summary); ONE scatter of whole pages a pool
     (a scatter of one page alone has the TPU compiler re-lay the whole
     pool out). mu, phi: (n, H, Dh)."""
-    from ..models.mixers import eva_summaries
-
     n, C, H, Dh = kk.shape
     bs, ring, c = scfg.block_size, scfg.table_widths[0], ev.chunk
     ns = C // c
     chunks = lambda t: t.reshape(n, ns, c, H, Dh)
-    sk, sv = eva_summaries(chunks(kk), chunks(vv), mu[:, None, None],
+    sk, sv = mx.eva_summaries(chunks(kk), chunks(vv), mu[:, None, None],
                            phi[:, None, None])               # (n, ns, H, Dh)
     whole = (jnp.arange(ns) < n_valid // c)[None, :, None, None]
     first = offset // c
@@ -1296,11 +1299,6 @@ def check_ring_pages(cfg: GPTConfig, scfg: ServingConfig) -> None:
     """A slot's table is ``[a page for every block_size positions | window
     / bs pages of the ring]``, each section naming pages of its own pool:
     the ring is whole pages."""
-    if scfg.page_rule != page_rule_for(cfg):
-        raise ValueError(
-            f"the serving configuration's page rule ({scfg.page_rule}) is "
-            f"not this cache's ({page_rule_for(cfg)}): size it with "
-            "ServingConfig.for_cache(page_rule_for(cfg))")
     if cfg.gqa.window % scfg.block_size:
         raise ValueError(
             f"ring pages: block_size ({scfg.block_size}) must divide the "
@@ -1527,3 +1525,268 @@ def write_ring_chunk(window: int, k_pool, v_pool, ring_row, offset, n_valid,
     write = lambda pool, t: pool.at[:, ids].set(
         jnp.where(real, pages(t), pool[:, ids]))
     return write(k_pool, kk), write(v_pool, vv)
+
+
+# ------------------------------------------------------------------ #
+# what ONE program sees of the cache, and its write after the layer loop
+# ------------------------------------------------------------------ #
+
+# the kinds whose decode step reads ONE list a slot, the same for all its
+# key heads (``decode_attend_all``)
+SLOT_LIST_KINDS = frozenset({"mamba_attn", "eva", "full_attn"})
+
+
+def chosen_forms(cfg: GPTConfig, scfg: ServingConfig, k_pool, mesh,
+                 C: Optional[int]):
+    """What the choosers pick for a stack's two programs, from shapes,
+    mesh and platform alone: known when an engine is built, for the host's
+    accounting. ``k_pool``: ``PagedKVCache.k``; ``C``: a prompt chunk's
+    positions (None for a stack that has no chunk program). -> (whether
+    the decode step's list-sharing layers copy a page once for all of a
+    slot's key heads (``takes_slot_form``); how a prompt chunk attends
+    over pages of two rules or of two roles (``chunk_attend_for``) and how
+    it runs a kda layer's delta rule (``kda_chunk_for``): "kernel" or
+    "xla", None where it does neither)."""
+    kinds = set(cfg.layer_kinds)
+    if isinstance(k_pool, tuple):
+        k_pool = k_pool[0]      # the pool of every key
+    # the list a slot: its whole table, or its first section beside a ring
+    width = scfg.table_widths[0] if scfg.page_rule.ring \
+        else scfg.blocks_per_slot
+    return (bool(kinds & SLOT_LIST_KINDS) and takes_slot_form(
+                k_pool, cfg.n_head, (scfg.num_slots, width), mesh),
+            chunk_attend_for(k_pool, cfg.n_head, C, mesh).name
+            if kinds & (GROUPED_KINDS | {"eva"}) else None,
+            kda_chunk_for(C, cfg.kda, mesh)[1] if "kda" in kinds else None)
+
+
+def decode_view(cfg: GPTConfig, scfg: ServingConfig, mesh):
+    """-> ``view(params, k_pool, v_pool, kc_pool, state, tables, lengths,
+    positions)`` for a decode step's trace: the cache's layout as ONE
+    decode step sees it, reckoned once before the layer loop for all
+    layers, read by the kinds' cores (``serving/kinds.py``) and by
+    ``write_decode_step``. A field has ONE meaning; a stack sets those its
+    kinds read:
+
+    ``cfg``, ``scfg``, ``params`` (the weights: the experts' stacks, eva's
+    mu and phi); ``positions`` (N, 1), ``tables`` (N, blocks_per_slot),
+    ``lengths`` (N,): where each slot's new token sits.
+    ``k``, ``v``: the pool whose pages follow the length (the only one, or
+    the first of two); ``k_ring``, ``v_ring``: the rings' pool beside it
+    (None without one); ``kc``: the selector's pooled keys.
+    ``pages`` (N, P), ``count`` (N,): the ONE list a slot whose every page
+    a layer reads and the rows of it that count: the table (its first
+    section beside a ring) under the length, or eva's list of two roles
+    under its count; set in one place, by the stack's page rule.
+    ``page``, ``row`` (N,): where the new token's row goes in ``k`` and
+    ``v``, for the ONE kind of a stack whose pages follow the length
+    (``mamba_attn``; ``full_attn`` beside kda rows or, from ``ring_at``,
+    beside a ring: three stacks that exclude each other,
+    ``PagedKVCache.__init__``, each setting them where the equations came
+    out before); a kind whose indexes say more keeps them whole: ``sparse_at``
+    (``decode_write_indices``), ``eva_at`` (``eva_decode_indices``),
+    ``ring_at`` (``ring_decode_indices``; None without a ring).
+    ``live`` (N, 1, 1, 1): the slots whose state rows this step writes
+    (one whose prompt is still being chunked in is idle here: its rows
+    are the chunks' to write); ``real`` (N, 1), or None: the lanes that
+    are tokens (an idle lane is routed to no expert).
+    The forms the choosers picked: ``attend_rows`` (``decode_attend_for``),
+    ``attend_pages`` (``sparse_attend_for``), ``attend_slots``
+    (``slot_attend_for`` over ``pages``), ``attend_ring`` (the same over
+    the ring's other pages), ``update_ssm`` (``ssm_rows_for``),
+    ``update_kda`` (``kda_rows_for``); ``slopes``: lightning's decays."""
+    kinds = set(cfg.layer_kinds)
+    # reckoned when the program is BUILT: constants of it, not equations
+    slopes = mx.lightning_slopes(cfg.n_head)
+
+    def view(params, k_pool, v_pool, kc_pool, state, tables, lengths,
+             positions):
+        N, bs = lengths.shape[0], scfg.block_size
+        f = SimpleNamespace(
+            cfg=cfg, scfg=scfg, params=params, positions=positions,
+            tables=tables, lengths=lengths, k=k_pool, v=v_pool, kc=kc_pool,
+            k_ring=None, v_ring=None, ring_at=None, real=None, slopes=slopes)
+        if isinstance(k_pool, tuple):
+            (f.k, f.k_ring), (f.v, f.v_ring) = k_pool, v_pool
+        if "attention" in kinds:
+            f.attend_rows = decode_attend_for(k_pool, tables, cfg.n_head, mesh)
+        if "minicpm4" in kinds:
+            f.attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
+            f.sparse_at = decode_write_indices(cfg.sparse, tables, lengths)
+        if "mamba_attn" in kinds:
+            f.page, f.row = tables[jnp.arange(N), lengths // bs], lengths % bs
+            f.update_ssm = ssm_rows_for(state["ssm"], cfg.ssm.n_groups, mesh)
+        if "eva" in kinds:
+            f.eva_at = eva_decode_indices(cfg.eva, scfg, tables, lengths)
+        if kinds & {"lightning", "mamba_attn", "kda"}:
+            f.live = (lengths > 0)[:, None, None, None]
+        if "kda" in kinds:
+            # the full_attn layers' pages follow the length in the ONE pool;
+            # the kda layers keep rows and tails
+            f.row, f.page = lengths % bs, tables[jnp.arange(N), lengths // bs]
+            f.update_kda = kda_rows_for(state["kda"], mesh)
+        elif kinds & GROUPED_KINDS:
+            at = f.ring_at = ring_decode_indices(scfg, tables, lengths)
+            f.page, f.row = at["page"], at["row"]
+            f.attend_ring = slot_attend_for(f.k_ring, cfg.n_head,
+                                            at["others"].shape, mesh)
+        if kinds & (GROUPED_KINDS | {"kda"}):
+            f.real = (lengths > 0)[:, None]
+        if kinds & SLOT_LIST_KINDS:
+            # the ONE list a slot, set here alone, by the stack's page rule
+            f.pages, f.count = (
+                (f.eva_at["pages"], f.eva_at["count"]) if "eva" in kinds else
+                (f.ring_at["full"] if f.ring_at else tables, lengths))
+            f.attend_slots = slot_attend_for(f.k, cfg.n_head, f.pages.shape,
+                                             mesh)
+        return f
+
+    return view
+
+
+def write_decode_step(view, kept):
+    """The layers' new rows (``kept`` by kind, as the kinds' blocks handed
+    them out, stacked over each kind's layers) into the donated pools, in
+    place, after the layer loop; idle slots all target (null block, 0),
+    never read unmasked. ``view``: ``decode_view``'s. -> (k_pool, v_pool,
+    kc_pool)."""
+    k, v, kc, k_ring, v_ring = view.k, view.v, view.kc, view.k_ring, \
+        view.v_ring
+    if "attention" in kept:
+        bs, t = view.scfg.block_size, view.lengths
+        wblk = view.tables[jnp.arange(t.shape[0]), t // bs]
+        woff = t % bs
+        k = k.at[:, wblk, woff].set(kept["attention"][0])   # (L, N, Hkv, Dh)
+        v = v.at[:, wblk, woff].set(kept["attention"][1])
+    if "minicpm4" in kept:
+        k, v, kc = write_decode_rows(view.cfg.sparse, k, v, kc,
+                                     view.sparse_at, *kept["minicpm4"])
+    if "mamba_attn" in kept:                        # (L, N, Hkv, Dh) each
+        k, v = write_kv_rows(k, v, view.page, view.row, *kept["mamba_attn"])
+    if "eva" in kept:
+        k, v = write_eva_decode(view.cfg.eva, k, v, view.eva_at,
+                                *kept["eva"], view.params["eva"]["mu"],
+                                view.params["eva"]["phi"])
+    if "full_attn" in kept:
+        k, v = write_kv_rows(k, v, view.page, view.row, *kept["full_attn"])
+    if "window_attn" in kept:
+        k_ring, v_ring = write_kv_rows(
+            k_ring, v_ring, view.ring_at["ring_page"], view.ring_at["row"],
+            *kept["window_attn"])
+    if view.ring_at is not None:
+        k, v = (k, k_ring), (v, v_ring)
+    return k, v, kc
+
+
+def chunk_view(cfg: GPTConfig, scfg: ServingConfig, mesh):
+    """-> ``view(params, k_pool, v_pool, kc_pool, state, table_row, slot,
+    offset, n_valid, positions)`` for the trace of a prompt chunk (of ``C
+    = len(positions)`` positions): the cache's layout as that chunk sees
+    it, read by the kinds' cores and by ``write_prefill_chunk``. As
+    ``decode_view``'s, a field has ONE meaning and a stack sets those its
+    kinds read:
+
+    ``cfg``, ``scfg``, ``mesh``, ``params``; ``positions`` (C,): offset +
+    0 .. C - 1; ``table_row``: the slot's table, null pages past its end
+    (the last chunk may run past it); ``slot``, ``offset``, ``n_valid``.
+    ``carried``: the slot's state rows as the chunk finds them, every
+    layer's (zeros at offset 0: a row is cleared by whoever enters it).
+    ``k``, ``v``, ``k_ring``, ``v_ring``, ``kc``: the pools, as
+    ``decode_view``'s.
+    ``full_row``: the slot's pages of every key (the table's first
+    section beside a ring, else all of it); ``ring_row``: its ring's (None
+    without one), and ``ring_pages``: the ring's as ``attend.ring`` reads
+    them.
+    ``past``, ``n_seen``, ``n_past``: eva's list of two roles before the
+    chunk: the pages, the rows that count, the entries (``eva_page_list``,
+    ``eva_chunk_past``).
+    ``real`` (1, C), or None: the positions that are tokens (a chunk's
+    padding is routed to no expert).
+    The forms the choosers picked: ``attend_pages`` (``sparse_attend_for``),
+    ``attend`` (``chunk_attend_for``), ``kda_rule`` (``kda_chunk_for``);
+    ``slopes``: lightning's decays."""
+    kinds = set(cfg.layer_kinds)
+    # reckoned when the program is BUILT: constants of it, not equations
+    slopes = mx.lightning_slopes(cfg.n_head)
+
+    def view(params, k_pool, v_pool, kc_pool, state, table_row, slot, offset,
+             n_valid, positions):
+        C, bs = positions.shape[0], scfg.block_size
+        f = SimpleNamespace(
+            cfg=cfg, scfg=scfg, mesh=mesh, params=params, positions=positions,
+            slot=slot, offset=offset, n_valid=n_valid, k=k_pool, v=v_pool,
+            kc=kc_pool, k_ring=None, v_ring=None, ring_row=None, real=None,
+            slopes=slopes, table_row=jnp.pad(table_row, (0, C // bs)))
+        f.full_row = f.table_row
+        if isinstance(k_pool, tuple):
+            (f.k, f.k_ring), (f.v, f.v_ring) = k_pool, v_pool
+        if "minicpm4" in kinds:
+            f.attend_pages = sparse_attend_for(k_pool, cfg.n_head, mesh)
+        if kinds & (GROUPED_KINDS | {"eva"}):
+            f.attend = chunk_attend_for(f.k, cfg.n_head, C, mesh)
+        if "eva" in kinds:
+            f.n_past = eva_chunk_past(cfg.eva, scfg, C)
+            f.past, f.n_seen = eva_page_list(cfg.eva, scfg, f.table_row,
+                                             offset, f.n_past)
+        f.carried = jax.tree.map(
+            lambda rows: jnp.where(
+                offset == 0, 0.0,
+                jax.lax.dynamic_index_in_dim(rows, slot, 1, keepdims=False)),
+            state)
+        if "kda" in kinds:
+            f.kda_rule, _ = kda_chunk_for(C, cfg.kda, mesh)
+        elif kinds & GROUPED_KINDS:
+            n_full, n_ring = scfg.table_widths
+            full_row, f.ring_row = f.table_row[:n_full + C // bs], \
+                f.table_row[n_full:n_full + n_ring]
+            f.full_row = full_row.at[n_full:].set(NULL_BLOCK)
+            # the ring's pages as that form reads them, once for all layers
+            f.ring_pages = f.attend.ring_pages(cfg.gqa.window, f.ring_row,
+                                               offset)
+        if kinds & (GROUPED_KINDS | {"kda"}):
+            f.real = (jnp.arange(C) < n_valid)[None, :]
+        return f
+
+    return view
+
+
+def write_prefill_chunk(view, state, kept):
+    """A prompt chunk's keys, values and pooled keys into the slot's pages
+    and its layers' new state rows into the slot's rows of ``state``
+    (``kept`` by kind, stacked over each kind's layers), in place, after
+    the layer loop. ``view``: ``chunk_view``'s. -> (k_pool, v_pool,
+    kc_pool, state)."""
+    k, v, kc, k_ring, v_ring = view.k, view.v, view.kc, view.k_ring, \
+        view.v_ring
+
+    def into_slot(state, new):
+        return jax.tree.map(
+            lambda rows, n: jax.lax.dynamic_update_slice(
+                rows, n[:, None].astype(rows.dtype),
+                (0, view.slot) + (0,) * (rows.ndim - 2)), state, new)
+
+    if "minicpm4" in kept:
+        k, v, kc = write_chunk(view.cfg.sparse, k, v, kc, view.table_row,
+                               view.offset, *kept["minicpm4"])
+    if "mamba_attn" in kept:
+        (kk, vv), new = kept["mamba_attn"]
+        k, v = write_chunk_pages(k, v, view.table_row, view.offset, kk, vv)
+        state = into_slot(state, new)
+    if "eva" in kept:
+        k, v = write_eva_chunk(
+            view.cfg.eva, view.scfg, k, v, view.table_row, view.offset,
+            view.n_valid, *kept["eva"], view.params["eva"]["mu"],
+            view.params["eva"]["phi"])
+    if "full_attn" in kept:
+        k, v = write_chunk_pages(k, v, view.full_row, view.offset,
+                                 *kept["full_attn"])
+    if "window_attn" in kept:
+        k_ring, v_ring = write_ring_chunk(
+            view.cfg.gqa.window, k_ring, v_ring, view.ring_row, view.offset,
+            view.n_valid, *kept["window_attn"])
+    for kind in ("lightning", "kda"):
+        if kind in kept:
+            state = into_slot(state, kept[kind])
+    if view.ring_row is not None:
+        k, v = (k, k_ring), (v, v_ring)
+    return k, v, kc, state

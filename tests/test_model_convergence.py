@@ -123,7 +123,7 @@ def _chain_batch(rng, rows, seq):
     return np.concatenate(cols, axis=1).astype(np.int32)
 
 
-def _long_losses(extra, seed=0, grad_drift=0.0):
+def _long_losses(extra, seed=0, grad_drift=0.0, steps=LONG_STEPS):
     cfg = GPTConfig(vocab_size=256, n_layer=2, n_head=2, d_model=64,
                     max_seq=SEQ, remat=False, dtype=jnp.float32,
                     attn_impl="xla", rotary=True)
@@ -157,7 +157,7 @@ def _long_losses(extra, seed=0, grad_drift=0.0):
     rows = MICRO * engine.data_parallel_size
     rng = np.random.default_rng(7)  # same stream for every config
     losses = []
-    for _ in range(LONG_STEPS):
+    for _ in range(steps):
         losses.append(float(engine.train_batch(
             jnp.asarray(_chain_batch(rng, rows, SEQ)))))
     return losses
@@ -181,12 +181,28 @@ def test_long_horizon_zero_matches_baseline(stage, long_baseline):
         stage, tail, base_tail)
 
 
+OFFLOAD = {"zero_optimization": {"stage": 2,
+                                 "offload_optimizer": {"device": "cpu"}}}
+
+
+def test_offload_follows_long_baseline_step_by_step(long_baseline):
+    """Sharded per-rank cpu-offloaded optimizer states under ACTIVE dp=8
+    sharding: the first 40 steps of the baseline's 300, each step's loss
+    at ``test_cpu_offload_matches_baseline``'s tolerance (stricter than a
+    2% gate on a tail's mean, and inside tier-1's time: the host's Adam
+    takes 1.8 s a step)."""
+    losses = _long_losses(OFFLOAD, steps=40)
+    np.testing.assert_allclose(losses, long_baseline[:40], rtol=5e-3,
+                               atol=5e-3)
+
+
+@pytest.mark.slow
 def test_long_horizon_offload_matches_baseline(long_baseline):
-    """Sharded per-rank cpu-offloaded optimizer states, 300-step 2% gate."""
-    losses = _long_losses({
-        "zero_optimization": {"stage": 2,
-                              "offload_optimizer": {"device": "cpu"}},
-    })
+    """Sharded per-rank cpu-offloaded optimizer states, 300-step 2% gate
+    (300 host-Adam steps at 1.8 s: nine minutes, so outside tier-1, where
+    ``test_offload_follows_long_baseline_step_by_step`` guards the same
+    property at a shorter horizon)."""
+    losses = _long_losses(OFFLOAD)
     base_tail = np.mean(long_baseline[-LONG_TAIL:])
     tail = np.mean(losses[-LONG_TAIL:])
     assert abs(tail - base_tail) / max(base_tail, 0.25) < 0.02, (

@@ -454,10 +454,10 @@ def test_the_span_and_the_count_say_when_the_chunks_kernel_engaged(
     forms here, interpreted: every chunk is counted, its span says
     ``kernel``, and the served bytes are still the reference's best."""
     from deeperspeed_tpu.ops.pallas import chunk_past_attn as kernel
-    from deeperspeed_tpu.serving import engine as engine_mod
     from deeperspeed_tpu.serving import kv_cache as kvc
 
     compiled, asked, lists = kernel.chunk_past_attn, [], []
+    chooser = kvc.chunk_attend_for
 
     def interpreted(*a, **kw):
         lists.append(a[6].shape)
@@ -467,10 +467,10 @@ def test_the_span_and_the_count_say_when_the_chunks_kernel_engaged(
 
     def as_on_one_tpu(pool, *args):
         asked.append(args)
-        return kvc.chunk_attend_for(pool, *args)._replace(
+        return chooser(pool, *args)._replace(
             name="kernel", listed=kvc.chunk_attend_all_kernel)
 
-    monkeypatch.setattr(engine_mod, "chunk_attend_for", as_on_one_tpu)
+    monkeypatch.setattr(kvc, "chunk_attend_for", as_on_one_tpu)
     eng, chunks, worst = chunk_spans(params, reference)
     assert eng._chunk_attn == "kernel"
     assert [e["args"]["attn"] for e in chunks] == ["kernel"] * 8

@@ -521,16 +521,15 @@ def test_the_span_and_the_count_say_when_the_chunks_kernel_engaged(
     kernel tiles, so the chooser is made to say so here; a stack of one
     cache rule says nothing."""
     from deeperspeed_tpu.monitor.tracer import Tracer, set_tracer
-    from deeperspeed_tpu.serving import engine as engine_mod
     from deeperspeed_tpu.serving import kv_cache as kvc
 
-    said = []
+    said, chooser = [], kvc.chunk_attend_for
 
     def as_on_one_tpu(*args):
         said.append(args[1:])
-        return kvc.chunk_attend_for(*args)._replace(name="kernel")
+        return chooser(*args)._replace(name="kernel")
 
-    monkeypatch.setattr(engine_mod, "chunk_attend_for", as_on_one_tpu)
+    monkeypatch.setattr(kvc, "chunk_attend_for", as_on_one_tpu)
     tracer = Tracer()
     set_tracer(tracer)
     try:
