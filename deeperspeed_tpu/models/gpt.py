@@ -205,13 +205,18 @@ class GroupedAttnConfig:
     each head's entries (one learned vector each a layer) before that.
     ``rotary`` False: q and k are not turned at all (a stack whose other
     layers carry the position); ``out_gate``: the heads' output times
-    ``sigmoid(W_g m)`` entry by entry before the projection out."""
+    ``sigmoid(W_g m)`` entry by entry before the projection out;
+    ``sandwich``: each sublayer's OUTPUT passes an RMSNorm of its own
+    before it joins the stream (``x + N1'(Attn(N1(x)))``, ``x +
+    N2'(FFN(N2(x)))``: four norms a layer, weights ``ln1_post``,
+    ``ln2_post``)."""
     window: int = 1024
     qk_norm: bool = True
     full_rope: RopeScaling = RopeScaling()
     window_rope: RopeScaling = RopeScaling()
     rotary: bool = True
     out_gate: bool = False
+    sandwich: bool = False
 
     def __post_init__(self):
         if self.window < 1:
@@ -386,6 +391,15 @@ class GPTConfig:
     # the head's product accumulated and kept in float32 over a stream in
     # ``dtype`` (``fp32_stream`` implies it)
     fp32_logits: bool = False
+    # a looped stack (served only): the whole stack of layers is applied
+    # ``loop_steps`` times to the stream with the SAME weights, the final
+    # norm after EVERY pass (its output is the next pass's input, the last
+    # pass's the head's), and every (pass, layer) pair keeps a cache of its
+    # own: cache layer ``pass * count(kind) + layer`` (``cache_layers``). A
+    # linear exit gate (``exit_gate``: d_model -> 1) reads each pass's
+    # normed output; it is read out, never acted on (every token runs
+    # every pass)
+    loop_steps: int = 1
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -401,6 +415,11 @@ class GPTConfig:
 
     def count(self, kind: str) -> int:
         return self.layer_kinds.count(kind)
+
+    def cache_layers(self, kind: str) -> int:
+        """How deep a cache of the kind's layers is: one layer a (pass,
+        layer) pair."""
+        return self.loop_steps * self.count(kind)
 
     @property
     def moe(self):
@@ -452,6 +471,14 @@ class GPTConfig:
                     "full_attn and window_attn layers need cfg.gqa")
             if "kda" in self.mixer_types and self.kda is None:
                 raise ValueError("kda layers need cfg.kda")
+        if self.loop_steps < 1 or (self.loop_steps > 1 and set(
+                self.layer_kinds) != {"full_attn"}):
+            raise ValueError(
+                f"loop_steps ({self.loop_steps}) must be >= 1, and a looped "
+                f"stack (loop_steps > 1) one of full_attn layers alone (got "
+                f"{sorted(set(self.layer_kinds))}): only a cache of pages "
+                f"that follow the length is laid out a pass deep, and the "
+                f"looped stack is served, not trained")
         if self.moe_rule not in ("softmax", "sigmoid_bias"):
             raise ValueError(
                 f"moe_rule must be 'softmax' or 'sigmoid_bias', got "

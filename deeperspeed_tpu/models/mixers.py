@@ -49,7 +49,10 @@ length (``eva``), a ring of pages that holds the last ``window`` keys
 
 A model whose ``GPTConfig.mixer_types`` names them keeps its weights
 stacked BY KIND (``STACK_KEY``) and is served only; the layer loop of every
-program goes run by run (``layer_runs``). ``mixed_block``,
+program goes run by run (``layer_runs``), once or, in a LOOPED stack
+(``GPTConfig.loop_steps``: the same weights ``loop_steps`` times a token,
+the final norm after every pass, a cache layer for every (pass, layer)
+pair, an exit gate read out), pass by pass (``scan_passes``). ``mixed_block``,
 ``mamba_attn_block``, ``eva_block``, ``grouped_attn_block`` and
 ``kda_block`` are the
 layers the whole forward, the chunked prefill and the decode step share: a
@@ -178,6 +181,58 @@ def scan_runs(cfg: GPTConfig, params, carry, body):
                    for m, o in outs.items()}
 
 
+def scan_passes(cfg: GPTConfig, params, x, rows, body, served):
+    """The layer loop of a serving program over a stack that may be looped:
+    ``cfg.loop_steps`` passes of ``scan_runs`` over ONE set of weights, the
+    final norm after every pass (its output is the next pass's input and,
+    after the last, the head's: ``head_logits(..., normed=True)``).
+    ``body(kind, (x, rows), layer_params, layer, at) -> ((x, rows), out)``:
+    ``layer`` the layer's place among its kind's WEIGHTS, ``at`` its cache
+    layer, ``pass * count(kind) + layer`` (the same number in a stack that
+    is not looped). ``served(x)``: the stream at the positions whose token
+    is served, for the exit gate. -> (x, rows, the passes' ``out`` by kind
+    stacked cache layer by cache layer, the gate ``lam`` (passes, ...)
+    float32 or None). One ``lax.scan`` over the passes: a looped program
+    is as long to trace and lower as one pass of it."""
+    if cfg.loop_steps == 1:
+        (x, rows), kept = scan_runs(
+            cfg, params, (x, rows),
+            lambda kind, carry, p, i: body(kind, carry, p, i, i))
+        return x, rows, kept, None
+
+    def one_pass(carry, t):
+        carry, kept = scan_runs(
+            cfg, params, carry, lambda kind, carry, p, i: body(
+                kind, carry, p, i, t * cfg.count(kind) + i))
+        x = final_norm(cfg, params, carry[0])
+        return (x, carry[1]), (kept, exit_gate(params, served(x)))
+
+    with jax.named_scope("ds.loop"):
+        (x, rows), (kept, lam) = jax.lax.scan(
+            one_pass, (x, rows), jnp.arange(cfg.loop_steps, dtype=jnp.int32))
+    # (passes, layers, ...) -> (cache layers, ...)
+    return x, rows, jax.tree.map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), kept), lam
+
+
+def exit_gate(params, h):
+    """A looped stack's exit gate on a pass's normed output h (..., D):
+    ``sigmoid(h . w + b)``, float32 -> (...)."""
+    g = params["exit_gate"]
+    return jax.nn.sigmoid(
+        jnp.sum(h.astype(jnp.float32) * g["w"].astype(jnp.float32), -1)
+        + g["b"].astype(jnp.float32))
+
+
+def exit_distribution(lam):
+    """The passes' gates ``lam`` (T, ...) -> the distribution over the pass
+    a token would leave after, (T, ...): ``p_t = lam_t prod_{j<t} (1 -
+    lam_j)`` before the last pass, which takes what is left."""
+    stay = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(lam[:1]), stay[:-1]])
+    return jnp.concatenate([(lam * before)[:-1], before[-1:]])
+
+
 # ------------------------------------------------------------------ #
 # weights
 # ------------------------------------------------------------------ #
@@ -271,6 +326,9 @@ def init_params(rng, cfg: GPTConfig):
                 p["k_norm"] = jnp.ones((n, Dh))
             if cfg.gqa.out_gate:
                 p["wg"] = w((n, D, H * Dh), std)
+            if cfg.gqa.sandwich:
+                p["ln1_post"] = jnp.ones((n, D))
+                p["ln2_post"] = jnp.ones((n, D))
             params[name] = p
     if cfg.count("kda"):
         n, kc = cfg.count("kda"), cfg.kda
@@ -288,6 +346,8 @@ def init_params(rng, cfg: GPTConfig):
             "wg_down": w((n, D, r), std), "wg_up": w((n, r, Hk * dv), std),
             "o_norm": jnp.ones((n, dv)),
             "wo": w((n, Hk * dv, D), out_std), "mlp": mlp(n)}
+    if cfg.loop_steps > 1:
+        params["exit_gate"] = {"w": w((D,), std), "b": jnp.zeros(())}
     return params
 
 
@@ -430,7 +490,9 @@ def eva_block(cfg: GPTConfig, x, p, positions, attend):
 def grouped_attn_block(cfg: GPTConfig, kind: str, x, p, positions, attend,
                        live=None, stacked=None):
     """A ``full_attn`` or ``window_attn`` layer: x + Attn(RMSNorm(x)),
-    then x + FFN(RMSNorm(x)), no bias; ``n_head`` query heads over
+    then x + FFN(RMSNorm(x)) (each sublayer's output through an RMSNorm of
+    its own first where ``cfg.gqa.sandwich``), no bias; ``n_head`` query
+    heads over
     ``kv_heads`` key heads of ``head_dim`` (whatever ``d_model`` is), q
     and k normed a head where ``cfg.gqa.qk_norm``, then turned by the
     kind's rotary constants; the feed-forward is the configuration's
@@ -461,7 +523,10 @@ def grouped_attn_block(cfg: GPTConfig, kind: str, x, p, positions, attend,
         ctx = ctx.astype(cdt).reshape(B, S, H * Dh)
         if cfg.gqa.out_gate:
             ctx = ctx * jax.nn.sigmoid(u @ p["wg"].astype(cdt))
-        x = x + ctx @ p["wo"].astype(cdt)
+        y = ctx @ p["wo"].astype(cdt)
+        if cfg.gqa.sandwich:
+            y = rms_norm(y, p["ln1_post"], eps)
+        x = x + y
     x, counts = routed_ffn(cfg, x, p, live, stacked)
     return x, (kept, counts)
 
@@ -478,6 +543,8 @@ def routed_ffn(cfg: GPTConfig, x, p, live, stacked):
         y, counts = feed_forward(cfg, rms_norm(x, p["ln2"], cfg.layernorm_eps),
                                  mlp, live, layer=layer,
                                  shared=p["mlp"].get("shared"))
+        if "ln2_post" in p:     # a sandwich-normed layer
+            y = rms_norm(y, p["ln2_post"], cfg.layernorm_eps)
         x = x + y
     return x, counts
 
@@ -537,20 +604,27 @@ def embed_tokens(cfg: GPTConfig, params, tokens, positions=None):
     return x.astype(jnp.float32) if cfg.fp32_stream else x
 
 
-def head_logits(cfg: GPTConfig, params, x):
-    """The final norm and the head, for either parameter tree: a stack of
-    attention layers ends in a LayerNorm (``final_ln``), a mixed one in an
-    RMSNorm (``final_norm``). ``cfg.n_pred * vocab_size`` columns, block p
-    scoring the token p + 1 positions on (``served_logits`` takes block
-    0); float32 where the model keeps its stream so."""
+def final_norm(cfg: GPTConfig, params, x):
+    """The norm a stack ends in, for either parameter tree: a stack of
+    attention layers' LayerNorm (``final_ln``), a mixed one's RMSNorm
+    (``final_norm``)."""
     if "final_ln" in params:
-        x = layer_norm(x, params["final_ln"]["scale"],
-                       params["final_ln"]["bias"], cfg.layernorm_eps)
-    else:
-        scale = params["final_norm"]["scale"]
-        if cfg.norm_offset:
-            scale = scale + cfg.norm_offset
-        x = rms_norm(x, scale, cfg.layernorm_eps)
+        return layer_norm(x, params["final_ln"]["scale"],
+                          params["final_ln"]["bias"], cfg.layernorm_eps)
+    scale = params["final_norm"]["scale"]
+    if cfg.norm_offset:
+        scale = scale + cfg.norm_offset
+    return rms_norm(x, scale, cfg.layernorm_eps)
+
+
+def head_logits(cfg: GPTConfig, params, x, normed: bool = False):
+    """The final norm (``final_norm``; not where ``normed``: a looped
+    stack's last pass ended in it) and the head. ``cfg.n_pred *
+    vocab_size`` columns, block p scoring the token p + 1 positions on
+    (``served_logits`` takes block 0); float32 where the model keeps its
+    stream so."""
+    if not normed:
+        x = final_norm(cfg, params, x)
     if cfg.fp32_stream or cfg.fp32_logits:
         def dot(a, b):
             return jnp.dot(a.astype(cfg.dtype), b,
@@ -1153,10 +1227,11 @@ def dense_windowed_attention(q, k, v, window: int = 0):
                       preferred_element_type=jnp.float32).reshape(S, H, Dh)
 
 
-def forward(cfg: GPTConfig, params, tokens):
+def forward(cfg: GPTConfig, params, tokens, gates: bool = False):
     """tokens (1, S) -> logits (1, S, V): the mixed stack with no cache,
     each mixer by its definition (S a multiple of the sparse block where
-    the stack has sparse layers)."""
+    the stack has sparse layers). ``gates`` (a looped stack): -> (logits,
+    the passes' exit gates (passes, 1, S))."""
     S = tokens.shape[1]
     slopes = lightning_slopes(cfg.n_head)
     x = embed_tokens(cfg, params, tokens)
@@ -1214,5 +1289,20 @@ def forward(cfg: GPTConfig, params, tokens):
             return o[None], None
         return mixed_block(cfg, kind, x, p, positions, core)[0], ()
 
-    x, _ = scan_runs(cfg, params, x, body)
-    return head_logits(cfg, params, x)
+    if cfg.loop_steps == 1:
+        x, _ = scan_runs(cfg, params, x, body)
+        return head_logits(cfg, params, x)
+    out = forward_looped(cfg, params, x, body)
+    return out if gates else out[0]
+
+
+def forward_looped(cfg: GPTConfig, params, x, body):
+    """``forward``'s tail for a looped stack: the passes one after another
+    over the same weights, the final norm after each. -> (logits (1, S, V)
+    of the last pass, the passes' exit gates (passes, 1, S) float32)."""
+    lam = []
+    for _ in range(cfg.loop_steps):
+        x, _ = scan_runs(cfg, params, x, body)
+        x = final_norm(cfg, params, x)
+        lam.append(exit_gate(params, x))
+    return head_logits(cfg, params, x, normed=True), jnp.stack(lam)

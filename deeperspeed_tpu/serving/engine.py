@@ -103,6 +103,7 @@ from .kv_cache import (  # noqa: F401
     eva_page_list,
     page_rule_for,
     write_decode_step,
+    position_bytes,
     write_prefill_chunk,
 )
 from .metrics import ServingMetrics
@@ -227,7 +228,12 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     (summed over the layers), the assignments (summed), the largest
     expert's assignments (the largest of any layer) and, where counted,
     the assignments that left, of this step, so that the one read-back
-    the loop makes brings them too.
+    the loop makes brings them too. A LOOPED stack (``cfg.loop_steps`` >
+    1) runs its layer loop that many times over the same weights inside
+    ONE scan (``mixers.scan_passes``: the final norm after each pass, a
+    cache layer of its own for every (pass, layer) pair, one write after
+    the last pass) and hands ``loop_steps`` entries more behind the
+    tokens: where its tokens would have left (``exits_behind``).
     temps[i] <= 0 selects greedy argmax for slot i; > 0 samples at
     that temperature under the config's static top_k, keyed by
     ``request_sample_key(seeds[i], counts[i])`` so the sampled stream is
@@ -248,7 +254,7 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         tables, lengths, tokens, temps, seeds, counts = unpack_slots(
             slots, scfg.blocks_per_slot)
         N = tokens.shape[0]
-        if counts_experts(cfg):
+        if behind_tokens(cfg):
             prev = prev[:N]     # behind the slots': the last step's counts
         tokens = jnp.where(tokens == TAKE_PREV, prev, tokens)
         positions = lengths[:, None]                        # (N, 1)
@@ -258,20 +264,21 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         view = view_of(params, k_pool, v_pool, kc_pool, state, tables,
                        lengths, positions)
 
-        def layer_body(kind, carry, layer_params, layer):
+        def layer_body(kind, carry, layer_params, layer, at):
             x, rows = carry
             x, rows, kept = KINDS[kind].block(view, KINDS[kind].decode, x,
-                                              layer_params, layer, rows)
+                                              layer_params, layer, rows, at)
             return (x, rows), kept
 
-        (x, state), kept = mixers.scan_runs(cfg, params, (x, state),
-                                            layer_body)
+        x, state, kept, lam = mixers.scan_passes(
+            cfg, params, x, state, layer_body, lambda x: x[:, 0])
         kept, experts = split_expert_counts(kept)
         with jax.named_scope("ds.decode/kv_write"):
             k_pool, v_pool, kc_pool = write_decode_step(view, kept)
         with jax.named_scope("ds.decode/sample"):
             logits = mixers.served_logits(
-                cfg, mixers.head_logits(cfg, params, x)[:, 0])  # (N, V)
+                cfg, mixers.head_logits(cfg, params, x,
+                                        looped(cfg))[:, 0])     # (N, V)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             l32 = logits.astype(jnp.float32) / jnp.maximum(
                 temps, 1e-6)[:, None]
@@ -285,9 +292,33 @@ def make_decode_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
             nxt = jnp.where(temps > 0.0, sampled, greedy)
         if counts_experts(cfg):
             nxt = jnp.concatenate([nxt, sum_expert_counts(experts)])
+        if looped(cfg):
+            nxt = jnp.concatenate([nxt, exits_behind(lam, lengths > 0)])
         return nxt, k_pool, v_pool, kc_pool, state
 
     return ds_decode_step
+
+
+def looped(cfg: GPTConfig) -> bool:
+    return cfg.loop_steps > 1
+
+
+def behind_tokens(cfg: GPTConfig) -> int:
+    """Entries a decode step's ``next_tokens`` carries behind the slots'
+    tokens: the routed experts' counts, then a looped stack's exit
+    distribution (``exits_behind``)."""
+    return counts_experts(cfg) * mixers.expert_counts_width(cfg) \
+        + looped(cfg) * cfg.loop_steps
+
+
+def exits_behind(lam, live):
+    """A looped decode step's exit gates ``lam`` (passes, N) float32 ->
+    (passes,) int32: the distribution over the pass a token would leave
+    after (``mixers.exit_distribution``), SUMMED over the live lanes, its
+    float32's bits (as a slot's temperature travels in), so that the one
+    read-back a step brings it behind the tokens."""
+    p = jnp.sum(jnp.where(live, mixers.exit_distribution(lam), 0.0), axis=1)
+    return jax.lax.bitcast_convert_type(p, jnp.int32)
 
 
 def prefill_chunk_for(cfg: GPTConfig, scfg: ServingConfig) -> int:
@@ -344,7 +375,9 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
     values and pooled keys after the layer loop, in place. Where the
     stack counts its experts (``counts_experts``) the first output is the
     pair (logits, the chunk's counts (3,) int32, or (4,):
-    ``sum_expert_counts``)."""
+    ``sum_expert_counts``); where it is looped, the pair (logits, the
+    exit distribution at the chunk's last real position (passes,)
+    float32)."""
     C = prefill_chunk_for(cfg, scfg)
     view_of = chunk_view(cfg, scfg, mesh)
 
@@ -357,20 +390,25 @@ def make_chunk_step(cfg: GPTConfig, scfg: ServingConfig, mesh=None):
         view = view_of(params, k_pool, v_pool, kc_pool, state, table_row,
                        slot, offset, n_valid, positions)
 
-        def layer_body(kind, x, layer_params, layer):
-            x, _, kept = KINDS[kind].block(view, KINDS[kind].chunk, x,
-                                           layer_params, layer, None)
-            return x, kept
+        def layer_body(kind, carry, layer_params, layer, at):
+            x, _, kept = KINDS[kind].block(view, KINDS[kind].chunk, carry[0],
+                                           layer_params, layer, None, at)
+            return (x, None), kept
 
-        x, kept = mixers.scan_runs(cfg, params, x, layer_body)
+        def served(x):
+            return jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0)
+
+        x, _, kept, lam = mixers.scan_passes(cfg, params, x, None,
+                                             layer_body, served)
         kept, experts = split_expert_counts(kept)
         with jax.named_scope("ds.prefill/kv_write"):
             k_pool, v_pool, kc_pool, state = write_prefill_chunk(
                 view, state, kept)
-        last = jax.lax.dynamic_index_in_dim(x[0], n_valid - 1, 0)
-        logits = mixers.head_logits(cfg, params, last)[0]
+        logits = mixers.head_logits(cfg, params, served(x), looped(cfg))[0]
         if counts_experts(cfg):
             logits = (logits, sum_expert_counts(experts))
+        if looped(cfg):
+            logits = (logits, mixers.exit_distribution(lam[:, 0]))
         return logits, k_pool, v_pool, kc_pool, state
 
     return ds_prefill_chunk
@@ -706,12 +744,15 @@ class ServingEngine(_ServingBase):
         # step's tokens then come with that many counts behind them
         self._counts_experts = counts_experts(cfg) \
             * mixers.expert_counts_width(cfg)
+        # a stack of full_attn layers alone (a looped stack is one): its
+        # decode step's span says the pages listed and the passes run
+        self._full_alone = set(cfg.layer_kinds) == {"full_attn"}
         # the last decode step's tokens as the device handed them back,
         # the next step's ``prev`` (zeros until a step has run), and the
         # steps launched and not yet read, oldest first: one between
         # step() calls, two between a launch and the collect after it
         self._prev = jnp.asarray(self._place_slot_array(
-            np.zeros(scfg.num_slots + self._counts_experts, np.int32)))
+            np.zeros(scfg.num_slots + behind_tokens(cfg), np.int32)))
         # the counts of the prompt chunks dispatched since the last launch,
         # on the device: read with the decode step queued behind them
         self._chunk_counts: List[Any] = []
@@ -751,6 +792,8 @@ class ServingEngine(_ServingBase):
         self._gap_held = None
         self.metrics.state_bytes = sum(
             a.nbytes for a in jax.tree.leaves(self.kv.state))
+        self.metrics.loop_steps = cfg.loop_steps
+        self.metrics.kv_bytes_per_position = position_bytes(self.kv)
         if self.telemetry is not None:
             # decode must stay one-compile forever; prefill legitimately
             # retraces per length bucket, so it is deliberately unwatched
@@ -934,8 +977,16 @@ class ServingEngine(_ServingBase):
         L = len(ctx)
         if self._chunk_step is not None:
             C = prefill_chunk_for(self.cfg, self.scfg)
+            n = -(-L // C)
+            # a cached prefix: the chunks that lie wholly inside its shared
+            # pages are not run again (the pages hold every cache layer's
+            # keys); the prompt's last chunk always runs, for its logits.
+            # A page matched in part is computed again with its chunk
+            first = min(req.prefix_shared_blocks * self.scfg.block_size // C,
+                        n - 1)
+            self.sched.release_prefix_src(req)
             state = {"req": req, "ctx": ctx, "L": L, "chunk": C,
-                     "n": -(-L // C), "next": 0,
+                     "n": n, "next": first, "first": first, "blocks": blocks,
                      "forward": self._forward_chunk,
                      "table": jnp.asarray(self.sched.slot_table_row(slot),
                                           jnp.int32)}
@@ -995,6 +1046,8 @@ class ServingEngine(_ServingBase):
         req = state["req"]
         with trace_span("serving/prefill/pick", lane="serving"):
             req.generated.append(self._pick_token(logits, req))
+        if "exits" in state:    # the pick waited the chunk out: no new wait
+            self.metrics.record_exits(np.asarray(state["exits"]), 1)
         del self._chunking[slot]
         self._record_emitted(req, prefill=True)
         self._gap_turn()
@@ -1136,6 +1189,9 @@ class ServingEngine(_ServingBase):
             with trace_span("serving/prefill/dispatch", lane="serving"):
                 logits, kv.k, kv.v, kv.kc, kv.state = \
                     self._chunk_step(*_pargs)
+            if looped(self.cfg):
+                # where the prompt's last position would have left
+                logits, state["exits"] = logits
             if self._counts_experts:
                 logits, counts = logits
                 self._chunk_counts.append(counts)
@@ -1154,7 +1210,8 @@ class ServingEngine(_ServingBase):
                 (hi - lo) // self.scfg.page_rule.chunk)
         state["next"] += 1
         if final:
-            self.metrics.record_reuse(0, state["L"])
+            self.metrics.record_reuse(state["first"] * C, state["L"])
+            self._index_prompt(req, state["blocks"])
 
     def _finish_staged(self, req: Request, state: dict) -> None:
         """Scatter the staged suffix into the slot's private blocks.
@@ -1389,6 +1446,12 @@ class ServingEngine(_ServingBase):
             roles = {"full_pages": str(live_pages),
                      "state_rows": str(self.scfg.num_slots
                                        * self.cfg.count("kda"))}
+        elif self._full_alone:
+            # the pages the live slots list: each is read by every cache
+            # layer, a (pass, layer) pair each
+            self.metrics.record_full_pages(len(lanes), live_pages)
+            roles = {"full_pages": str(live_pages),
+                     "passes": str(self.cfg.loop_steps)}
         with trace_span("serving/decode/dispatch", lane="serving",
                         live_pages=live_pages, view_pages=tables.size,
                         selected_pages=selected_pages,
@@ -1426,10 +1489,16 @@ class ServingEngine(_ServingBase):
         with trace_span("serving/decode/wait", lane="serving"):
             nxt = np.asarray(step.nxt)          # device sync
         experts = {}
+        N = self.scfg.num_slots
+        if looped(self.cfg):
+            # last behind the tokens: where the step's tokens would have left
+            self.metrics.record_exits(
+                np.ascontiguousarray(nxt[-self.cfg.loop_steps:]).view(
+                    np.float32), len(step.lanes))
         if self._counts_experts:
             # behind the slots' tokens: what the step's routed experts did
             touched, assigned, most, *away = (
-                int(n) for n in nxt[-self._counts_experts:])
+                int(n) for n in nxt[N:N + self._counts_experts])
             self.metrics.record_experts("decode", touched, assigned, most,
                                         self.cfg.n_layer, *away)
             experts = {"experts": str(touched), "assignments": str(assigned),

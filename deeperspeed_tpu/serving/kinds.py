@@ -7,8 +7,12 @@ nothing of what a layer reads, keeps or writes: they look the layer's kind
 program needs of the kind and nothing else (where its weights are stacked
 is the model's to say, ``mixers.STACK_KEY``):
 
-``block(view, core, x, layer_params, layer, rows) -> (x, rows, kept)``
-    the layer inside a program, ONE shape for every kind. The math is the
+``block(view, core, x, layer_params, layer, rows, at) -> (x, rows, kept)``
+    the layer inside a program, ONE shape for every kind. ``layer`` is the
+    layer's place among its kind's WEIGHTS, ``at`` its cache layer: what
+    the cores index pools and state rows by (``pass * count(kind) +
+    layer``: the same number in a stack that is not looped,
+    ``mixers.scan_passes``). The math is the
     model's own (``gpt.decoder_block``, the five bodies of
     ``models/mixers.py``, each under its own signature, as the references
     call them); the adapter here hands it the core and untangles what it
@@ -19,7 +23,7 @@ is the model's to say, ``mixers.STACK_KEY``):
     feed-forward is routed). A prompt chunk carries no rows (``rows`` is
     None: it read the slot's in ``view.carried``), so there the layer's new
     rows are the write's to take and come back in ``kept``.
-``decode(view, layer, layer_params, rows, ...)``, ``chunk(...)``
+``decode(view, at, layer_params, rows, ...)``, ``chunk(...)``
     the part of the layer that knows the cache, for a decode step and for
     a prompt chunk: with the first four arguments bound, what the model's
     block calls with the new tokens' q, k and v (a ``mamba_attn`` layer
@@ -101,11 +105,11 @@ def _paged_block(cfg: GPTConfig, x, layer_params, positions, attend):
     return x, kv
 
 
-def _keeps_no_rows(body, view, core, x, p, layer, rows):
+def _keeps_no_rows(body, view, core, x, p, layer, rows, at):
     """A kind with no state row, its layer ``body(cfg, x, p, positions,
     core) -> (x, kept)``."""
     x, kept = body(view.cfg, x, p, view.positions,
-                   partial(core, view, layer, p, rows))
+                   partial(core, view, at, p, rows))
     return x, rows, kept
 
 
@@ -113,34 +117,34 @@ def _minicpm4_block(cfg, *rest):
     return mixers.mixed_block(cfg, "minicpm4", *rest)
 
 
-def _lightning(view, core, x, p, layer, rows):
+def _lightning(view, core, x, p, layer, rows, at):
     x, new = mixers.mixed_block(view.cfg, "lightning", x, p, view.positions,
-                                partial(core, view, layer, p, rows))
+                                partial(core, view, at, p, rows))
     if rows is None:
         return x, rows, new
-    return x, jax.lax.dynamic_update_index_in_dim(rows, new, layer, 0), ()
+    return x, jax.lax.dynamic_update_index_in_dim(rows, new, at, 0), ()
 
 
-def _mamba_attn(view, cores, x, p, layer, rows):
+def _mamba_attn(view, cores, x, p, layer, rows, at):
     x, (kept, new) = mixers.mamba_attn_block(
         view.cfg, x, p, view.positions,
-        *(partial(core, view, layer, p, rows) for core in cores))
+        *(partial(core, view, at, p, rows) for core in cores))
     return (x, rows, (kept, new)) if rows is None else (x, new, kept)
 
 
-def _grouped(kind, view, core, x, p, layer, rows):
+def _grouped(kind, view, core, x, p, layer, rows, at):
     # the kind's whole stack of feed-forwards and the layer's place in it:
     # routed experts read their weights where they lie
     x, kept = mixers.grouped_attn_block(
         view.cfg, kind, x, p, view.positions,
-        partial(core, view, layer, p, rows), view.real,
+        partial(core, view, at, p, rows), view.real,
         (view.params[mixers.STACK_KEY[kind]].get("mlp"), layer))
     return x, rows, kept
 
 
-def _kda(view, core, x, p, layer, rows):
+def _kda(view, core, x, p, layer, rows, at):
     x, (new, counts) = mixers.kda_block(
-        view.cfg, x, p, partial(core, view, layer, p, rows), view.real,
+        view.cfg, x, p, partial(core, view, at, p, rows), view.real,
         (view.params[mixers.STACK_KEY["kda"]].get("mlp"), layer))
     return (x, rows, (new, counts)) if rows is None else (x, new, ((), counts))
 

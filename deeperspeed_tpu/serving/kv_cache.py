@@ -409,8 +409,10 @@ class PagedKVCache:
                     f"a page is one selection block: block_size must be "
                     f"{sp.block_size} (got {scfg.block_size}) and "
                     f"max_seq_len cover dense_len ({sp.dense_len})")
+            # a cache layer for every (pass, layer) pair: as deep as the
+            # stack but where the stack is looped
             n_sp, n_li, n_ma, n_ev, n_fu, n_wi, n_kd = (
-                cfg.count(kind) for kind in (
+                cfg.cache_layers(kind) for kind in (
                     "minicpm4", "lightning", "mamba_attn", "eva", "full_attn",
                     "window_attn", "kda"))
             kinds = set(cfg.mixer_types)
@@ -469,7 +471,7 @@ class PagedKVCache:
         if scfg.page_rule.ring:
             # the rings' pool beside it: as deep as the window layers
             nr = scfg.pool_blocks[1]
-            ring = (cfg.count("window_attn"), nr) + shape[2:]
+            ring = (cfg.cache_layers("window_attn"), nr) + shape[2:]
             self.k = (self.k, jnp.zeros(ring, cfg.dtype))
             self.v = (self.v, jnp.zeros(ring, cfg.dtype))
             self.allocators.append(BlockAllocator(nr))
@@ -880,14 +882,27 @@ def lay_rows(cur, row, new):
     return jnp.where(hit, new[..., None, :].astype(cur.dtype), cur)
 
 
+WRITE_DEPTH = 64
+
+
 def write_rows(pool, page, row, new):
     """pool (L, num_blocks, ..., R, Dh) with ``new`` (L, N, ..., Dh) laid
     over row ``row[n]`` of page ``page[n]``: whole pages are read, changed
     and written back, so that nothing indexes inside a page of the pool
     (XLA re-lays the WHOLE pool out for a scatter or a gather that does).
     Rows of several slots on one page (idle slots, the null page) race;
-    what lands there is never read unmasked."""
-    return pool.at[:, page].set(lay_rows(pool[:, page], row, new))
+    what lands there is never read unmasked. A pool deeper than
+    ``WRITE_DEPTH`` layers (a looped stack's: a cache layer a (pass, layer)
+    pair) is written ``WRITE_DEPTH`` layers at a time: the TPU compiler
+    cuts a gather over more than 128 layers into SLICES of the pool, which
+    it copies (3.3 GB beside two pools of 3.4 at Ouro's 192:
+    ``tests/test_tpu_compile.py``)."""
+    for lo in range(0, pool.shape[0], WRITE_DEPTH):
+        part = slice(lo, lo + WRITE_DEPTH) if pool.shape[0] > WRITE_DEPTH \
+            else slice(None)
+        pool = pool.at[part, page].set(
+            lay_rows(pool[part, page], row, new[part]))
+    return pool
 
 
 def write_kv_rows(k_pool, v_pool, page, row, k_rows, v_rows):
@@ -1312,6 +1327,16 @@ def pool_bytes(kv: "PagedKVCache") -> Tuple[int, ...]:
     return tuple(k.nbytes + v.nbytes for k, v in pairs)
 
 
+def position_bytes(kv: "PagedKVCache") -> int:
+    """Bytes one cached position costs, from the pools' own shapes: a row
+    of every page pool (keys, values, the selector's pooled keys), as deep
+    as its cache layers (a looped stack's are ``loop_steps`` times its
+    layers)."""
+    bs = kv.scfg.block_size
+    return sum(a.nbytes // (a.shape[1] * bs)
+               for a in jax.tree.leaves((kv.k, kv.v, kv.kc)))
+
+
 def ring_decode_indices(scfg: ServingConfig, tables, lengths):
     """Where a decode step reads and writes in a stack of full_attn and
     window_attn layers, the same for every layer of a kind. Slot i's new
@@ -1564,9 +1589,11 @@ def decode_view(cfg: GPTConfig, scfg: ServingConfig, mesh):
     """-> ``view(params, k_pool, v_pool, kc_pool, state, tables, lengths,
     positions)`` for a decode step's trace: the cache's layout as ONE
     decode step sees it, reckoned once before the layer loop for all
-    layers, read by the kinds' cores (``serving/kinds.py``) and by
-    ``write_decode_step``. A field has ONE meaning; a stack sets those its
-    kinds read:
+    layers (and, of a looped stack, all passes: a core is handed its CACHE
+    layer, ``pass * count(kind) + layer``, and ``kept`` comes stacked
+    cache layer by cache layer), read by the kinds' cores
+    (``serving/kinds.py``) and by ``write_decode_step``. A field has ONE
+    meaning; a stack sets those its kinds read:
 
     ``cfg``, ``scfg``, ``params`` (the weights: the experts' stacks, eva's
     mu and phi); ``positions`` (N, 1), ``tables`` (N, blocks_per_slot),
@@ -1580,8 +1607,8 @@ def decode_view(cfg: GPTConfig, scfg: ServingConfig, mesh):
     under its count; set in one place, by the stack's page rule.
     ``page``, ``row`` (N,): where the new token's row goes in ``k`` and
     ``v``, for the ONE kind of a stack whose pages follow the length
-    (``mamba_attn``; ``full_attn`` beside kda rows or, from ``ring_at``,
-    beside a ring: three stacks that exclude each other,
+    (``mamba_attn``; ``full_attn`` alone, beside kda rows or, from
+    ``ring_at``, beside a ring: four stacks that exclude each other,
     ``PagedKVCache.__init__``, each setting them where the equations came
     out before); a kind whose indexes say more keeps them whole: ``sparse_at``
     (``decode_write_indices``), ``eva_at`` (``eva_decode_indices``),
@@ -1625,11 +1652,14 @@ def decode_view(cfg: GPTConfig, scfg: ServingConfig, mesh):
             # the kda layers keep rows and tails
             f.row, f.page = lengths % bs, tables[jnp.arange(N), lengths // bs]
             f.update_kda = kda_rows_for(state["kda"], mesh)
-        elif kinds & GROUPED_KINDS:
+        elif scfg.page_rule.ring:
             at = f.ring_at = ring_decode_indices(scfg, tables, lengths)
             f.page, f.row = at["page"], at["row"]
             f.attend_ring = slot_attend_for(f.k_ring, cfg.n_head,
                                             at["others"].shape, mesh)
+        elif "full_attn" in kinds:
+            # full_attn layers alone: their pages follow the length
+            f.row, f.page = lengths % bs, tables[jnp.arange(N), lengths // bs]
         if kinds & (GROUPED_KINDS | {"kda"}):
             f.real = (lengths > 0)[:, None]
         if kinds & SLOT_LIST_KINDS:
@@ -1735,7 +1765,7 @@ def chunk_view(cfg: GPTConfig, scfg: ServingConfig, mesh):
             state)
         if "kda" in kinds:
             f.kda_rule, _ = kda_chunk_for(C, cfg.kda, mesh)
-        elif kinds & GROUPED_KINDS:
+        elif scfg.page_rule.ring:
             n_full, n_ring = scfg.table_widths
             full_row, f.ring_row = f.table_row[:n_full + C // bs], \
                 f.table_row[n_full:n_full + n_ring]

@@ -263,6 +263,16 @@ class ServingMetrics:
         self.state_bytes = 0
         self.state_bytes_moved = 0
         self.state_resets = 0
+        # a looped stack (the engine sets the first two once): the passes
+        # a token takes over the stack, the bytes one cached position
+        # costs in the page pools (from their shapes: a cache layer a
+        # (pass, layer) pair), and the exit gate's read-out: over the
+        # tokens served, the summed distribution over the pass a token
+        # would have left after (nothing acts on it)
+        self.loop_steps = 1
+        self.kv_bytes_per_position = 0
+        self.exit_mass = np.zeros(0)
+        self.exit_tokens = 0
         self.prefills = 0
         self.preemptions = 0
         # prefix reuse / chunked prefill: admissions is every context
@@ -493,6 +503,14 @@ class ServingMetrics:
         self.moe_calls[program] += 1
         self.moe_assignments_away[program] += away
 
+    def record_exits(self, mass, tokens: int) -> None:
+        """The exit distribution of ``tokens`` served tokens of a looped
+        stack, summed over them: ``mass`` (loop_steps,)."""
+        mass = np.asarray(mass, np.float64)
+        self.exit_mass = mass + (self.exit_mass if self.exit_mass.size
+                                 else 0.0)
+        self.exit_tokens += tokens
+
     def record_full_pages(self, rows: int, full_pages: int) -> None:
         """One launched decode step of a stack whose full_attn layers
         alone keep pages: its ``rows`` live slots held ``full_pages``."""
@@ -665,6 +683,15 @@ class ServingMetrics:
             "chunk_listed_page_share": (
                 self.chunk_listed_pages / self.chunk_named_pages
                 if self.chunk_named_pages else 0.0),
+            "loop_steps": int(self.loop_steps),
+            "kv_bytes_per_position": int(self.kv_bytes_per_position),
+            # where a looped stack's tokens would have left: the mean
+            # distribution over its passes and its mean, counted from 1
+            "exit_p": [float(m) / max(self.exit_tokens, 1)
+                       for m in self.exit_mass],
+            "exit_step_expected": float(
+                1.0 + np.dot(np.arange(self.exit_mass.size), self.exit_mass)
+                / max(self.exit_tokens, 1)) if self.exit_mass.size else 0.0,
             "state_bytes": int(self.state_bytes),
             "state_bytes_per_step": (self.state_bytes_moved
                                      / self.decode_steps

@@ -121,7 +121,7 @@ def test_a_block_adapter_returns_x_rows_kept(kind, program):
             x = mixers.embed_tokens(cfg, params,
                                     jnp.zeros((1, C), jnp.int32))
             rows = None
-        out = record.block(view, getattr(record, program), x, p, 0, rows)
+        out = record.block(view, getattr(record, program), x, p, 0, rows, 0)
         assert len(out) == 3
         return x, rows, out
 

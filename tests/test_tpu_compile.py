@@ -674,3 +674,75 @@ def test_solar_programs_move_neither_the_pool_nor_the_rows_nor_a_weight_stack(
     assert weights + caches < ask < weights + caches + 2 ** 30, (
         ask, weights, caches)
     assert ask < 11.2 * 2 ** 30
+
+
+def _ouro():
+    from benchmark import manifest as mf
+    from benchmark.adapters import ouro as adapter
+    from deeperspeed_tpu.serving import ServingConfig
+    from deeperspeed_tpu.serving.kv_cache import page_rule_for
+
+    man = mf.Manifest()
+    cfg = adapter.model_config(man.config("ouro-2.6b"))
+    scfg = ServingConfig.from_dict(
+        man.workload_file("ouro-2.6b.serve-solve")["serving"])
+    return cfg, scfg.for_cache(page_rule_for(cfg))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_ouro_programs_loop_in_place_inside_the_chip(one_chip, as_if_on_tpu,
+                                                     program):
+    """The decode step and the prompt-chunk program of the looped cell at
+    the published size, whole (48 layers run 4 times, 8 slots, 73 pages of
+    64 positions 192 cache layers deep = 6.84 GiB beside 4.97 GiB of
+    weights): the donated pools are outputs in place, nothing copies the
+    pool or a weight stack, the passes are ONE loop (the page-list kernel
+    and the chunk kernel each appear once in the text, not four times),
+    and the compiler's ask stays inside the chip's 15.75 GiB with the
+    margin the other cells keep."""
+    from deeperspeed_tpu.models import mixers
+    from deeperspeed_tpu.serving.engine import (make_chunk_step,
+                                                make_decode_step)
+
+    def sds(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg, scfg = _ouro()
+    params = jax.tree.map(
+        lambda a: sds(a.shape),
+        jax.eval_shape(lambda: mixers.init_params(jax.random.PRNGKey(0), cfg)))
+    N, bps = scfg.num_slots, scfg.blocks_per_slot
+    assert (N, scfg.pool_blocks, bps, scfg.table_widths) == (
+        8, (73,), 20, (20,))
+    assert (cfg.loop_steps, cfg.cache_layers("full_attn")) == (4, 192)
+    i32 = jnp.int32
+    pool = sds((192, 73, 16, 64, 128))
+    if program == "decode":
+        compiled = make_decode_step(cfg, scfg).lower(
+            params, pool, pool, sds(*_idle_slots(N, bps)),
+            sds((N + 4,), i32), None, None).compile()
+    else:
+        compiled = make_chunk_step(cfg, scfg).lower(
+            params, pool, pool, None, None, sds((1, 256), i32),
+            sds((bps,), i32), sds((), i32), sds((), i32), sds((), i32)).compile()
+    text = compiled.as_text()
+    kernel = ("paged_sparse_attn_slots" if program == "decode"
+              else "chunk_past_attn")
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and kernel in ln]
+    assert len(calls) == 1, len(calls)
+    assert count_alias_pairs(text) == 2        # k, v
+    big = ("bf16[192,73,", "bf16[48,2048,", "bf16[48,5632,", "bf16[49152,",
+           "bf16[2048,49152]")
+    moved = [ln.strip()[:140] for ln in text.splitlines()
+             if (" copy(" in ln or "dynamic-slice_bitcast_fusion" in ln)
+             and ln.split(" = ")[1].startswith(big)]
+    assert moved == []
+    weights = sum(2 * math.prod(a.shape) for a in jax.tree.leaves(params))
+    assert weights == 2 * 2_667_974_657
+    caches = 2 * 2 * math.prod(pool.shape)
+    assert caches == 73 * 64 * 1_572_864
+    ask = extract_memory_analysis(compiled)["peak_bytes"]
+    assert weights + caches < ask < weights + caches + 2 ** 30, (
+        ask, weights, caches)
+    assert ask < 12.8 * 2 ** 30
